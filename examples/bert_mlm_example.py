@@ -83,12 +83,6 @@ def demo_fill_mask(
     masked = np.where(sel, cfg.mask_id, clean)
 
     def fill():
-        import os
-
-        import jax
-
-        if os.environ.get("JAX_PLATFORMS") == "cpu":
-            jax.config.update("jax_platforms", "cpu")
         m = BERTEncoder(config=cfg)
         m.params = params
         return np.asarray(m.fill_mask(masked))

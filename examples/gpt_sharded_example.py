@@ -91,12 +91,6 @@ def train_gpt(
     prompt = np.asarray([[1, 12, 3]], np.int32)
 
     def decode():
-        import os
-
-        import jax
-
-        if os.environ.get("JAX_PLATFORMS") == "cpu":
-            jax.config.update("jax_platforms", "cpu")
         from ray_lightning_tpu.models.gpt import gpt_generate
 
         return np.asarray(

@@ -1323,24 +1323,26 @@ def run_serve(config: Dict[str, Any]) -> Dict[str, Any]:
 
     if not fabric.is_initialized():
         fabric.init()
-    # Replicas on a chipless fabric decode on CPU; pin the platform so the
-    # actor does not stall probing for devices it will not get. A mesh
-    # spec on CPU additionally forces that many VIRTUAL host devices in
-    # the replica process (the same trick the strategies' CPU worker
-    # planning uses) — a "4x2" mesh needs 8 devices wherever it runs.
-    env = (
-        {"JAX_PLATFORMS": "cpu"}
-        if fabric.cluster_resources().get("TPU", 0) < 1
-        else {}
-    )
-    if env and mesh_spec is not None:
-        model, data = parse_mesh_spec(mesh_spec)
+    # Each replica process reserves the chips its mesh spans (the fabric
+    # pins them, so replicas sharing a host never open each other's
+    # chips). Only a fabric that says it has no chips decodes on CPU: the
+    # platform is pinned so the actor never probes for devices, and a mesh
+    # spec additionally forces that many VIRTUAL host devices in the
+    # replica process — a "4x2" mesh needs 8 devices wherever it runs.
+    model, data = parse_mesh_spec(mesh_spec)
+    env: Dict[str, str] = {}
+    chips_per_process = 0
+    if fabric.cluster_resources().get("TPU", 0) >= 1:
+        chips_per_process = max(1, model * data // hosts_per_replica)
+    else:
+        env["JAX_PLATFORMS"] = "cpu"
         if model * data > 1:
             env["XLA_FLAGS"] = (
                 f"--xla_force_host_platform_device_count={model * data}"
             )
     client = start_replicas(
         replicas,
+        num_tpus_per_replica=chips_per_process,
         env=env,
         hosts_per_replica=hosts_per_replica,
         rpc_timeout_s=rpc_timeout_s,
@@ -2518,14 +2520,14 @@ def main(argv: Optional[List[str]] = None) -> Any:
 def cli_entry(argv: Optional[List[str]] = None) -> Any:
     """Actual command-line entrypoint (console script / ``python -m``).
 
-    Re-applies ``JAX_PLATFORMS`` over any sitecustomize-forced plugin
-    platform config — on the command line the env var IS the user's
-    intent. Programmatic callers use :func:`main`, which never clobbers
-    an application's own ``jax.config`` pins.
+    Places the persistent compile cache (``utils.compile_cache``) before
+    any worker is spawned, so every process of the run shares it.
+    Programmatic callers use :func:`main`, which leaves the environment
+    alone.
     """
-    from ray_lightning_tpu.utils.platform import apply_jax_platform_env
+    from ray_lightning_tpu.utils.compile_cache import place_compile_cache
 
-    apply_jax_platform_env()
+    place_compile_cache()
     out = main(argv)
     args = sys.argv[1:] if argv is None else argv
     if args and args[0] == "doctor":

@@ -768,6 +768,9 @@ def gpt_forward(
             return flash_attention(
                 q, k, v, causal=True, window=cfg.attn_window,
                 sinks=cfg.attn_sinks,
+                # Inside the pp stage shard_map the other axes stay with
+                # the partitioner; the kernel is not re-wrapped there.
+                mesh=mesh if pp_size == 1 else None,
             )
         return attention_reference(
             q, k, v, causal=True, window=cfg.attn_window,
@@ -1037,6 +1040,7 @@ def gpt_prefill(
     params: Dict[str, Any],
     cfg: GPTConfig,
     prompt: jax.Array,
+    mesh: Optional[jax.sharding.Mesh] = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """One parallel forward over ``prompt`` (B, P) int32 that yields the
     decode cache: returns pre-final-norm hidden states (B, P, D) and the
@@ -1051,7 +1055,9 @@ def gpt_prefill(
     real rows. MoE configs dispatch with capacity set to never drop tokens
     (see :func:`gpt_generate`), so padding cannot displace real tokens.
     ``params`` must already be device arrays (quantized int8 trees are
-    consumed directly).
+    consumed directly). ``mesh`` is the serving mesh when the caller's
+    program is partitioned over one (the flash kernel then runs per head
+    shard; see ``ops.flash_attention``).
     """
     cfg.validate_variants()
     cdt = jnp.dtype(cfg.compute_dtype)
@@ -1060,10 +1066,14 @@ def gpt_prefill(
     Hkv = cfg.kv_head
     rep = H // Hkv
     _, P = prompt.shape
+    import functools
+
     from ray_lightning_tpu.ops import attention_reference, flash_attention
 
     attn_fn = (
-        flash_attention if cfg.attn_impl == "flash" else attention_reference
+        functools.partial(flash_attention, mesh=mesh)
+        if cfg.attn_impl == "flash"
+        else attention_reference
     )
     pf_tables = (
         _rope_tables(jnp.arange(P), cfg.rope_theta, hd)
@@ -2063,6 +2073,7 @@ def model_propose(
     *,
     depth: int,
     window: int,
+    mesh: Optional[jax.sharding.Mesh] = None,
 ) -> jax.Array:
     """Draft-model drafter: a small (optionally int8) GPT proposes
     ``depth`` greedy continuations from a sliding window of history.
@@ -2083,7 +2094,9 @@ def model_propose(
     toks_w = jnp.take_along_axis(hist, jnp.clip(idx, 0, S - 1), axis=1)
     # Left-fill short sequences with the first live token (position 0).
     toks_w = jnp.where(idx >= 0, toks_w, hist[:, :1])
-    h_pf, pf_k, pf_v = gpt_prefill(draft_params, draft_cfg, toks_w)
+    h_pf, pf_k, pf_v = gpt_prefill(
+        draft_params, draft_cfg, toks_w, mesh=mesh
+    )
     cdt = jnp.dtype(draft_cfg.compute_dtype)
     norm_fn = _make_norm(draft_cfg)
     Hkv, hd = draft_cfg.kv_head, draft_cfg.head_dim

@@ -13,11 +13,10 @@ engine's "compile count frozen after construction" into a METRIC —
 ``ServeReplica.stats()`` ships ``compiles_since_init``, which must read
 0 in steady state — instead of something only the test suite can see.
 
-jax 0.4.x listeners receive (event_name, duration) only — no executable
-name — so attribution is per event KIND; per-executable naming waits on
-a newer jax. Listener registration is process-global and irrevocable
-(there is no unregister short of clearing every listener), hence the
-idempotent :func:`install_compile_listener`.
+Listeners receive (event_name, duration) only — no executable name — so
+attribution is per event KIND. Listener registration is process-global
+and irrevocable (there is no unregister short of clearing every
+listener), hence the idempotent :func:`install_compile_listener`.
 """
 from __future__ import annotations
 
@@ -92,12 +91,9 @@ def install_compile_listener(
             counter.inc(1, event=label)
             seconds.inc(float(dur), event=label)
 
-        try:
-            import jax.monitoring
+        import jax.monitoring
 
-            jax.monitoring.register_event_duration_secs_listener(_listener)
-        except Exception:  # noqa: BLE001 - no monitoring, stats stay zero
-            pass
+        jax.monitoring.register_event_duration_secs_listener(_listener)
         _STATS = stats
         return stats
 

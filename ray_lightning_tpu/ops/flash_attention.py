@@ -9,21 +9,38 @@ over query blocks — so the backward never materializes (Sq, Sk) either
 (the naive recompute costs B*H*S^2*4 bytes of HBM: 400 MB at B=8, H=12,
 S=1024).
 
-On non-TPU backends the same kernels run in Pallas interpret mode (tests),
-or fall back to ``attention_reference``.
+On non-TPU backends the same kernels run in Pallas interpret mode (tests).
+Shapes the kernels cannot tile go to ``attention_reference``, with one
+warning per shape naming the rule that rejected it.
+
+On a mesh of more than one device the kernels run under ``shard_map``: a
+Pallas kernel is a custom call that XLA's SPMD partitioner cannot split
+(jax refuses to lower one inside a partitioned program), so the batch and
+head shards are handed to it explicitly.
 """
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from ray_lightning_tpu.ops.attention import attention_reference, band_allowed
+from ray_lightning_tpu.utils.rank_zero import rank_zero_warn
 
 _NEG_INF = float("-inf")
+
+#: (reason, shapes) already warned about: one warning per distinct cause,
+#: not one per traced layer.
+_warned: set = set()
+
+
+def _warn_once(key: Any, msg: str) -> None:
+    if key not in _warned:
+        _warned.add(key)
+        rank_zero_warn(msg)
 
 
 def _fwd_kernel(
@@ -405,6 +422,7 @@ def flash_attention(
     interpret: Optional[bool] = None,
     window: int = 0,
     sinks: int = 0,
+    mesh: Optional[jax.sharding.Mesh] = None,
 ) -> jax.Array:
     """Pallas flash attention on (B, S, H, D) tensors.
 
@@ -415,8 +433,14 @@ def flash_attention(
     compute scales with S*W instead of S^2. ``sinks=N`` keeps the first N
     positions visible to every query (StreamingLLM attention sinks; the
     block-skip optimization is disabled since early blocks stay live).
-    Falls back to ``attention_reference`` for shapes the kernel does not
-    support.
+    Falls back to ``attention_reference`` for shapes the kernel cannot
+    tile, warning once per shape.
+
+    ``mesh``: the mesh of the enclosing partitioned program, when there is
+    one. With more than one device the kernel runs per shard under
+    ``shard_map`` — batch over the data-parallel axes, heads over
+    ``"model"`` (the layout GSPMD gives q/k/v) — instead of meeting the
+    partitioner as an unsplittable custom call.
     """
     if sm_scale is None:
         sm_scale = 1.0 / (q.shape[-1] ** 0.5)
@@ -430,23 +454,58 @@ def flash_attention(
         interpret = jax.default_backend() != "tpu"
     seq_q, seq_k = q.shape[1], k.shape[1]
     bq, bk = min(block_q, seq_q), min(block_k, seq_k)
-    if (
-        seq_q % bq
-        or seq_k % bk
-        or (causal and seq_q != seq_k)
-        # TPU tiling wants the blocks' second-minor dim 8-aligned (the
-        # kernel's own lse row is padded to 8 lanes for the same reason);
-        # a clipped block like bq=65 (ViT's n_patches+1) would otherwise
-        # reach Mosaic unaligned. Interpret mode doesn't tile, but keep
-        # ONE rule so CPU tests exercise the same path selection as TPU.
-        or bq % 8
-        or bk % 8
-    ):
+    # TPU tiling wants the blocks' second-minor dim 8-aligned (the kernel's
+    # own lse row is padded to 8 lanes for the same reason); a clipped
+    # block like bq=65 (ViT's n_patches+1) would otherwise reach Mosaic
+    # unaligned. Interpret mode doesn't tile, but keep ONE rule so CPU
+    # tests exercise the same path selection as TPU.
+    if seq_q % bq or seq_k % bk:
+        rejected = f"sequence lengths not divisible by blocks ({bq}, {bk})"
+    elif causal and seq_q != seq_k:
+        rejected = "causal kernel needs Sq == Sk"
+    elif bq % 8 or bk % 8:
+        rejected = f"blocks ({bq}, {bk}) not 8-aligned"
+    else:
+        rejected = None
+    if rejected:
+        _warn_once(
+            ("reference", q.shape, k.shape, causal),
+            f"flash_attention: q {q.shape} / k {k.shape} causal={causal} "
+            f"runs attention_reference, not the Pallas kernel: {rejected}",
+        )
         return attention_reference(
             q, k, v, causal=causal, sm_scale=sm_scale, window=int(window),
             sinks=int(sinks),
         )
-    return _flash(
-        q, k, v, causal, sm_scale, block_q, block_k, interpret, int(window),
-        int(sinks),
-    )
+
+    def kernel(q, k, v):
+        return _flash(
+            q, k, v, causal, sm_scale, block_q, block_k, interpret,
+            int(window), int(sinks),
+        )
+
+    if mesh is None or mesh.size == 1:
+        return kernel(q, k, v)
+    # Shared (B, S, H, D) spec policy with the ring/zigzag wrappers.
+    from ray_lightning_tpu.ops.zigzag_attention import _seq_specs
+
+    spec, vary = _seq_specs(mesh, None, q.shape[2])
+    dp = 1
+    for ax in vary:
+        if ax != "model":
+            dp *= mesh.shape[ax]
+    if q.shape[0] % dp:
+        # Every device then attends over the whole batch: correct, but
+        # the work is replicated instead of split.
+        _warn_once(
+            ("replicated", q.shape, dp),
+            f"flash_attention: batch {q.shape[0]} does not divide the "
+            f"{dp} data-parallel shards of the mesh; the kernel runs on "
+            "the full batch on every device",
+        )
+        spec = jax.sharding.PartitionSpec(None, *spec[1:])
+    # check_vma=False: pallas_call's outputs carry no varying-axes type.
+    return jax.shard_map(
+        kernel, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+        check_vma=False,
+    )(q, k, v)

@@ -21,8 +21,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-
-from ray_lightning_tpu.utils.compat import shard_map
+from jax import shard_map
 
 _NEG_INF = float("-inf")
 
@@ -122,11 +121,7 @@ def ring_attention(
         # shard_map's vma type system requires the scan carry to be marked
         # device-varying over every axis the inputs are sharded on (the
         # accumulators genuinely differ per rank on each of them).
-        if hasattr(jax.lax, "pcast"):
-            return jax.lax.pcast(x, axes, to="varying")
-        if hasattr(jax.lax, "pvary"):
-            return jax.lax.pvary(x, axes)
-        return x  # pre-vma JAX (0.4.x): no varying types, nothing to mark
+        return jax.lax.pcast(x, axes, to="varying")
 
     init = (
         k,

@@ -32,8 +32,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-from ray_lightning_tpu.utils.compat import shard_map
+from jax import shard_map
 
 _NEG_INF = float("-inf")
 
@@ -182,9 +181,12 @@ def zigzag_ring_attention(
     return out.astype(q.dtype)
 
 
-def _seq_specs(mesh: jax.sharding.Mesh, axis_name: str, n_heads: int):
+def _seq_specs(
+    mesh: jax.sharding.Mesh, axis_name: Optional[str], n_heads: int
+):
     """(PartitionSpec, vary_axes) for (B, S, H, D) activations on this mesh
-    — shared by the ring and zigzag wrappers."""
+    — shared by the ring, zigzag and flash wrappers (``axis_name=None``:
+    the sequence stays whole)."""
     from jax.sharding import PartitionSpec as P
 
     dp_axes = tuple(
@@ -197,7 +199,9 @@ def _seq_specs(mesh: jax.sharding.Mesh, axis_name: str, n_heads: int):
     if "model" != axis_name and model_size > 1 and n_heads % model_size == 0:
         head_axis = "model"
     spec = P(dp_axes or None, axis_name, head_axis, None)
-    vary = (axis_name,) + dp_axes + ((head_axis,) if head_axis else ())
+    vary = tuple(
+        ax for ax in (axis_name, *dp_axes, head_axis) if ax is not None
+    )
     return spec, vary
 
 

@@ -112,13 +112,8 @@ def build_mesh(
             f"XLA_FLAGS=--xla_force_host_platform_device_count={total} set "
             f"before jax initializes."
         )
-    if hasattr(jax.sharding, "AxisType"):
-        axis_types = (jax.sharding.AxisType.Auto,) * len(axis_names)
-        return jax.make_mesh(
-            tuple(axis_shape), axis_names, axis_types=axis_types
-        )
-    # Older JAX (< 0.5): no sharding-in-types; every axis is already Auto.
-    return jax.make_mesh(tuple(axis_shape), axis_names)
+    axis_types = (jax.sharding.AxisType.Auto,) * len(axis_names)
+    return jax.make_mesh(tuple(axis_shape), axis_names, axis_types=axis_types)
 
 
 def setup_distributed(env) -> None:

@@ -28,8 +28,7 @@ from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
-
-from ray_lightning_tpu.utils.compat import shard_map
+from jax import shard_map
 
 
 def init_moe_params(

@@ -29,9 +29,8 @@ from typing import Any, Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from ray_lightning_tpu.utils.compat import shard_map
 
 
 def bubble_fraction(pp: int, num_microbatches: Optional[int] = None) -> float:
@@ -86,11 +85,7 @@ def pipeline_apply(
         def varying(v):
             # The scan carry genuinely differs per pp rank; mark it so for
             # shard_map's varying-mesh-axes type system.
-            if hasattr(jax.lax, "pcast"):
-                return jax.lax.pcast(v, (axis_name,), to="varying")
-            if hasattr(jax.lax, "pvary"):
-                return jax.lax.pvary(v, (axis_name,))
-            return v  # pre-vma JAX (0.4.x): nothing to mark
+            return jax.lax.pcast(v, (axis_name,), to="varying")
 
         def apply_local(h: jax.Array) -> Tuple[jax.Array, jax.Array]:
             def body(carry, lp):

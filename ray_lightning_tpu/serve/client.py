@@ -1665,6 +1665,17 @@ class ServeClient:
                 return p
         return None
 
+    def device_check(
+        self, replica: int, prompt: Sequence[int], max_new_tokens: int = 32
+    ) -> Dict[str, Any]:
+        """``ServeReplica.device_check`` on ONE replica: solo greedy
+        tokens and per-bucket Mosaic kernel counts, from inside the
+        process that holds the device."""
+        return self._rpc(
+            int(replica), "device_check", list(prompt), int(max_new_tokens),
+            timeout=self._init_timeout,
+        )
+
     # -- fault injection (chaos tests / bench) -----------------------------
     def inject_fault(self, replica: int, plan: Any) -> list:
         """Arm a deterministic fault plan (serve.faults) on ONE live

@@ -881,7 +881,7 @@ class DecodeEngine:
             # pay 4x the dispatch latency per request. The slot
             # deactivates itself in-graph when the request is already
             # done at its first token (n_new == 1 or eos).
-            h, pf_k, pf_v = gpt_prefill(params, cfg, prompt)
+            h, pf_k, pf_v = gpt_prefill(params, cfg, prompt, mesh=self.mesh)
             h_last = jax.lax.dynamic_slice_in_dim(h, last_idx, 1, axis=1)
             h_last = norm_fn(h_last, params["lnf_g"], params["lnf_b"])[:, 0]
             logits = _lm_head(h_last, _head_weight(params, cfg))
@@ -965,6 +965,7 @@ class DecodeEngine:
                     draft_fn=lambda h, p, c: model_propose(
                         dparams, self._spec_cfg, h, p, c,
                         depth=self.spec_depth, window=self.spec_window,
+                        mesh=self.mesh,
                     ),
                     piggyback=pb or None,
                 )
@@ -1263,6 +1264,7 @@ class DecodeEngine:
                     draft_fn=lambda h, p, c: model_propose(
                         dparams, self._spec_cfg, h, p, c,
                         depth=self.spec_depth, window=self.spec_window,
+                        mesh=self.mesh,
                     ),
                     page_table=table, page_size=page,
                     piggyback=pb or None,
@@ -1855,6 +1857,15 @@ class DecodeEngine:
         }
 
     # -- introspection ---------------------------------------------------
+    def prefill_mosaic_calls(self) -> Dict[int, int]:
+        """Mosaic (Pallas) custom calls in each prefill bucket's compiled
+        admission. Zero for a bucket means its attention did not run the
+        TPU kernel: interpret mode off-chip, or the reference fallback."""
+        return {
+            pb: ex.as_text().count("tpu_custom_call")
+            for pb, ex in self._admit_exec.items()
+        }
+
     @property
     def mesh_desc(self) -> str:
         """``"MODELxDATA"`` of the bound mesh; ``"1x1"`` single-device."""

@@ -519,8 +519,7 @@ class FleetKVStore:
     # -- warm-start -------------------------------------------------------
     def manifest(self) -> List[str]:
         """Every stored digest hex, most-recently-used last — the
-        restarted fleet's directory seed (and the ``tpu_watch``
-        manifest stage's payload)."""
+        restarted fleet's directory seed."""
         try:
             ents = sorted(self.backend.entries(), key=lambda e: e[2])
         except Exception:  # noqa: BLE001 - no dir, no manifest

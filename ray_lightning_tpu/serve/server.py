@@ -588,6 +588,11 @@ class ServeReplica:
 
         jax.random.PRNGKey(0)
         self._compiles_at_init = self._compile_stats.count("backend_compile")
+        self._compile_s_at_init = (
+            self._compile_stats.snapshot()
+            .get("backend_compile", {})
+            .get("total_s", 0.0)
+        )
         self.metrics = ServeMetrics(
             self.engine.num_slots, registry=self._registry
         )
@@ -1002,14 +1007,55 @@ class ServeReplica:
         self._work.set()
         return ok
 
+    def device_check(
+        self, prompt: Sequence[int], max_new_tokens: int = 32
+    ) -> Dict[str, Any]:
+        """What only this process can say about the programs it runs
+        (``chip_smoke.py`` asks once, after its requests): greedy
+        ``gpt_generate`` over this replica's weights on ONE device,
+        outside the engine — the reference the engine's bit-exactness
+        contract is stated against — and the count of Mosaic kernels in
+        each compiled prefill bucket. It compiles its own programs, so
+        read ``compiles_since_init`` first."""
+        import jax
+        import numpy as np
+
+        from ray_lightning_tpu.models.gpt import gpt_generate
+
+        out = gpt_generate(
+            jax.device_get(self.engine.params),
+            self.engine.cfg,
+            np.asarray([list(prompt)], np.int32),
+            int(max_new_tokens),
+        )
+        return {
+            "solo_tokens": [int(t) for t in np.asarray(out)[0, len(prompt):]],
+            "prefill_mosaic_calls": self.engine.prefill_mosaic_calls(),
+        }
+
     def stats(self) -> Dict[str, Any]:
         """The stats endpoint: metrics snapshot + engine anatomy +
         embedded registry values."""
+        import jax
+
+        devs = jax.devices()
         snap = self.metrics.snapshot()
         snap.update(
             {
+                # The device as jax reports it INSIDE this process — a
+                # replica that fell to another platform says so itself.
+                "device": {
+                    "platform": devs[0].platform,
+                    "kind": devs[0].device_kind,
+                    "count": len(devs),
+                },
                 "active_slots": self.engine.num_active,
                 "compiled_count": self.engine.compiled_count,
+                # Backend compiles the listener saw while the engine was
+                # built: zero means the listener is not live, and then
+                # compiles_since_init below proves nothing.
+                "compiles_at_init": self._compiles_at_init,
+                "compile_s_at_init": self._compile_s_at_init,
                 # The frozen-compile contract as a metric: backend
                 # compiles observed since construction ended. Non-zero in
                 # steady state means a shape leaked into the hot path.

@@ -70,10 +70,9 @@ class Strategy:
         if self.use_tpu == "auto":
             from ray_lightning_tpu import fabric
 
-            try:
-                return fabric.cluster_resources().get("TPU", 0) >= 1
-            except Exception:  # noqa: BLE001
-                return False
+            # A chip probe that crashed or timed out raises FabricError
+            # here; answering False would plan a CPU run on a TPU host.
+            return fabric.cluster_resources().get("TPU", 0) >= 1
         return bool(self.use_tpu)
 
     def _resolve_num_hosts(self, use_tpu: bool) -> int:
@@ -116,16 +115,21 @@ class Strategy:
                         f"{len(per_node)} TPU nodes are visible; placement "
                         "will fail unless more nodes join"
                     )
+            elif self.num_workers < chips_per_host:
+                # Part of one host: ONE actor holding num_workers chips,
+                # which the fabric pins to exactly those chips (or refuses,
+                # naming the counts a host can be cut into).
+                num_hosts = 1
             else:
-                num_hosts = self.num_workers  # fall back to 1 chip per actor
-                # Single-chip actors pack many-per-node; feasibility is
-                # bounded by total chips, not node count.
-                if self.num_workers > sum(per_node):
-                    rank_zero_warn(
-                        f"planning {self.num_workers} single-chip TPU worker "
-                        f"actors but only {sum(per_node)} chips are visible; "
-                        "placement will fail unless more chips join"
-                    )
+                # Several processes would have to cooperate across parts
+                # of one host's chips; each process of a TPU job owns whole
+                # hosts (or, alone, one pinned part of a host).
+                raise ValueError(
+                    f"num_workers={self.num_workers} does not fill whole TPU "
+                    f"hosts of {chips_per_host} chips: use a multiple of "
+                    f"{chips_per_host}, or fewer than {chips_per_host} "
+                    "workers (one process on part of one host)"
+                )
             return max(1, num_hosts)
         return 1  # CPU: one process with N virtual devices
 
@@ -225,8 +229,14 @@ class Strategy:
     def bind_module(self, module: Any) -> None:
         """Give the strategy the user module before state placement, so
         sharding rules can consult module hooks (``param_logical_axes``,
-        ``bind_mesh``). Called by the loop once the mesh exists."""
+        ``bind_mesh``). Called by the loop once the mesh exists.
+
+        Mesh-aware modules get the mesh: under GSPMD a Pallas kernel is a
+        custom call the partitioner cannot split, so the module wraps it
+        in a ``shard_map`` over the mesh axes that shard its operands."""
         self._module = module
+        if hasattr(module, "bind_mesh"):
+            module.bind_mesh(self.mesh, None)
 
     def build_mesh(self):
         from ray_lightning_tpu.parallel.mesh import build_mesh
@@ -323,9 +333,9 @@ class Strategy:
         """Iterate device-resident global batches, overlapping host->device
         transfer with compute.
 
-        Over a tunneled/remote PJRT backend a blocking ``device_put`` costs a
-        full round trip; a small thread pool keeps ``depth`` transfers in
-        flight (order-preserving) so the step stream never stalls on H2D.
+        A small thread pool keeps ``depth`` transfers in flight
+        (order-preserving) so the step stream never stalls on a blocking
+        ``device_put``.
         This is the TPU analog of the reference relying on torch DataLoader
         ``pin_memory`` + async ``.cuda()`` copies in its hot loop.
 

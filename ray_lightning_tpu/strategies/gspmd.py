@@ -82,10 +82,8 @@ class GSPMDStrategy(RayTPUStrategy):
     # -- module hook ----------------------------------------------------
     def bind_module(self, module: Any) -> None:
         super().bind_module(module)
-        if hasattr(module, "bind_mesh"):
-            module.bind_mesh(
-                self.mesh, "seq" if self.sequence_parallel else None
-            )
+        if self.sequence_parallel and hasattr(module, "bind_mesh"):
+            module.bind_mesh(self.mesh, "seq")
 
     # -- shardings ------------------------------------------------------
     def param_sharding(self, params: Any) -> Any:

@@ -22,6 +22,11 @@ from ray_lightning_tpu.utils.rank_zero import rank_zero_warn
 class RingTPUStrategy(RayTPUStrategy):
     strategy_name = "horovod_ray"
 
+    def bind_module(self, module: Any) -> None:
+        # The per-rank step already runs inside shard_map, where a kernel
+        # sees its local shard: the module gets no mesh to wrap it in.
+        self._module = module
+
     def compile_train_step(
         self,
         module: Any,
@@ -35,7 +40,7 @@ class RingTPUStrategy(RayTPUStrategy):
         import optax
         from jax.sharding import PartitionSpec as P
 
-        from ray_lightning_tpu.utils.compat import shard_map
+        from jax import shard_map
 
         mesh = self.mesh
         prep = self._prep_compute(module)
@@ -85,7 +90,7 @@ class RingTPUStrategy(RayTPUStrategy):
         import jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
 
-        from ray_lightning_tpu.utils.compat import shard_map
+        from jax import shard_map
 
         if stage == "predict":
             return super().compile_eval_step(module, stage)

@@ -69,7 +69,9 @@ class ModelCheckpoint(Callback):
     """Save the training state each validation/epoch end; track the best.
 
     Files are state-stream checkpoints (utils/state_stream.py) containing
-    params + optimizer state + loop counters, so resume restores exactly.
+    params + optimizer state + loop counters, so resume restores exactly;
+    ``save_weights_only=True`` leaves the optimizer state out (a third of
+    the bytes under Adam; enough to serve or evaluate from).
     ``best_model_path`` propagates to the driver in the worker output, like
     the reference's (ray_launcher.py:319-321, :357-360).
     """
@@ -83,9 +85,16 @@ class ModelCheckpoint(Callback):
         save_top_k: int = 1,
         save_last: bool = False,
         save_sharded: bool = False,
+        save_weights_only: bool = False,
     ) -> None:
         assert mode in ("min", "max")
+        if save_sharded and save_weights_only:
+            raise ValueError(
+                "save_weights_only applies to state-stream files; a sharded "
+                "(orbax) checkpoint always carries the optimizer state"
+            )
         self.save_sharded = save_sharded
+        self.save_weights_only = save_weights_only
         self.dirpath = dirpath
         self.filename = filename
         self.monitor = monitor
@@ -156,11 +165,11 @@ class ModelCheckpoint(Callback):
             # collective under multi-process sharding (a rank-0-only call
             # deadlocks); rank 0 alone writes bytes and keeps bookkeeping.
             path = os.path.join(dirpath, name + ".ckpt")
-            trainer.save_checkpoint(path)
+            trainer.save_checkpoint(path, weights_only=self.save_weights_only)
             last = None
             if self.save_last:
                 last = os.path.join(dirpath, "last.ckpt")
-                trainer.save_checkpoint(last)
+                trainer.save_checkpoint(last, weights_only=self.save_weights_only)
             if trainer.global_rank != 0:
                 return
             if last:

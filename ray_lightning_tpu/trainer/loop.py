@@ -124,6 +124,17 @@ class TrainingPreempted(RuntimeError):
         return (type(self), (self.ckpt_path, self.global_step))
 
 
+def _host_device() -> Any:
+    """This process's CPU device, or None (jax's default placement) when
+    ``JAX_PLATFORMS`` left it no CPU backend."""
+    import jax
+
+    try:
+        return jax.local_devices(backend="cpu")[0]
+    except RuntimeError:
+        return None
+
+
 def _limit(n_batches: Optional[int], limit: Any) -> Optional[int]:
     """None n_batches = a streaming loader (unknown length): int limits
     bound it, fractional limits have nothing to take a fraction OF."""
@@ -262,9 +273,14 @@ class TrainingLoop:
         # assembling batches that get discarded.
         sample_batch = next(iter(self._train_loader.iter_batches(1, prefetch=0)))
         init_rng, self._rng = jax.random.split(self._rng)
-        params = self.module.init_params(init_rng, sample_batch)
-        self._tx = self._wrap_optimizer(self._unpack_optimizers())
-        opt_state = self._tx.init(params)
+        # Initial state is built on the host: built on the default device,
+        # the first chip would hold the whole unsharded model AND optimizer
+        # state before place_* shards them — a peak the other chips never
+        # see, and the first thing to overflow on a model ZeRO makes fit.
+        with jax.default_device(_host_device()):
+            params = self.module.init_params(init_rng, sample_batch)
+            self._tx = self._wrap_optimizer(self._unpack_optimizers())
+            opt_state = self._tx.init(params)
         sharded_path = (
             ckpt_stream.get("orbax_path")
             if isinstance(ckpt_stream, dict)
@@ -517,13 +533,18 @@ class TrainingLoop:
                 cb.load_state_dict(cb_state)
 
     # ------------------------------------------------------------------
-    def save_checkpoint(self, path: str, sharded: bool = False) -> None:
+    def save_checkpoint(
+        self, path: str, sharded: bool = False, weights_only: bool = False
+    ) -> None:
         """Write a checkpoint.
 
         Default: rank 0 gathers full state into a state-stream file (the
         reference's wire format, SURVEY.md §3.4). ``sharded=True``: every
         process writes its own shards via orbax — no gather, scales with
-        GSPMD/ZeRO state (call from ALL ranks).
+        GSPMD/ZeRO state (call from ALL ranks). ``weights_only=True``
+        (state-stream files only) leaves the optimizer state out: a third
+        of the bytes under Adam, loadable for serving and evaluation, and
+        a resumed fit warns that its moments restart.
         """
         events = getattr(self, "_events", None)  # None outside a fit
         if events is not None:
@@ -569,7 +590,7 @@ class TrainingLoop:
         # plain-device_get strategies non-zero ranks skip the gather.)
         if self.global_rank != 0 and not self.strategy.gather_is_collective:
             return
-        state = self.checkpoint_state()
+        state = self.checkpoint_state(weights_only=weights_only)
         if self.global_rank != 0:
             return
         stream = to_state_stream(state)
@@ -595,10 +616,9 @@ class TrainingLoop:
             self._sharded_io.finalize()
             self.strategy.barrier("finalize_checkpoints")
 
-    def checkpoint_state(self) -> Dict[str, Any]:
+    def checkpoint_state(self, weights_only: bool = False) -> Dict[str, Any]:
         state = {
             "params": self.strategy.gather_state(self.params),
-            "opt_state": self.strategy.gather_state(self.opt_state),
             "epoch": self.current_epoch,
             "mid_epoch": not getattr(self, "_epoch_complete", True),
             "global_step": self.global_step,
@@ -606,6 +626,8 @@ class TrainingLoop:
                 type(cb).__name__: cb.state_dict() for cb in self.callbacks
             },
         }
+        if not weights_only:
+            state["opt_state"] = self.strategy.gather_state(self.opt_state)
         rb = getattr(self, "_preempt_resume_batch", None)
         if rb:
             # Checkpoint-on-notice only: the exact epoch position for a

@@ -1,7 +1,7 @@
 """bench.py smoke: the harness must produce its one JSON line on CPU.
 
-Guards the driver-run benchmark against code drift; the real numbers come
-from the TPU run (BENCH_r{N}.json)."""
+Guards the benchmark against code drift; device numbers come only from a
+chip run."""
 import json
 import os
 import subprocess
@@ -22,7 +22,6 @@ def _run_bench(extra_env, *args, timeout=900):
     }
     env.pop("RLT_BENCH_ALLOW_CPU", None)
     env.pop("RLT_REQUIRE_TPU", None)
-    env.pop("RLT_BENCH_STRICT", None)
     env.update(extra_env)
     env["PYTHONPATH"] = os.pathsep.join(
         [REPO_ROOT, env.get("PYTHONPATH", "")]
@@ -61,7 +60,6 @@ def test_bench_smoke_cpu():
     # Self-proving env metadata (VERDICT r2 weak #2).
     assert out["env"]["backend"] == "cpu"
     assert "device_kind" in out["env"]
-    assert "tpu_probe_failed" not in out["env"]  # deliberate CPU run: no flag
     assert "pair_ratios" in out["extra"]
     # Drift control: baseline-vs-itself ratios quantify the noise floor
     # (rounds=1 -> empty list, but the key must exist).
@@ -396,32 +394,13 @@ def test_bench_smoke_cpu():
         )
 
 
-@pytest.mark.slow
-def test_bench_probe_exhaustion_records_flagged_cpu_run():
-    """A dead TPU at bench time must leave a structured record: the probe
-    exhausts (bench-DEFAULTED requirement, no operator override), the bench
-    falls back to CPU, and the JSON says so loudly."""
-    proc = _run_bench(
-        {"RLT_BENCH_TPU_RETRIES": "0"},
-        "--rounds", "1", "--epochs", "2", "--n-train", "256", "--skip-extra",
-    )
-    data = _json_line(proc)
-    assert data["env"]["tpu_probe_failed"] is True
-    assert data["env"]["backend"] == "cpu"
-    assert "probe_error" in data["env"]
-    assert data["vs_baseline"] > 0
-
-
-def test_bench_operator_contracts_hard_fail():
-    """An OPERATOR-set RLT_REQUIRE_TPU=1 (or RLT_BENCH_STRICT=1) keeps the
-    documented hard-failure contract — no flagged fallback."""
-    for extra in (
-        {"RLT_REQUIRE_TPU": "1", "RLT_BENCH_TPU_RETRIES": "0"},
-        {"RLT_BENCH_STRICT": "1", "RLT_BENCH_TPU_RETRIES": "0"},
-    ):
-        proc = _run_bench(extra, "--rounds", "1", "--skip-extra", timeout=300)
-        assert proc.returncode != 0, extra
-        assert "RLT_REQUIRE_TPU" in proc.stderr
+def test_bench_without_chip_is_an_error():
+    """No TPU and no RLT_BENCH_ALLOW_CPU=1: the bench refuses to run —
+    there is no CPU record under a device metric's name, flagged or not."""
+    proc = _run_bench({}, "--rounds", "1", "--skip-extra", timeout=300)
+    assert proc.returncode != 0
+    assert "RLT_REQUIRE_TPU" in proc.stderr
+    assert not [l for l in proc.stdout.splitlines() if l.startswith("{")]
 
 
 def test_gpt_ladder_falls_back(start_fabric, monkeypatch):

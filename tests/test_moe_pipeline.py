@@ -11,23 +11,6 @@ from ray_lightning_tpu.strategies import GSPMDStrategy
 from tests.test_gpt import TINY, make_inprocess
 from ray_lightning_tpu.trainer.module import unpack_optimizers
 
-# On the 0.4.x JAX line (no jax.shard_map) the XLA CPU backend WEDGES
-# (minutes-to-forever compile, not a clean failure) partitioning the
-# ep-mesh / all-to-all dispatch programs; skip rather than hang the lane.
-ep_partitioner_wedges = pytest.mark.skipif(
-    not hasattr(jax, "shard_map"),
-    reason="XLA CPU compile of the ep/a2a SPMD program hangs on jax<0.5",
-)
-
-# Partial-auto shard_map (manual over ONE axis of a multi-axis mesh) is
-# jax >= 0.5: the 0.4.x lowering emits PartitionId/Zero-tangent artifacts
-# the partitioner rejects. The pp/a2a paths need it; skip cleanly there.
-partial_auto_shard_map = pytest.mark.skipif(
-    not hasattr(jax, "shard_map"),
-    reason="partial-auto shard_map (pp/a2a over a multi-axis mesh) needs "
-    "jax >= 0.5",
-)
-
 MOE_CFG = dataclasses.replace(TINY, n_experts=4, d_ff=64)
 
 
@@ -207,7 +190,6 @@ def test_moe_swiglu_experts_match_manual_mixture():
     )
 
 
-@ep_partitioner_wedges
 def test_mixtral_style_gpt_trains_on_ep_mesh():
     """Llama variants x MoE (the Mixtral shape): RMSNorm + SwiGLU experts
     + RoPE + untied head trains under an ep2 x fsdp2 x data2 mesh with
@@ -281,7 +263,6 @@ def test_moe_decode_matches_full_forward():
         )
 
 
-@partial_auto_shard_map
 def test_moe_a2a_matches_oracle_values_and_grads():
     """moe_ffn_ep (explicit all-to-all over ep) == moe_ffn exactly in the
     drop-free regime: outputs, grads, and aux stats, across 1D/2D/3D
@@ -352,7 +333,6 @@ def test_moe_a2a_matches_oracle_values_and_grads():
             )
 
 
-@ep_partitioner_wedges
 def test_moe_a2a_lowers_to_all_to_all():
     """The point of moe_ffn_ep: dispatch must ride all-to-alls, not the
     all-gather lowering GSPMD produces for the sorted dispatch (checked on
@@ -439,7 +419,6 @@ def test_moe_auto_fallback_warns_once(caplog):
     ]
 
 
-@ep_partitioner_wedges
 def test_moe_gpt_a2a_matches_gspmd_dispatch():
     """GPT on an ep2 mesh: the a2a dispatch reproduces the gspmd dispatch
     and the dense oracle exactly (drop-free capacity)."""
@@ -564,7 +543,6 @@ def test_pp_composes_with_grad_accumulation():
         )
 
 
-@ep_partitioner_wedges
 def test_moe_gpt_expert_parallel_step():
     """MoE GPT on an ep2 x model2 x fsdp2 mesh: expert weights shard on
     "ep", the step runs, loss decreases, aux metric is logged."""
@@ -598,7 +576,6 @@ def test_moe_gpt_expert_parallel_step():
     assert losses[-1] < losses[0], losses
 
 
-@partial_auto_shard_map
 def test_pipeline_apply_matches_serial():
     """Pipelined stacked-linear stack == serial scan, values and grads."""
     import jax
@@ -634,7 +611,6 @@ def test_pipeline_apply_matches_serial():
     )
 
 
-@partial_auto_shard_map
 def test_pipeline_aux_channel_matches_serial():
     """with_aux: the pipelined aux (psum over ranks, /M over microbatches)
     equals the serial full-batch value exactly for token-mean aux — pinning
@@ -687,7 +663,6 @@ def test_pipeline_aux_channel_matches_serial():
     )
 
 
-@partial_auto_shard_map
 def test_gpt_pipeline_matches_dense():
     """GPT with layers sharded over pp2 reproduces the dense logits."""
     import jax
@@ -710,7 +685,6 @@ def test_gpt_pipeline_matches_dense():
     np.testing.assert_allclose(np.asarray(piped), np.asarray(dense), atol=1e-4)
 
 
-@partial_auto_shard_map
 def test_gpt_pipeline_train_step():
     import jax
 
@@ -736,7 +710,6 @@ def test_gpt_pipeline_train_step():
     assert losses[-1] < losses[0], losses
 
 
-@partial_auto_shard_map
 def test_moe_pipeline_matches_dense_oracle():
     """MoE x pipeline composition (VERDICT r4 item 4): a pp2 x ep2 x data2
     mesh reproduces the unsharded dense-mixture logits. Capacity is set
@@ -767,7 +740,6 @@ def test_moe_pipeline_matches_dense_oracle():
     )
 
 
-@partial_auto_shard_map
 def test_moe_pipeline_train_step():
     """MoE x pp training: the step compiles and runs on a pp2 x ep2 mesh,
     the loss decreases, and the load-balancing aux is finite and logged."""

@@ -141,6 +141,35 @@ def test_checkpoint_roundtrip(tmp_path):
     np.testing.assert_array_equal(ref, got)
 
 
+def test_checkpoint_weights_only(tmp_path):
+    """save_weights_only leaves the optimizer state out of the file; what
+    is left still loads for evaluation. Sharded saves refuse the option."""
+    from ray_lightning_tpu.utils.state_stream import load_state_stream
+
+    paths = {}
+    for weights_only in (False, True):
+        module = BoringModule()
+        ckpt = ModelCheckpoint(
+            dirpath=str(tmp_path / str(weights_only)),
+            save_weights_only=weights_only,
+        )
+        get_trainer(callbacks=[ckpt], enable_checkpointing=True).fit(module)
+        paths[weights_only] = ckpt.best_model_path
+    with open(paths[False], "rb") as f:
+        assert "opt_state" in load_state_stream(f.read())
+    with open(paths[True], "rb") as f:
+        state = load_state_stream(f.read())
+    assert "opt_state" not in state and state["global_step"] > 0
+    np.testing.assert_array_equal(
+        np.asarray(state["params"]["w"]), np.asarray(module.params["w"])
+    )
+    assert os.path.getsize(paths[True]) < os.path.getsize(paths[False])
+    res = get_trainer().validate(BoringModule(), ckpt_path=paths[True])
+    assert "val_loss" in res[0]
+    with pytest.raises(ValueError, match="save_weights_only"):
+        ModelCheckpoint(save_sharded=True, save_weights_only=True)
+
+
 def test_resume_from_checkpoint(tmp_path):
     module = BoringModule()
     ckpt = ModelCheckpoint(dirpath=str(tmp_path), monitor="val_loss")

@@ -35,6 +35,9 @@ def main() -> None:
     p.add_argument("--window", type=int, default=1024)
     args = p.parse_args()
 
+    from ray_lightning_tpu.utils.compile_cache import place_compile_cache
+
+    place_compile_cache()
     import jax
     import jax.numpy as jnp
 

@@ -1,6 +1,5 @@
 """On-chip A/B: GPT-2 124M tokens/s across (batch, loss_chunk) configs.
 
-Run AFTER any headline bench (single-core host: no concurrent loads).
 Each config gets a fresh worker process (fresh XLA runtime), mirroring
 bench_gpt's methodology. Prints one JSON line per config.
 """
@@ -57,11 +56,10 @@ def main() -> None:
     )
     args = p.parse_args()
 
-    import os
-
-    os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", "/tmp/rlt_jax_cache")
     from ray_lightning_tpu import fabric
+    from ray_lightning_tpu.utils.compile_cache import place_compile_cache
 
+    place_compile_cache()
     fabric.init(num_cpus=8.0)
     for spec in args.configs.split(","):
         parts = [int(v) for v in spec.split(":")]

@@ -27,28 +27,23 @@ instead: a local fabric with TWO replica actors behind a ServeClient,
 the driver-side fleet poller and obs endpoint exactly as ``rlt serve
 --serve.metrics_port`` wires them, and archives one ``/fleet``
 snapshot plus one stitched cross-process ``/traces`` export fetched
-over real HTTP — the tpu_watch ``fleet`` manifest stage's artifact.
+over real HTTP.
 (Replica actors are pinned to CPU: the artifact records the
 aggregation plane, not chip throughput.)
 
 ``--out-why PATH`` (fleet path only) additionally runs the real
 ``rlt why <addr> <request_id>`` CLI against the live endpoint for one
-completed request and archives its rendered phase-ledger timeline —
-the tpu_watch ``anatomy`` manifest stage's artifact (the request
-anatomy wire path proven end-to-end: replica rings -> /why ->
+completed request and archives its rendered phase-ledger timeline
+(the request anatomy wire path end to end: replica rings -> /why ->
 rendered decomposition).
 
 ``--out-alerts PATH`` (fleet path only) additionally starts the
 watchtower (retained TSDB + alert engine) on the driver, lets it
 ingest a few fleet snapshots, and archives the ``/alerts`` payload
 plus one ``/query`` series pull fetched over real HTTP as one JSON
-file — the tpu_watch ``watchtower`` manifest stage's artifact.
+file.
 
-The tpu_watch `obs`, `doctor`, `fleet`, `anatomy`, and `watchtower`
-manifest stages run this and archive the files, so every healthy TPU
-window leaves a scrapeable-metrics + viewable-trace + pullable-bundle
-+ fleet-snapshot + request-anatomy + retained-alerting record
-alongside the bench JSONs. Runs fine on CPU.
+Runs on CPU: it records the wire paths, not chip throughput.
 """
 import argparse
 import contextlib
@@ -255,6 +250,9 @@ def main() -> None:
     p.add_argument("--new-tokens", type=int, default=16)
     args = p.parse_args()
 
+    from ray_lightning_tpu.utils.compile_cache import place_compile_cache
+
+    place_compile_cache()
     if args.out_fleet:
         fleet_main(args)
         return
