@@ -1318,8 +1318,7 @@ def gpt_decode_step(
         # (S, Hkv, hd) cache row update at this slot's own position.
         return jax.lax.dynamic_update_slice_in_dim(c, new[None], p, axis=0)
 
-    def layer(h, args):
-        lp, kc_l, vc_l = args
+    def qkv_rope(h, lp):
         a = norm_fn(h[:, None], lp["ln1_g"], lp["ln1_b"])[:, 0]
         if Hkv == H:
             qkv = (
@@ -1340,8 +1339,9 @@ def gpt_decode_step(
         if rope_tables is not None:
             q = _rope_slot(q)
             k_new = _rope_slot(k_new)
-        kc_l = jax.vmap(_write_slot)(kc_l, k_new, pos)
-        vc_l = jax.vmap(_write_slot)(vc_l, v_new, pos)
+        return q, k_new, v_new
+
+    def attend(q, kc_l, vc_l):
         # Grouped attention against the Hkv-headed cache: q heads fold
         # to (Hkv, rep) groups (head h reads kv head h // rep, matching
         # _project_qkv's jnp.repeat layout).
@@ -1363,12 +1363,11 @@ def gpt_decode_step(
             float("-inf"),
         )
         p = jax.nn.softmax(s, axis=-1)
-        o = jnp.einsum(
+        return jnp.einsum(
             "bgrs,bsgk->bgrk", p, vc_l.astype(jnp.float32)
         ).reshape(B, H, hd).astype(cdt)
-        h = h + jnp.einsum("bhk,hkd->bd", o, dequant(lp["wo"], cdt)) + lp[
-            "bo"
-        ].astype(cdt)
+
+    def mlp(h, lp):
         m = norm_fn(h[:, None], lp["ln2_g"], lp["ln2_b"])
         if cfg.n_experts > 0:
             from ray_lightning_tpu.parallel.moe import moe_ffn
@@ -1382,10 +1381,27 @@ def gpt_decode_step(
                 compute_dtype=cdt,
                 top_k=cfg.moe_top_k,
             )
-            m_out = m_out[:, 0]
-        else:
-            m_out = _dense_mlp(m[:, 0], lp, cfg, cdt)
-        return h + m_out, (kc_l, vc_l)
+            return m_out[:, 0]
+        return _dense_mlp(m[:, 0], lp, cfg, cdt)
+
+    # The parts of a layer carry names into the compiled program's
+    # metadata (jax.named_scope), so a profile says which part an
+    # operation belongs to; the math is untouched.
+    def layer(h, args):
+        lp, kc_l, vc_l = args
+        with jax.named_scope("qkv_rope"):
+            q, k_new, v_new = qkv_rope(h, lp)
+        with jax.named_scope("cache_write"):
+            kc_l = jax.vmap(_write_slot)(kc_l, k_new, pos)
+            vc_l = jax.vmap(_write_slot)(vc_l, v_new, pos)
+        with jax.named_scope("cache_attention"):
+            o = attend(q, kc_l, vc_l)
+            h = h + jnp.einsum(
+                "bhk,hkd->bd", o, dequant(lp["wo"], cdt)
+            ) + lp["bo"].astype(cdt)
+        with jax.named_scope("mlp"):
+            h = h + mlp(h, lp)
+        return h, (kc_l, vc_l)
 
     h = x
     new_k, new_v = [], []
@@ -1396,10 +1412,12 @@ def gpt_decode_step(
         h, (kc_l, vc_l) = layer(h, (lp, k_cache[li], v_cache[li]))
         new_k.append(kc_l)
         new_v.append(vc_l)
-    k_cache = jnp.stack(new_k)
-    v_cache = jnp.stack(new_v)
-    h = norm_fn(h[:, None], params["lnf_g"], params["lnf_b"])[:, 0]
-    logits = _lm_head(h, _head_weight(params, cfg))
+    with jax.named_scope("cache_write"):
+        k_cache = jnp.stack(new_k)
+        v_cache = jnp.stack(new_v)
+    with jax.named_scope("lm_head"):
+        h = norm_fn(h[:, None], params["lnf_g"], params["lnf_b"])[:, 0]
+        logits = _lm_head(h, _head_weight(params, cfg))
     return logits, k_cache, v_cache
 
 
@@ -1666,9 +1684,12 @@ def gpt_decode_fold(
                 params, cfg, cur, pos, k_cache, v_cache, page_table,
                 page_size,
             )
-        split = jax.vmap(jax.random.split)(keys)  # (B, 2, 2)
-        new_keys, subs = split[:, 0], split[:, 1]
-        toks = sample_logits_batched(subs, logits, temps, top_ks, top_ps)
+        with jax.named_scope("sample"):
+            split = jax.vmap(jax.random.split)(keys)  # (B, 2, 2)
+            new_keys, subs = split[:, 0], split[:, 1]
+            toks = sample_logits_batched(
+                subs, logits, temps, top_ks, top_ps
+            )
         emit = active
         cur = jnp.where(active, toks, cur)
         pos = jnp.where(active, pos + 1, pos)
@@ -2460,26 +2481,32 @@ class GPTLM(TPUModule):
     ) -> Any:
         toks = batch[0] if isinstance(batch, (tuple, list)) else batch
         chunked = self._use_chunked_loss()
-        out = gpt_forward(
-            params,
-            toks[:, :-1],
-            self.config,
-            mesh=self._mesh,
-            seq_axis=self._seq_axis,
-            return_aux=return_aux,
-            return_hidden=chunked,
-        )
+        # Named in the compiled program's metadata, so a profile tells
+        # the blocks from the LM-head loss (and, through autodiff's own
+        # marks, their backward passes).
+        with jax.named_scope("forward"):
+            out = gpt_forward(
+                params,
+                toks[:, :-1],
+                self.config,
+                mesh=self._mesh,
+                seq_axis=self._seq_axis,
+                return_aux=return_aux,
+                return_hidden=chunked,
+            )
         if chunked:
             def head(o):
-                return chunked_lm_loss(
-                    o,
-                    _head_weight(params, self.config),
-                    toks[:, 1:],
-                    self.config.loss_chunk,
-                )
+                with jax.named_scope("loss"):
+                    return chunked_lm_loss(
+                        o,
+                        _head_weight(params, self.config),
+                        toks[:, 1:],
+                        self.config.loss_chunk,
+                    )
         else:
             def head(o):
-                return lm_loss(o, toks[:, 1:])
+                with jax.named_scope("loss"):
+                    return lm_loss(o, toks[:, 1:])
         if return_aux:
             hidden_or_logits, aux = out
             loss, acc = head(hidden_or_logits)

@@ -8,7 +8,10 @@ PASSIVE (telemetry — the eyes):
 
 - :mod:`obs.trace` — request tracing: typed lifecycle spans in a bounded
   per-replica ring buffer (:class:`RequestTracer`), exported as Chrome
-  trace-event JSON (:func:`to_chrome_trace`) that opens in Perfetto.
+  trace-event JSON (:func:`to_chrome_trace`) that opens in Perfetto; and
+  what a THREAD is doing (``obs.trace.span`` into ``SpanTotals``):
+  monotone totals in ``stats()["spans"]``, and annotations on the
+  profiler's clock while a ``jax.profiler`` session is active.
 - :mod:`obs.anatomy` — request anatomy (:func:`assemble_anatomy`,
   :func:`render_anatomy`): one request's cross-process phase ledger
   stitched from every tracer ring + the journal + the event rings, with
@@ -26,9 +29,11 @@ PASSIVE (telemetry — the eyes):
   heartbeat aggregation (:class:`TrainTelemetry`).
 - :mod:`obs.jaxmon` — JAX compile-event counters
   (:func:`install_compile_listener`): the frozen-compile contract as a
-  metric, not just a test.
+  metric, not just a test; and the cyclic collector's pauses
+  (``install_gc_hook``).
 - :mod:`obs.profiling` — on-demand ``jax.profiler`` capture
-  (:func:`capture_profile`) behind the ``profile(duration_s)`` RPCs.
+  (:func:`capture_profile`) behind the ``profile(duration_s)`` RPCs, in
+  a thread of its own where the caller is a serial actor.
 
 ACTIVE (judgment — something looks through the eyes):
 

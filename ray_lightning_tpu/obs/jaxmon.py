@@ -17,11 +17,19 @@ Listeners receive (event_name, duration) only — no executable name — so
 attribution is per event KIND. Listener registration is process-global
 and irrevocable (there is no unregister short of clearing every
 listener), hence the idempotent :func:`install_compile_listener`.
+
+Beside it, the other thing that stalls a host loop from inside the
+runtime: the cyclic collector. :func:`install_gc_hook` times every
+collection through ``gc.callbacks`` into a :class:`GcStats` (per
+generation: collections, summed pause, longest pause), mirrored as
+``rlt_gc_pause_seconds_total{gen}`` where the registry is read.
 """
 from __future__ import annotations
 
+import gc
 import threading
-from typing import Dict, Optional
+import time
+from typing import Any, Dict, List, Optional
 
 from ray_lightning_tpu.obs.registry import MetricsRegistry, get_registry
 
@@ -101,3 +109,85 @@ def install_compile_listener(
 def compile_stats() -> Optional[CompileStats]:
     """The installed stats, or None when no listener was installed yet."""
     return _STATS
+
+
+class GcStats:
+    """Collector pauses by generation, from ``gc.callbacks``.
+
+    The callback runs inside whichever thread tripped the collector,
+    possibly while that thread holds a lock of the registry or of a
+    ``SpanTotals``; it therefore takes no lock and touches no registry:
+    it adds to plain integers (collections do not nest, and the
+    interpreter lock orders the stores). :meth:`mirror` moves the totals
+    into a registry counter from the reader's side.
+    """
+
+    def __init__(self) -> None:
+        #: generation -> [collections, summed ns, longest ns]
+        self._gen: List[List[int]] = [[0, 0, 0] for _ in range(3)]
+        self._t0 = 0
+        self._mirrored = [0.0, 0.0, 0.0]
+        self._mirror_lock = threading.Lock()
+
+    def _callback(self, phase: str, info: Dict[str, Any]) -> None:
+        if phase == "start":
+            self._t0 = time.perf_counter_ns()
+            return
+        dur = time.perf_counter_ns() - self._t0
+        row = self._gen[min(int(info.get("generation", 2)), 2)]
+        row[0] += 1
+        row[1] += dur
+        if dur > row[2]:
+            row[2] = dur
+
+    def snapshot(self) -> Dict[str, Dict[str, float]]:
+        """``{gen: {"n", "s", "max_s"}}`` since the hook was installed;
+        never decreasing."""
+        return {
+            str(g): {"n": n, "s": ns * 1e-9, "max_s": mx * 1e-9}
+            for g, (n, ns, mx) in enumerate(self._gen)
+        }
+
+    def mirror(self, seconds: Any) -> None:
+        with self._mirror_lock:
+            for g, row in self.snapshot().items():
+                seconds.inc(row["s"] - self._mirrored[int(g)], gen=g)
+                self._mirrored[int(g)] = row["s"]
+
+
+_GC: Optional[GcStats] = None
+_GC_USERS = 0
+
+
+def install_gc_hook() -> GcStats:
+    """Hook the collector once per process; returns the shared
+    :class:`GcStats`. Every caller pairs it with one
+    :func:`remove_gc_hook`."""
+    global _GC, _GC_USERS
+    with _INSTALL_LOCK:
+        if _GC is None:
+            _GC = GcStats()
+            gc.callbacks.append(_GC._callback)
+        _GC_USERS += 1
+        return _GC
+
+
+def remove_gc_hook() -> None:
+    """Drop one user of the hook; the last one takes it out of
+    ``gc.callbacks`` (the totals start again at the next install)."""
+    global _GC, _GC_USERS
+    with _INSTALL_LOCK:
+        if _GC is None:
+            return
+        _GC_USERS -= 1
+        if _GC_USERS <= 0:
+            try:
+                gc.callbacks.remove(_GC._callback)
+            except ValueError:
+                pass
+            _GC, _GC_USERS = None, 0
+
+
+def gc_stats() -> Optional[GcStats]:
+    """The installed collector totals, or None when no hook is in."""
+    return _GC

@@ -6,7 +6,11 @@ tools (tools/gpt_profile.py traces a known span of work) and (2)
 ``profile(duration_s)`` RPC on serve replicas and TrainWorkers: start a
 trace, sleep while the process's OWN worker threads keep the device
 busy, stop, report the artifact files. The captured trace opens in
-Perfetto / TensorBoard's profile plugin.
+Perfetto / TensorBoard's profile plugin. The profiler takes seconds to
+start and to write, so an actor that must keep answering calls runs the
+capture in a thread of its own (:class:`BackgroundCapture`). While a
+session is active, every ``obs.trace.span`` in the process is also a
+``TraceAnnotation`` in it.
 
 Everything degrades gracefully: when the profiler is unavailable (or a
 capture is already running — jax allows one at a time per process) the
@@ -87,3 +91,28 @@ def capture_profile(
         return {"ok": False, "error": f"{type(exc).__name__}: {exc}"}
     finally:
         _ACTIVE.release()
+
+
+class BackgroundCapture:
+    """:func:`capture_profile` in a thread of its own: the caller (a
+    serial actor's RPC thread) returns at once and collects the result
+    with a later call."""
+
+    def __init__(
+        self, duration_s: float = 1.0, outdir: Optional[str] = None
+    ) -> None:
+        self._result: Optional[Dict[str, Any]] = None
+        self._thread = threading.Thread(
+            target=self._run, args=(duration_s, outdir),
+            name="rlt-profile-capture", daemon=True,
+        )
+        self._thread.start()
+
+    def _run(self, duration_s: float, outdir: Optional[str]) -> None:
+        self._result = capture_profile(duration_s, outdir)
+
+    def result(self, wait_s: float = 0.0) -> Optional[Dict[str, Any]]:
+        """The capture's report, or None while it is still running
+        (after waiting up to ``wait_s`` for it)."""
+        self._thread.join(timeout=max(0.0, float(wait_s)))
+        return self._result

@@ -5,16 +5,24 @@ record per dispatched chunk, split into the three host-observable
 segments of a step's wall time::
 
     data_wait : blocking on the staged-batch iterator (host assembly +
-                H2D backpressure — with async dispatch this is also
-                where device compute surfaces)
+                H2D backpressure; the device's compute surfaces here
+                only when no log drains between dispatches)
     step      : the compiled-step call (dispatch; near-zero when async)
     drain     : log fetch, callbacks, mid-epoch val — everything between
-                the step returning and the next batch pull
+                the step returning and the next batch pull. A log drain
+                blocks on the dispatch it fetches from, so with a drain
+                every dispatch (``log_every_n_steps`` <= the fold) the
+                device's compute surfaces HERE.
 
-The segments are consecutive monotonic-clock intervals, so they sum to
-the chunk's wall time by construction (the test asserts it to guard the
-instrumentation against drift as the loop evolves). Aggregates feed the
-process registry (``rlt_train_*``) and ship to the driver in
+The segments are consecutive intervals of one clock, so they sum to the
+chunk's wall time by construction (the test asserts it to guard the
+instrumentation against drift as the loop evolves). Each is a span of
+the loop (``obs.trace.span`` into :attr:`TrainTelemetry.spans`:
+``fit.stage``, ``fit.dispatch``, and ``fit.callbacks`` with the
+blocking fetch inside it as ``fit.drain_wait``; drain =
+``fit.drain_wait`` + ``fit.callbacks``), so a profiler session shows
+them beside the device's operations. Aggregates feed the process
+registry (``rlt_train_*``) and ship to the driver in
 ``trainer_state["telemetry"]``.
 
 Throughput: when the module exposes ``batch_size`` and a config with
@@ -31,7 +39,9 @@ from __future__ import annotations
 import time
 from typing import Any, Dict, Optional
 
+from ray_lightning_tpu.obs.jaxmon import compile_stats, gc_stats
 from ray_lightning_tpu.obs.registry import MetricsRegistry, get_registry
+from ray_lightning_tpu.obs.trace import SpanTotals
 
 
 class TrainTelemetry:
@@ -52,6 +62,10 @@ class TrainTelemetry:
         self._mfu = reg.gauge(
             "rlt_train_mfu", "Model FLOPs utilization (0-1), when peak known"
         )
+        #: The fit loop's spans (fit.stage / fit.dispatch /
+        #: fit.drain_wait / fit.callbacks): monotone totals, shipped in
+        #: snapshot()["spans"].
+        self.spans = SpanTotals()
         # Host mirrors (snapshot() must not depend on registry internals).
         self.steps = 0
         self.chunks = 0
@@ -122,11 +136,15 @@ class TrainTelemetry:
             out["tokens_total"] = self.tokens_total
         if self.mfu is not None:
             out["mfu"] = self.mfu
-        from ray_lightning_tpu.obs.jaxmon import compile_stats
-
         stats = compile_stats()
         if stats is not None:
             out["compile_events"] = stats.snapshot()
+        # The same block ServeReplica.stats() ships: monotone since the
+        # fit began, so two snapshots differ by exactly the time between.
+        out["spans"] = self.spans.snapshot()
+        gc_totals = gc_stats()
+        if gc_totals is not None:
+            out["spans"]["gc"] = gc_totals.snapshot()
         return out
 
 

@@ -4,9 +4,7 @@ Every serve request leaves a trail of timestamped events — submit →
 queued → admitted → each prefill chunk → prefix-cache seed → each decode
 fold it rode → first token → finish/cancel/expire — appended to a
 bounded per-replica ring buffer (:class:`RequestTracer`). Recording is a
-tuple append under one lock, no I/O and no string formatting, so the
-decode hot loop pays nanoseconds per event (the bench measures the
-observer effect as ``obs_overhead``; the smoke test pins it < 5%).
+tuple append under one lock, no I/O and no string formatting.
 
 Reconstruction happens at READ time: ``trace(request_id)`` scans the
 ring, and :func:`to_chrome_trace` converts traces into Chrome
@@ -26,13 +24,20 @@ first chunk — the engine records it while seeding the slot) on a
 prefix-cache hit, and ``decode_fold`` events between first_token and the
 terminal event. tests/test_obs.py asserts it across chunked-prefill x
 prefix-hit x mid-fold-cancel.
+
+What a THREAD is doing (as opposed to where a request is) goes through
+:func:`span` into a :class:`SpanTotals`: monotone per-name totals that
+``stats()`` ships, and — exactly while a ``jax.profiler`` session is
+active in the process — a ``TraceAnnotation`` on the profiler's own
+clock, beside the device's operations in the same ``.xplane.pb``.
 """
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 from collections import deque
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, Iterator, List, Optional, Tuple
 
 # -- span names (the typed vocabulary) ---------------------------------
 #: Request entered the DRIVER-side client (``submit()`` entry or batch
@@ -98,8 +103,8 @@ class RequestTracer:
 
     ``capacity`` bounds memory for a long-lived replica: old requests'
     events fall off the back as new ones append. ``enabled=False`` turns
-    :meth:`event` into an immediate return (the bench's tracing-off
-    mode); flipping it at runtime is safe.
+    :meth:`event` into an immediate return; flipping it at runtime is
+    safe.
     """
 
     def __init__(self, capacity: int = 8192, enabled: bool = True) -> None:
@@ -223,6 +228,206 @@ class RequestTracer:
     def __len__(self) -> int:
         with self._lock:
             return len(self._events)
+
+
+# -- what a thread is doing: spans --------------------------------------
+# Span names are dotted: ``serve.loop.*`` / ``serve.sched.*`` /
+# ``serve.engine.*`` on the replica's loop thread, ``serve.rpc.*`` on the
+# actor's RPC thread, ``fit.*`` on the fit loop. docs/observability.md
+# has the table of sites.
+
+
+_ANNOTATION: Any = None
+
+
+def _annotation_cls() -> Any:
+    """``jax.profiler.TraceAnnotation``, imported on first use: this
+    module stays stdlib-only at import time, and a process that has not
+    loaded jax has no profiler session to annotate."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        from jax.profiler import TraceAnnotation
+
+        _ANNOTATION = TraceAnnotation
+    return _ANNOTATION
+
+
+class SpanTotals:
+    """Monotone totals of the spans of ONE thread's loop: per name the
+    count, summed nanoseconds and longest single span, on
+    ``time.perf_counter_ns``. Nothing is kept per span. The time is SELF
+    time — a span's duration less that of the spans nested directly
+    inside it — so the names of one thread add up to its wall time
+    however they nest.
+
+    It also reckons EXPOSED host time. The code that enqueues device
+    work says when a program goes in flight (:meth:`device_busy`) and
+    when a sync has shown the queue empty (:meth:`device_idle`); while
+    the loop has work (:meth:`work`) and nothing is in flight, the
+    device waits on the host, and that time is charged to the span that
+    is open. A lower bound: a device that runs dry before the host's
+    next sync is seen only by the profiler.
+
+    Spans and marks come from the owning thread; :meth:`snapshot` and
+    :meth:`mirror` may be called from any: the lock guards what they
+    read (the two dictionaries and the working time), nothing else.
+    """
+
+    def __init__(self) -> None:
+        self._lock = threading.Lock()
+        #: name -> [count, summed self ns, longest self ns]
+        self._seg: Dict[str, List[int]] = {}
+        #: name -> ns charged while the loop had work and no device
+        #: program was in flight
+        self._exposed: Dict[str, int] = {}
+        self._work_ns = 0
+        self._working = False
+        self._busy = False
+        #: open spans, innermost last: [name, ns of its closed children]
+        self._open: List[List[Any]] = []
+        #: exposure is charged up to here
+        self._mark = 0
+        #: (seconds, spans) already mirrored into registry counters
+        self._mirrored: Dict[str, Tuple[float, int]] = {}
+
+    # -- the owning thread ------------------------------------------------
+    def _charge(self, now: int) -> None:
+        if self._working and not self._busy and self._open:
+            name = self._open[-1][0]
+            self._exposed[name] = (
+                self._exposed.get(name, 0) + now - self._mark
+            )
+        self._mark = now
+
+    def _enter(self, name: str, now: int) -> None:
+        if self._working:
+            with self._lock:  # may add a name to _exposed
+                self._charge(now)
+        self._open.append([name, 0])
+
+    def _exit(self, name: str, t0: int, now: int) -> None:
+        with self._lock:
+            if self._working:
+                self._charge(now)
+            dur = now - t0
+            own = dur - self._open.pop()[1]
+            if self._open:
+                self._open[-1][1] += dur
+            seg = self._seg.get(name)
+            if seg is None:
+                seg = self._seg[name] = [0, 0, 0]
+            seg[0] += 1
+            seg[1] += own
+            if own > seg[2]:
+                seg[2] = own
+
+    def device_busy(self) -> None:
+        """A device program was enqueued."""
+        with self._lock:
+            self._charge(time.perf_counter_ns())
+            self._busy = True
+
+    def device_idle(self) -> None:
+        """A sync returned and nothing else is in flight."""
+        with self._lock:
+            self._mark = time.perf_counter_ns()
+            self._busy = False
+
+    @contextlib.contextmanager
+    def work(self) -> Iterator[None]:
+        """One iteration of the loop in which it had work to do."""
+        t0 = time.perf_counter_ns()
+        with self._lock:
+            self._mark = t0
+            self._working = True
+        try:
+            yield
+        finally:
+            now = time.perf_counter_ns()
+            with self._lock:
+                self._charge(now)
+                self._working = False
+                self._work_ns += now - t0
+
+    # -- any thread --------------------------------------------------------
+    def snapshot(self) -> Dict[str, Any]:
+        """``{"segments": {name: {"n", "s", "max_s"}}, "exposed_s":
+        {name: s}, "work_s"}`` (``s`` and ``max_s`` self time), all
+        since construction and never decreasing: the difference of two
+        snapshots is exactly the time between them."""
+        with self._lock:
+            seg = {k: list(v) for k, v in self._seg.items()}
+            exposed = dict(self._exposed)
+            work_ns = self._work_ns
+        return {
+            "segments": {
+                k: {"n": n, "s": ns * 1e-9, "max_s": mx * 1e-9}
+                for k, (n, ns, mx) in sorted(seg.items())
+            },
+            "exposed_s": {
+                k: ns * 1e-9 for k, ns in sorted(exposed.items())
+            },
+            "work_s": work_ns * 1e-9,
+        }
+
+    def mirror(self, seconds: Any, spans: Any) -> None:
+        """Bring two registry counters (labelled ``segment``) up to
+        these totals. Called where the registry is read, not per span:
+        the hot path pays for one sink only."""
+        snap = self.snapshot()["segments"]
+        with self._lock:
+            for name, row in snap.items():
+                s0, n0 = self._mirrored.get(name, (0.0, 0))
+                seconds.inc(row["s"] - s0, segment=name)
+                spans.inc(row["n"] - n0, segment=name)
+                self._mirrored[name] = (row["s"], row["n"])
+
+
+class span:  # noqa: N801 - used as ``with span(totals, name, **attrs):``
+    """Time a block into ``totals`` and, exactly while a
+    ``jax.profiler`` session is active in this process, annotate it on
+    the profiler's clock (``TraceAnnotation(name, **attrs)``: the
+    ``/host:CPU`` plane of the same trace as the device's operations).
+    Whoever starts the session turns the annotations on; there is no
+    other switch. With no session a site costs the check, two clock
+    reads and the totals' update."""
+
+    __slots__ = ("_totals", "_name", "_attrs", "_ann", "_t0", "ns")
+
+    def __init__(self, totals: SpanTotals, name: str, **attrs: Any) -> None:
+        self._totals = totals
+        self._name = name
+        self._attrs = attrs
+        self._ann = None
+        #: the block's whole duration (children included), once it ended
+        self.ns = 0
+
+    def __enter__(self) -> "span":
+        cls = _ANNOTATION or _annotation_cls()
+        if cls.is_enabled():
+            self._ann = cls(self._name, **self._attrs)
+            self._ann.__enter__()
+        self._t0 = time.perf_counter_ns()
+        self._totals._enter(self._name, self._t0)
+        return self
+
+    def __exit__(self, *exc: Any) -> None:
+        now = time.perf_counter_ns()
+        self.ns = now - self._t0
+        self._totals._exit(self._name, self._t0, now)
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+
+
+def step_annotation(name: str, step_num: int) -> Any:
+    """``jax.profiler.StepTraceAnnotation(name, step_num=...)`` while a
+    profiler session is active, else a context that does nothing: the
+    profiler's step markers for the fit loop's dispatches."""
+    if (_ANNOTATION or _annotation_cls()).is_enabled():
+        from jax.profiler import StepTraceAnnotation
+
+        return StepTraceAnnotation(name, step_num=int(step_num))
+    return contextlib.nullcontext()
 
 
 # -- Chrome trace-event export -----------------------------------------

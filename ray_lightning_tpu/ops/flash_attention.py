@@ -180,6 +180,7 @@ def _flash_fwd(
             jax.ShapeDtypeStruct((batch * heads, seq_q, 8), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(qf, kf, vf)
     out = out.reshape(batch, heads, seq_q, head_dim).transpose(0, 2, 1, 3)
     lse = lse[:, :, 0].reshape(batch, heads, seq_q)
@@ -371,6 +372,7 @@ def _flash_vjp_bwd(
             jax.ShapeDtypeStruct((batch * heads, seq_k, head_dim), v.dtype),
         ],
         interpret=interpret,
+        name="flash_dkv",
     )(qf, kf, vf, dof, lse8, delta8)
 
     dq = pl.pallas_call(
@@ -396,6 +398,7 @@ def _flash_vjp_bwd(
             jax.ShapeDtypeStruct((batch * heads, seq_q, head_dim), q.dtype)
         ],
         interpret=interpret,
+        name="flash_dq",
     )(qf, kf, vf, dof, lse8, delta8)[0]
 
     unflatten = lambda x, s: x.reshape(  # noqa: E731

@@ -1942,12 +1942,21 @@ class ServeClient:
     def profile(
         self, duration_s: float = 1.0, replica: int = 0
     ) -> Dict[str, Any]:
-        """On-demand jax.profiler capture on one replica (the replica's
-        serve loop keeps running; this blocks ~duration_s)."""
-        return self._rpc(
-            int(replica), "profile", duration_s,
-            timeout=duration_s + 120.0,
-        )
+        """On-demand jax.profiler capture on one replica. The replica
+        captures in a thread of its own and keeps answering calls; this
+        blocks the CALLER until the trace is written (~duration_s plus
+        the profiler's start and write), polling for the result."""
+        started = self._rpc(int(replica), "profile", duration_s)
+        if not started.get("ok"):
+            return started
+        deadline = time.monotonic() + float(duration_s) + 120.0
+        while True:
+            res = self._rpc(int(replica), "profile_result")
+            if not res.get("pending"):
+                return res
+            if time.monotonic() > deadline:
+                return {"ok": False, "error": "profile capture timed out"}
+            time.sleep(0.1)
 
     def shutdown(self) -> None:
         # Leaders first: their stop() pushes the gang sentinel, so any

@@ -172,6 +172,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from ray_lightning_tpu.models.gpt import GPTConfig
+from ray_lightning_tpu.obs.trace import SpanTotals, span
 
 
 @dataclasses.dataclass
@@ -790,6 +791,12 @@ class DecodeEngine:
         #: forensic log cares about (prefix-pool evictions). Set by the
         #: Scheduler/ServeReplica after construction; None = off.
         self.events: Optional[Any] = None
+        #: What the driving thread does, by name (obs.trace.span), and
+        #: the host time the device waits for: this engine says when a
+        #: program goes in flight and when a sync shows the queue empty.
+        #: The Scheduler and the ServeReplica time their own parts of
+        #: the loop into the same totals.
+        self.spans = SpanTotals()
 
         self.compiled_count = 0
         self._compile()
@@ -2018,14 +2025,14 @@ class DecodeEngine:
         cache write + first-token sample + slot-state write), and ALL
         chains are dispatched before the first D2H token sync — the host
         round trip of request i overlaps the device work of requests
-        i+1..n instead of fencing it. Chunked mode: each request walks
+        i+1..n instead of fencing it. (Each request's PRNG-key fetch is
+        a device sync of its own, ahead of its dispatch: see
+        :meth:`_request_key`.) Chunked mode: each request walks
         the prefix pool, dispatches its seeding copies + parking state
         write, and returns ``(slot, None, False)``; chunks then advance
         through :meth:`prefill_step`. Requests are validated up front, so
         a bad spec rejects the whole burst before any device state moves.
         """
-        import jax
-
         free = self.free_slots()
         if len(requests) > len(free):
             raise RuntimeError(
@@ -2053,9 +2060,7 @@ class DecodeEngine:
         if self.chunked:
             out: List[Tuple[int, Optional[int], bool]] = []
             for slot, r, prompt, P, n_new, _, eos in staged:
-                key0 = np.asarray(
-                    jax.random.PRNGKey(int(r.get("seed", 0))), np.uint32
-                ).reshape(2)
+                key0 = self._request_key(r)
                 matched_idxs, matched_tiers = self._match_prefix(prompt)
                 matched = len(matched_idxs) * self.prefix_block
                 if self.prefix_blocks:
@@ -2183,9 +2188,7 @@ class DecodeEngine:
             top_p = r.get("top_p")
             tk = np.int32(0 if top_k is None else top_k)
             tp = np.float32(1.0 if top_p is None else top_p)
-            key0 = np.asarray(
-                jax.random.PRNGKey(int(r.get("seed", 0))), np.uint32
-            ).reshape(2)
+            key0 = self._request_key(r)
             (
                 self._k, self._v, self._cur, self._pos, self._temps,
                 self._top_ks, self._top_ps, self._keys, self._active,
@@ -2198,6 +2201,7 @@ class DecodeEngine:
                 temp, tk, tp, np.int32(n_new), np.int32(eos),
             )
             pending.append((slot, r, n_new, eos, tok))
+            self.spans.device_busy()
             if self.tracer is not None:
                 from ray_lightning_tpu.obs.trace import SPAN_PREFILL
 
@@ -2205,9 +2209,15 @@ class DecodeEngine:
                     r["request_id"], SPAN_PREFILL,
                     attrs={"bucket": pb, "tokens": P, "slot": slot},
                 )
+        # The first-token syncs: the host blocked on the device, behind
+        # the fold in flight and the prefills just enqueued. They are the
+        # last programs enqueued, so once they return the device is idle
+        # (the fold in flight has finished too) until the next dispatch.
+        with span(self.spans, "serve.engine.admit_wait", n=len(pending)):
+            first = [int(np.asarray(tok)) for *_, tok in pending]
+        self.spans.device_idle()
         out: List[Tuple[int, int, bool]] = []
-        for slot, r, n_new, eos, tok in pending:
-            tok = int(np.asarray(tok))
+        for (slot, r, n_new, eos, _), tok in zip(pending, first):
             # Mirrors the in-graph `live` predicate: a request done at
             # its first token never occupies the slot (the device wrote
             # its own active=False).
@@ -2221,6 +2231,21 @@ class DecodeEngine:
                 )
             out.append((slot, tok, done))
         return out
+
+    def _request_key(self, r: Dict[str, Any]) -> np.ndarray:
+        """The request's PRNG key as the host's two words. The key is
+        made on the device and fetched, and that fetch queues behind
+        whatever is in flight: the host blocked on the device, named as
+        such (``serve.engine.key_wait``), and once it returns the device
+        is idle until the host's next enqueue."""
+        import jax
+
+        with span(self.spans, "serve.engine.key_wait"):
+            key0 = np.asarray(
+                jax.random.PRNGKey(int(r.get("seed", 0))), np.uint32
+            ).reshape(2)
+        self.spans.device_idle()
+        return key0
 
     def prefill_step(
         self, max_chunks: int = 1
@@ -2302,6 +2327,7 @@ class DecodeEngine:
                         self._top_ps, self._keys, self._active,
                         self._remaining, self._eos, *scalars,
                     )
+                self.spans.device_busy()
                 task.next += this_len
                 task.chunks += 1
                 if self.tracer is not None:
@@ -2325,7 +2351,10 @@ class DecodeEngine:
                 # tenant can overwrite the slot's rows (decode only
                 # writes at pos >= P, so the prompt rows stay intact).
                 self._insert_prefix(slot, task.tokens)
-                tok = int(np.asarray(tok))  # the one D2H sync per admit
+                # the one D2H sync per admit: behind everything enqueued
+                with span(self.spans, "serve.engine.admit_wait", n=1):
+                    tok = int(np.asarray(tok))
+                self.spans.device_idle()
                 done = task.max_new_tokens == 1 or tok == task.eos_token
                 if not done:
                     self._slots[slot] = SlotInfo(
@@ -3368,6 +3397,22 @@ class DecodeEngine:
         (their first-token samples come back appended), and the fold
         depth K is picked per dispatch from the pre-lowered ladder."""
         k = self._pick_fold_k()
+        with span(
+            self.spans, "serve.engine.dispatch",
+            fold=k, slots=self.num_active,
+        ):
+            out = self._enqueue_fold(k)
+        self.spans.device_busy()
+        return out
+
+    def _enqueue_fold(
+        self, k: int
+    ) -> Tuple[
+        Tuple[Any, Any, Any],
+        List[Optional[SlotInfo]],
+        List[Tuple[int, int, PrefillTask, Optional[SlotInfo]]],
+        int,
+    ]:
         self.fold_dispatches[k] = self.fold_dispatches.get(k, 0) + 1
         self._m_fold_depth.observe(float(k))
         pb_args: Tuple[Any, ...] = ()
@@ -3500,9 +3545,27 @@ class DecodeEngine:
     ) -> List[Tuple[int, str, int, bool]]:
         # The ONE D2H sync per fold: the (K, B) token block + emit mask
         # (K = fold * (spec_depth + 1) with spec on).
-        toks = np.asarray(outs[0])
-        emits = np.asarray(outs[1])
-        # The sync above proves every fold dispatched up to this one has
+        with span(self.spans, "serve.engine.harvest_wait"):
+            toks = np.asarray(outs[0])
+            emits = np.asarray(outs[1])
+        if self._inflight is None:
+            # nothing was dispatched behind this fold: the device is
+            # idle until the host enqueues again
+            self.spans.device_idle()
+        with span(self.spans, "serve.engine.harvest", rows=toks.shape[0]):
+            return self._fan_out(toks, emits, outs, snapshot, pb_finals)
+
+    def _fan_out(
+        self,
+        toks: np.ndarray,
+        emits: np.ndarray,
+        outs: Tuple[Any, Any, Any],
+        snapshot: List[Optional[SlotInfo]],
+        pb_finals: Sequence[
+            Tuple[int, int, PrefillTask, Optional[SlotInfo]]
+        ],
+    ) -> List[Tuple[int, str, int, bool]]:
+        # The harvest's sync proves every fold dispatched up to this one has
         # finished on device — pages quarantined BEFORE this harvest can
         # no longer be scribbled and recycle now. Pages quarantined
         # DURING it (_release_synced below) wait for the next harvest:

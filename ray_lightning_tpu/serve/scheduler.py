@@ -37,6 +37,7 @@ behind device compute — with a folded engine a single dispatch can cover
 """
 from __future__ import annotations
 
+import contextlib
 import heapq
 import itertools
 import threading
@@ -46,6 +47,7 @@ from dataclasses import dataclass, field
 from typing import TYPE_CHECKING, Any, Dict, List, Optional, Sequence
 
 from ray_lightning_tpu.obs import trace as _trace
+from ray_lightning_tpu.obs.trace import SpanTotals, span
 from ray_lightning_tpu.serve.metrics import CANARY_TENANT, ServeMetrics
 
 if TYPE_CHECKING:  # engine pulls jax; keep the package import light
@@ -177,6 +179,10 @@ class Scheduler:
         self.tracer = tracer
         if tracer is not None and getattr(engine, "tracer", None) is None:
             engine.tracer = tracer
+        #: What step() does, by name (obs.trace.span), into the engine's
+        #: totals: its dispatch / harvest spans and the scheduler's are
+        #: consecutive pieces of one loop iteration.
+        self.spans: SpanTotals = getattr(engine, "spans", None) or SpanTotals()
         # The fleet KV plane records its own phase-boundary marks (ship
         # landings, faults) — share this scheduler's tracer/injector so
         # its spans land in the same ring the anatomy ledger stitches.
@@ -189,8 +195,8 @@ class Scheduler:
         #: fold the request's lifecycle timestamps into a compact
         #: {phase: seconds} map emitted to the metrics window (fleet
         #: latency decomposition) and the journal outcome record
-        #: (offline autopsy). Toggleable for the anatomy_overhead bench;
-        #: the per-request cost is a handful of float subtractions.
+        #: (offline autopsy). The per-request cost is a handful of float
+        #: subtractions.
         self.phase_ledger = True
         #: Structured event log (obs.events): coarse lifecycle happenings
         #: (admission bursts, cancels, expiries) — one event per
@@ -772,315 +778,324 @@ class Scheduler:
         it, so submit()/cancel() never wait on device compute."""
         events: List[TokenEvent] = []
         t0 = time.monotonic()
-        # Peer KV handoff + preemption drain ride the loop thread:
-        # apply queued block imports first, then consume any armed drain
-        # request so its cancellations land in THIS step's boundary
-        # scan (engine state never mutates off the driving thread).
-        with self._lock:
-            imports, self._pending_imports = self._pending_imports, []
-        for blocks in imports:
-            self.engine.import_prefix_blocks(blocks)
-        if self.kvfleet is not None:
-            # Fleet KV plane: serve peer fetches (compiled pool reads —
-            # this thread), import inbound ships/fetch responses BEFORE
-            # the admission scan below (so a shipped request admits
-            # warm), and re-queue parked requests whose transfer landed
-            # (warm) or failed (cold prefill — timeout/staleness never
-            # lose the request, they only lose the shortcut).
-            self._service_kvfleet()
-        if self._drain_req is not None:
-            self._apply_drain(events)
-        if self._park_req is not None:
-            self._apply_park()
-        to_evict: List[Any] = []
-        admits: List[Request] = []
-        #: (priority, seq, Request, peer, digests): candidates popped
-        #: for a cross-replica KV fetch instead of admission — the
-        #: fetch RPC runs outside the lock; success parks them
-        #: transfer-pending, refusal re-queues them for cold prefill.
-        to_fetch: List[Any] = []
-        #: (rid, outcome) terminals from ENGINE work this step; their
-        #: ledger records flush after this step's device-seconds are
-        #: attributed, so a request's final fold is in its bill.
-        closed: List[Any] = []
-        with self._lock:
-            resident_rids = [
-                r.request_id for r in self._slot_req.values()
-            ]
-            # 0) Priority aging: re-score the queue so long-waiting
-            # requests drift toward priority 0 (FIFO seq breaks ties, so
-            # an aged request outranks younger same-priority arrivals).
-            if self.priority_age_s is not None and self._pending:
-                self._pending = [
-                    (
-                        max(
-                            0,
-                            r.priority
-                            - int(
-                                (t0 - r.submitted_at) / self.priority_age_s
-                            ),
-                        ),
-                        s,
-                        r,
-                    )
-                    for _, s, r in self._pending
+        spans = self.spans
+        with span(spans, "serve.sched.boundary"):
+            # Peer KV handoff + preemption drain ride the loop thread:
+            # apply queued block imports first, then consume any armed drain
+            # request so its cancellations land in THIS step's boundary
+            # scan (engine state never mutates off the driving thread).
+            with self._lock:
+                imports, self._pending_imports = self._pending_imports, []
+            for blocks in imports:
+                self.engine.import_prefix_blocks(blocks)
+            if self.kvfleet is not None:
+                # Fleet KV plane: serve peer fetches (compiled pool reads —
+                # this thread), import inbound ships/fetch responses BEFORE
+                # the admission scan below (so a shipped request admits
+                # warm), and re-queue parked requests whose transfer landed
+                # (warm) or failed (cold prefill — timeout/staleness never
+                # lose the request, they only lose the shortcut).
+                self._service_kvfleet()
+            if self._drain_req is not None:
+                self._apply_drain(events)
+            if self._park_req is not None:
+                self._apply_park()
+            to_evict: List[Any] = []
+            admits: List[Request] = []
+            #: (priority, seq, Request, peer, digests): candidates popped
+            #: for a cross-replica KV fetch instead of admission — the
+            #: fetch RPC runs outside the lock; success parks them
+            #: transfer-pending, refusal re-queues them for cold prefill.
+            to_fetch: List[Any] = []
+            #: (rid, outcome) terminals from ENGINE work this step; their
+            #: ledger records flush after this step's device-seconds are
+            #: attributed, so a request's final fold is in its bill.
+            closed: List[Any] = []
+            with self._lock:
+                resident_rids = [
+                    r.request_id for r in self._slot_req.values()
                 ]
-                heapq.heapify(self._pending)
-            # 1) Collect boundary evictions of in-flight cancels/expiries
-            # (mid-prefill requests included — release drops their state
-            # machine and unpins their prefix blocks).
-            for slot, req in list(self._slot_req.items()):
-                rid = req.request_id
-                cancelled = rid in self._cancelled
-                if cancelled or req.expired(t0):
-                    del self._slot_req[slot]
-                    self._cancelled.discard(rid)
-                    if rid in self._migrating:
-                        self._migrating.discard(rid)
-                        kind = "migrated"
-                    else:
-                        kind = "cancelled" if cancelled else "expired"
-                    to_evict.append((slot, req, kind))
-            # 2) Pop admission candidates: bounded prefills per step,
-            # sized to the slots that are (or are about to be) free.
-            # Paged engines add a PAGE budget: a candidate is admitted
-            # only while the allocatable pages cover its whole life
-            # (prompt + decode reserve — engine.pages_for); otherwise
-            # the queue head PARKS in place (no pop, priority order
-            # kept) until residents finish and free pages — out of
-            # pages backpressures, it never deadlocks and never lets
-            # an admission fail inside the engine.
-            budget = min(
-                self.max_prefills_per_step,
-                len(self.engine.free_slots()) + len(to_evict),
-            )
-            paged = getattr(self.engine, "paged", False)
-            pages_left = self.engine.pages_available() if paged else 0
-            parked = False
-            while len(admits) < budget and self._pending:
-                prio, seqno, req = self._pending[0]
-                if req.request_id in self._cancelled:
-                    heapq.heappop(self._pending)
-                    self._cancelled.discard(req.request_id)
-                    self.metrics.record_cancel(
-                        queue_depth=self._organic_depth_locked()
-                    )
-                    self._trace(req.request_id, _trace.SPAN_CANCEL)
-                    self._event("cancel", request_id=req.request_id,
-                                where="queued")
-                    self._acct_close(req.request_id, "cancelled")
-                    events.append(
-                        TokenEvent(req.request_id, None, True, "cancelled")
-                    )
-                    continue
-                if req.expired(t0):
-                    heapq.heappop(self._pending)
-                    self.metrics.record_expire(
-                        queue_depth=self._organic_depth_locked()
-                    )
-                    self._trace(req.request_id, _trace.SPAN_EXPIRE)
-                    self._event("expire", level="warn",
-                                request_id=req.request_id, where="queued")
-                    self._acct_close(req.request_id, "expired")
-                    events.append(
-                        TokenEvent(req.request_id, None, True, "expired")
-                    )
-                    continue
-                if self.kvfleet is not None and req.kv_hint is not None:
-                    # Cross-replica prefix sharing: the router said a
-                    # peer holds this prompt's chain — or, with
-                    # ``store: True``, that no live replica does but
-                    # the persistent store does. One attempt per
-                    # request (the hint is consumed here); only worth a
-                    # fetch when the LOCAL tiers hold strictly less
-                    # than the hint promises — the probe is a pure
-                    # host-side digest walk, safe under the lock.
-                    hint, req.kv_hint = req.kv_hint, None
-                    digests = list(hint.get("digests") or [])
-                    peer = hint.get("peer")
-                    from_store = bool(hint.get("store"))
-                    probe = getattr(
-                        self.engine, "cached_prefix_blocks", None
-                    )
-                    if (
-                        digests
-                        and (peer is not None or from_store)
-                        and probe is not None
-                        and getattr(self.engine, "prefix_blocks", 0)
-                        and probe(req.prompt) < len(digests)
-                    ):
+                # 0) Priority aging: re-score the queue so long-waiting
+                # requests drift toward priority 0 (FIFO seq breaks ties, so
+                # an aged request outranks younger same-priority arrivals).
+                if self.priority_age_s is not None and self._pending:
+                    self._pending = [
+                        (
+                            max(
+                                0,
+                                r.priority
+                                - int(
+                                    (t0 - r.submitted_at) / self.priority_age_s
+                                ),
+                            ),
+                            s,
+                            r,
+                        )
+                        for _, s, r in self._pending
+                    ]
+                    heapq.heapify(self._pending)
+                # 1) Collect boundary evictions of in-flight cancels/expiries
+                # (mid-prefill requests included — release drops their state
+                # machine and unpins their prefix blocks).
+                for slot, req in list(self._slot_req.items()):
+                    rid = req.request_id
+                    cancelled = rid in self._cancelled
+                    if cancelled or req.expired(t0):
+                        del self._slot_req[slot]
+                        self._cancelled.discard(rid)
+                        if rid in self._migrating:
+                            self._migrating.discard(rid)
+                            kind = "migrated"
+                        else:
+                            kind = "cancelled" if cancelled else "expired"
+                        to_evict.append((slot, req, kind))
+                # 2) Pop admission candidates: bounded prefills per step,
+                # sized to the slots that are (or are about to be) free.
+                # Paged engines add a PAGE budget: a candidate is admitted
+                # only while the allocatable pages cover its whole life
+                # (prompt + decode reserve — engine.pages_for); otherwise
+                # the queue head PARKS in place (no pop, priority order
+                # kept) until residents finish and free pages — out of
+                # pages backpressures, it never deadlocks and never lets
+                # an admission fail inside the engine.
+                budget = min(
+                    self.max_prefills_per_step,
+                    len(self.engine.free_slots()) + len(to_evict),
+                )
+                paged = getattr(self.engine, "paged", False)
+                pages_left = self.engine.pages_available() if paged else 0
+                parked = False
+                while len(admits) < budget and self._pending:
+                    prio, seqno, req = self._pending[0]
+                    if req.request_id in self._cancelled:
                         heapq.heappop(self._pending)
-                        to_fetch.append((
-                            prio, seqno, req,
-                            None if from_store else int(peer),
-                            digests,
-                        ))
+                        self._cancelled.discard(req.request_id)
+                        self.metrics.record_cancel(
+                            queue_depth=self._organic_depth_locked()
+                        )
+                        self._trace(req.request_id, _trace.SPAN_CANCEL)
+                        self._event("cancel", request_id=req.request_id,
+                                    where="queued")
+                        self._acct_close(req.request_id, "cancelled")
+                        events.append(
+                            TokenEvent(req.request_id, None, True, "cancelled")
+                        )
                         continue
-                if paged:
-                    need = self.engine.pages_for(
-                        len(req.prompt), req.sampling.max_new_tokens
+                    if req.expired(t0):
+                        heapq.heappop(self._pending)
+                        self.metrics.record_expire(
+                            queue_depth=self._organic_depth_locked()
+                        )
+                        self._trace(req.request_id, _trace.SPAN_EXPIRE)
+                        self._event("expire", level="warn",
+                                    request_id=req.request_id, where="queued")
+                        self._acct_close(req.request_id, "expired")
+                        events.append(
+                            TokenEvent(req.request_id, None, True, "expired")
+                        )
+                        continue
+                    if self.kvfleet is not None and req.kv_hint is not None:
+                        # Cross-replica prefix sharing: the router said a
+                        # peer holds this prompt's chain — or, with
+                        # ``store: True``, that no live replica does but
+                        # the persistent store does. One attempt per
+                        # request (the hint is consumed here); only worth a
+                        # fetch when the LOCAL tiers hold strictly less
+                        # than the hint promises — the probe is a pure
+                        # host-side digest walk, safe under the lock.
+                        hint, req.kv_hint = req.kv_hint, None
+                        digests = list(hint.get("digests") or [])
+                        peer = hint.get("peer")
+                        from_store = bool(hint.get("store"))
+                        probe = getattr(
+                            self.engine, "cached_prefix_blocks", None
+                        )
+                        if (
+                            digests
+                            and (peer is not None or from_store)
+                            and probe is not None
+                            and getattr(self.engine, "prefix_blocks", 0)
+                            and probe(req.prompt) < len(digests)
+                        ):
+                            heapq.heappop(self._pending)
+                            to_fetch.append((
+                                prio, seqno, req,
+                                None if from_store else int(peer),
+                                digests,
+                            ))
+                            continue
+                    if paged:
+                        need = self.engine.pages_for(
+                            len(req.prompt), req.sampling.max_new_tokens
+                        )
+                        if need > pages_left:
+                            parked = True
+                            break
+                        pages_left -= need
+                    heapq.heappop(self._pending)
+                    admits.append(req)
+                    self._admitting.add(req.request_id)
+                if parked and not self._kv_parked:
+                    self._event(
+                        "kv_pages_backpressure", level="warn",
+                        queue_depth=len(self._pending),
+                        pages_available=pages_left,
                     )
-                    if need > pages_left:
-                        parked = True
-                        break
-                    pages_left -= need
-                heapq.heappop(self._pending)
-                admits.append(req)
-                self._admitting.add(req.request_id)
-            if parked and not self._kv_parked:
-                self._event(
-                    "kv_pages_backpressure", level="warn",
-                    queue_depth=len(self._pending),
-                    pages_available=pages_left,
+                self._kv_parked = parked
+            # -- engine work, lock NOT held --------------------------------
+            for prio, seqno, req, peer, digests in to_fetch:
+                # The fetch RPC (a queue put, possibly cross-process) runs
+                # here; a refused fetch (budget, unknown peer, bandwidth
+                # cap) re-queues for cold prefill NEXT step — bounded
+                # in-flight bytes never turn into a queue. ``peer is None``
+                # means the hint pointed at the persistent store, not a
+                # live replica; same park→import→admit-warm path, different
+                # resolver.
+                ok = (
+                    self.kvfleet.request_store_fetch(req.request_id, digests)
+                    if peer is None
+                    else self.kvfleet.request_fetch(req.request_id, peer, digests)
                 )
-            self._kv_parked = parked
-        # -- engine work, lock NOT held --------------------------------
-        for prio, seqno, req, peer, digests in to_fetch:
-            # The fetch RPC (a queue put, possibly cross-process) runs
-            # here; a refused fetch (budget, unknown peer, bandwidth
-            # cap) re-queues for cold prefill NEXT step — bounded
-            # in-flight bytes never turn into a queue. ``peer is None``
-            # means the hint pointed at the persistent store, not a
-            # live replica; same park→import→admit-warm path, different
-            # resolver.
-            ok = (
-                self.kvfleet.request_store_fetch(req.request_id, digests)
-                if peer is None
-                else self.kvfleet.request_fetch(req.request_id, peer, digests)
-            )
-            if ok:
-                with self._lock:
-                    self._transfer_pending[req.request_id] = (
-                        prio, seqno, req,
+                if ok:
+                    with self._lock:
+                        self._transfer_pending[req.request_id] = (
+                            prio, seqno, req,
+                        )
+                    acct = self._acct.get(req.request_id)
+                    if acct is not None:
+                        acct["_kv_park_t"] = time.monotonic()
+                        acct["_kv_src"] = "store" if peer is None else "peer"
+                    self._trace(
+                        req.request_id,
+                        _trace.SPAN_KVSTORE_FETCH if peer is None
+                        else _trace.SPAN_KV_FETCH,
+                        peer=peer, blocks=len(digests),
                     )
-                acct = self._acct.get(req.request_id)
-                if acct is not None:
-                    acct["_kv_park_t"] = time.monotonic()
-                    acct["_kv_src"] = "store" if peer is None else "peer"
-                self._trace(
-                    req.request_id,
-                    _trace.SPAN_KVSTORE_FETCH if peer is None
-                    else _trace.SPAN_KV_FETCH,
-                    peer=peer, blocks=len(digests),
-                )
-                self._event(
-                    "kv_transfer_park", request_id=req.request_id,
-                    peer=peer, blocks=len(digests),
-                    store=peer is None,
-                )
-            else:
-                with self._lock:
-                    heapq.heappush(self._pending, (prio, seqno, req))
-        for slot, req, kind in to_evict:
-            self.engine.release(slot)
-            (self.metrics.record_expire if kind == "expired"
-             else self.metrics.record_cancel)(
-                queue_depth=self.queue_depth()
-            )
-            self._trace(
-                req.request_id,
-                _trace.SPAN_EXPIRE if kind == "expired"
-                else _trace.SPAN_CANCEL,
-                slot=slot,
-            )
-            self._event(
-                "expire" if kind == "expired" else "cancel",
-                level="warn" if kind == "expired" else "info",
-                request_id=req.request_id, where="slot", slot=slot,
-                migrated=kind == "migrated",
-            )
-            closed.append((req.request_id, kind))
-            events.append(TokenEvent(req.request_id, None, True, kind))
+                    self._event(
+                        "kv_transfer_park", request_id=req.request_id,
+                        peer=peer, blocks=len(digests),
+                        store=peer is None,
+                    )
+                else:
+                    with self._lock:
+                        heapq.heappush(self._pending, (prio, seqno, req))
         newly: Dict[int, Request] = {}
         finished_rids: List[str] = []
         finished_slots: List[int] = []
-        if admits:
-            # One burst: every admission chain is dispatched before the
-            # first token sync (engine.admit_many), so admission i's host
-            # round trip overlaps admission i+1's prefill. Chunked
-            # engines return first_tok=None here — the first token
-            # arrives from prefill_step below once the final chunk runs.
-            t_admit = time.monotonic()
-            results = self.engine.admit_many(
-                [
-                    dict(
-                        prompt=req.prompt,
-                        request_id=req.request_id,
-                        max_new_tokens=req.sampling.max_new_tokens,
-                        temperature=req.sampling.temperature,
-                        top_k=req.sampling.top_k,
-                        top_p=req.sampling.top_p,
-                        seed=req.sampling.seed,
-                        eos_token=req.sampling.eos_token,
-                    )
-                    for req in admits
-                ]
-            )
-            # One event per BURST, not per admission — the hot loop's
-            # event budget.
-            self._event(
-                "admit_burst", n=len(admits),
-                queue_depth=self.queue_depth(),
-            )
-            for req, (slot, first_tok, done) in zip(admits, results):
-                req.admitted_at = t_admit
-                self.metrics.record_admit(
-                    t_admit - req.submitted_at, self.queue_depth()
+        # Opened only on steps that admit or evict, so its count is the
+        # count of admission bursts.
+        with span(
+            spans, "serve.sched.admit", n=len(admits),
+            longest_prompt=max((len(r.prompt) for r in admits), default=0),
+            evicted=len(to_evict),
+        ) if admits or to_evict else contextlib.nullcontext():
+            for slot, req, kind in to_evict:
+                self.engine.release(slot)
+                (self.metrics.record_expire if kind == "expired"
+                 else self.metrics.record_cancel)(
+                    queue_depth=self.queue_depth()
                 )
-                acct = self._acct.get(req.request_id)
-                if acct is not None:
-                    acct["queue_s"] = t_admit - req.submitted_at
-                    acct["_admit_t"] = t_admit
-                # Record-time timestamp (not t_admit): the engine's own
-                # admission-block events (prefix_seed) land between
-                # queued and here, and a trace's timestamps must be
-                # monotonic in record order. queue_s keeps the exact
-                # admission clock.
                 self._trace(
-                    req.request_id, _trace.SPAN_ADMITTED,
+                    req.request_id,
+                    _trace.SPAN_EXPIRE if kind == "expired"
+                    else _trace.SPAN_CANCEL,
                     slot=slot,
-                    queue_s=round(t_admit - req.submitted_at, 6),
                 )
-                if first_tok is None:
-                    newly[slot] = req  # chunked prefill in progress
-                    continue
-                now = time.monotonic()
-                self.metrics.record_first_token(
-                    now - req.submitted_at, now - t_admit, 1, 0,
-                    len(req.prompt),
+                self._event(
+                    "expire" if kind == "expired" else "cancel",
+                    level="warn" if kind == "expired" else "info",
+                    request_id=req.request_id, where="slot", slot=slot,
+                    migrated=kind == "migrated",
                 )
-                self._trace(
-                    req.request_id, _trace.SPAN_FIRST_TOKEN, t=now,
-                    ttft_s=round(now - req.submitted_at, 6),
+                closed.append((req.request_id, kind))
+                events.append(TokenEvent(req.request_id, None, True, kind))
+            if admits:
+                # One burst: every admission chain is dispatched before the
+                # first token sync (engine.admit_many), so admission i's host
+                # round trip overlaps admission i+1's prefill. Chunked
+                # engines return first_tok=None here — the first token
+                # arrives from prefill_step below once the final chunk runs.
+                t_admit = time.monotonic()
+                results = self.engine.admit_many(
+                    [
+                        dict(
+                            prompt=req.prompt,
+                            request_id=req.request_id,
+                            max_new_tokens=req.sampling.max_new_tokens,
+                            temperature=req.sampling.temperature,
+                            top_k=req.sampling.top_k,
+                            top_p=req.sampling.top_p,
+                            seed=req.sampling.seed,
+                            eos_token=req.sampling.eos_token,
+                        )
+                        for req in admits
+                    ]
                 )
-                if acct is not None:
-                    acct["emitted_tokens"] += 1
-                    acct["_ttft_s"] = now - req.submitted_at
-                if self.journal is not None:
-                    self._jr_tokens[req.request_id] = [int(first_tok)]
-                    self._jr_ttft[req.request_id] = (
-                        now - req.submitted_at
+                # One event per BURST, not per admission — the hot loop's
+                # event budget.
+                self._event(
+                    "admit_burst", n=len(admits),
+                    queue_depth=self.queue_depth(),
+                )
+                for req, (slot, first_tok, done) in zip(admits, results):
+                    req.admitted_at = t_admit
+                    self.metrics.record_admit(
+                        t_admit - req.submitted_at, self.queue_depth()
                     )
-                events.append(
-                    TokenEvent(
-                        req.request_id, first_tok, done,
-                        "finished" if done else "token",
+                    acct = self._acct.get(req.request_id)
+                    if acct is not None:
+                        acct["queue_s"] = t_admit - req.submitted_at
+                        acct["_admit_t"] = t_admit
+                    # Record-time timestamp (not t_admit): the engine's own
+                    # admission-block events (prefix_seed) land between
+                    # queued and here, and a trace's timestamps must be
+                    # monotonic in record order. queue_s keeps the exact
+                    # admission clock.
+                    self._trace(
+                        req.request_id, _trace.SPAN_ADMITTED,
+                        slot=slot,
+                        queue_s=round(t_admit - req.submitted_at, 6),
                     )
-                )
-                if done:
-                    self.metrics.record_finish(
-                        queue_depth=self.queue_depth()
+                    if first_tok is None:
+                        newly[slot] = req  # chunked prefill in progress
+                        continue
+                    now = time.monotonic()
+                    self.metrics.record_first_token(
+                        now - req.submitted_at, now - t_admit, 1, 0,
+                        len(req.prompt),
                     )
-                    self._trace(req.request_id, _trace.SPAN_FINISH)
-                    finished_rids.append(req.request_id)
-                    closed.append((req.request_id, "finished"))
-                else:
-                    newly[slot] = req
-        if admits:
-            # Fault point: requests hold slots, chunked ones have no
-            # first token yet — dying here strands admitted-not-started
-            # work (the failover set's hardest case).
-            self._fault("post_admit")
+                    self._trace(
+                        req.request_id, _trace.SPAN_FIRST_TOKEN, t=now,
+                        ttft_s=round(now - req.submitted_at, 6),
+                    )
+                    if acct is not None:
+                        acct["emitted_tokens"] += 1
+                        acct["_ttft_s"] = now - req.submitted_at
+                    if self.journal is not None:
+                        self._jr_tokens[req.request_id] = [int(first_tok)]
+                        self._jr_ttft[req.request_id] = (
+                            now - req.submitted_at
+                        )
+                    events.append(
+                        TokenEvent(
+                            req.request_id, first_tok, done,
+                            "finished" if done else "token",
+                        )
+                    )
+                    if done:
+                        self.metrics.record_finish(
+                            queue_depth=self.queue_depth()
+                        )
+                        self._trace(req.request_id, _trace.SPAN_FINISH)
+                        finished_rids.append(req.request_id)
+                        closed.append((req.request_id, "finished"))
+                    else:
+                        newly[slot] = req
+            if admits:
+                # Fault point: requests hold slots, chunked ones have no
+                # first token yet — dying here strands admitted-not-started
+                # work (the failover set's hardest case).
+                self._fault("post_admit")
         # 3) Advance chunked prefills. Two shapes: the classic
         # chunk-vs-fold interleave (separate prefill_step dispatches
         # competing with the fold for device time), or — with
@@ -1090,201 +1105,203 @@ class Scheduler:
         # count first: the fault hook below must fire on every step
         # that ADVANCED a chunk, not only the one that completed a
         # prefill — "mid-prefill" is the point.)
-        piggyback = getattr(self.engine, "piggyback_chunks", 0) > 0
-        prefilling = getattr(self.engine, "num_prefilling", 0)
-        chunk_events = (
-            []
-            if piggyback
-            else self.engine.prefill_step(self.max_prefill_chunks_per_step)
-        )
-        prefilled = self._finish_prefills(
-            chunk_events, newly, events, finished_rids, finished_slots,
-            closed,
-        )
-        if not piggyback and (chunk_events or prefilling):
-            # Fault point: a multi-chunk prompt is part-way through its
-            # prefill (device KV holds a partial range nobody can read
-            # back — the request MUST be replayed from its submit).
-            self._fault("mid_prefill_chunk")
+        with span(spans, "serve.sched.prefill_chunks"):
+            piggyback = getattr(self.engine, "piggyback_chunks", 0) > 0
+            prefilling = getattr(self.engine, "num_prefilling", 0)
+            chunk_events = (
+                []
+                if piggyback
+                else self.engine.prefill_step(self.max_prefill_chunks_per_step)
+            )
+            prefilled = self._finish_prefills(
+                chunk_events, newly, events, finished_rids, finished_slots,
+                closed,
+            )
+            if not piggyback and (chunk_events or prefilling):
+                # Fault point: a multi-chunk prompt is part-way through its
+                # prefill (device KV holds a partial range nobody can read
+                # back — the request MUST be replayed from its submit).
+                self._fault("mid_prefill_chunk")
         # 4) One engine fold for everything resident (up to decode_fold
         # tokens per slot fan out of a single dispatch+harvest).
         active = self.engine.num_active
         emitted = 0
         fold_results = self.engine.step()
-        if piggyback:
-            # Piggybacked chunk rows rode INSIDE that fold dispatch;
-            # their completions drain here and flow through the same
-            # finish path (first-token metrics, writethrough, ship) —
-            # one dispatch did all the work, the host accounting is
-            # identical either way.
-            pb_events = self.engine.pop_chunk_events()
-            if pb_events:
-                chunk_events = list(chunk_events) + pb_events
-                prefilled += self._finish_prefills(
-                    pb_events, newly, events, finished_rids,
-                    finished_slots, closed, piggyback=True,
-                )
-            if pb_events or prefilling:
-                # Same fault point as the separate-dispatch path, just
-                # after the fused fold that advanced the chunks.
-                self._fault("mid_prefill_chunk")
-        # Tokens per request this fold: the shared granularity of the
-        # decode-side trace events, the spec attribution, and the cost
-        # ledger (one dict pass per fold, never per token).
-        fold_tokens: Dict[str, int] = {}
-        for _, rid, _, _ in fold_results:
-            fold_tokens[rid] = fold_tokens.get(rid, 0) + 1
-        if getattr(self.engine, "spec", "off") != "off":
-            # Accept accounting: the engine's cumulative counters diffed
-            # into this step's delta (zombie tokens already excluded at
-            # harvest). One metrics record per step, never per token.
-            v = self.engine.spec_verifies
-            d = self.engine.spec_drafted_tokens
-            a = self.engine.spec_accepted_tokens
-            dv = v - self._spec_seen[0]
-            if dv:
-                da = a - self._spec_seen[2]
-                self.metrics.record_spec(dv, d - self._spec_seen[1], da)
-                # Ledger attribution: the verify forwards are batched
-                # over slots, so per-request shares are estimates —
-                # accepted tokens proportional to tokens emitted this
-                # fold, verifies split evenly among the riders.
-                total = sum(fold_tokens.values())
-                for rid, n in fold_tokens.items():
-                    acct = self._acct.get(rid)
-                    if acct is not None:
-                        acct["spec_verifies"] += dv / len(fold_tokens)
-                        if total:
-                            acct["spec_accepted_tokens"] += da * n / total
-                if self.tracer is not None:
+        with span(spans, "serve.sched.account", tokens=len(fold_results)):
+            if piggyback:
+                # Piggybacked chunk rows rode INSIDE that fold dispatch;
+                # their completions drain here and flow through the same
+                # finish path (first-token metrics, writethrough, ship) —
+                # one dispatch did all the work, the host accounting is
+                # identical either way.
+                pb_events = self.engine.pop_chunk_events()
+                if pb_events:
+                    chunk_events = list(chunk_events) + pb_events
+                    prefilled += self._finish_prefills(
+                        pb_events, newly, events, finished_rids,
+                        finished_slots, closed, piggyback=True,
+                    )
+                if pb_events or prefilling:
+                    # Same fault point as the separate-dispatch path, just
+                    # after the fused fold that advanced the chunks.
+                    self._fault("mid_prefill_chunk")
+            # Tokens per request this fold: the shared granularity of the
+            # decode-side trace events, the spec attribution, and the cost
+            # ledger (one dict pass per fold, never per token).
+            fold_tokens: Dict[str, int] = {}
+            for _, rid, _, _ in fold_results:
+                fold_tokens[rid] = fold_tokens.get(rid, 0) + 1
+            if getattr(self.engine, "spec", "off") != "off":
+                # Accept accounting: the engine's cumulative counters diffed
+                # into this step's delta (zombie tokens already excluded at
+                # harvest). One metrics record per step, never per token.
+                v = self.engine.spec_verifies
+                d = self.engine.spec_drafted_tokens
+                a = self.engine.spec_accepted_tokens
+                dv = v - self._spec_seen[0]
+                if dv:
+                    da = a - self._spec_seen[2]
+                    self.metrics.record_spec(dv, d - self._spec_seen[1], da)
+                    # Ledger attribution: the verify forwards are batched
+                    # over slots, so per-request shares are estimates —
+                    # accepted tokens proportional to tokens emitted this
+                    # fold, verifies split evenly among the riders.
+                    total = sum(fold_tokens.values())
                     for rid, n in fold_tokens.items():
-                        self.tracer.event(
-                            rid, _trace.SPAN_SPEC_VERIFY,
-                            attrs={
-                                "tokens": n,
-                                "drafted": d - self._spec_seen[1],
-                                "accepted": da,
-                            },
-                        )
-            self._spec_seen = (v, d, a)
-        # Tiered prefix cache: diff the engine's cumulative per-tier
-        # counters into one metrics record per step that saw tier
-        # traffic (admissions walk the tiers; steady decode never does).
-        tier_fn = getattr(self.engine, "prefix_tier_counters", None)
-        if tier_fn is not None and getattr(self.engine, "prefix_blocks", 0):
-            tiers = tier_fn()
-            if tiers != self._prefix_seen:
-                seen = self._prefix_seen
-                self.metrics.record_prefix_tiers(
-                    {
-                        t: {
-                            k: n - seen.get(t, {}).get(k, 0)
+                        acct = self._acct.get(rid)
+                        if acct is not None:
+                            acct["spec_verifies"] += dv / len(fold_tokens)
+                            if total:
+                                acct["spec_accepted_tokens"] += da * n / total
+                    if self.tracer is not None:
+                        for rid, n in fold_tokens.items():
+                            self.tracer.event(
+                                rid, _trace.SPAN_SPEC_VERIFY,
+                                attrs={
+                                    "tokens": n,
+                                    "drafted": d - self._spec_seen[1],
+                                    "accepted": da,
+                                },
+                            )
+                self._spec_seen = (v, d, a)
+            # Tiered prefix cache: diff the engine's cumulative per-tier
+            # counters into one metrics record per step that saw tier
+            # traffic (admissions walk the tiers; steady decode never does).
+            tier_fn = getattr(self.engine, "prefix_tier_counters", None)
+            if tier_fn is not None and getattr(self.engine, "prefix_blocks", 0):
+                tiers = tier_fn()
+                if tiers != self._prefix_seen:
+                    seen = self._prefix_seen
+                    self.metrics.record_prefix_tiers(
+                        {
+                            t: {
+                                k: n - seen.get(t, {}).get(k, 0)
+                                for k, n in kv.items()
+                            }
+                            for t, kv in tiers.items()
+                        },
+                        self.engine.prefix_tier_bytes(),
+                    )
+                    self._prefix_seen = tiers
+            # Paged KV: diff the engine's cumulative page-allocator counters
+            # into one metrics record per step that saw page traffic, and
+            # refresh the state gauges (free/resident/aliased) alongside.
+            if getattr(self.engine, "paged", False):
+                kv = self.engine.kv_page_counters()
+                if kv != self._kv_seen:
+                    self.metrics.record_kv_pages(
+                        {
+                            k: n - self._kv_seen.get(k, 0)
                             for k, n in kv.items()
-                        }
-                        for t, kv in tiers.items()
-                    },
-                    self.engine.prefix_tier_bytes(),
-                )
-                self._prefix_seen = tiers
-        # Paged KV: diff the engine's cumulative page-allocator counters
-        # into one metrics record per step that saw page traffic, and
-        # refresh the state gauges (free/resident/aliased) alongside.
-        if getattr(self.engine, "paged", False):
-            kv = self.engine.kv_page_counters()
-            if kv != self._kv_seen:
-                self.metrics.record_kv_pages(
-                    {
-                        k: n - self._kv_seen.get(k, 0)
-                        for k, n in kv.items()
-                    },
-                    self.engine.kv_page_stats(),
-                )
-                self._kv_seen = kv
-        for rid, n in fold_tokens.items():
-            acct = self._acct.get(rid)
-            if acct is not None:
-                acct["decode_folds"] += 1
-                acct["emitted_tokens"] += n
-        if self.tracer is not None and fold_tokens:
-            # One event per request per fold (not per token): "this fold,
-            # this request rode it for n tokens" — the decode-side trace
-            # granularity the hot loop can afford. Recorded before the
-            # finish events below so a trace's fold events always precede
-            # its terminal span.
+                        },
+                        self.engine.kv_page_stats(),
+                    )
+                    self._kv_seen = kv
             for rid, n in fold_tokens.items():
-                self.tracer.event(
-                    rid, _trace.SPAN_DECODE_FOLD, attrs={"tokens": n}
-                )
-        jr_on = self.journal is not None
-        for slot, rid, tok, done in fold_results:
-            emitted += 1
-            if jr_on:
-                self._jr_tokens.setdefault(rid, []).append(int(tok))
-            events.append(
-                TokenEvent(rid, tok, done, "finished" if done else "token")
-            )
-            if done:
-                self.metrics.record_finish(queue_depth=self.queue_depth())
-                self._trace(rid, _trace.SPAN_FINISH)
-                finished_slots.append(slot)
-                finished_rids.append(rid)
-                closed.append((rid, "finished"))
-        if fold_results:
-            # Fault point: a decode fold's tokens are harvested (and
-            # journaled below) but the step has not returned — mid-decode
-            # death with partially-streamed outputs.
-            self._fault("fold_boundary")
-        with self._lock:
-            self._slot_req.update(newly)
-            for req in admits:
-                self._admitting.discard(req.request_id)
-            for slot in finished_slots:
-                self._slot_req.pop(slot, None)
-            # Purge cancels that raced a same-fold finish: the id left
-            # _slot_req above, so the next eviction scan would never see
-            # it — without this, a cancel landing while the lock-free
-            # engine section ran would pin the id in _cancelled forever
-            # and spuriously evict a later request reusing it.
-            self._cancelled.difference_update(finished_rids)
-            self._migrating.difference_update(finished_rids)
-        # Device-seconds attribution: this step's wall time split evenly
-        # over the requests that held engine state through it (resident
-        # slots + this step's admissions). An estimate by construction —
-        # the fold executes all resident slots in one batched dispatch —
-        # but it sums exactly to serving wall time, so fleet goodput
-        # (tokens per device-second) is conserved.
-        wall = time.monotonic() - t0
-        participants = set(resident_rids)
-        participants.update(req.request_id for req in admits)
-        participants.update(fold_tokens)
-        participants.update(ev[1].request_id for ev in chunk_events)
-        if participants:
-            share = wall / len(participants)
-            for rid in participants:
                 acct = self._acct.get(rid)
                 if acct is not None:
-                    acct["device_s"] += share
-        for rid, outcome in closed:
-            self._acct_close(rid, outcome)
-        if any(outcome == "finished" for _, outcome in closed):
-            # Fault point: the terminal ledger/journal flush happened but
-            # the finish events never reach the replica's buffers — the
-            # replica RECORDED an outcome the client never saw, so the
-            # client-side journal must still classify it incomplete and
-            # resubmit (dedup keeps the stream exact).
-            self._fault("post_finish_pre_ack")
-        # Token accounting must be EXACT (the ledger balances against
-        # it): count only admissions that really emitted a first token —
-        # chunked admissions return None and their token is counted at
-        # prefill completion.
-        admit_tokens = sum(
-            1 for _, first_tok, _ in (results if admits else [])
-            if first_tok is not None
-        )
-        self.metrics.record_step(
-            wall, active,
-            emitted + prefilled + admit_tokens, self.queue_depth(),
-        )
+                    acct["decode_folds"] += 1
+                    acct["emitted_tokens"] += n
+            if self.tracer is not None and fold_tokens:
+                # One event per request per fold (not per token): "this fold,
+                # this request rode it for n tokens" — the decode-side trace
+                # granularity the hot loop can afford. Recorded before the
+                # finish events below so a trace's fold events always precede
+                # its terminal span.
+                for rid, n in fold_tokens.items():
+                    self.tracer.event(
+                        rid, _trace.SPAN_DECODE_FOLD, attrs={"tokens": n}
+                    )
+            jr_on = self.journal is not None
+            for slot, rid, tok, done in fold_results:
+                emitted += 1
+                if jr_on:
+                    self._jr_tokens.setdefault(rid, []).append(int(tok))
+                events.append(
+                    TokenEvent(rid, tok, done, "finished" if done else "token")
+                )
+                if done:
+                    self.metrics.record_finish(queue_depth=self.queue_depth())
+                    self._trace(rid, _trace.SPAN_FINISH)
+                    finished_slots.append(slot)
+                    finished_rids.append(rid)
+                    closed.append((rid, "finished"))
+            if fold_results:
+                # Fault point: a decode fold's tokens are harvested (and
+                # journaled below) but the step has not returned — mid-decode
+                # death with partially-streamed outputs.
+                self._fault("fold_boundary")
+            with self._lock:
+                self._slot_req.update(newly)
+                for req in admits:
+                    self._admitting.discard(req.request_id)
+                for slot in finished_slots:
+                    self._slot_req.pop(slot, None)
+                # Purge cancels that raced a same-fold finish: the id left
+                # _slot_req above, so the next eviction scan would never see
+                # it — without this, a cancel landing while the lock-free
+                # engine section ran would pin the id in _cancelled forever
+                # and spuriously evict a later request reusing it.
+                self._cancelled.difference_update(finished_rids)
+                self._migrating.difference_update(finished_rids)
+            # Device-seconds attribution: this step's wall time split evenly
+            # over the requests that held engine state through it (resident
+            # slots + this step's admissions). An estimate by construction —
+            # the fold executes all resident slots in one batched dispatch —
+            # but it sums exactly to serving wall time, so fleet goodput
+            # (tokens per device-second) is conserved.
+            wall = time.monotonic() - t0
+            participants = set(resident_rids)
+            participants.update(req.request_id for req in admits)
+            participants.update(fold_tokens)
+            participants.update(ev[1].request_id for ev in chunk_events)
+            if participants:
+                share = wall / len(participants)
+                for rid in participants:
+                    acct = self._acct.get(rid)
+                    if acct is not None:
+                        acct["device_s"] += share
+            for rid, outcome in closed:
+                self._acct_close(rid, outcome)
+            if any(outcome == "finished" for _, outcome in closed):
+                # Fault point: the terminal ledger/journal flush happened but
+                # the finish events never reach the replica's buffers — the
+                # replica RECORDED an outcome the client never saw, so the
+                # client-side journal must still classify it incomplete and
+                # resubmit (dedup keeps the stream exact).
+                self._fault("post_finish_pre_ack")
+            # Token accounting must be EXACT (the ledger balances against
+            # it): count only admissions that really emitted a first token —
+            # chunked admissions return None and their token is counted at
+            # prefill completion.
+            admit_tokens = sum(
+                1 for _, first_tok, _ in (results if admits else [])
+                if first_tok is not None
+            )
+            self.metrics.record_step(
+                wall, active,
+                emitted + prefilled + admit_tokens, self.queue_depth(),
+            )
         return events
 
     def _finish_prefills(

@@ -14,6 +14,8 @@ from __future__ import annotations
 import threading
 from typing import Any, Dict, List, Optional, Sequence
 
+from ray_lightning_tpu.obs.trace import span
+
 
 def load_serve_params(
     ckpt_path: str, model_config: Optional[Dict[str, Any]] = None
@@ -501,11 +503,14 @@ class ServeReplica:
         from ray_lightning_tpu.obs import blackbox as obs_blackbox
         from ray_lightning_tpu.obs import health as obs_health
         from ray_lightning_tpu.obs.events import get_event_log
-        from ray_lightning_tpu.obs.jaxmon import install_compile_listener
+        from ray_lightning_tpu.obs.jaxmon import (
+            install_compile_listener,
+            install_gc_hook,
+        )
         from ray_lightning_tpu.obs.registry import get_registry
         from ray_lightning_tpu.serve.metrics import ServeMetrics
         from ray_lightning_tpu.serve.scheduler import Scheduler
-        from ray_lightning_tpu.obs.trace import RequestTracer
+        from ray_lightning_tpu.obs.trace import RequestTracer, SpanTotals
 
         # Gang leader on a multi-host mesh: rendezvous FIRST — after
         # jax.distributed.initialize every gang member sees the global
@@ -581,6 +586,25 @@ class ServeReplica:
             "rlt_serve_compiled_executables",
             "Engine executables compiled at construction",
         ).set(self.engine.compiled_count)
+        # What the host does, by name (obs.trace.span): the loop
+        # thread's totals are the engine's (it reckons exposed host time
+        # from its own in-flight state); the RPC thread has its own, so
+        # the two threads never share a clock of open spans.
+        self.spans: SpanTotals = self.engine.spans
+        self._rpc_spans = SpanTotals()
+        self._span_seconds = self._registry.counter(
+            "rlt_serve_loop_seconds_total",
+            "Replica host seconds by span (loop thread and RPC surface)",
+        )
+        self._span_count = self._registry.counter(
+            "rlt_serve_loop_spans_total",
+            "Replica host spans completed, by span",
+        )
+        self._gc_seconds = self._registry.counter(
+            "rlt_gc_pause_seconds_total",
+            "Seconds the cyclic collector paused this process, by generation",
+        )
+        self._capture: Any = None
         # Warm the PRNGKey builder before the compile baseline: the first
         # submit would otherwise compile it in a fresh process and
         # spuriously trip compiles_since_init.
@@ -831,6 +855,11 @@ class ServeReplica:
         self._cond = threading.Condition()
         self._stop = threading.Event()
         self._work = threading.Event()
+        # The collector's pauses stall the loop from inside the runtime
+        # just as a compile does: count them from here on (stop() takes
+        # the hook out again).
+        self._gc: Any = install_gc_hook()
+        self._gc_hooked = True
         self._thread = threading.Thread(
             target=self._loop, name="serve-replica-loop", daemon=True
         )
@@ -838,41 +867,48 @@ class ServeReplica:
 
     # -- loop thread (owns all jax work) ----------------------------------
     def _loop(self) -> None:
+        spans = self.spans
         while not self._stop.is_set():
             if not self.scheduler.has_work():
-                self._work.wait(timeout=0.1)
+                with span(spans, "serve.loop.idle"):
+                    self._work.wait(timeout=0.1)
                 self._work.clear()
                 continue
-            events = self.scheduler.step()
-            if events:
-                with self._cond:
-                    for ev in events:
-                        buf = self._buffers.setdefault(
-                            ev.request_id,
-                            {"tokens": [], "done": False, "status": "running"},
+            with spans.work():
+                events = self.scheduler.step()
+                with span(spans, "serve.loop.publish", events=len(events)):
+                    if events:
+                        self._publish(events)
+                    self.metrics.maybe_log()
+                if self._tick:
+                    with span(spans, "serve.loop.tick"):
+                        self._stop.wait(self._tick)
+
+    def _publish(self, events: Sequence[Any]) -> None:
+        with self._cond:
+            for ev in events:
+                buf = self._buffers.setdefault(
+                    ev.request_id,
+                    {"tokens": [], "done": False, "status": "running"},
+                )
+                if ev.token is not None:
+                    buf["tokens"].append(ev.token)
+                if ev.done:
+                    buf["done"] = True
+                    buf["status"] = (
+                        "finished" if ev.reason in ("token", "finished")
+                        else ev.reason
+                    )
+                    target = getattr(ev, "ship_to", None)
+                    if target is not None:
+                        # Disagg handoff: the client resubmits to this
+                        # decode replica and the stream continues warm
+                        # there.
+                        buf["ship_to"] = int(target)
+                        buf["ship_digests"] = list(
+                            getattr(ev, "ship_digests", None) or []
                         )
-                        if ev.token is not None:
-                            buf["tokens"].append(ev.token)
-                        if ev.done:
-                            buf["done"] = True
-                            buf["status"] = (
-                                "finished" if ev.reason in ("token", "finished")
-                                else ev.reason
-                            )
-                            target = getattr(ev, "ship_to", None)
-                            if target is not None:
-                                # Disagg handoff: the client resubmits
-                                # to this decode replica and the stream
-                                # continues warm there.
-                                buf["ship_to"] = int(target)
-                                buf["ship_digests"] = list(
-                                    getattr(ev, "ship_digests", None)
-                                    or []
-                                )
-                    self._cond.notify_all()
-            self.metrics.maybe_log()
-            if self._tick:
-                self._stop.wait(self._tick)
+            self._cond.notify_all()
 
     # -- RPC surface ------------------------------------------------------
     def ping(self) -> str:
@@ -905,28 +941,31 @@ class ServeReplica:
 
         if self.faults is not None:
             self.faults.hit("rpc_submit")
-        rid = self.scheduler.submit(
-            prompt,
-            SamplingParams(
-                max_new_tokens=max_new_tokens,
-                temperature=temperature,
-                top_k=top_k,
-                top_p=top_p,
-                seed=seed,
-                eos_token=eos_token,
-            ),
-            request_id=request_id,
-            priority=priority,
-            deadline_s=deadline_s,
-            tenant=tenant,
-            kv_hint=kv_hint,
-            ship_to=ship_to,
-        )
-        with self._cond:
-            self._buffers[rid] = {
-                "tokens": [], "done": False, "status": "queued",
-            }
-        self._work.set()
+        with span(
+            self._rpc_spans, "serve.rpc.submit", request_id=request_id or ""
+        ):
+            rid = self.scheduler.submit(
+                prompt,
+                SamplingParams(
+                    max_new_tokens=max_new_tokens,
+                    temperature=temperature,
+                    top_k=top_k,
+                    top_p=top_p,
+                    seed=seed,
+                    eos_token=eos_token,
+                ),
+                request_id=request_id,
+                priority=priority,
+                deadline_s=deadline_s,
+                tenant=tenant,
+                kv_hint=kv_hint,
+                ship_to=ship_to,
+            )
+            with self._cond:
+                self._buffers[rid] = {
+                    "tokens": [], "done": False, "status": "queued",
+                }
+            self._work.set()
         return rid
 
     def submit_many(
@@ -942,32 +981,33 @@ class ServeReplica:
         from ray_lightning_tpu.serve.scheduler import SamplingParams
 
         rids: List[str] = []
-        for req in requests:
-            if self.faults is not None:
-                self.faults.hit("rpc_submit")
-            rids.append(self.scheduler.submit(
-                req["prompt"],
-                SamplingParams(
-                    max_new_tokens=req.get("max_new_tokens", 32),
-                    temperature=req.get("temperature", 0.0),
-                    top_k=req.get("top_k"),
-                    top_p=req.get("top_p"),
-                    seed=req.get("seed", 0),
-                    eos_token=req.get("eos_token"),
-                ),
-                request_id=req.get("request_id"),
-                priority=req.get("priority", 0),
-                deadline_s=req.get("deadline_s"),
-                tenant=req.get("tenant"),
-                kv_hint=req.get("kv_hint"),
-                ship_to=req.get("ship_to"),
-            ))
-        with self._cond:
-            for rid in rids:
-                self._buffers[rid] = {
-                    "tokens": [], "done": False, "status": "queued",
-                }
-        self._work.set()
+        with span(self._rpc_spans, "serve.rpc.submit", n=len(requests)):
+            for req in requests:
+                if self.faults is not None:
+                    self.faults.hit("rpc_submit")
+                rids.append(self.scheduler.submit(
+                    req["prompt"],
+                    SamplingParams(
+                        max_new_tokens=req.get("max_new_tokens", 32),
+                        temperature=req.get("temperature", 0.0),
+                        top_k=req.get("top_k"),
+                        top_p=req.get("top_p"),
+                        seed=req.get("seed", 0),
+                        eos_token=req.get("eos_token"),
+                    ),
+                    request_id=req.get("request_id"),
+                    priority=req.get("priority", 0),
+                    deadline_s=req.get("deadline_s"),
+                    tenant=req.get("tenant"),
+                    kv_hint=req.get("kv_hint"),
+                    ship_to=req.get("ship_to"),
+                ))
+            with self._cond:
+                for rid in rids:
+                    self._buffers[rid] = {
+                        "tokens": [], "done": False, "status": "queued",
+                    }
+            self._work.set()
         return rids
 
     def result(
@@ -980,18 +1020,31 @@ class ServeReplica:
 
         if self.faults is not None:
             self.faults.hit("rpc_result")
-        deadline = _time.monotonic() + max(0.0, wait_s)
-        with self._cond:
-            while True:
-                buf = self._buffers.get(request_id)
-                if buf is None:
-                    raise KeyError(f"unknown request {request_id!r}")
-                if buf["done"] or len(buf["tokens"]) > cursor:
-                    break
-                remaining = deadline - _time.monotonic()
-                if remaining <= 0:
-                    break
-                self._cond.wait(timeout=remaining)
+        if wait_s > 0:
+            # A long poll sleeps on the condition by design: a span of
+            # its own, so serve.rpc.result stays work and lock wait.
+            deadline = _time.monotonic() + wait_s
+            with span(
+                self._rpc_spans, "serve.rpc.result_wait",
+                request_id=request_id,
+            ), self._cond:
+                while True:
+                    buf = self._buffers.get(request_id)
+                    if (
+                        buf is None or buf["done"]
+                        or len(buf["tokens"]) > cursor
+                    ):
+                        break
+                    remaining = deadline - _time.monotonic()
+                    if remaining <= 0:
+                        break
+                    self._cond.wait(timeout=remaining)
+        with span(
+            self._rpc_spans, "serve.rpc.result", request_id=request_id
+        ), self._cond:
+            buf = self._buffers.get(request_id)
+            if buf is None:
+                raise KeyError(f"unknown request {request_id!r}")
             out = {
                 "tokens": list(buf["tokens"][cursor:]),
                 "done": buf["done"],
@@ -1036,9 +1089,32 @@ class ServeReplica:
     def stats(self) -> Dict[str, Any]:
         """The stats endpoint: metrics snapshot + engine anatomy +
         embedded registry values."""
+        with span(self._rpc_spans, "serve.rpc.stats"):
+            return self._stats()
+
+    def _mirror_spans(self) -> None:
+        """Bring the registry's span and collector counters up to the
+        totals (the hot paths feed the totals only)."""
+        for totals in (self.spans, self._rpc_spans):
+            totals.mirror(self._span_seconds, self._span_count)
+        self._gc.mirror(self._gc_seconds)
+
+    def _spans_snapshot(self) -> Dict[str, Any]:
+        """``stats()["spans"]``: what the host did, all monotone since
+        construction, so the difference of two calls is exactly the time
+        between them. ``segments`` holds both threads' spans; the loop
+        thread's are those not named ``serve.rpc.*``."""
+        out = self.spans.snapshot()
+        out["segments"].update(self._rpc_spans.snapshot()["segments"])
+        out["folds"] = int(sum(self.engine.fold_dispatches.values()))
+        out["gc"] = self._gc.snapshot()
+        return out
+
+    def _stats(self) -> Dict[str, Any]:
         import jax
 
         devs = jax.devices()
+        self._mirror_spans()
         snap = self.metrics.snapshot()
         snap.update(
             {
@@ -1137,6 +1213,7 @@ class ServeReplica:
             snap["spec_stats"] = self.engine.spec_stats()
         snap["health"] = self.health()["verdict"]
         snap["preempt"] = self.preempt.state()
+        snap["spans"] = self._spans_snapshot()
         return snap
 
     # -- health / forensics RPCs ------------------------------------------
@@ -1313,19 +1390,44 @@ class ServeReplica:
 
     def metrics_text(self) -> str:
         """This replica process's registry in Prometheus text format."""
+        self._mirror_spans()
         return self._registry.render()
 
     def profile(
         self, duration_s: float = 1.0, outdir: Optional[str] = None
     ) -> Dict[str, Any]:
-        """Capture ``duration_s`` of jax.profiler trace while the loop
-        thread keeps serving (this RPC only sleeps); returns the artifact
-        paths. Serialized with any other capture in the process."""
-        from ray_lightning_tpu.obs.profiling import capture_profile
+        """Start a ``duration_s`` jax.profiler capture in a thread of
+        this replica and return at once: the actor keeps answering
+        submits and polls while the profiler starts, runs and writes, so
+        the trace shows a replica that is serving, with the loop's and
+        the RPC surface's spans on its host plane. Collect the artifact
+        paths with :meth:`profile_result`. One capture at a time."""
+        from ray_lightning_tpu.obs.profiling import BackgroundCapture
 
-        return capture_profile(duration_s, outdir)
+        if self._capture is not None and self._capture.result() is None:
+            return {
+                "ok": False,
+                "error": "a profile capture is already running",
+            }
+        self._capture = BackgroundCapture(duration_s, outdir)
+        return {"ok": True, "started": True, "duration_s": float(duration_s)}
+
+    def profile_result(self, wait_s: float = 0.0) -> Dict[str, Any]:
+        """The last :meth:`profile` capture's report (``{ok, dir, files,
+        duration_s}`` or ``{ok: False, error}``), or ``{"ok": False,
+        "pending": True}`` while it runs. ``wait_s`` blocks this actor
+        for up to that long; a client polls with 0."""
+        if self._capture is None:
+            return {"ok": False, "error": "no profile capture was started"}
+        res = self._capture.result(wait_s)
+        return {"ok": False, "pending": True} if res is None else res
 
     def stop(self) -> None:
+        from ray_lightning_tpu.obs.jaxmon import remove_gc_hook
+
+        if self._gc_hooked:
+            self._gc_hooked = False
+            remove_gc_hook()
         if self.watchdog is not None:
             self.watchdog.stop()
         if self.journal is not None:

@@ -483,11 +483,19 @@ class Strategy:
                 loss, logs = module.training_step(p, b, rng)
                 return loss, dict(logs)
 
-            (loss, logs), grads = jax.value_and_grad(loss_fn, has_aux=True)(params)
+            # Scope names go into the program's metadata only: a profile
+            # then tells the forward-and-backward pass (the module names
+            # its forward and its loss; autodiff marks their transposes)
+            # from the optimizer update.
+            with jax.named_scope("forward_backward"):
+                (loss, logs), grads = jax.value_and_grad(
+                    loss_fn, has_aux=True
+                )(params)
             if log_grad_norm:
                 logs["grad_norm"] = optax.global_norm(grads)
-            updates, opt_state2 = tx.update(grads, opt_state, params)
-            params2 = optax.apply_updates(params, updates)
+            with jax.named_scope("optimizer"):
+                updates, opt_state2 = tx.update(grads, opt_state, params)
+                params2 = optax.apply_updates(params, updates)
             # Pin outputs to the strategy's shardings: without the
             # constraint GSPMD may pick a different layout for the updated
             # state, causing a reshard every step (observed on multi-axis

@@ -794,6 +794,11 @@ class TrainingLoop:
                 if wd is not None:
                     wd.stop()
                     self._watchdog = None
+                if getattr(self, "_gc_hooked", False):
+                    from ray_lightning_tpu.obs.jaxmon import remove_gc_hook
+
+                    self._gc_hooked = False
+                    remove_gc_hook()
 
     def _run_fit_impl(
         self, ckpt_stream: Optional[bytes] = None
@@ -807,11 +812,20 @@ class TrainingLoop:
         # (tokens/s, MFU) lands at fit end. A few monotonic() reads per
         # dispatched chunk — noise next to a compiled step.
         from ray_lightning_tpu.obs.events import get_event_log
-        from ray_lightning_tpu.obs.jaxmon import install_compile_listener
+        from ray_lightning_tpu.obs.jaxmon import (
+            install_compile_listener,
+            install_gc_hook,
+        )
         from ray_lightning_tpu.obs.telemetry import TrainTelemetry
+        from ray_lightning_tpu.obs.trace import span, step_annotation
 
         install_compile_listener()
+        # The collector's pauses for the length of the fit (run_fit's
+        # finally takes the hook out): telemetry's snapshot ships them.
+        install_gc_hook()
+        self._gc_hooked = True
         self.telemetry = TrainTelemetry()
+        spans = self.telemetry.spans
         self._events = get_event_log()
         self._events.record(
             "trainer", "fit_start",
@@ -968,7 +982,8 @@ class TrainingLoop:
                 n stacked per-step scalars."""
                 if not pending_logs:
                     return {}
-                fetched = jax.device_get(pending_logs)
+                with span(spans, "fit.drain_wait", entries=len(pending_logs)):
+                    fetched = jax.device_get(pending_logs)
                 pending_logs.clear()
                 last: Dict[str, float] = {}
                 for d, n in fetched:
@@ -1084,91 +1099,98 @@ class TrainingLoop:
             )
             batch_idx = skip - 1
             # Explicit iterator so each chunk's wall time splits into the
-            # three host-observable segments (obs.telemetry): data wait
-            # (blocking on the staged pipeline — where device compute
-            # surfaces under async dispatch), the step call (dispatch),
-            # and the drain (log fetch, callbacks, mid-epoch val).
+            # three host-observable segments (obs.telemetry), each one a
+            # span (obs.trace.span) whose clock reads the telemetry
+            # shares: data wait (fit.stage: blocking on the staged
+            # pipeline), the step call (fit.dispatch) and the drain
+            # (fit.callbacks: callbacks and mid-epoch val, with the
+            # blocking log fetch inside it as fit.drain_wait — where the
+            # device's compute surfaces when logs drain every dispatch).
             stream = iter(() if stop else staged)
+            end_of_stream = object()
             try:
                 while True:
-                    t_pull = _time.monotonic()
-                    try:
-                        item = next(stream)
-                    except StopIteration:
+                    with span(spans, "fit.stage") as staged_s:
+                        item = next(stream, end_of_stream)
+                    if item is end_of_stream:
                         break
-                    t_fetch = _time.monotonic()
                     n_chunk, payload = item if fold > 1 else (1, item)
                     start_step = self.global_step
-                    if n_chunk > 1:
-                        self.params, self.opt_state, logs = train_step(
-                            self.params,
-                            self.opt_state,
-                            payload,
-                            self._rng,
-                            start_step,
-                        )
-                        pending_logs.append((logs, n_chunk))  # no sync here
-                    else:
-                        self.params, self.opt_state, logs = single_step(
-                            self.params,
-                            self.opt_state,
-                            payload,
-                            self._rng,
-                            start_step,
-                        )
-                        pending_logs.append((logs, 1))
-                    t_dispatch = _time.monotonic()
-                    batch_idx += n_chunk
-                    self.global_step += n_chunk
-                    if self._update_count is not None:
-                        self._mini_host += n_chunk
-                        self._update_count += (
-                            self._mini_host // self.spec.accumulate_grad_batches
-                        )
-                        self._mini_host %= self.spec.accumulate_grad_batches
-                    if (
-                        # Crossed a log boundary within this chunk (for
-                        # fold=1 this is exactly `global_step % N == 0`).
-                        self.global_step // self.spec.log_every_n_steps
-                        != start_step // self.spec.log_every_n_steps
-                        # Streaming epochs (n_batches None) have no known
-                        # final batch; the post-loop drain covers the tail.
-                        or (n_batches is not None and batch_idx == n_batches - 1)
-                    ):
-                        host_logs = _drain_logs()
-                        self.logged_metrics.update(host_logs)
-                        self._call_callbacks("on_train_batch_end", host_logs, batch_idx)
-                    if (
-                        val_step is not None
-                        and vci
-                        and val_epoch
-                        and (batch_idx + 1) % vci == 0
-                    ):
+                    with span(
+                        spans, "fit.dispatch", step=start_step, n=n_chunk
+                    ) as dispatch_s, step_annotation("fit", start_step):
+                        if n_chunk > 1:
+                            self.params, self.opt_state, logs = train_step(
+                                self.params,
+                                self.opt_state,
+                                payload,
+                                self._rng,
+                                start_step,
+                            )
+                            pending_logs.append((logs, n_chunk))  # no sync here
+                        else:
+                            self.params, self.opt_state, logs = single_step(
+                                self.params,
+                                self.opt_state,
+                                payload,
+                                self._rng,
+                                start_step,
+                            )
+                            pending_logs.append((logs, 1))
+                    with span(spans, "fit.callbacks") as drain_s:
+                        batch_idx += n_chunk
+                        self.global_step += n_chunk
+                        if self._update_count is not None:
+                            self._mini_host += n_chunk
+                            self._update_count += (
+                                self._mini_host // self.spec.accumulate_grad_batches
+                            )
+                            self._mini_host %= self.spec.accumulate_grad_batches
                         if (
-                            n_batches is not None
-                            and batch_idx == n_batches - 1
-                            and self._mini_host == 0
+                            # Crossed a log boundary within this chunk (for
+                            # fold=1 this is exactly `global_step % N == 0`).
+                            self.global_step // self.spec.log_every_n_steps
+                            != start_step // self.spec.log_every_n_steps
+                            # Streaming epochs (n_batches None) have no known
+                            # final batch; the post-loop drain covers the tail.
+                            or (n_batches is not None and batch_idx == n_batches - 1)
                         ):
-                            # Final batch, nothing left to flush: any
-                            # checkpoint this val writes is epoch-complete.
-                            self._epoch_complete = True
-                        self._run_eval_epoch(val_step, self._val_loader, "val")
-                        self._call_callbacks("on_validation_end")
-                        last_val_step = self.global_step
-                        # Every rank just finished the same val epoch: a
-                        # safe point for the max_time consensus check
-                        # (and the multi-process preemption consensus).
-                        if self._out_of_time(synced=True):
-                            self.should_stop = True
-                        if not self._preempt_per_step and (
-                            self._preempt_pending(synced=True)
+                            host_logs = _drain_logs()
+                            self.logged_metrics.update(host_logs)
+                            self._call_callbacks(
+                                "on_train_batch_end", host_logs, batch_idx
+                            )
+                        if (
+                            val_step is not None
+                            and vci
+                            and val_epoch
+                            and (batch_idx + 1) % vci == 0
                         ):
-                            self._preempt_exit(batch_idx + 1)
+                            if (
+                                n_batches is not None
+                                and batch_idx == n_batches - 1
+                                and self._mini_host == 0
+                            ):
+                                # Final batch, nothing left to flush: any
+                                # checkpoint this val writes is epoch-complete.
+                                self._epoch_complete = True
+                            self._run_eval_epoch(val_step, self._val_loader, "val")
+                            self._call_callbacks("on_validation_end")
+                            last_val_step = self.global_step
+                            # Every rank just finished the same val epoch: a
+                            # safe point for the max_time consensus check
+                            # (and the multi-process preemption consensus).
+                            if self._out_of_time(synced=True):
+                                self.should_stop = True
+                            if not self._preempt_per_step and (
+                                self._preempt_pending(synced=True)
+                            ):
+                                self._preempt_exit(batch_idx + 1)
                     self.telemetry.record_chunk(
                         n_chunk,
-                        data_wait=t_fetch - t_pull,
-                        step=t_dispatch - t_fetch,
-                        drain=_time.monotonic() - t_dispatch,
+                        data_wait=staged_s.ns * 1e-9,
+                        step=dispatch_s.ns * 1e-9,
+                        drain=drain_s.ns * 1e-9,
                     )
                     if self._preempt_per_step and self._preempt_pending(
                         synced=False

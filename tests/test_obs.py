@@ -363,7 +363,10 @@ def test_replica_obs_rpcs(obs_params):
         assert stats["compiles_since_init"] == 0
         assert stats["tracing"] is True
         assert stats["metrics"]["rlt_serve_engine_steps_total"] >= 1
-        prof = rep.profile(0.05)
+        # profile() returns at once; the capture runs in a thread of the
+        # replica and a second call collects it.
+        assert rep.profile(0.05)["started"]
+        prof = rep.profile_result(wait_s=60.0)
         assert prof["ok"], prof
         assert prof["files"]
     finally:

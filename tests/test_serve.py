@@ -64,6 +64,24 @@ def _reference(params, prompt, n):
     return np.asarray(out)[0].tolist()
 
 
+def _prompt_whose_greedy(params, want, n, tries=64):
+    """``(prompt, solo)``: the first 6-token prompt of a seeded batch
+    whose greedy continuation of ``n`` tokens satisfies ``want``. A
+    fixture that names one prompt breaks whenever the toy model's
+    numerics move under a new jax; a search keeps the precondition true.
+    The batch finds candidates in one call, the solo run decides."""
+    prompts = np.random.default_rng(0).integers(0, 97, size=(tries, 6))
+    outs = np.asarray(
+        gpt_generate(params, SERVE_CFG, prompts.astype(np.int32), n)
+    )[:, 6:]
+    for prompt, out in zip(prompts.tolist(), outs.tolist()):
+        if want(out):
+            solo = _reference(params, prompt, n)[6:]
+            if want(solo):
+                return prompt, solo
+    pytest.fail(f"none of {tries} prompts has the continuation wanted")
+
+
 def test_engine_concurrent_matches_sequential_generate(engine, serve_params):
     """Different prompt/output lengths admitted together, a request joining
     mid-flight as another leaves: every output token-identical to solo
@@ -308,12 +326,12 @@ def test_engine_fold_eos_truncates_mid_fold(serve_params):
     same folds unperturbed."""
     from ray_lightning_tpu.serve.engine import DecodeEngine
 
-    prompt = list(range(1, 7))
-    solo = _reference(serve_params, prompt, 8)[len(prompt):]
-    # eos = the 6th generated token: the first value in this greedy
-    # sequence with no earlier occurrence (the head is a 6,6,6,... run),
+    # eos = the 6th generated token, a value with no earlier occurrence,
     # landing on the FIRST iteration of the second fold — the slot must
     # freeze with three fold iterations still to run under it.
+    prompt, solo = _prompt_whose_greedy(
+        serve_params, lambda s: s[5] not in s[:5], 8
+    )
     eos = solo[5]
     assert eos not in solo[:5]
     eng = DecodeEngine(
@@ -784,23 +802,30 @@ def test_engine_spec_matches_sequential_generate(
 
 
 def test_engine_spec_eos_inside_accepted_block(serve_params):
-    """EOS landing mid-accept-scan: the fixture prompt's greedy
-    continuation is a long constant run with one transition, so the
-    n-gram drafter accepts 4-token blocks until the verify's own sample
-    hits the transition value — the eos — with accepted drafts before
-    it in the SAME verify and proposals after it discarded. The slot
-    must freeze exactly there (no post-EOS emission from the remaining
-    scan indices or fold iterations), and a batchmate decodes through
-    the same speculative folds unperturbed."""
+    """EOS landing mid-accept-scan: the prompt's greedy continuation is
+    a long constant run with one transition, so the n-gram drafter
+    accepts 4-token blocks (every verify of the run emits depth + 1
+    tokens, from index 1 on) until a verify meets the transition value —
+    the eos — with accepted drafts before it in the SAME verify and
+    proposals after it discarded. The slot must freeze exactly there (no
+    post-EOS emission from the remaining scan indices or fold
+    iterations), and a batchmate decodes through the same speculative
+    folds unperturbed."""
     from ray_lightning_tpu.serve.engine import DecodeEngine
 
-    prompt = [7, 1, 17, 78, 62, 88]
-    solo = _reference(serve_params, prompt, 20)[len(prompt):]
-    # Fixture precondition (locks the construction; if model numerics
-    # ever drift this fails loudly instead of testing nothing): a
-    # constant run, then a transition at index 11.
-    assert solo[:11] == [solo[0]] * 11 and solo[11] != solo[0]
-    eos = solo[11]
+    def run_then_new_value_mid_verify(s):
+        t = next((j for j in range(1, len(s)) if s[j] != s[0]), None)
+        # scan index of the transition inside its verify: 1..3 of 0..4
+        return t is not None and t >= 6 and (t - 1) % 5 in (1, 2, 3)
+
+    prompt, solo = _prompt_whose_greedy(
+        serve_params, run_then_new_value_mid_verify, 20
+    )
+    t = next(j for j in range(1, 20) if solo[j] != solo[0])
+    # Precondition (locks the construction): a constant run, then a
+    # value not seen before at index t.
+    assert solo[:t] == [solo[0]] * t and solo[t] not in solo[:t]
+    eos = solo[t]
     eng = DecodeEngine(
         serve_params, SERVE_CFG, num_slots=2, max_seq=64,
         prefill_buckets=[8, 16], decode_fold=2, spec="ngram",
@@ -817,7 +842,7 @@ def test_engine_spec_eos_inside_accepted_block(serve_params):
     while eng.num_active:
         for _, rid, tok, _ in eng.step():
             (toks if rid == "e" else mtoks).append(tok)
-    assert toks == solo[:12]  # stopped AT eos, mid-scan, mid-fold
+    assert toks == solo[: t + 1]  # stopped AT eos, mid-scan, mid-fold
     assert mate_prompt + mtoks == _reference(serve_params, mate_prompt, 9)
     st = eng.spec_stats()
     # The run really was speculative: whole draft blocks were accepted
