@@ -1256,6 +1256,29 @@ def gpt_prefill_chunk(
     return h, jnp.stack(new_k), jnp.stack(new_v)
 
 
+def _write_cache_rows(
+    cache: jax.Array, li: int, new: jax.Array, pos: jax.Array
+) -> jax.Array:
+    """Write layer ``li``'s new rows ``new`` (B, Hkv, hd) into the stacked
+    (L, B, S, Hkv, hd) cache at ``[li, b, pos[b]]`` and return the cache:
+    B rows move, everything else stays where it lies, so a caller that
+    donates the cache (or carries it through a scan) has it updated in
+    place.
+
+    A position past the end lands on the last row, ``S - 1``, as a
+    ``dynamic_update_slice`` clamps its start; the scatter used here would
+    drop such a row, so the clamp is explicit. Frozen slots and
+    :func:`gpt_decode_step_paged` rely on it.
+    """
+    B, S = new.shape[0], cache.shape[2]
+    return cache.at[li, jnp.arange(B), jnp.clip(pos, 0, S - 1)].set(
+        new,
+        indices_are_sorted=True,
+        unique_indices=True,
+        mode="promise_in_bounds",
+    )
+
+
 def gpt_decode_step(
     params: Dict[str, Any],
     cfg: GPTConfig,
@@ -1281,6 +1304,13 @@ def gpt_decode_step(
     exactly zero through the softmax). Positions beyond ``pos[b]`` may hold
     stale K/V from an evicted tenant; the band mask makes them invisible,
     and the step's own write refreshes each position before any read.
+
+    The caches come in and go out as the two stacked arrays. Each layer
+    writes its B new rows straight into them (:func:`_write_cache_rows`:
+    ``[li, b, pos[b]]``, a position past the end clamped to the last row)
+    and attends against ``cache[li]`` after its own write; nothing
+    rebuilds the arrays, so a caller that donates them or carries them
+    through a scan (:func:`gpt_decode_fold`) has them updated in place.
     """
     cfg.validate_variants()
     cdt = jnp.dtype(cfg.compute_dtype)
@@ -1313,10 +1343,6 @@ def gpt_decode_step(
         return jnp.concatenate(
             [y1 * c - y2 * s, y1 * s + y2 * c], axis=-1
         ).astype(y.dtype)
-
-    def _write_slot(c: jax.Array, new: jax.Array, p: jax.Array) -> jax.Array:
-        # (S, Hkv, hd) cache row update at this slot's own position.
-        return jax.lax.dynamic_update_slice_in_dim(c, new[None], p, axis=0)
 
     def qkv_rope(h, lp):
         a = norm_fn(h[:, None], lp["ln1_g"], lp["ln1_b"])[:, 0]
@@ -1387,34 +1413,27 @@ def gpt_decode_step(
     # The parts of a layer carry names into the compiled program's
     # metadata (jax.named_scope), so a profile says which part an
     # operation belongs to; the math is untouched.
-    def layer(h, args):
-        lp, kc_l, vc_l = args
+    def layer(h, li, k_cache, v_cache):
+        lp = jax.tree_util.tree_map(lambda a: a[li], params["blocks"])
         with jax.named_scope("qkv_rope"):
             q, k_new, v_new = qkv_rope(h, lp)
         with jax.named_scope("cache_write"):
-            kc_l = jax.vmap(_write_slot)(kc_l, k_new, pos)
-            vc_l = jax.vmap(_write_slot)(vc_l, v_new, pos)
+            k_cache = _write_cache_rows(k_cache, li, k_new, pos)
+            v_cache = _write_cache_rows(v_cache, li, v_new, pos)
         with jax.named_scope("cache_attention"):
-            o = attend(q, kc_l, vc_l)
+            o = attend(q, k_cache[li], v_cache[li])
             h = h + jnp.einsum(
                 "bhk,hkd->bd", o, dequant(lp["wo"], cdt)
             ) + lp["bo"].astype(cdt)
         with jax.named_scope("mlp"):
             h = h + mlp(h, lp)
-        return h, (kc_l, vc_l)
+        return h, k_cache, v_cache
 
     h = x
-    new_k, new_v = [], []
-    # Python loop over layers: L is small and static; keeps per-layer
-    # cache threading simple (a scan would need stacked cache updates).
+    # Python loop over layers (L is small and static); the caches stay
+    # the stacked arrays throughout (see the docstring).
     for li in range(L):
-        lp = jax.tree_util.tree_map(lambda a: a[li], params["blocks"])
-        h, (kc_l, vc_l) = layer(h, (lp, k_cache[li], v_cache[li]))
-        new_k.append(kc_l)
-        new_v.append(vc_l)
-    with jax.named_scope("cache_write"):
-        k_cache = jnp.stack(new_k)
-        v_cache = jnp.stack(new_v)
+        h, k_cache, v_cache = layer(h, li, k_cache, v_cache)
     with jax.named_scope("lm_head"):
         h = norm_fn(h[:, None], params["lnf_g"], params["lnf_b"])[:, 0]
         logits = _lm_head(h, _head_weight(params, cfg))
@@ -1958,7 +1977,7 @@ def gpt_decode_step_paged(
     pages into the dense (L, B, S, Hkv, hd) layout, run the UNCHANGED
     dense step (bit-identical logits), and scatter the one written row
     per slot (position ``clip(pos, S-1)`` — the same clamp the dense
-    ``dynamic_update_slice`` applies) back to its page."""
+    step's :func:`_write_cache_rows` applies) back to its page."""
     S = table.shape[1] * int(page)
     k_view = paged_gather(pool_k, table, page)
     v_view = paged_gather(pool_v, table, page)

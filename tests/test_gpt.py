@@ -810,3 +810,126 @@ def test_gptlm_fit_gspmd_with_fold(start_fabric, tmp_path):
     )
     train_test(trainer, module)
     assert trainer.callback_metrics.get("val_loss") is not None
+
+
+# -- the decode step's cache write (in place into the stacked arrays) ------
+DECODE_VARIANTS = {
+    "gpt2-mha-learned": {},
+    "llama-gqa-rope": dict(
+        n_head=4, n_kv_head=2, pos_embed="rope", norm_impl="rmsnorm",
+        mlp_variant="swiglu", tie_word_embeddings=False,
+    ),
+}
+
+
+def _decode_step_case(variant, S=8, B=3, seed=5):
+    """Toy decode-step inputs: params, config, one token and one position
+    a slot, and two random caches of ``S`` rows (shorter than ``max_seq``,
+    so a position past the cache is still a position the model has)."""
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+
+    cfg = dataclasses.replace(TINY, **DECODE_VARIANTS[variant])
+    params = init_gpt_params(jax.random.PRNGKey(seed), cfg)
+    shape = (cfg.n_layer, B, S, cfg.kv_head, cfg.head_dim)
+    kk, kv, kc = jax.random.split(jax.random.PRNGKey(seed + 1), 3)
+    k_cache = jax.random.normal(kk, shape, jnp.dtype(cfg.compute_dtype))
+    v_cache = jax.random.normal(kv, shape, jnp.dtype(cfg.compute_dtype))
+    cur = jax.random.randint(kc, (B,), 0, cfg.vocab_size, jnp.int32)
+    return cfg, params, cur, k_cache, v_cache
+
+
+def _walk_eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs nested in it."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (list, tuple)) else (v,):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _walk_eqns(inner)
+
+
+@pytest.mark.parametrize("variant", sorted(DECODE_VARIANTS))
+def test_decode_step_writes_rows_into_the_stacked_cache(variant):
+    """Counted from the jaxpr: the step never rebuilds a cache (no
+    concatenate — what ``jnp.stack`` lowers to — with the stacked shape),
+    writes each cache exactly once a layer, and returns both with the
+    shape and dtype they came in with."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_lightning_tpu.models.gpt import gpt_decode_step
+
+    cfg, params, cur, k_cache, v_cache = _decode_step_case(variant)
+    pos = jnp.asarray([0, 3, 7], jnp.int32)
+    closed = jax.make_jaxpr(
+        lambda p, c, q, k, v: gpt_decode_step(p, cfg, c, q, k, v)
+    )(params, cur, pos, k_cache, v_cache)
+    stacked = k_cache.shape
+    builds, writes = [], []
+    for eqn in _walk_eqns(closed.jaxpr):
+        name = eqn.primitive.name
+        shapes = [getattr(v.aval, "shape", None) for v in eqn.outvars]
+        if stacked not in shapes:
+            continue
+        if name == "concatenate":
+            builds.append(eqn)
+        elif name.startswith("scatter") or name == "dynamic_update_slice":
+            writes.append(eqn)
+    assert builds == []
+    assert len(writes) == 2 * cfg.n_layer  # one a layer a cache
+    for eqn in writes:
+        # B rows of (Hkv, hd) go in, not a layer of the cache
+        assert eqn.invars[-1].aval.shape == stacked[1:2] + stacked[3:]
+    logits, k_out, v_out = closed.out_avals
+    assert logits.shape == (cur.shape[0], cfg.vocab_size)
+    for got, want in ((k_out, k_cache), (v_out, v_cache)):
+        assert (got.shape, got.dtype) == (want.shape, want.dtype)
+
+
+@pytest.mark.parametrize("past_end", [0, 3])
+@pytest.mark.parametrize("variant", sorted(DECODE_VARIANTS))
+def test_decode_step_clamps_a_position_past_the_cache(
+    variant, past_end, monkeypatch
+):
+    """A slot at ``pos == S`` (and ``S + 3``) writes its row at ``S - 1``,
+    as the ``dynamic_update_slice`` the step used to write with clamped
+    its start: that formulation is kept here as the expected behaviour,
+    and caches and logits are compared bit for bit."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_lightning_tpu.models import gpt as G
+
+    S = 8
+    cfg, params, cur, k_cache, v_cache = _decode_step_case(variant, S=S)
+    pos = jnp.asarray([2, S + past_end, S - 1], jnp.int32)
+
+    def step(*a):
+        return jax.jit(lambda p, c, q, k, v: G.gpt_decode_step(p, cfg, c, q, k, v))(*a)
+
+    logits, k_out, v_out = step(params, cur, pos, k_cache, v_cache)
+
+    def write_by_slot(cache, li, new, pos):
+        # The write before the change: a layer out of the stack, one
+        # clamping dynamic_update_slice a slot, the layer back in.
+        def one(c, n, p):
+            return jax.lax.dynamic_update_slice_in_dim(c, n[None], p, axis=0)
+
+        return cache.at[li].set(jax.vmap(one)(cache[li], new, pos))
+
+    monkeypatch.setattr(G, "_write_cache_rows", write_by_slot)
+    want_logits, want_k, want_v = step(params, cur, pos, k_cache, v_cache)
+    np.testing.assert_array_equal(np.asarray(k_out), np.asarray(want_k))
+    np.testing.assert_array_equal(np.asarray(v_out), np.asarray(want_v))
+    np.testing.assert_array_equal(np.asarray(logits), np.asarray(want_logits))
+    # and the row is where the clamp puts it: only row S - 1 of that slot
+    # changed, in every layer
+    changed = np.any(
+        np.asarray(k_out != k_cache), axis=(0, 3, 4)
+    )  # (B, S)
+    assert changed[1].tolist() == [False] * (S - 1) + [True]
+    assert changed[0].tolist() == [i == 2 for i in range(S)]

@@ -276,21 +276,8 @@ def test_scheduler_outputs_match_reference_under_load(serve_params):
     assert snap["tokens_per_sec"] > 0
 
 
-@pytest.mark.parametrize("fold", [1, 2, 4])
-def test_engine_folded_matches_sequential_generate(serve_params, fold):
-    """decode_fold=K: K tokens per dispatch, mixed lengths, a mid-flight
-    join at a fold boundary — every output token-identical to solo
-    gpt_generate (K=1 included: the fold generalizes, never forks, the
-    unfolded behavior), with ZERO compiles after construction even
-    across admissions and folded steps."""
-    from ray_lightning_tpu.serve.engine import DecodeEngine
-
-    eng = DecodeEngine(
-        serve_params, SERVE_CFG, num_slots=3, max_seq=64,
-        prefill_buckets=[8, 16], decode_fold=fold,
-    )
-    compiles = eng.compiled_count
-    rng = np.random.default_rng(0)
+def _drive_mixed_join(eng, rng):
+    """Mixed lengths and a mid-flight join at a fold boundary."""
     reqs = [
         (rng.integers(0, 97, size=5).tolist(), 7),
         (rng.integers(0, 97, size=8).tolist(), 4),
@@ -313,7 +300,77 @@ def test_engine_folded_matches_sequential_generate(serve_params, fold):
             outs["r3"] = [tok]
             reqs.append((p4, 5))
             joined = True
-    assert joined and eng.num_active == 0
+    assert joined
+    return reqs, outs
+
+
+def _drive_budget_freeze(eng, rng):
+    """Slots at three depths (``budget_freeze_requests``). The deepest
+    runs out of its token budget inside a fold and stays frozen under its
+    batchmates for the folds that follow, rewriting the stale row at its
+    frozen position on every iteration; its slot then goes to a shorter
+    prompt, whose rows past the prompt still hold the old tenant's K/V;
+    and the last request decodes up to the cache's last row."""
+    from tests.utils import budget_freeze_requests
+
+    reqs, late = budget_freeze_requests(rng)
+    outs, slot_of = {}, {}
+    for i, (p, n) in enumerate(reqs):
+        slot_of[f"r{i}"], tok, done = eng.admit(
+            p, request_id=f"r{i}", max_new_tokens=n
+        )
+        outs[f"r{i}"] = [tok]
+        assert not done
+    frozen_folds = 0
+    for _ in range(200):
+        if not eng.num_active:
+            break
+        for _, rid, tok, _ in eng.step():
+            outs[rid].append(tok)
+        if len(outs["r1"]) < reqs[1][1]:
+            continue
+        frozen_folds += 1
+        # two folds with the freed slot idle under the others, then
+        # the short prompt takes it; the long one takes the next free
+        if late and frozen_folds > 2 and eng.free_slots():
+            p, n = late.pop(0)
+            rid = f"r{len(reqs)}"
+            slot_of[rid], tok, _ = eng.admit(
+                p, request_id=rid, max_new_tokens=n
+            )
+            outs[rid] = [tok]
+            reqs.append((p, n))
+    assert not late
+    assert slot_of["r3"] == slot_of["r1"]  # the stale rows' slot
+    return reqs, outs
+
+
+_FOLD_TRAFFIC = {
+    "mixed_join": _drive_mixed_join,
+    "budget_freeze": _drive_budget_freeze,
+}
+
+
+@pytest.mark.parametrize("traffic", sorted(_FOLD_TRAFFIC))
+@pytest.mark.parametrize("fold", [1, 2, 4])
+def test_engine_folded_matches_sequential_generate(
+    serve_params, fold, traffic
+):
+    """decode_fold=K: K tokens per dispatch, mixed lengths, a mid-flight
+    join at a fold boundary — every output token-identical to solo
+    gpt_generate (K=1 included: the fold generalizes, never forks, the
+    unfolded behavior), with ZERO compiles after construction even
+    across admissions and folded steps. ``traffic`` picks who shares the
+    fold: see ``_drive_mixed_join`` and ``_drive_budget_freeze``."""
+    from ray_lightning_tpu.serve.engine import DecodeEngine
+
+    eng = DecodeEngine(
+        serve_params, SERVE_CFG, num_slots=3, max_seq=64,
+        prefill_buckets=[8, 16], decode_fold=fold,
+    )
+    compiles = eng.compiled_count
+    reqs, outs = _FOLD_TRAFFIC[traffic](eng, np.random.default_rng(0))
+    assert eng.num_active == 0
     for i, (p, n) in enumerate(reqs):
         assert p + outs[f"r{i}"] == _reference(serve_params, p, n), f"r{i}"
     assert eng.compiled_count == compiles

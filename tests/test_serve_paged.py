@@ -168,6 +168,51 @@ def test_paged_exactness_and_frozen_compiles(params):
     assert st["allocs"] - st["frees"] == st["resident"], st
 
 
+@pytest.mark.parametrize("fold", [1, 2, 4])
+def test_paged_fold_budget_freeze_matches_generate(params, fold):
+    """The paged twin of tests/test_serve.py's ``budget_freeze`` traffic:
+    slots at three depths share the paged fold, the deepest runs out of
+    its token budget inside a fold and stays frozen under the others
+    (its stale writes go to the scratch page once its pages are freed),
+    a shorter prompt then takes its slot, and the last request decodes
+    up to the view's last row — every output bit-identical to solo
+    gpt_generate, with no compile after construction."""
+    from tests.utils import budget_freeze_requests
+
+    reqs, late = budget_freeze_requests(np.random.default_rng(0))
+    # pages for three residents at once, the 64-row one among them
+    eng = _paged(params, decode_fold=fold, kv_pages=64)
+    compiled = eng.compiled_count
+    outs, slot_of = {}, {}
+
+    def admit(p, n):
+        rid = f"r{len(outs)}"
+        slot_of[rid], _, _ = eng.admit(p, request_id=rid, max_new_tokens=n)
+        outs[rid] = []
+
+    for p, n in reqs:
+        admit(p, n)
+    frozen_folds = 0
+    for _ in range(400):
+        if not eng.num_active:
+            break
+        for _, task, tok, _ in eng.prefill_step(4):
+            outs[task.request_id].append(tok)
+        for _, rid, tok, _ in eng.step():
+            outs[rid].append(tok)
+        if len(outs["r1"]) < reqs[1][1]:
+            continue
+        frozen_folds += 1
+        if late and frozen_folds > 2 and eng.free_slots():
+            reqs.append(late.pop(0))
+            admit(*reqs[-1])
+    assert not late and eng.num_active == 0
+    assert slot_of["r3"] == slot_of["r1"]  # the frozen tenant's slot
+    for i, (p, n) in enumerate(reqs):
+        assert p + outs[f"r{i}"] == _reference(params, p, n), f"r{i}"
+    assert eng.compiled_count == compiled
+
+
 def test_paged_vs_dense_same_tokens(params):
     """Paged and dense engines, same workload, token-for-token equal —
     the direct A/B the bit-exact contract promises."""

@@ -156,6 +156,30 @@ def test_sharded_engine_plain_bit_identical_and_frozen_compiles(
         assert p + sharded[f"r{i}"] == _reference(shard_params, p, n), f"r{i}"
 
 
+def test_sharded_fold_writes_the_cache_shard_locally(tp_mesh, shard_params):
+    """The decode step's cache write indexes layer, slot and position;
+    the sharded axis is the KV heads, so each shard writes its own heads'
+    rows. Counted in the fold's compiled HLO: the two all-reduces a layer
+    the sharded matmuls need (attention output, MLP output) and no other
+    collective — nothing gathers or moves the cache."""
+    import re
+    from collections import Counter
+
+    eng = _engine(
+        shard_params, tp_mesh, num_slots=3, max_seq=64,
+        prefill_buckets=[8, 16], decode_fold=2,
+    )
+    (text,) = [ex.as_text() for ex in eng._step_exec.values()]
+    found = Counter(
+        re.findall(
+            r"= \S+ (all-reduce|all-gather|collective-permute|all-to-all"
+            r"|reduce-scatter)(?:-start)?\(",
+            text,
+        )
+    )
+    assert dict(found) == {"all-reduce": 2 * SHARD_CFG.n_layer}
+
+
 def test_sharded_engine_chunked_prefix_bit_identical(tp_mesh, shard_params):
     """Chunked prefill + a prefix-cache hit under the mesh: the suffix
     prefill seeds from pool blocks through the sharded cache-to-cache

@@ -55,3 +55,18 @@ def predict_test(trainer: Trainer, module: Any, min_acc: float = 0.5) -> None:
     trainer.fit(module)
     acc = trainer.callback_metrics.get("ptl/val_accuracy")
     assert acc is not None and acc >= min_acc, f"accuracy {acc} < {min_acc}"
+
+
+def budget_freeze_requests(rng: Any, max_seq: int = 64) -> tuple:
+    """``(first, late)`` request lists ``[(prompt, max_new_tokens)]`` of
+    the serve tests' ``budget_freeze`` traffic (vocabulary 97): three
+    prompts 3, 14 and 9 rows deep that share the first folds, the deepest
+    with a budget that ends inside a fold; then a short prompt for the
+    slot that one leaves, and one that decodes to the cache's last row."""
+
+    def prompt(n: int) -> list:
+        return rng.integers(0, 97, size=n).tolist()
+
+    first = [(prompt(3), 30), (prompt(14), 3), (prompt(9), 11)]
+    late = [(prompt(4), 8), (prompt(16), max_seq - 16)]
+    return first, late
