@@ -33,6 +33,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 
+from ray_lightning_tpu.models.mixed import refuse_mixed
 from ray_lightning_tpu.trainer.data import ArrayDataset, DataLoader, Dataset
 from ray_lightning_tpu.trainer.module import TPUModule
 from ray_lightning_tpu.utils.quantize import dequant, embed_rows
@@ -110,6 +111,48 @@ class GPTConfig:
     # (hidden states are seq-sharded; the per-rank dense logits are
     # already small).
     loss_chunk: int = 0
+    # A model whose layers differ in kind (models/mixed.py). ``layer_types``
+    # gives each layer's (attention kind, MLP kind): attention "full" or
+    # "window" (``attn_window`` positions), MLP "dense" (``d_ff``) or
+    # "experts" (``d_ff_expert`` wide, ``n_experts`` routed over, ``moe_top_k``
+    # a token). Empty = every layer alike, the fields above say how. JSON
+    # hands these over as lists; they are held as tuples.
+    layer_types: Tuple[Tuple[str, str], ...] = ()
+    # Head widths where q·k and v differ (0 = ``d_model // n_head``).
+    qk_head_dim: int = 0
+    v_head_dim: int = 0
+    # KV heads of the window layers (0 = ``n_kv_head``, the full layers').
+    n_kv_head_window: int = 0
+    # Rotary over the first ``rope_dim`` dims of a head only (0 = all of
+    # it); ``rope_theta_window`` is the window layers' base (0 = ``rope_theta``).
+    rope_dim: int = 0
+    rope_theta_window: float = 0.0
+    # Attention kinds with a learnable per-head sink logit: it takes
+    # probability in the softmax and contributes no value.
+    attn_sink_logit: Tuple[str, ...] = ()
+    attn_value_scale: float = 1.0
+    d_ff_expert: int = 0
+    # Router scores: "softmax" as above, or "sigmoid" (scores plus a
+    # selection-only correction bias pick the top k; the chosen scores,
+    # normalised over the k, weigh the experts).
+    moe_scoring: str = "softmax"
+    # ``(first, count)``: the experts this process holds, of ``n_experts``.
+    # It routes over all of them and computes its own experts' part of the
+    # result. Empty = all.
+    experts_held: Tuple[int, ...] = ()
+
+    def __post_init__(self) -> None:
+        for name in ("layer_types", "attn_sink_logit", "experts_held"):
+            v = getattr(self, name)
+            t = tuple(tuple(x) if isinstance(x, list) else x for x in v)
+            if t != v:
+                object.__setattr__(self, name, t)
+
+    @property
+    def mixed(self) -> bool:
+        """Layers of more than one kind, or a share of the experts: the
+        block of models/mixed.py runs this configuration."""
+        return bool(self.layer_types)
 
     @property
     def head_dim(self) -> int:
@@ -138,6 +181,16 @@ class GPTConfig:
             raise ValueError(
                 f"unknown norm_impl {self.norm_impl!r}; use 'layernorm' or "
                 "'rmsnorm'"
+            )
+        if self.mixed:
+            from ray_lightning_tpu.models.mixed import validate_mixed
+
+            validate_mixed(self)
+        elif self.experts_held or self.moe_scoring != "softmax":
+            raise ValueError(
+                "experts_held and moe_scoring='sigmoid' need layer_types: "
+                "the expert layer that is told which experts it holds runs "
+                "in the block of models/mixed.py"
             )
 
     @staticmethod
@@ -171,6 +224,10 @@ class GPTConfig:
 def init_gpt_params(rng: jax.Array, cfg: GPTConfig) -> Dict[str, Any]:
     """Parameter pytree with stacked per-layer leaves (leading dim L)."""
     cfg.validate_variants()
+    if cfg.mixed:
+        from ray_lightning_tpu.models.mixed import init_mixed_params
+
+        return init_mixed_params(rng, cfg)
     L, D, H, hd, F = (
         cfg.n_layer,
         cfg.d_model,
@@ -271,6 +328,7 @@ def gpt_logical_axes(cfg: GPTConfig) -> Dict[str, Any]:
     """Logical axis names per parameter, consumed by GSPMDStrategy via
     ``parallel.logical`` rules (embed->fsdp, heads/mlp/vocab->model,
     expert->ep)."""
+    refuse_mixed(cfg, "a sharded parameter tree (a training or serve mesh)")
     if cfg.n_experts > 0:
         if cfg.mlp_variant == "swiglu":
             wi_axes = ("layers", "expert", "embed", None, "mlp")
@@ -464,6 +522,10 @@ def _moe_layer_params(lp: Dict[str, jax.Array]) -> Dict[str, jax.Array]:
     }
 
 
+def _mesh_is_one_device(mesh: Any) -> bool:
+    return mesh is None or mesh.size == 1
+
+
 def _lm_head(h: jax.Array, wte: jax.Array) -> jax.Array:
     """Tied LM head: ``(..., D) x (V, D) -> (..., V)`` logits.
 
@@ -536,7 +598,8 @@ def _head_weight(params: Dict[str, Any], cfg: GPTConfig) -> jax.Array:
 def _rope_tables(
     pos: jax.Array, theta: float, head_dim: int
 ) -> Tuple[jax.Array, jax.Array]:
-    """cos/sin tables (S, hd/2) for explicit positions (S,).
+    """cos/sin tables (S, hd/2) for explicit positions (S,) (any leading
+    shape of positions gives tables of that shape + (hd/2,)).
 
     Positions are passed (not implied by index) so permuted layouts —
     zigzag sequence parallelism — rotate by the TRUE token position.
@@ -546,7 +609,7 @@ def _rope_tables(
     """
     half = head_dim // 2
     freqs = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
-    ang = pos.astype(jnp.float32)[:, None] * freqs[None]  # (S, half)
+    ang = pos.astype(jnp.float32)[..., None] * freqs  # (S, half)
     return jnp.cos(ang), jnp.sin(ang)
 
 
@@ -633,6 +696,16 @@ def gpt_forward(
     )
 
     cfg.validate_variants()
+    if cfg.mixed:
+        # Layers of more than one kind: the block of models/mixed.py.
+        from ray_lightning_tpu.models.mixed import mixed_rows
+
+        if not _mesh_is_one_device(mesh):
+            refuse_mixed(cfg, "a forward pass over a mesh of more than one device")
+        x = mixed_rows(params, cfg, tokens)[0]
+        x = _rmsnorm(x, params["lnf_g"], cfg.norm_eps)
+        out = x if return_hidden else _lm_head(x, params["lm_head"])
+        return (out, jnp.zeros((), jnp.float32)) if return_aux else out
     cdt = jnp.dtype(cfg.compute_dtype)
     norm_fn = _make_norm(cfg)
     B, S = tokens.shape
@@ -1058,8 +1131,18 @@ def gpt_prefill(
     consumed directly). ``mesh`` is the serving mesh when the caller's
     program is partitioned over one (the flash kernel then runs per head
     shard; see ``ops.flash_attention``).
+
+    A configuration with mixed layer kinds (``cfg.layer_types``) returns
+    ``pf_k``/``pf_v`` as ``{kind: (Lk, B, P, Hkv, d)}``, one entry an
+    attention kind (models/mixed.py).
     """
     cfg.validate_variants()
+    if cfg.mixed:
+        from ray_lightning_tpu.models.mixed import mixed_rows
+
+        if not _mesh_is_one_device(mesh):
+            refuse_mixed(cfg, "prefill over a serve mesh of more than one device")
+        return mixed_rows(params, cfg, prompt)[:3]
     cdt = jnp.dtype(cfg.compute_dtype)
     norm_fn = _make_norm(cfg)
     H, hd = cfg.n_head, cfg.head_dim
@@ -1161,6 +1244,7 @@ def gpt_prefill_chunk(
     from ray_lightning_tpu.ops.attention import band_allowed
 
     cfg.validate_variants()
+    refuse_mixed(cfg, "chunked prefill (gpt_prefill_chunk)")
     cdt = jnp.dtype(cfg.compute_dtype)
     norm_fn = _make_norm(cfg)
     L, H, hd = cfg.n_layer, cfg.n_head, cfg.head_dim
@@ -1311,8 +1395,16 @@ def gpt_decode_step(
     and attends against ``cache[li]`` after its own write; nothing
     rebuilds the arrays, so a caller that donates them or carries them
     through a scan (:func:`gpt_decode_fold`) has them updated in place.
+
+    With mixed layer kinds (``cfg.layer_types``) each cache is a dict,
+    one stacked array an attention kind, the window layers' a ring
+    (models/mixed.py).
     """
     cfg.validate_variants()
+    if cfg.mixed:
+        from ray_lightning_tpu.models.mixed import mixed_decode_step
+
+        return mixed_decode_step(params, cfg, cur, pos, k_cache, v_cache)[:3]
     cdt = jnp.dtype(cfg.compute_dtype)
     norm_fn = _make_norm(cfg)
     L, H, hd = cfg.n_layer, cfg.n_head, cfg.head_dim
@@ -1550,6 +1642,7 @@ def _piggyback_prefill(
         pb_chunk, pb_start, pb_len, pb_slot, pb_key0, pb_temp, pb_tk,
         pb_tp, pb_n_new, pb_eos, pb_final, pb_on,
     ) = piggyback
+    refuse_mixed(cfg, "piggybacked prefill chunks in the decode fold")
     norm_fn = _make_norm(cfg)
     L, Hkv, hd = cfg.n_layer, cfg.kv_head, cfg.head_dim
     C_rows, cb = pb_chunk.shape
@@ -1690,11 +1783,31 @@ def gpt_decode_fold(
     ``piggyback`` set (see :func:`_piggyback_prefill`) the fold also
     runs up to C prefill-chunk rows after the scan — one fused dispatch
     for all work — and appends ``pb_toks (C,)`` to the return tuple.
+
+    With mixed layer kinds (``cfg.layer_types``) the caches are the two
+    dicts of models/mixed.py, idle lanes route to no expert, and the
+    expert layers' counts leave the fold with the tokens: ``moe (4,)
+    int32`` — pairs routed, pairs on held experts, held experts hit
+    (summed over expert layers and iterations), iterations with a live
+    slot — is appended to the return tuple.
     """
+    if cfg.mixed:
+        if page_table is not None:
+            refuse_mixed(cfg, "a paged KV cache (gpt_decode_step_paged)")
+        if piggyback is not None:
+            refuse_mixed(cfg, "piggybacked prefill chunks in the decode fold")
+        from ray_lightning_tpu.models.mixed import mixed_decode_step
 
     def body(carry, _):
-        cur, pos, keys, active, remaining, k_cache, v_cache = carry
-        if page_table is None:
+        cur, pos, keys, active, remaining, k_cache, v_cache, moe = carry
+        if cfg.mixed:
+            logits, k_cache, v_cache, st = mixed_decode_step(
+                params, cfg, cur, pos, k_cache, v_cache, active=active
+            )
+            moe = moe + jnp.concatenate(
+                [st, active.any().astype(jnp.int32)[None]]
+            )
+        elif page_table is None:
             logits, k_cache, v_cache = gpt_decode_step(
                 params, cfg, cur, pos, k_cache, v_cache
             )
@@ -1715,18 +1828,24 @@ def gpt_decode_fold(
         keys = jnp.where(active[:, None], new_keys, keys)
         remaining = jnp.where(active, remaining - 1, remaining)
         active = active & (remaining > 0) & (toks != eos_toks)
-        return (cur, pos, keys, active, remaining, k_cache, v_cache), (
+        return (cur, pos, keys, active, remaining, k_cache, v_cache, moe), (
             jnp.where(emit, toks, -1),
             emit,
         )
 
     carry, (tok_block, emit_block) = jax.lax.scan(
         body,
-        (cur, pos, keys, active, remaining, k_cache, v_cache),
+        (cur, pos, keys, active, remaining, k_cache, v_cache,
+         jnp.zeros((4,), jnp.int32)),
         None,
         length=int(fold),
     )
-    cur, pos, keys, active, remaining, k_cache, v_cache = carry
+    cur, pos, keys, active, remaining, k_cache, v_cache, moe = carry
+    if cfg.mixed:
+        return (
+            tok_block, emit_block, cur, pos, keys, active, remaining,
+            k_cache, v_cache, moe,
+        )
     if piggyback is None:
         return (
             tok_block, emit_block, cur, pos, keys, active, remaining,
@@ -1778,6 +1897,7 @@ def gpt_decode_verify(
     from ray_lightning_tpu.ops.attention import band_allowed
 
     cfg.validate_variants()
+    refuse_mixed(cfg, "speculative decoding (gpt_decode_verify)")
     cdt = jnp.dtype(cfg.compute_dtype)
     norm_fn = _make_norm(cfg)
     L, H, hd = cfg.n_layer, cfg.n_head, cfg.head_dim
@@ -1978,6 +2098,7 @@ def gpt_decode_step_paged(
     dense step (bit-identical logits), and scatter the one written row
     per slot (position ``clip(pos, S-1)`` — the same clamp the dense
     step's :func:`_write_cache_rows` applies) back to its page."""
+    refuse_mixed(cfg, "a paged KV cache (gpt_decode_step_paged)")
     S = table.shape[1] * int(page)
     k_view = paged_gather(pool_k, table, page)
     v_view = paged_gather(pool_v, table, page)
@@ -2010,6 +2131,7 @@ def gpt_decode_verify_paged(
     within-verify attention exact), and scatter rows ``[pos, pos + Q)``
     back — rows past the view end are dropped exactly like the dense
     masked row-gather drops them."""
+    refuse_mixed(cfg, "a paged KV cache (gpt_decode_verify_paged)")
     Q = toks.shape[1]
     S = table.shape[1] * int(page)
     k_view = paged_gather(pool_k, table, page)
@@ -2045,6 +2167,7 @@ def gpt_prefill_chunk_paged(
     unchanged dense chunk, scatter rows ``[start_pos, start_pos +
     true_len)`` back (padded rows redirect to scratch — the dense path
     never writes them)."""
+    refuse_mixed(cfg, "a paged KV cache (gpt_prefill_chunk_paged)")
     C = chunk.shape[1]
     S = table_row.shape[1] * int(page)
     k_view = paged_gather(pool_k, table_row, page)
@@ -2215,6 +2338,7 @@ def gpt_decode_fold_spec(
     (:func:`_piggyback_prefill`, which also heals the piggybacked
     rows' token history) ``pb_toks (C,)`` is appended.
     """
+    refuse_mixed(cfg, "speculative decoding (gpt_decode_fold_spec)")
     D = int(depth)
 
     def body(carry, _):
@@ -2352,6 +2476,7 @@ def gpt_generate(
     per-position analog, and a dropped token at decode would silently make
     one sequence's output depend on its batchmates.
     """
+    refuse_mixed(cfg, "gpt_generate (the one-program decode loop)")
     B, P = prompt.shape
     total = P + int(max_new_tokens)
     if total > cfg.max_seq:

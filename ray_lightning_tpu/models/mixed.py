@@ -1,0 +1,587 @@
+"""A decoder whose layers differ in kind, and one block function for it.
+
+``GPTConfig.layer_types`` gives each layer an attention kind — "full"
+(the whole context) or "window" (the last ``attn_window`` positions) —
+and an MLP kind — "dense" (SwiGLU, ``d_ff``) or "experts" (routed SwiGLU
+experts of ``d_ff_expert``, of which this process may hold a share:
+``experts_held``). The kinds may differ in KV heads, rotary base and in
+whether a learnable per-head sink logit joins the softmax; q·k and v may
+differ in width, and the rotation may cover only the first ``rope_dim``
+dims of a head. RMSNorm, no biases, rotary positions.
+
+One block, :func:`mixed_block`, serves the three modes the model runs in:
+
+- no cache (``gpt_forward``): attention among the rows given;
+- prefill (``gpt_prefill``): the same, and the rows' K/V come out so that
+  the caller can write them into a slot (:func:`write_prefill_rows`);
+- decode (``gpt_decode_step``): one row a slot at per-slot positions, its
+  K/V written in place into the caches and attention read from them.
+
+The caches are two, side by side, because the kinds need different
+amounts: a full layer keeps every position of a request, a window layer
+only the last ``attn_window`` of them, in a ring (row ``pos mod R``).
+Each is a dict with one stacked array an attention kind, ``{"full": ...,
+"window": ...}``, ``(Lk, B, rows, Hkv * d)`` with rows ``S`` or ``R`` and
+``d`` the q·k width for K and the v width for V: a position's KV heads
+lie side by side in one row. A step's write is then one row a slot, and
+its two matmuls read a slot's rows as one (rows, Hkv * d) matrix, against
+queries laid out block-diagonally over the KV heads (:func:`_attend_cache`).
+With the KV heads on an axis of their own, the write and the matmuls
+each wanted another physical layout and the compiler re-laid a whole
+layer's cache out on every step (0.5 GB a full layer at the benchmark's
+size; compile-only for the v5e, PR 28).
+
+The parameter tree is top-level ``wte``, ``lm_head``, ``lnf_g`` and one
+flat ``blocks`` dict (:func:`mixed_param_shapes`): leaves of one kind of
+layer are stacked on a leading axis over the layers OF THAT KIND. Gate
+and up of a SwiGLU are two (D, F) matrices side by side (``(2, D, F)``):
+the layout the TPU's matmul takes them in, so that no step re-lays them.
+"""
+from __future__ import annotations
+
+from dataclasses import dataclass
+from typing import Any, Dict, List, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+ATTN_KINDS = ("full", "window")
+MLP_KINDS = ("dense", "experts")
+#: Prefix of an attention kind's leaves in ``blocks``.
+_ATTN_PREFIX = {"full": "full", "window": "swa"}
+#: Query rows a block of the no-cache full attention takes at a time: the
+#: float32 scores of a block against its causal prefix are what is live.
+_Q_BLOCK = 512
+
+
+@dataclass(frozen=True)
+class LayerSpec:
+    """One layer: its kinds and its index among the layers of each kind
+    (where its leaves and its cache rows lie)."""
+
+    index: int
+    attn: str
+    mlp: str
+    attn_index: int
+    mlp_index: int
+
+
+def layer_specs(cfg: Any) -> List[LayerSpec]:
+    seen: Dict[str, int] = {}
+    out = []
+    for i, (attn, mlp) in enumerate(cfg.layer_types):
+        out.append(LayerSpec(i, attn, mlp, seen.get(attn, 0), seen.get(mlp, 0)))
+        seen[attn] = seen.get(attn, 0) + 1
+        seen[mlp] = seen.get(mlp, 0) + 1
+    return out
+
+
+def count_kind(cfg: Any, kind: str) -> int:
+    return sum(kind in pair for pair in cfg.layer_types)
+
+
+def validate_mixed(cfg: Any) -> None:
+    """A mixed configuration that the block below cannot run is refused
+    here, by what is wrong with it."""
+    if len(cfg.layer_types) != cfg.n_layer:
+        raise ValueError(
+            f"layer_types names {len(cfg.layer_types)} layers, n_layer is "
+            f"{cfg.n_layer}"
+        )
+    for pair in cfg.layer_types:
+        if len(pair) != 2 or pair[0] not in ATTN_KINDS or pair[1] not in MLP_KINDS:
+            raise ValueError(
+                f"layer_types entry {pair!r}: use (attention kind of "
+                f"{ATTN_KINDS}, MLP kind of {MLP_KINDS})"
+            )
+    if (cfg.norm_impl, cfg.pos_embed, cfg.mlp_variant) != ("rmsnorm", "rope", "swiglu"):
+        raise ValueError(
+            "layer_types runs RMSNorm, rotary positions and SwiGLU: set "
+            "norm_impl='rmsnorm', pos_embed='rope', mlp_variant='swiglu'"
+        )
+    if cfg.tie_word_embeddings:
+        raise ValueError("layer_types needs an untied head (tie_word_embeddings=False)")
+    if count_kind(cfg, "window") and cfg.attn_window < 1:
+        raise ValueError("window layers need attn_window >= 1")
+    if cfg.attn_sinks:
+        raise ValueError(
+            "attn_sinks (positional sinks) do not apply to layer_types; a "
+            "learnable sink logit is attn_sink_logit"
+        )
+    for kind in cfg.attn_sink_logit:
+        if kind not in ATTN_KINDS:
+            raise ValueError(f"attn_sink_logit names {kind!r}, not one of {ATTN_KINDS}")
+    for kind in ATTN_KINDS:
+        if count_kind(cfg, kind) and cfg.n_head % kv_heads(cfg, kind):
+            raise ValueError(
+                f"n_head ({cfg.n_head}) must be divisible by the {kind} "
+                f"layers' KV heads ({kv_heads(cfg, kind)})"
+            )
+    if rope_dim(cfg) % 2 or rope_dim(cfg) > qk_dim(cfg):
+        raise ValueError(
+            f"rope_dim {rope_dim(cfg)} must be even and at most the q·k "
+            f"head width {qk_dim(cfg)}"
+        )
+    if count_kind(cfg, "experts"):
+        if cfg.n_experts < 1 or not 1 <= cfg.moe_top_k <= cfg.n_experts:
+            raise ValueError(
+                "expert layers need n_experts >= 1 and 1 <= moe_top_k <= n_experts"
+            )
+        if cfg.moe_scoring not in ("softmax", "sigmoid"):
+            raise ValueError(
+                f"unknown moe_scoring {cfg.moe_scoring!r}; use 'softmax' or 'sigmoid'"
+            )
+        first, count = experts_held(cfg)
+        if len(cfg.experts_held) not in (0, 2) or first < 0 or count < 1 or (
+            first + count > cfg.n_experts
+        ):
+            raise ValueError(
+                f"experts_held {cfg.experts_held!r} must be (first, count) "
+                f"inside the {cfg.n_experts} experts"
+            )
+
+
+def refuse_mixed(cfg: Any, mechanism: str) -> None:
+    """The modes that have no block for mixed layers or held experts say
+    so by name; none of them computes silently."""
+    if cfg.mixed:
+        raise ValueError(
+            f"{mechanism} does not run a configuration with mixed layer "
+            "kinds or held experts (GPTConfig.layer_types): it has the "
+            "dense engine's bucketed prefill and decode fold only"
+        )
+
+
+# -- sizes ---------------------------------------------------------------------
+def qk_dim(cfg: Any) -> int:
+    return cfg.qk_head_dim or cfg.head_dim
+
+
+def v_dim(cfg: Any) -> int:
+    return cfg.v_head_dim or cfg.head_dim
+
+
+def rope_dim(cfg: Any) -> int:
+    return cfg.rope_dim or qk_dim(cfg)
+
+
+def kv_heads(cfg: Any, kind: str) -> int:
+    if kind == "window" and cfg.n_kv_head_window:
+        return cfg.n_kv_head_window
+    return cfg.n_kv_head or cfg.n_head
+
+
+def rope_theta(cfg: Any, kind: str) -> float:
+    if kind == "window" and cfg.rope_theta_window:
+        return cfg.rope_theta_window
+    return cfg.rope_theta
+
+
+def experts_held(cfg: Any) -> Tuple[int, int]:
+    if cfg.experts_held:
+        return int(cfg.experts_held[0]), int(cfg.experts_held[1])
+    return 0, cfg.n_experts
+
+
+def ring_rows(cfg: Any) -> int:
+    """Rows a window layer keeps for one request. A step writes position
+    ``pos`` (row ``pos mod R``) before it reads positions ``pos - W + 1
+    .. pos``, and an admission writes a prompt's last rows before any
+    read: ``R = W`` rows hold exactly what is read, and the row that a
+    write overwrites (``pos - W``) has just left the window."""
+    return int(cfg.attn_window)
+
+
+def mixed_param_shapes(cfg: Any) -> Dict[str, Any]:
+    """``name -> shape`` of the tree the block takes (``blocks`` nested)."""
+    D, H, V = cfg.d_model, cfg.n_head, cfg.vocab_size
+    dqk, dv = qk_dim(cfg), v_dim(cfg)
+    blocks: Dict[str, Tuple[int, ...]] = {
+        "ln1_g": (cfg.n_layer, D), "ln2_g": (cfg.n_layer, D),
+    }
+    for kind in ATTN_KINDS:
+        n, hkv, p = count_kind(cfg, kind), kv_heads(cfg, kind), _ATTN_PREFIX[kind]
+        if not n:
+            continue
+        blocks.update({
+            f"{p}_wq": (n, D, H, dqk), f"{p}_wk": (n, D, hkv, dqk),
+            f"{p}_wv": (n, D, hkv, dv), f"{p}_wo": (n, H, dv, D),
+        })
+        if kind in cfg.attn_sink_logit:
+            blocks[f"{p}_sink"] = (n, H)
+    n = count_kind(cfg, "dense")
+    if n:
+        blocks.update({"dense_wi": (n, 2, D, cfg.ff_dim), "dense_wo2": (n, cfg.ff_dim, D)})
+    n = count_kind(cfg, "experts")
+    if n:
+        held, F = experts_held(cfg)[1], cfg.d_ff_expert or cfg.ff_dim
+        blocks.update({
+            "moe_router": (n, D, cfg.n_experts),
+            "moe_wi": (n, held, 2, D, F), "moe_wo2": (n, held, F, D),
+        })
+        if cfg.moe_scoring == "sigmoid":
+            blocks["moe_router_bias"] = (n, cfg.n_experts)
+    return {"wte": (V, D), "lm_head": (V, D), "lnf_g": (D,), "blocks": blocks}
+
+
+def init_mixed_params(rng: jax.Array, cfg: Any) -> Dict[str, Any]:
+    """Seeded float32 parameters: normal ``init_std``, the two writes into
+    the residual stream scaled by 1/sqrt(2L), gains and sink logits one,
+    the router's correction bias zero."""
+    shapes = mixed_param_shapes(cfg)
+    res_std = cfg.init_std / np.sqrt(2.0 * cfg.n_layer)
+    flat = [(k, v) for k, v in shapes.items() if k != "blocks"] + [
+        ("blocks/" + k, v) for k, v in shapes["blocks"].items()
+    ]
+    out: Dict[str, Any] = {"blocks": {}}
+    for i, (name, shape) in enumerate(sorted(flat)):
+        leaf = name.rsplit("/", 1)[-1]
+        if leaf.endswith("_g") or leaf.endswith("_sink"):
+            w = jnp.ones(shape, jnp.float32)
+        elif leaf == "moe_router_bias":
+            w = jnp.zeros(shape, jnp.float32)
+        else:
+            std = res_std if leaf.endswith(("_wo", "_wo2")) else cfg.init_std
+            w = std * jax.random.normal(jax.random.fold_in(rng, i), shape, jnp.float32)
+        if name.startswith("blocks/"):
+            out["blocks"][leaf] = w
+        else:
+            out[name] = w
+    return out
+
+
+def empty_caches(cfg: Any, slots: int, max_seq: int, dtype: Any) -> Tuple[Dict[str, Any], Dict[str, Any]]:
+    """Zeroed K and V caches for ``slots`` requests of up to ``max_seq``
+    positions (a kind the model has no layer of is left out)."""
+    rows = {"full": int(max_seq), "window": ring_rows(cfg)}
+    k, v = {}, {}
+    for kind in ATTN_KINDS:
+        n = count_kind(cfg, kind)
+        if n:
+            lead, hkv = (n, slots, rows[kind]), kv_heads(cfg, kind)
+            k[kind] = jnp.zeros(lead + (hkv * qk_dim(cfg),), dtype)
+            v[kind] = jnp.zeros(lead + (hkv * v_dim(cfg),), dtype)
+    return k, v
+
+
+# -- pieces --------------------------------------------------------------------
+def _rope(x: jax.Array, tables: Tuple[jax.Array, jax.Array]) -> jax.Array:
+    """Rotate the first ``2 * half`` dims of x (B, S, H, d) by position
+    (half-split pairs ``(i, i + half)``); the dims after them pass."""
+    cos, sin = tables  # (B, S, half)
+    half = cos.shape[-1]
+    cos, sin = cos[:, :, None, :], sin[:, :, None, :]
+    x32 = x.astype(jnp.float32)
+    x1, x2, rest = x32[..., :half], x32[..., half:2 * half], x32[..., 2 * half:]
+    return jnp.concatenate(
+        [x1 * cos - x2 * sin, x1 * sin + x2 * cos, rest], axis=-1
+    ).astype(x.dtype)
+
+
+def _softmax_with_sink(s: jax.Array, sink: Optional[jax.Array]) -> jax.Array:
+    """Softmax over the last axis of float32 scores (B, G, R, Q, K). With
+    ``sink`` (G, R), one logit a query head, the sink joins the
+    normalisation and has no column in the result: it takes probability
+    and gives no value. A row with no key allowed comes out as zeros."""
+    m = jnp.max(s, axis=-1, keepdims=True)
+    if sink is not None:
+        b = sink.astype(jnp.float32)[None, :, :, None, None]
+        m = jnp.maximum(m, b)
+    m = jnp.where(jnp.isfinite(m), m, 0.0)
+    e = jnp.exp(s - m)
+    z = e.sum(-1, keepdims=True)
+    if sink is not None:
+        z = z + jnp.exp(b - m)
+    return e / jnp.where(z > 0, z, 1.0)
+
+
+def _scores(q: jax.Array, k: jax.Array) -> jax.Array:
+    """q (B, Q, G, R, d) x k (B, K, G, d) -> float32 (B, G, R, Q, K)."""
+    return jnp.einsum(
+        "bqgrd,bkgd->bgrqk", q, k, preferred_element_type=jnp.float32
+    ) * (1.0 / np.sqrt(q.shape[-1]))
+
+
+def _values(p: jax.Array, v: jax.Array) -> jax.Array:
+    """p (B, G, R, Q, K) x v (B, K, G, d) -> (B, Q, G * R, d) in v's type."""
+    o = jnp.einsum("bgrqk,bkgd->bqgrd", p.astype(v.dtype), v)
+    return o.reshape(o.shape[0], o.shape[1], -1, o.shape[-1])
+
+
+def _attend_rows_full(q, k, v, sink):
+    """Causal attention among S rows, a block of queries at a time
+    against its causal prefix (static slices: only the scores of one
+    block are live, and no key after the block is multiplied)."""
+    S = q.shape[1]
+    out = []
+    for s0 in range(0, S, _Q_BLOCK):
+        s1 = min(S, s0 + _Q_BLOCK)
+        s = _scores(q[:, s0:s1], k[:, :s1])
+        ok = jnp.arange(s1)[None, :] <= jnp.arange(s0, s1)[:, None]
+        p = _softmax_with_sink(jnp.where(ok, s, -jnp.inf), sink)
+        out.append(_values(p, v[:, :s1]))
+    return out[0] if len(out) == 1 else jnp.concatenate(out, axis=1)
+
+
+def _attend_rows_window(q, k, v, sink, window: int):
+    """Causal attention among S rows, each query seeing its last
+    ``window`` positions: the queries go in blocks of ``window`` rows, and
+    a block needs the keys of its own rows and of the block before."""
+    B, S = q.shape[:2]
+    W = int(window)
+    nb = -(-S // W)
+    pad = nb * W - S
+
+    def blocks_of(x, front):
+        x = jnp.pad(x, ((0, 0), (front, pad)) + ((0, 0),) * (x.ndim - 2))
+        return x.reshape((B, -1, W) + x.shape[2:])
+
+    qb = blocks_of(q, 0)  # (B, nb, W, G, R, d)
+    kb, vb = blocks_of(k, W), blocks_of(v, W)  # (B, nb + 1, W, G, d)
+    k2 = jnp.concatenate([kb[:, :-1], kb[:, 1:]], axis=2)  # (B, nb, 2W, G, d)
+    v2 = jnp.concatenate([vb[:, :-1], vb[:, 1:]], axis=2)
+    s = jnp.einsum(
+        "bnqgrd,bnkgd->bngrqk", qb, k2, preferred_element_type=jnp.float32
+    ) * (1.0 / np.sqrt(q.shape[-1]))
+    # within a block: query a is at position n*W + a, key c at (n-1)*W + c
+    a = jnp.arange(W)[:, None] + W
+    c = jnp.arange(2 * W)[None, :]
+    ok = (c <= a) & (c > a - W)
+    first = (jnp.arange(nb) == 0)[:, None, None] & (c < W)[None]  # before position 0
+    ok = ok[None] & ~first  # (nb, W, 2W)
+    s = jnp.where(ok[None, :, None, None], s, -jnp.inf)
+    G, R = s.shape[2], s.shape[3]
+    p = _softmax_with_sink(
+        s.reshape((B * nb,) + s.shape[2:]), sink
+    ).reshape(s.shape)
+    o = jnp.einsum("bngrqk,bnkgd->bnqgrd", p.astype(v.dtype), v2)
+    return o.reshape(B, nb * W, G * R, v.shape[-1])[:, :S]
+
+
+def _attend_cache(q, kc, vc, pos, sink, window: int, ring: bool):
+    """One query row a slot, q (B, 1, G, R, d), against the slot's cache
+    rows kc (B, rows, G * d) and vc (B, rows, G * dv) after this step's
+    write; (B, 1, G * R, dv). ``pos`` (B,) is the query's position. Full
+    cache: row r holds position r. Ring: row r holds the newest position
+    ``<= pos`` that is ``r mod rows``.
+
+    A row of the cache holds all G KV heads, so a query head is laid out
+    over a whole row with zeros under the other KV heads' dims: one matmul
+    against the cache as it lies gives every head's scores (G times the
+    multiplications of the grouped form, on a read that the cache's bytes
+    bound), and of p·V's (G * R, G * dv) result each head keeps its own KV
+    head's block. The zeros add exact zeros: the numbers are the grouped
+    form's."""
+    B, _, G, R, d = q.shape
+    rows, dv = kc.shape[1], vc.shape[-1] // G
+    eye = jnp.eye(G, dtype=q.dtype)
+    q_rows = jnp.einsum("bgrd,gh->bgrhd", q[:, 0], eye).reshape(B, G * R, G * d)
+    s = jnp.einsum(
+        "bhc,bsc->bhs", q_rows, kc, preferred_element_type=jnp.float32
+    ) * (1.0 / np.sqrt(d))
+    r = jnp.arange(rows, dtype=jnp.int32)[None, :]
+    p = pos.astype(jnp.int32)[:, None]
+    held = p - ((p - r) % rows) if ring else r
+    ok = (held >= 0) & (held <= p)
+    if window:
+        ok = ok & (held > p - window)
+    s = jnp.where(ok[:, None, :], s, -jnp.inf).reshape(B, G, R, 1, rows)
+    probs = _softmax_with_sink(s, sink).reshape(B, G * R, rows)
+    o = jnp.einsum("bhs,bsc->bhc", probs.astype(vc.dtype), vc)
+    o = jnp.einsum("bgrhd,gh->bgrd", o.reshape(B, G, R, G, dv), eye.astype(o.dtype))
+    return o.reshape(B, 1, G * R, dv)
+
+
+def write_prefill_rows(
+    k_cache: Dict[str, Any], v_cache: Dict[str, Any], pf_k: Dict[str, Any],
+    pf_v: Dict[str, Any], slot: jax.Array, true_len: jax.Array,
+) -> Tuple[Dict[str, Any], Dict[str, Any]]:
+    """An admitted prompt's K/V (``{kind: (Lk, 1, Pb, Hkv, d)}``, of
+    which the first ``true_len`` rows are real) into slot ``slot``, the
+    KV heads side by side in a row: all ``Pb`` rows of the full layers (rows past
+    ``true_len`` lie behind the position mask, as in the dense engine),
+    and of the window layers the prompt's last ``min(true_len, R)``
+    positions, each at its ring row."""
+    zero = jnp.zeros((), jnp.int32)
+    k_cache, v_cache = dict(k_cache), dict(v_cache)
+    for cache, pf in ((k_cache, pf_k), (v_cache, pf_v)):
+        for kind, rows in pf.items():
+            if kind == "window":
+                R, Pb = cache[kind].shape[2], rows.shape[2]
+                r = jnp.arange(R, dtype=jnp.int32)
+                last = true_len.astype(jnp.int32) - 1
+                held = last - ((last - r) % R)  # newest prompt position at row r
+                rows = rows[:, :, jnp.clip(held, 0, Pb - 1)]  # (Lw, 1, R, ...)
+            cache[kind] = jax.lax.dynamic_update_slice(
+                cache[kind], rows.reshape(rows.shape[:3] + (-1,)).astype(cache[kind].dtype),
+                (zero, slot, zero, zero),
+            )
+    return k_cache, v_cache
+
+
+
+# -- the block -----------------------------------------------------------------
+def _layer_leaves(blocks: Dict[str, Any], ls: LayerSpec) -> Dict[str, Any]:
+    """This layer's leaves, under names without the kind's prefix (the
+    experts' weights stay stacked: see ``moe_ffn_held``'s ``layer``)."""
+    p = _ATTN_PREFIX[ls.attn] + "_"
+    out = {"ln1_g": blocks["ln1_g"][ls.index], "ln2_g": blocks["ln2_g"][ls.index]}
+    for name, leaf in blocks.items():
+        if name.startswith(p):
+            out[name[len(p):]] = leaf[ls.attn_index]
+        elif name.startswith("dense_") and ls.mlp == "dense":
+            out[name[len("dense_"):]] = leaf[ls.mlp_index]
+        elif name in ("moe_router", "moe_router_bias") and ls.mlp == "experts":
+            out[name[len("moe_"):]] = leaf[ls.mlp_index]
+    if ls.mlp == "experts":
+        out["wi"], out["wo2"] = blocks["moe_wi"], blocks["moe_wo2"]
+    return out
+
+
+def mixed_block(
+    h: jax.Array,
+    lp: Dict[str, Any],
+    ls: LayerSpec,
+    cfg: Any,
+    rope: Dict[str, Tuple[jax.Array, jax.Array]],
+    pos: Optional[jax.Array] = None,
+    caches: Optional[Tuple[Dict[str, Any], Dict[str, Any]]] = None,
+    valid: Optional[jax.Array] = None,
+) -> Tuple[jax.Array, Any, jax.Array]:
+    """One layer over h (B, S, D) -> ``(h, kv, moe_stats)``.
+
+    ``caches`` None: the S rows attend among themselves (forward,
+    prefill) and ``kv`` is their ``(k, v)`` at the layer's KV width.
+    ``caches = (k_cache, v_cache)``: decode, S = 1 and ``pos`` (B,) each
+    slot's position; the row's K/V are written in place and ``kv`` is the
+    updated pair. ``valid`` (B, S) bool marks the real tokens for the
+    expert layer. ``moe_stats`` is :func:`moe_ffn_held`'s (zeros for a
+    dense layer)."""
+    from ray_lightning_tpu.models.gpt import _rmsnorm, _write_cache_rows
+    from ray_lightning_tpu.parallel.moe import moe_ffn_held
+
+    cdt = jnp.dtype(cfg.compute_dtype)
+    B, S, D = h.shape
+    G = kv_heads(cfg, ls.attn)
+    window = cfg.attn_window if ls.attn == "window" else 0
+    with jax.named_scope("attn_" + ls.attn):
+        a = _rmsnorm(h, lp["ln1_g"], cfg.norm_eps)
+        q = jnp.einsum("bsd,dhk->bshk", a, lp["wq"].astype(cdt))
+        k = jnp.einsum("bsd,dhk->bshk", a, lp["wk"].astype(cdt))
+        v = jnp.einsum("bsd,dhk->bshk", a, lp["wv"].astype(cdt))
+        q, k = _rope(q, rope[ls.attn]), _rope(k, rope[ls.attn])
+        if cfg.attn_value_scale != 1.0:
+            v = v * jnp.asarray(cfg.attn_value_scale, v.dtype)
+        q = q.reshape(B, S, G, cfg.n_head // G, q.shape[-1])
+        sink = lp["sink"].reshape(G, -1) if "sink" in lp else None
+        if caches is None:
+            kv: Any = (k, v)
+            o = (
+                _attend_rows_window(q, k, v, sink, window)
+                if window
+                else _attend_rows_full(q, k, v, sink)
+            )
+        else:
+            k_cache, v_cache = dict(caches[0]), dict(caches[1])
+            ring = ls.attn == "window"
+            # a full layer's position past the end lands on the last row
+            # (frozen slots: _write_cache_rows clamps); a ring row is
+            # always in bounds
+            row = pos % k_cache[ls.attn].shape[2] if ring else pos
+            k_cache[ls.attn] = _write_cache_rows(
+                k_cache[ls.attn], ls.attn_index, k[:, 0].reshape(B, -1), row
+            )
+            v_cache[ls.attn] = _write_cache_rows(
+                v_cache[ls.attn], ls.attn_index, v[:, 0].reshape(B, -1), row
+            )
+            kv = (k_cache, v_cache)
+            o = _attend_cache(
+                q, k_cache[ls.attn][ls.attn_index], v_cache[ls.attn][ls.attn_index],
+                pos, sink, window, ring,
+            )
+        h = h + jnp.einsum("bshk,hkd->bsd", o.astype(cdt), lp["wo"].astype(cdt))
+    m = _rmsnorm(h, lp["ln2_g"], cfg.norm_eps)
+    if ls.mlp == "dense":
+        with jax.named_scope("mlp"):
+            z = jnp.einsum("bsd,cdf->bscf", m, lp["wi"].astype(cdt))
+            out = jnp.einsum(
+                "bsf,fd->bsd", jax.nn.silu(z[:, :, 0]) * z[:, :, 1], lp["wo2"].astype(cdt)
+            )
+        return h + out, kv, jnp.zeros((3,), jnp.int32)
+    out, stats = moe_ffn_held(
+        {"wo" if k == "wo2" else k: lp[k] for k in ("router", "router_bias", "wi", "wo2") if k in lp},
+        m.reshape(B * S, D), held=experts_held(cfg), top_k=cfg.moe_top_k,
+        scoring=cfg.moe_scoring, compute_dtype=cdt, layer=ls.mlp_index,
+        valid=None if valid is None else valid.reshape(B * S),
+    )
+    return h + out.reshape(B, S, D), kv, stats
+
+
+def _rope_by_kind(cfg: Any, pos: jax.Array) -> Dict[str, Tuple[jax.Array, jax.Array]]:
+    """The rotation tables of positions ``pos`` (B, S), one pair a kind of
+    attention the model has: computed once, shared by its layers."""
+    from ray_lightning_tpu.models.gpt import _rope_tables
+
+    return {
+        kind: _rope_tables(pos, rope_theta(cfg, kind), rope_dim(cfg))
+        for kind in ATTN_KINDS if count_kind(cfg, kind)
+    }
+
+
+def mixed_rows(
+    params: Dict[str, Any], cfg: Any, tokens: jax.Array, true_len: Optional[jax.Array] = None,
+) -> Tuple[jax.Array, Dict[str, Any], Dict[str, Any], jax.Array]:
+    """The layers over tokens (B, S) with no cache: pre-final-norm hidden
+    states, the rows' K and V by kind (``{kind: (Lk, B, S, Hkv, d)}``) and
+    the expert layers' summed ``moe_stats``. ``true_len`` (scalar): only
+    the first ``true_len`` rows are real (a right-padded prompt)."""
+    from ray_lightning_tpu.utils.quantize import embed_rows
+
+    B, S = tokens.shape
+    cdt = jnp.dtype(cfg.compute_dtype)
+    h = embed_rows(params["wte"], tokens).astype(cdt)
+    pos = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
+    rope = _rope_by_kind(cfg, pos)
+    valid = None if true_len is None else pos < true_len
+    ks: Dict[str, List[jax.Array]] = {}
+    vs: Dict[str, List[jax.Array]] = {}
+    stats = jnp.zeros((3,), jnp.int32)
+    for ls in layer_specs(cfg):
+        lp = _layer_leaves(params["blocks"], ls)
+        h, (k, v), st = mixed_block(h, lp, ls, cfg, rope, valid=valid)
+        ks.setdefault(ls.attn, []).append(k.astype(cdt))
+        vs.setdefault(ls.attn, []).append(v.astype(cdt))
+        stats = stats + st
+    return (
+        h, {a: jnp.stack(x) for a, x in ks.items()},
+        {a: jnp.stack(x) for a, x in vs.items()}, stats,
+    )
+
+
+def mixed_decode_step(
+    params: Dict[str, Any], cfg: Any, cur: jax.Array, pos: jax.Array,
+    k_cache: Dict[str, Any], v_cache: Dict[str, Any], active: Optional[jax.Array] = None,
+) -> Tuple[jax.Array, Dict[str, Any], Dict[str, Any], jax.Array]:
+    """``gpt_decode_step`` for mixed layers: one token a slot at per-slot
+    positions through both caches; float32 logits (B, V), the caches and
+    the summed ``moe_stats``. ``active`` (B,) bool: idle lanes route to
+    no expert (their logits are not read)."""
+    from ray_lightning_tpu.models.gpt import _lm_head, _rmsnorm
+    from ray_lightning_tpu.utils.quantize import embed_rows
+
+    cdt = jnp.dtype(cfg.compute_dtype)
+    h = embed_rows(params["wte"], cur).astype(cdt)[:, None]  # (B, 1, D)
+    rope = _rope_by_kind(cfg, pos[:, None])
+    valid = None if active is None else active[:, None]
+    stats = jnp.zeros((3,), jnp.int32)
+    for ls in layer_specs(cfg):
+        lp = _layer_leaves(params["blocks"], ls)
+        h, (k_cache, v_cache), st = mixed_block(
+            h, lp, ls, cfg, rope, pos=pos, caches=(k_cache, v_cache), valid=valid,
+        )
+        stats = stats + st
+    with jax.named_scope("lm_head"):
+        h = _rmsnorm(h[:, 0], params["lnf_g"], cfg.norm_eps)
+        logits = _lm_head(h, params["lm_head"])
+    return logits, k_cache, v_cache, stats

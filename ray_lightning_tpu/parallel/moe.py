@@ -13,6 +13,11 @@ Two dispatch implementations:
   stable argsort and moved with gather/scatter-add — O(T·K·D + E·C·D)
   memory, supports top-1 and top-2 routing. Static shapes throughout
   (argsort/scatter are XLA-native), so it jits and shards like any other op.
+- ``moe_ffn_held``: the layer of a process that holds a share of the
+  experts (``held = (first, count)``): it routes over all of them, groups
+  the pairs that land on its own by expert and runs a drop-free grouped
+  SwiGLU sized by the counts. Sigmoid or softmax scores. Serving only: the
+  loop over the tiles in use has no reverse mode.
 - ``moe_ffn_dense``: the original one-hot einsum formulation, O(T·E·C)
   dispatch tensors. Kept as the readable oracle the tests check the sparse
   path against, and as a fallback for tiny expert counts where the dense
@@ -188,6 +193,165 @@ def moe_ffn(
         "aux_loss": aux_loss,
         "dropped": dropped,
     }
+
+
+def route_top_k(
+    tokens: jax.Array,
+    router: jax.Array,
+    bias: Any,
+    top_k: int,
+    scoring: str,
+) -> Tuple[jax.Array, jax.Array]:
+    """tokens (T, D), router (D, E) -> gates (T, K) float32 and experts
+    (T, K) int32 over ALL ``E`` experts.
+
+    ``softmax``: :func:`_route_and_pack`'s rule (top-k of the softmax,
+    renormalised over the k when k > 1). ``sigmoid``: the k largest of
+    ``sigmoid(logits) + bias`` are chosen, ``bias`` (E,) taking part in
+    the choice only, and the chosen sigmoids, normalised over the k, are
+    the gates. The logits are float32 at the highest precision: the
+    matmul is small, and a choice that flips on rounding changes which
+    experts a token sees.
+    """
+    logits = jnp.einsum(
+        "td,de->te",
+        tokens.astype(jnp.float32),
+        router.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST,
+    )
+    K = int(top_k)
+    if scoring == "sigmoid":
+        score = jax.nn.sigmoid(logits)
+        _, top_e = jax.lax.top_k(score + bias.astype(jnp.float32), K)
+        top_s = jnp.take_along_axis(score, top_e, axis=-1)
+    elif scoring == "softmax":
+        top_s, top_e = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), K)
+    else:
+        raise ValueError(
+            f"unknown moe_scoring {scoring!r}; use 'softmax' or 'sigmoid'"
+        )
+    if K > 1 or scoring == "sigmoid":
+        top_s = top_s / jnp.clip(top_s.sum(-1, keepdims=True), 1e-9, None)
+    return top_s, top_e.astype(jnp.int32)
+
+
+def held_row_tile(n_tokens: int, top_k: int, n_experts: int) -> int:
+    """Rows of one tile of :func:`moe_ffn_held`'s grouped matmul: twice
+    what an expert gets under even routing, a power of two in [16, 256].
+    A tile belongs to one expert, so an expert's weights are read once a
+    tile; small tiles waste little padding at decode, large ones keep the
+    matmuls of a prefill wide."""
+    want = max(1, 2 * n_tokens * top_k // max(1, n_experts))
+    return int(min(256, max(16, 1 << (want - 1).bit_length())))
+
+
+def moe_ffn_held(
+    params: Dict[str, jax.Array],
+    x: jax.Array,
+    *,
+    held: Tuple[int, int],
+    top_k: int,
+    scoring: str = "sigmoid",
+    valid: Any = None,
+    compute_dtype: Any = jnp.float32,
+    layer: Any = None,
+) -> Tuple[jax.Array, jax.Array]:
+    """The expert layer of a process that holds ``held = (first, count)``
+    of the experts: x (T, D) -> (T, D), the part of the layer's result
+    that its own experts give, and ``[pairs routed, pairs on held
+    experts, held experts hit]`` (int32).
+
+    ``params``: ``router`` (D, E) over ALL experts (with ``router_bias``
+    (E,) under sigmoid scoring), ``wi`` (count, 2, D, F) — gate and up,
+    each a (D, F) matrix as the matmul wants it — and ``wo`` (count, F, D)
+    of the held experts alone (SwiGLU). With ``layer`` (a static index)
+    ``wi`` and ``wo`` are the leaves of ALL expert layers, stacked on a
+    leading axis, and the loop reads ``[layer, expert]`` out of them: a
+    caller that sliced its layer out first would hand the loop a copy of
+    the layer's weights, made again on every call. The choice and the
+    gates are over all E and all k (:func:`route_top_k`); only the
+    (token, expert) pairs whose expert is held are computed. They are
+    grouped by expert into tiles of :func:`held_row_tile` rows, each
+    group padded to whole tiles, and a loop over the tiles IN USE runs one
+    expert's SwiGLU a tile: no pair is dropped, no capacity factor, and
+    both the matmuls and the weights read follow the counts. An expert no
+    token chose is never read. Nothing here stands in for the processes
+    that hold the other experts: what they would add is not in the result.
+
+    ``valid`` (T,) bool marks the real tokens (padding of a bucketed
+    prefill, idle decode lanes): the others route nowhere.
+    """
+    T, D = x.shape
+    first, Eh = int(held[0]), int(held[1])
+    K = int(top_k)
+    E = params["router"].shape[1]
+    cdt = jnp.dtype(compute_dtype)
+    wi, wo = params["wi"], params["wo"]
+    with jax.named_scope("router"):
+        gates, experts = route_top_k(
+            x, params["router"], params.get("router_bias"), K, scoring
+        )
+    with jax.named_scope("moe_dispatch"):
+        local = experts - first
+        here = (local >= 0) & (local < Eh)
+        routed = jnp.asarray(T * K, jnp.int32)
+        if valid is not None:
+            here = here & valid[:, None]
+            routed = valid.sum().astype(jnp.int32) * K
+        N = T * K
+        e_flat = jnp.where(here, local, Eh).reshape(N)  # pair n = t * K + k
+        onehot = (e_flat[:, None] == jnp.arange(Eh)[None]).astype(jnp.int32)
+        rank = jnp.take_along_axis(
+            jnp.cumsum(onehot, axis=0), jnp.minimum(e_flat, Eh - 1)[:, None], 1
+        )[:, 0] - 1
+        counts = onehot.sum(0)  # (Eh,) pairs per held expert
+        tile = held_row_tile(T, K, E)
+        tiles_e = (counts + tile - 1) // tile
+        tile_end = jnp.cumsum(tiles_e)
+        n_tiles = tile_end[-1]
+        R = (-(-N // tile) + Eh) * tile  # every group padded to whole tiles
+        row0 = (tile_end - tiles_e) * tile  # first row of each group
+        dest = jnp.where(
+            e_flat < Eh, row0[jnp.minimum(e_flat, Eh - 1)] + rank, R
+        )  # R: no row
+        row_token = (
+            jnp.zeros((R,), jnp.int32)
+            .at[dest]
+            .set(jnp.arange(N, dtype=jnp.int32) // K, mode="drop")
+        )
+        tile_expert = jnp.searchsorted(
+            tile_end, jnp.arange(R // tile), side="right"
+        ).astype(jnp.int32)
+    with jax.named_scope("moe_experts"):
+        xc = x.astype(cdt)
+
+        def one_expert(w, e):
+            if layer is None:
+                return jax.lax.dynamic_index_in_dim(w, e, 0, keepdims=False)
+            start = (layer, e) + (0,) * (w.ndim - 2)
+            return jax.lax.dynamic_slice(w, start, (1, 1) + w.shape[2:])[0, 0]
+
+        def one_tile(i, y):
+            e = tile_expert[i]
+            rows = jax.lax.dynamic_slice(row_token, (i * tile,), (tile,))
+            wi_e, wo_e = one_expert(wi, e), one_expert(wo, e)
+            z = jnp.einsum("td,cdf->tcf", xc[rows], wi_e.astype(cdt))
+            h = jax.nn.silu(z[:, 0]) * z[:, 1]
+            yt = jnp.einsum("tf,fd->td", h, wo_e.astype(cdt))
+            return jax.lax.dynamic_update_slice(y, yt, (i * tile, 0))
+
+        y = jax.lax.fori_loop(0, n_tiles, one_tile, jnp.zeros((R, D), cdt))
+    with jax.named_scope("moe_combine"):
+        picked = y[jnp.minimum(dest, R - 1)].reshape(T, K, D)
+        g = jnp.where(here, gates, 0.0)
+        out = jnp.einsum(
+            "tkd,tk->td", picked.astype(jnp.float32), g
+        ).astype(x.dtype)
+    stats = jnp.stack(
+        [routed, here.sum().astype(jnp.int32),
+         (counts > 0).sum().astype(jnp.int32)]
+    )
+    return out, stats
 
 
 def moe_ffn_ep(

@@ -285,6 +285,30 @@ class DecodeEngine:
             config = GPTConfig(**config)
         config.validate_variants()
         self.cfg = config
+        if config.mixed:
+            # Mixed layer kinds / held experts run through the dense
+            # engine's bucketed admission and decode fold only. Every other
+            # mode has a restatement of the block that does not know them:
+            # refuse by name, before anything is placed or compiled.
+            from ray_lightning_tpu.models.mixed import refuse_mixed
+            from ray_lightning_tpu.utils.quantize import is_quantized
+
+            for on, mechanism in (
+                (kv_pages or kv_page, "a paged KV cache (kv_pages)"),
+                (prefix_blocks or prefix_host_mb or prefix_disk_dir,
+                 "the prefix pool (prefix_blocks)"),
+                (kvstore_dir, "the KV store / KV fleet export (kvstore_dir)"),
+                (prefill_chunk, "chunked prefill (prefill_chunk)"),
+                (piggyback_chunks, "piggybacked prefill chunks (piggyback_chunks)"),
+                (spec != "off", f"speculative decoding (spec={spec!r})"),
+                (mesh is not None and mesh.size > 1,
+                 "a serve mesh of more than one device"),
+                (any(is_quantized(x) for x in jax.tree_util.tree_leaves(
+                    params, is_leaf=is_quantized)), "int8 weights"),
+            ):
+                if on:
+                    refuse_mixed(config, mechanism)
+            mesh = None
         self.num_slots = int(num_slots)
         if self.num_slots < 1:
             raise ValueError("num_slots must be >= 1")
@@ -610,6 +634,14 @@ class DecodeEngine:
             self._table = self._dfull(
                 (B, S // self.kv_page), jnp.int32, self._rep_sh
             )
+        elif config.mixed:
+            # Two caches side by side, one an attention kind: the full
+            # layers keep max_seq rows a slot, the window layers a ring of
+            # ring_rows(cfg) (models/mixed.py).
+            from ray_lightning_tpu.models.mixed import empty_caches
+
+            self._k, self._v = empty_caches(config, B, S, cdt)
+            self._table = None
         else:
             self._k = self._dfull((L, B, S, Hkv, hd), cdt, self._cache_sh)
             self._v = self._dfull((L, B, S, Hkv, hd), cdt, self._cache_sh)
@@ -754,6 +786,16 @@ class DecodeEngine:
         self.fold_dispatches: Dict[int, int] = {
             k: 0 for k in self.fold_ladder
         }
+        #: The expert layers' counts, monotone since construction: they
+        #: leave the device with the tokens (fold) or the first token
+        #: (admission) and are added up where those are fetched. Mixed
+        #: configurations with expert layers only; else it stays zeros.
+        self.moe_totals: Dict[str, Dict[str, int]] = {
+            "decode": {"pairs_routed": 0, "pairs_held": 0,
+                       "experts_hit": 0, "token_steps": 0},
+            "prefill": {"pairs_routed": 0, "pairs_held": 0,
+                        "experts_hit": 0, "admissions": 0},
+        }
         from ray_lightning_tpu.obs.registry import get_registry as _greg
 
         _reg = _greg()
@@ -771,12 +813,12 @@ class DecodeEngine:
             "Fold depth K chosen per decode dispatch",
             buckets=(1, 2, 4, 8, 16, 32, 64),
         )
-        #: Double buffer: ((tok_block, emit_block, pb_toks|None),
+        #: Double buffer: ((tok_block, emit_block, pb_toks|None, moe|None),
         #: dispatch-time slot snapshot, piggybacked finals, fold K) of
         #: the fold currently executing on device.
         self._inflight: Optional[
             Tuple[
-                Tuple[Any, Any, Any],
+                Tuple[Any, Any, Any, Any],
                 List[Optional[SlotInfo]],
                 List[Tuple[int, int, PrefillTask, Optional[SlotInfo]]],
                 int,
@@ -888,14 +930,35 @@ class DecodeEngine:
             # pay 4x the dispatch latency per request. The slot
             # deactivates itself in-graph when the request is already
             # done at its first token (n_new == 1 or eos).
-            h, pf_k, pf_v = gpt_prefill(params, cfg, prompt, mesh=self.mesh)
+            if cfg.mixed:
+                # Rows past the prompt's end route to no expert; the full
+                # layers take all Pb rows, the window layers' ring the
+                # prompt's last rows; the expert layers' counts come out
+                # with the first token.
+                from ray_lightning_tpu.models.mixed import (
+                    mixed_rows,
+                    write_prefill_rows,
+                )
+
+                h, pf_k, pf_v, moe = mixed_rows(
+                    params, cfg, prompt, true_len=last_idx + 1
+                )
+                k_cache, v_cache = write_prefill_rows(
+                    k_cache, v_cache, pf_k, pf_v, slot, last_idx + 1
+                )
+            else:
+                h, pf_k, pf_v = gpt_prefill(
+                    params, cfg, prompt, mesh=self.mesh
+                )
+                zero = jnp.zeros((), jnp.int32)
+                start = (zero, slot, zero, zero, zero)
+                k_cache = jax.lax.dynamic_update_slice(k_cache, pf_k, start)
+                v_cache = jax.lax.dynamic_update_slice(v_cache, pf_v, start)
             h_last = jax.lax.dynamic_slice_in_dim(h, last_idx, 1, axis=1)
-            h_last = norm_fn(h_last, params["lnf_g"], params["lnf_b"])[:, 0]
+            h_last = norm_fn(
+                h_last, params["lnf_g"], params.get("lnf_b")
+            )[:, 0]
             logits = _lm_head(h_last, _head_weight(params, cfg))
-            zero = jnp.zeros((), jnp.int32)
-            start = (zero, slot, zero, zero, zero)
-            k_cache = jax.lax.dynamic_update_slice(k_cache, pf_k, start)
-            v_cache = jax.lax.dynamic_update_slice(v_cache, pf_v, start)
             key, sub = jax.random.split(key0)
             tok = sample_logits_batched(
                 sub[None], logits, temp[None], tk[None], tp[None]
@@ -918,7 +981,7 @@ class DecodeEngine:
                 upd(remaining, n_new - 1),
                 upd(eos_toks, eos),
                 tok,
-            )
+            ) + ((moe,) if cfg.mixed else ())
 
         # The fold factories take fold-K explicitly: one executable per
         # ladder rung, all pre-lowered below, so _pick_fold_k switches
@@ -1012,7 +1075,18 @@ class DecodeEngine:
                 upd(eos_toks, eos_v),
             )
 
-        cache_spec = spec(self._k) if self._k is not None else None
+        # K and V alike but for a configuration with mixed layer kinds,
+        # whose caches are dicts and whose v rows are narrower.
+        cache_spec = (
+            jax.tree_util.tree_map(spec, self._k)
+            if self._k is not None
+            else None
+        )
+        vcache_spec = (
+            jax.tree_util.tree_map(spec, self._v)
+            if self._v is not None
+            else None
+        )
         state_specs = (
             spec(self._cur),
             spec(self._pos),
@@ -1415,7 +1489,7 @@ class DecodeEngine:
                     .lower(
                         p_spec,
                         cache_spec,
-                        cache_spec,
+                        vcache_spec,
                         *state_specs,
                         prompt_spec,
                         i32,
@@ -1625,7 +1699,7 @@ class DecodeEngine:
                         make_step_impl(fk), (1, 2, 3, 4, 8, 9, 10),
                         step_out,
                     )
-                    .lower(p_spec, cache_spec, cache_spec, *state_specs,
+                    .lower(p_spec, cache_spec, vcache_spec, *state_specs,
                            *pb_specs)
                     .compile()
                 )
@@ -1890,9 +1964,10 @@ class DecodeEngine:
         the mesh's model axis, so their per-device bytes must shrink
         ~linearly in it; the token history and slot scalars replicate.
         Metadata only — reads buffer sizes, never syncs values."""
+        import jax
 
         def row(*arrs) -> Dict[str, int]:
-            live = [a for a in arrs if a is not None]
+            live = jax.tree_util.tree_leaves([a for a in arrs if a is not None])
             total = sum(int(a.nbytes) for a in live)
             if self.mesh is None:
                 return {"bytes": total, "per_device_bytes": total}
@@ -1924,6 +1999,41 @@ class DecodeEngine:
             ),
         }
         return out
+
+    def cache_stats(self) -> Dict[str, Dict[str, int]]:
+        """The dense KV cache by layer kind: layers, rows a slot and
+        bytes (K and V). Every layer of a uniform configuration is
+        ``full``; a paged engine has no dense cache and reports ``{}``."""
+        if self._k is None:
+            return {}
+        k, v = self._k, self._v
+        if not isinstance(k, dict):
+            k, v = {"full": k}, {"full": v}
+        return {
+            kind: {
+                "layers": int(k[kind].shape[0]),
+                "rows_per_slot": int(k[kind].shape[2]),
+                "bytes": int(k[kind].nbytes + v[kind].nbytes),
+            }
+            for kind in k
+        }
+
+    def moe_stats(self) -> Dict[str, Any]:
+        """``stats()["moe"]``: what the expert layers of a mixed
+        configuration routed and computed, monotone since construction
+        (``{}`` for any other configuration)."""
+        from ray_lightning_tpu.models.mixed import count_kind, experts_held
+
+        if not (self.cfg.mixed and count_kind(self.cfg, "experts")):
+            return {}
+        return {
+            "n_experts": self.cfg.n_experts,
+            "experts_held": list(experts_held(self.cfg)),
+            "top_k": self.cfg.moe_top_k,
+            "expert_layers": count_kind(self.cfg, "experts"),
+            "decode": dict(self.moe_totals["decode"]),
+            "prefill": dict(self.moe_totals["prefill"]),
+        }
 
     @property
     def num_active(self) -> int:
@@ -2175,6 +2285,7 @@ class DecodeEngine:
                 out.append((slot, None, False))
             return out
         pending = []
+        moe_counts: List[Any] = []  # mixed configurations: one a request
         for slot, r, prompt, P, n_new, pb, eos in staged:
             if self.spec != "off":
                 # Prompt into the drafters' history; the fold writes the
@@ -2192,7 +2303,7 @@ class DecodeEngine:
             (
                 self._k, self._v, self._cur, self._pos, self._temps,
                 self._top_ks, self._top_ps, self._keys, self._active,
-                self._remaining, self._eos, tok,
+                self._remaining, self._eos, tok, *moe,
             ) = self._admit_exec[pb](
                 self.params, self._k, self._v, self._cur, self._pos,
                 self._temps, self._top_ks, self._top_ps, self._keys,
@@ -2201,6 +2312,7 @@ class DecodeEngine:
                 temp, tk, tp, np.int32(n_new), np.int32(eos),
             )
             pending.append((slot, r, n_new, eos, tok))
+            moe_counts.extend(moe)
             self.spans.device_busy()
             if self.tracer is not None:
                 from ray_lightning_tpu.obs.trace import SPAN_PREFILL
@@ -2215,6 +2327,8 @@ class DecodeEngine:
         # (the fold in flight has finished too) until the next dispatch.
         with span(self.spans, "serve.engine.admit_wait", n=len(pending)):
             first = [int(np.asarray(tok)) for *_, tok in pending]
+            for m in moe_counts:
+                self._count_moe("prefill", np.asarray(m), 1)
         self.spans.device_idle()
         out: List[Tuple[int, int, bool]] = []
         for (slot, r, n_new, eos, _), tok in zip(pending, first):
@@ -3383,7 +3497,7 @@ class DecodeEngine:
     def _dispatch(
         self,
     ) -> Tuple[
-        Tuple[Any, Any, Any],
+        Tuple[Any, Any, Any, Any],
         List[Optional[SlotInfo]],
         List[Tuple[int, int, PrefillTask, Optional[SlotInfo]]],
         int,
@@ -3408,7 +3522,7 @@ class DecodeEngine:
     def _enqueue_fold(
         self, k: int
     ) -> Tuple[
-        Tuple[Any, Any, Any],
+        Tuple[Any, Any, Any, Any],
         List[Optional[SlotInfo]],
         List[Tuple[int, int, PrefillTask, Optional[SlotInfo]]],
         int,
@@ -3445,9 +3559,12 @@ class DecodeEngine:
         if spec_on:
             args.append(self._hist)
         res = self._step_exec[k](*args, *pb_args)
-        pb_toks = None
+        pb_toks = moe = None
         if self.piggyback_chunks:
             pb_toks = res[-1]
+            res = res[:-1]
+        if self.cfg.mixed:
+            moe = res[-1]
             res = res[:-1]
         if spec_on:
             (
@@ -3469,7 +3586,7 @@ class DecodeEngine:
         for slot, tokens in inserts:
             self._insert_prefix(slot, tokens)
         return (
-            (tok_block, emit_block, pb_toks),
+            (tok_block, emit_block, pb_toks, moe),
             list(self._slots),
             pb_finals,
             k,
@@ -3537,7 +3654,7 @@ class DecodeEngine:
 
     def _harvest(
         self,
-        outs: Tuple[Any, Any, Any],
+        outs: Tuple[Any, Any, Any, Any],
         snapshot: List[Optional[SlotInfo]],
         pb_finals: Sequence[
             Tuple[int, int, PrefillTask, Optional[SlotInfo]]
@@ -3548,6 +3665,11 @@ class DecodeEngine:
         with span(self.spans, "serve.engine.harvest_wait"):
             toks = np.asarray(outs[0])
             emits = np.asarray(outs[1])
+            if outs[3] is not None:
+                # the expert layers' counts of this fold: four numbers that
+                # were ready with the tokens
+                m = np.asarray(outs[3])
+                self._count_moe("decode", m[:3], int(m[3]))
         if self._inflight is None:
             # nothing was dispatched behind this fold: the device is
             # idle until the host enqueues again
@@ -3555,11 +3677,21 @@ class DecodeEngine:
         with span(self.spans, "serve.engine.harvest", rows=toks.shape[0]):
             return self._fan_out(toks, emits, outs, snapshot, pb_finals)
 
+    def _count_moe(self, phase: str, counts: np.ndarray, n: int) -> None:
+        """Add one fold's or one admission's ``[pairs routed, pairs on
+        held experts, held experts hit]`` to the totals; ``n`` is its
+        token steps (decode) or 1 (an admission)."""
+        row = self.moe_totals[phase]
+        row["pairs_routed"] += int(counts[0])
+        row["pairs_held"] += int(counts[1])
+        row["experts_hit"] += int(counts[2])
+        row["token_steps" if phase == "decode" else "admissions"] += n
+
     def _fan_out(
         self,
         toks: np.ndarray,
         emits: np.ndarray,
-        outs: Tuple[Any, Any, Any],
+        outs: Tuple[Any, Any, Any, Any],
         snapshot: List[Optional[SlotInfo]],
         pb_finals: Sequence[
             Tuple[int, int, PrefillTask, Optional[SlotInfo]]

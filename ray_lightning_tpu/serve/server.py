@@ -12,7 +12,7 @@ jax from its env before anything heavy loads.
 from __future__ import annotations
 
 import threading
-from typing import Any, Dict, List, Optional, Sequence
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 from ray_lightning_tpu.obs.trace import span
 
@@ -604,6 +604,26 @@ class ServeReplica:
             "rlt_gc_pause_seconds_total",
             "Seconds the cyclic collector paused this process, by generation",
         )
+        # The expert layers' totals (engine.moe_totals) and the dense KV
+        # cache's bytes by layer kind, as registry series.
+        self._moe_counters = {
+            key: self._registry.counter(
+                f"rlt_serve_moe_{key}_total", help_
+            )
+            for key, help_ in (
+                ("pairs_routed", "(token, expert) pairs the router chose, over all experts, by phase"),
+                ("pairs_held", "(token, expert) pairs that landed on experts this replica holds, by phase"),
+                ("experts_hit", "Held experts that received a token, summed over expert layers, by phase"),
+                ("token_steps", "Decode iterations with a live slot (phase=decode) and admissions (phase=prefill)"),
+            )
+        }
+        self._moe_mirrored: Dict[Tuple[str, str], int] = {}
+        kv_bytes = self._registry.gauge(
+            "rlt_serve_kv_bytes",
+            "Dense KV cache bytes (K and V) by layer kind",
+        )
+        for kind, row in self.engine.cache_stats().items():
+            kv_bytes.set(float(row["bytes"]), kind=kind)
         self._capture: Any = None
         # Warm the PRNGKey builder before the compile baseline: the first
         # submit would otherwise compile it in a fresh process and
@@ -1098,6 +1118,13 @@ class ServeReplica:
         for totals in (self.spans, self._rpc_spans):
             totals.mirror(self._span_seconds, self._span_count)
         self._gc.mirror(self._gc_seconds)
+        for phase, row in self.engine.moe_totals.items():
+            for key, total in row.items():
+                name = "token_steps" if key == "admissions" else key
+                done = self._moe_mirrored.get((phase, key), 0)
+                if total != done:
+                    self._moe_counters[name].inc(total - done, phase=phase)
+                    self._moe_mirrored[(phase, key)] = total
 
     def _spans_snapshot(self) -> Dict[str, Any]:
         """``stats()["spans"]``: what the host did, all monotone since
@@ -1156,11 +1183,20 @@ class ServeReplica:
                 # sharding): the row that validates tp=N divides the
                 # footprint by ~N.
                 "memory": self.engine.memory_stats(),
+                # The dense KV cache by layer kind (full / window): layers,
+                # rows a slot, bytes.
+                "cache": self.engine.cache_stats(),
                 "tracing": self.tracer.enabled,
                 "metrics": self._registry.to_dict(),
             }
         )
         snap["role"] = self.role
+        moe = self.engine.moe_stats()
+        if moe:
+            # Expert layers of a configuration that holds a share of the
+            # experts: pairs routed, pairs on held experts, held experts
+            # hit, token steps — monotone totals.
+            snap["moe"] = moe
         if self.kvfleet is not None:
             snap["kvfleet"] = self.kvfleet.stats()
         if self.engine.kvstore is not None:
