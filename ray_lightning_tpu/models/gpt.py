@@ -394,10 +394,13 @@ def gpt_logical_axes(cfg: GPTConfig) -> Dict[str, Any]:
 
 
 #: Logical axes of the serving engine's (L, slots, S, Hkv, hd) KV tensors
-#: — the per-slot decode cache and the prefix-pool blocks share the
-#: layout. KV heads shard over the mesh's "model" axis (DEFAULT_RULES
-#: "heads" -> "model"); slots, positions, and head_dim stay replicated so
-#: slot bookkeeping and the per-fold token harvest never cross devices.
+#: under a mesh — the per-slot decode cache and the prefix-pool blocks
+#: share the layout. KV heads shard over the mesh's "model" axis
+#: (DEFAULT_RULES "heads" -> "model"); slots, positions, and head_dim stay
+#: replicated so slot bookkeeping and the per-fold token harvest never
+#: cross devices. The single-device engine has no axis to shard and keeps
+#: (L, slots, S, Hkv * hd) instead, which the decode step reads where it
+#: lies (:func:`_attend_layer_cache`).
 DECODE_CACHE_AXES: Tuple[Optional[str], ...] = (
     "layers", None, None, "heads", "kv",
 )
@@ -1343,11 +1346,12 @@ def gpt_prefill_chunk(
 def _write_cache_rows(
     cache: jax.Array, li: int, new: jax.Array, pos: jax.Array
 ) -> jax.Array:
-    """Write layer ``li``'s new rows ``new`` (B, Hkv, hd) into the stacked
-    (L, B, S, Hkv, hd) cache at ``[li, b, pos[b]]`` and return the cache:
-    B rows move, everything else stays where it lies, so a caller that
-    donates the cache (or carries it through a scan) has it updated in
-    place.
+    """Write layer ``li``'s new rows ``new`` into the stacked cache at
+    ``[li, b, pos[b]]`` and return the cache: ``new`` (B, Hkv, hd) into a
+    cache of (L, B, S, Hkv, hd), or (B, Hkv * hd) into one of
+    (L, B, S, Hkv * hd). B rows move, everything else stays where it
+    lies, so a caller that donates the cache (or carries it through a
+    scan) has it updated in place.
 
     A position past the end lands on the last row, ``S - 1``, as a
     ``dynamic_update_slice`` clamps its start; the scatter used here would
@@ -1363,6 +1367,109 @@ def _write_cache_rows(
     )
 
 
+def cache_strip(
+    cache: jax.Array, slot: Any, row: Any, rows: int, kv_shape: Tuple[int, int]
+) -> jax.Array:
+    """``rows`` positions of one slot, from ``row`` on, out of the stacked
+    slot cache as a (L, 1, rows, Hkv, hd) strip — the form prefill chunks,
+    pool blocks and exported pages have — whichever layout the cache keeps:
+    (L, B, S, Hkv, hd), or (L, B, S, Hkv * hd) (``kv_shape`` = (Hkv, hd)
+    splits its rows; only the strip is reshaped, never the cache)."""
+    L = cache.shape[0]
+    tail = cache.shape[3:]
+    strip = jax.lax.dynamic_slice(
+        cache, (0, slot, row) + (0,) * len(tail), (L, 1, rows) + tail
+    )
+    return strip.reshape((L, 1, rows) + tuple(kv_shape))
+
+
+def cache_strip_put(
+    cache: jax.Array, strip: jax.Array, slot: Any, row: Any
+) -> jax.Array:
+    """Write a (L, 1, rows, Hkv, hd) strip into one slot of the stacked
+    cache from ``row`` on: :func:`cache_strip`'s inverse, for either
+    layout."""
+    tail = cache.shape[3:]
+    return jax.lax.dynamic_update_slice(
+        cache,
+        strip.reshape(strip.shape[:3] + tail),
+        (0, slot, row) + (0,) * len(tail),
+    )
+
+
+def _kv_head_of_group(n_kv_head: int) -> jax.Array:
+    """(Hkv, Hkv) float32, 1 where query group g reads KV head h: the
+    identity (group g is query heads g * rep .. (g + 1) * rep - 1). Its
+    own function so that a test can plant the wrong map."""
+    return jnp.eye(n_kv_head, dtype=jnp.float32)
+
+
+def _attend_layer_cache(
+    q: jax.Array, kc_l: jax.Array, vc_l: jax.Array, allowed: jax.Array
+) -> jax.Array:
+    """Attention of Q query rows a slot against one layer's cache, after
+    that layer's write: ``q`` (B, Q, H, hd), ``allowed`` (B, Q, S) bool
+    (the band mask on absolute positions); float32 (B, Q, H, hd). The one
+    statement of the cached-attention math: :func:`gpt_decode_step`
+    (Q = 1) and :func:`gpt_decode_verify` both call it.
+
+    The cache's rank says which read it wants.
+
+    - ``(B, S, Hkv, hd)``: grouped attention. The q heads fold to
+      (Hkv, rep) groups (head h reads KV head h // rep, matching
+      :func:`_project_qkv`'s ``jnp.repeat`` layout) and each group
+      contracts with its own KV head.
+    - ``(B, S, Hkv * hd)``, a position's KV heads side by side in one
+      row: every query head is laid out over a whole row with zeros under
+      the other KV heads' dims, so ONE matmul a slot against the cache as
+      it lies gives all heads' scores — Hkv times the multiplications of
+      the grouped form, every added term an exact zero, on a read that the
+      cache's bytes bound — and of p·V's (H, Hkv * hd) result each head
+      keeps its own KV head's block. With the KV heads on an axis of their
+      own the scatter of a step's rows wants (slot, row, head, d) and the
+      matmul wants the rows minor: whichever order is stored, the TPU
+      compiler copies the layer's cache out of the stacked array every
+      step. Rows satisfy both (PERF.md §6, PR 28 and PR 29).
+
+    Either way: q scaled before the product, cache upcast to float32
+    (exact), float32 scores and softmax, exact ``-inf`` masking, p kept in
+    float32 for p·V.
+    """
+    B, Q, H, hd = q.shape
+    S = kc_l.shape[1]
+    rows = kc_l.ndim == 3
+    Hkv = kc_l.shape[-1] // hd if rows else kc_l.shape[2]
+    rep = H // Hkv
+    # (B, Hkv, Q * rep, hd): a group's Q * rep query rows side by side
+    # (with Q = 1 a plain reshape).
+    qg = jnp.moveaxis(q.reshape(B, Q, Hkv, rep, hd), 1, 2).reshape(
+        B, Hkv, Q * rep, hd
+    ).astype(jnp.float32) * (1.0 / np.sqrt(hd))
+    kf, vf = kc_l.astype(jnp.float32), vc_l.astype(jnp.float32)
+    if rows:
+        # Laid out elementwise, not by an einsum: a product with 1 or 0 is
+        # exact whatever precision the backend multiplies matrices in.
+        own = _kv_head_of_group(Hkv)[:, None, :, None]
+        q_rows = (qg[:, :, :, None, :] * own).reshape(B, Q * H, Hkv * hd)
+        s = jnp.einsum("bnc,bsc->bns", q_rows, kf)
+    else:
+        s = jnp.einsum("bgrk,bsgk->bgrs", qg, kf)
+    s = jnp.where(
+        allowed[:, None, :, None, :],
+        s.reshape(B, Hkv, Q, rep, S),
+        float("-inf"),
+    )
+    p = jax.nn.softmax(s, axis=-1)
+    if rows:
+        o = jnp.einsum("bns,bsc->bnc", p.reshape(B, Q * H, S), vf)
+        o = (o.reshape(B, Hkv, Q * rep, Hkv, hd) * own).sum(axis=3)
+    else:
+        o = jnp.einsum("bgrs,bsgk->bgrk", p.reshape(B, Hkv, Q * rep, S), vf)
+    return jnp.moveaxis(o.reshape(B, Hkv, Q, rep, hd), 2, 1).reshape(
+        B, Q, H, hd
+    )
+
+
 def gpt_decode_step(
     params: Dict[str, Any],
     cfg: GPTConfig,
@@ -1375,7 +1482,10 @@ def gpt_decode_step(
 
     ``cur`` (B,) int32 holds each slot's current token; ``pos`` (B,) int32
     the position that token occupies. The step computes each token's k/v,
-    writes them into the (L, B, S, Hkv, hd) caches at that slot's position,
+    writes them into the caches — (L, B, S, Hkv, hd), or (L, B, S, Hkv * hd)
+    with a position's KV heads side by side in one row: the rank says
+    which, and :func:`_attend_layer_cache` which read follows — at that
+    slot's position,
     attends against ``position <= pos[b]`` (band-limited by
     ``attn_window``/``attn_sinks``), and returns fp32 logits (B, V) for the
     NEXT position plus the updated caches.
@@ -1409,7 +1519,6 @@ def gpt_decode_step(
     norm_fn = _make_norm(cfg)
     L, H, hd = cfg.n_layer, cfg.n_head, cfg.head_dim
     Hkv = cfg.kv_head
-    rep = H // Hkv
     B = cur.shape[0]
     S = k_cache.shape[2]
 
@@ -1459,31 +1568,16 @@ def gpt_decode_step(
             k_new = _rope_slot(k_new)
         return q, k_new, v_new
 
-    def attend(q, kc_l, vc_l):
-        # Grouped attention against the Hkv-headed cache: q heads fold
-        # to (Hkv, rep) groups (head h reads kv head h // rep, matching
-        # _project_qkv's jnp.repeat layout).
-        qg = q.reshape(B, Hkv, rep, hd).astype(jnp.float32)
-        s = jnp.einsum(
-            "bgrk,bsgk->bgrs",
-            qg * (1.0 / np.sqrt(hd)),
-            kc_l.astype(jnp.float32),
-        )
-        from ray_lightning_tpu.ops.attention import band_allowed
+    from ray_lightning_tpu.ops.attention import band_allowed
 
-        pos_ids = jnp.arange(S)[None, None, None]
-        s = jnp.where(
-            band_allowed(
-                pos[:, None, None, None], pos_ids, cfg.attn_window,
-                cfg.attn_sinks,
-            ),
-            s,
-            float("-inf"),
-        )
-        p = jax.nn.softmax(s, axis=-1)
-        return jnp.einsum(
-            "bgrs,bsgk->bgrk", p, vc_l.astype(jnp.float32)
-        ).reshape(B, H, hd).astype(cdt)
+    # (B, 1, S): each slot's one query row against absolute positions.
+    allowed = band_allowed(
+        pos[:, None, None], jnp.arange(S)[None, None], cfg.attn_window,
+        cfg.attn_sinks,
+    )
+    # A step's K/V rows in the cache's own form: (B, Hkv, hd), or
+    # (B, Hkv * hd) for a cache of rows.
+    row_shape = (B,) + k_cache.shape[3:]
 
     def mlp(h, lp):
         m = norm_fn(h[:, None], lp["ln2_g"], lp["ln2_b"])
@@ -1510,10 +1604,16 @@ def gpt_decode_step(
         with jax.named_scope("qkv_rope"):
             q, k_new, v_new = qkv_rope(h, lp)
         with jax.named_scope("cache_write"):
-            k_cache = _write_cache_rows(k_cache, li, k_new, pos)
-            v_cache = _write_cache_rows(v_cache, li, v_new, pos)
+            k_cache = _write_cache_rows(
+                k_cache, li, k_new.reshape(row_shape), pos
+            )
+            v_cache = _write_cache_rows(
+                v_cache, li, v_new.reshape(row_shape), pos
+            )
         with jax.named_scope("cache_attention"):
-            o = attend(q, k_cache[li], v_cache[li])
+            o = _attend_layer_cache(
+                q[:, None], k_cache[li], v_cache[li], allowed
+            )[:, 0].astype(cdt)
             h = h + jnp.einsum(
                 "bhk,hkd->bd", o, dequant(lp["wo"], cdt)
             ) + lp["bo"].astype(cdt)
@@ -1644,7 +1744,7 @@ def _piggyback_prefill(
     ) = piggyback
     refuse_mixed(cfg, "piggybacked prefill chunks in the decode fold")
     norm_fn = _make_norm(cfg)
-    L, Hkv, hd = cfg.n_layer, cfg.kv_head, cfg.head_dim
+    Hkv, hd = cfg.kv_head, cfg.head_dim
     C_rows, cb = pb_chunk.shape
     head_w = _head_weight(params, cfg)
     toks_out = []
@@ -1661,21 +1761,13 @@ def _piggyback_prefill(
         chunk_r = pb_chunk[r][None]  # (1, cb)
         if page_table is None:
             S = k_cache.shape[2]
-            k_slot = jax.lax.dynamic_slice(
-                k_cache, (0, slot, 0, 0, 0), (L, 1, S, Hkv, hd)
-            )
-            v_slot = jax.lax.dynamic_slice(
-                v_cache, (0, slot, 0, 0, 0), (L, 1, S, Hkv, hd)
-            )
+            k_slot = cache_strip(k_cache, slot, 0, S, (Hkv, hd))
+            v_slot = cache_strip(v_cache, slot, 0, S, (Hkv, hd))
             h, k_slot, v_slot = gpt_prefill_chunk(
                 params, cfg, chunk_r, k_slot, v_slot, start, tl
             )
-            k_cache = jax.lax.dynamic_update_slice(
-                k_cache, k_slot, (0, slot, 0, 0, 0)
-            )
-            v_cache = jax.lax.dynamic_update_slice(
-                v_cache, v_slot, (0, slot, 0, 0, 0)
-            )
+            k_cache = cache_strip_put(k_cache, k_slot, slot, 0)
+            v_cache = cache_strip_put(v_cache, v_slot, slot, 0)
         else:
             trow = jax.lax.dynamic_slice(
                 page_table, (slot, 0), (1, page_table.shape[1])
@@ -1884,11 +1976,13 @@ def gpt_decode_verify(
     returns fp32 logits (B, Q, V): ``logits[:, i]`` predicts the token at
     position ``pos + i + 1`` GIVEN inputs ``toks[:, :i+1]``.
 
-    Exactness: this is :func:`gpt_decode_step` with a query axis — same
-    einsum contractions, same fp32 score/softmax order, same grouped-KV
-    fold, same per-row norms — so ``logits[:, i]`` is bit-identical to
-    running ``gpt_decode_step`` sequentially over ``toks[:, :i+1]``
-    (asserted in tests/test_serve.py under the reference config). Rows
+    Exactness: this is :func:`gpt_decode_step` with a query axis — the
+    same attention (:func:`_attend_layer_cache`, on either cache layout:
+    a group's Q * rep query rows stand where the step's rep rows stand),
+    same fp32 score/softmax order, same per-row norms — so the tokens
+    sampled from ``logits[:, i]`` are those of running ``gpt_decode_step``
+    sequentially over ``toks[:, :i+1]`` (asserted in tests/test_serve.py
+    under the reference config). Rows
     whose draft is later rejected leave garbage K/V behind; those rows
     sit at ``position > pos`` after the accept shrinks ``pos`` back, so
     the slot masks hide them and the next verify's own writes refresh
@@ -1902,7 +1996,6 @@ def gpt_decode_verify(
     norm_fn = _make_norm(cfg)
     L, H, hd = cfg.n_layer, cfg.n_head, cfg.head_dim
     Hkv = cfg.kv_head
-    rep = H // Hkv
     B, Q = toks.shape
     S = k_cache.shape[2]
 
@@ -1939,9 +2032,14 @@ def gpt_decode_verify(
     idx = rows[None] - pos[:, None]  # (B, S): row's index into the chunk
     wvalid = (idx >= 0) & (idx < Q)
     gidx = jnp.clip(idx, 0, Q - 1)
+    # (B, Q, S): query row i of a slot sees absolute positions <= pos + i.
+    allowed = band_allowed(
+        positions[:, :, None], rows[None, None], cfg.attn_window,
+        cfg.attn_sinks,
+    )
 
     def layer(h, args):
-        lp, kc_l, vc_l = args  # caches (B, S, Hkv, hd)
+        lp, kc_l, vc_l = args  # caches (B, S, Hkv, hd) or (B, S, Hkv * hd)
         a = norm_fn(h, lp["ln1_g"], lp["ln1_b"])
         if Hkv == H:
             qkv = (
@@ -1962,44 +2060,27 @@ def gpt_decode_verify(
         if rope_tables is not None:
             q = _rope_rows(q)
             k_new = _rope_rows(k_new)
-        # Masked row-gather write of all Q rows into [pos, pos + Q).
-        wmask = wvalid[:, :, None, None]
+        # Masked row-gather write of all Q rows into [pos, pos + Q); a
+        # cache of rows (B, S, Hkv * hd) takes them with the KV heads
+        # side by side.
+        tail = kc_l.shape[2:]
+        wmask = wvalid.reshape((B, S) + (1,) * len(tail))
+        widx = gidx.reshape(wmask.shape)
         kc_l = jnp.where(
             wmask,
             jnp.take_along_axis(
-                k_new.astype(cdt), gidx[:, :, None, None], axis=1
+                k_new.astype(cdt).reshape((B, Q) + tail), widx, axis=1
             ),
             kc_l,
         )
         vc_l = jnp.where(
             wmask,
             jnp.take_along_axis(
-                v_new.astype(cdt), gidx[:, :, None, None], axis=1
+                v_new.astype(cdt).reshape((B, Q) + tail), widx, axis=1
             ),
             vc_l,
         )
-        # gpt_decode_step's grouped attention, one extra query axis: q
-        # heads fold to (Hkv, rep) groups; scale BEFORE the einsum, fp32
-        # scores, exact -inf band mask on absolute positions.
-        qg = q.reshape(B, Q, Hkv, rep, hd).astype(jnp.float32)
-        s = jnp.einsum(
-            "bqgrk,bsgk->bqgrs",
-            qg * (1.0 / np.sqrt(hd)),
-            kc_l.astype(jnp.float32),
-        )
-        pos_ids = rows[None, None, None, None]
-        s = jnp.where(
-            band_allowed(
-                positions[:, :, None, None, None], pos_ids,
-                cfg.attn_window, cfg.attn_sinks,
-            ),
-            s,
-            float("-inf"),
-        )
-        p = jax.nn.softmax(s, axis=-1)
-        o = jnp.einsum(
-            "bqgrs,bsgk->bqgrk", p, vc_l.astype(jnp.float32)
-        ).reshape(B, Q, H, hd).astype(cdt)
+        o = _attend_layer_cache(q, kc_l, vc_l, allowed).astype(cdt)
         h = h + jnp.einsum(
             "bqhk,hkd->bqd", o, dequant(lp["wo"], cdt)
         ) + lp["bo"].astype(cdt)

@@ -40,6 +40,25 @@ sync per token):
   snapshot, and the deactivate/admission writes queue AFTER the in-flight
   fold, so a recycled slot can never inherit a stale token.
 
+The cache's layout follows who reads it. The single-device dense engine
+keeps its slot cache as ``(L, slots, max_seq, Hkv * hd)``: a position's
+KV heads side by side in one row. The decode step's scatter of new rows
+and its two cache matmuls (queries laid out block-diagonally over the KV
+heads, ``models/gpt.py:_attend_layer_cache``) then both take a layer's
+cache where it lies; with the KV heads on an axis of their own the
+scatter wants (slot, row, head, d), the matmul wants the rows minor, and
+the TPU compiler copies the layer (268 MB at 64 slots x 2048) out of the
+stack before every attention read (PERF.md §6, PR 28 and PR 29). The step
+decides from the rank of the cache it is given; every other program of
+this engine (admission, chunked and piggybacked prefill, the prefix
+pool's copy) converts the prompt's rows, one slot's strip or one block
+at its boundary (``cache_strip`` / ``cache_strip_put``), never the
+cache. Under a mesh the cache keeps ``(L, slots, max_seq, Hkv, hd)`` —
+the head axis is what shards — and a paged engine has no dense cache;
+pool blocks, spilled and exported blocks are ``(L, 1, block, Hkv, hd)``
+in every engine. ``cache_stats()[kind]["row_layout"]`` says which read an
+engine runs.
+
 Exactness contract: a request decodes token-identically to a solo
 ``gpt_generate`` call (greedy), no matter which batchmates share its
 steps and no matter the fold. Two properties deliver it, both asserted
@@ -79,8 +98,9 @@ prefix reuse, pool-of-blocks form):
   perturbs the numerics. The final chunk samples the first token and arms
   the slot in-graph, exactly like the fused admit.
 - **Prefix caching (``prefix_blocks=N``).** A device-resident block pool
-  (L, N, ``prefix_block``, Hkv, hd) keyed by chained block digests of the
-  token prefix. Admission walks the longest cached prefix, seeds the
+  (L, N, ``prefix_block``, Hkv, hd) — that layout in every engine, so
+  spilled and exported blocks have one format — keyed by chained block
+  digests of the token prefix. Admission walks the longest cached prefix, seeds the
   slot's KV rows through ONE compiled bidirectional cache-to-cache copy
   executable, and chunk-prefills only the suffix; completed prefills
   insert their new full blocks back (same executable, reversed). Blocks
@@ -143,7 +163,8 @@ cancel + recycle with a verify in flight).
 All of the above is single-device; ``mesh=`` makes the engine
 MESH-NATIVE (tensor-parallel decode across chips — the serving-side
 analogue of the training meshes in ``parallel/``): attention heads, the
-Hkv-headed KV cache, and the prefix pool shard over the mesh's "model"
+KV cache — ``(L, slots, max_seq, Hkv, hd)`` here, the head axis kept for
+this — and the prefix pool shard over the mesh's "model"
 axis (``models/gpt.py:DECODE_CACHE_AXES`` resolved through the same
 ``spec_from_logical`` rules the trainer uses; weights through
 ``gpt_param_shardings``), while slot metadata and the token history stay
@@ -643,8 +664,18 @@ class DecodeEngine:
             self._k, self._v = empty_caches(config, B, S, cdt)
             self._table = None
         else:
-            self._k = self._dfull((L, B, S, Hkv, hd), cdt, self._cache_sh)
-            self._v = self._dfull((L, B, S, Hkv, hd), cdt, self._cache_sh)
+            # One device: a position's KV heads side by side in one row.
+            # The decode step's scatter and its two cache matmuls then
+            # both take a layer's cache where it lies (models/gpt.py:
+            # _attend_layer_cache); with a head axis the TPU compiler
+            # copies the layer out of the stack every token step. Under a
+            # mesh the KV heads keep that axis: DECODE_CACHE_AXES shards
+            # it over "model", and each device contracts its own heads.
+            shape = (
+                (L, B, S, Hkv * hd) if mesh is None else (L, B, S, Hkv, hd)
+            )
+            self._k = self._dfull(shape, cdt, self._cache_sh)
+            self._v = self._dfull(shape, cdt, self._cache_sh)
             self._table = None
         # Prefix pool: device-resident K/V blocks + host digest map/LRU.
         if self.prefix_blocks:
@@ -876,6 +907,8 @@ class DecodeEngine:
             _head_weight,
             _lm_head,
             _make_norm,
+            cache_strip,
+            cache_strip_put,
             gpt_decode_fold,
             gpt_decode_fold_spec,
             gpt_prefill,
@@ -950,10 +983,8 @@ class DecodeEngine:
                 h, pf_k, pf_v = gpt_prefill(
                     params, cfg, prompt, mesh=self.mesh
                 )
-                zero = jnp.zeros((), jnp.int32)
-                start = (zero, slot, zero, zero, zero)
-                k_cache = jax.lax.dynamic_update_slice(k_cache, pf_k, start)
-                v_cache = jax.lax.dynamic_update_slice(v_cache, pf_v, start)
+                k_cache = cache_strip_put(k_cache, pf_k, slot, 0)
+                v_cache = cache_strip_put(v_cache, pf_v, slot, 0)
             h_last = jax.lax.dynamic_slice_in_dim(h, last_idx, 1, axis=1)
             h_last = norm_fn(
                 h_last, params["lnf_g"], params.get("lnf_b")
@@ -1121,21 +1152,13 @@ class DecodeEngine:
             # the slot inactive with pos = start+true_len: the only row
             # an interleaved fold's idle-lane write can scribble on, and
             # the next chunk overwrites it before any read.
-            k_slot = jax.lax.dynamic_slice(
-                k_cache, (0, slot, 0, 0, 0), (L, 1, S, Hkv, hd)
-            )
-            v_slot = jax.lax.dynamic_slice(
-                v_cache, (0, slot, 0, 0, 0), (L, 1, S, Hkv, hd)
-            )
+            k_slot = cache_strip(k_cache, slot, 0, S, (Hkv, hd))
+            v_slot = cache_strip(v_cache, slot, 0, S, (Hkv, hd))
             h, k_slot, v_slot = gpt_prefill_chunk(
                 params, cfg, chunk, k_slot, v_slot, start, true_len
             )
-            k_cache = jax.lax.dynamic_update_slice(
-                k_cache, k_slot, (0, slot, 0, 0, 0)
-            )
-            v_cache = jax.lax.dynamic_update_slice(
-                v_cache, v_slot, (0, slot, 0, 0, 0)
-            )
+            k_cache = cache_strip_put(k_cache, k_slot, slot, 0)
+            v_cache = cache_strip_put(v_cache, v_slot, slot, 0)
             h_last = jax.lax.dynamic_slice_in_dim(h, true_len - 1, 1, axis=1)
             h_last = norm_fn(h_last, params["lnf_g"], params["lnf_b"])[:, 0]
             logits = _lm_head(h_last, _head_weight(params, cfg))
@@ -1204,20 +1227,12 @@ class DecodeEngine:
             src_v = jax.lax.dynamic_slice(
                 pool_v, (0, block, 0, 0, 0), (L, 1, bs, Hkv, hd)
             )
-            dst_k = jax.lax.dynamic_slice(
-                k_cache, (0, slot, row, 0, 0), (L, 1, bs, Hkv, hd)
-            )
-            dst_v = jax.lax.dynamic_slice(
-                v_cache, (0, slot, row, 0, 0), (L, 1, bs, Hkv, hd)
-            )
+            dst_k = cache_strip(k_cache, slot, row, bs, (Hkv, hd))
+            dst_v = cache_strip(v_cache, slot, row, bs, (Hkv, hd))
             new_k = jnp.where(to_slot, src_k, dst_k)
             new_v = jnp.where(to_slot, src_v, dst_v)
-            k_cache = jax.lax.dynamic_update_slice(
-                k_cache, new_k, (0, slot, row, 0, 0)
-            )
-            v_cache = jax.lax.dynamic_update_slice(
-                v_cache, new_v, (0, slot, row, 0, 0)
-            )
+            k_cache = cache_strip_put(k_cache, new_k, slot, row)
+            v_cache = cache_strip_put(v_cache, new_v, slot, row)
             pool_k = jax.lax.dynamic_update_slice(
                 pool_k, new_k, (0, block, 0, 0, 0)
             )
@@ -2001,9 +2016,13 @@ class DecodeEngine:
         return out
 
     def cache_stats(self) -> Dict[str, Dict[str, int]]:
-        """The dense KV cache by layer kind: layers, rows a slot and
-        bytes (K and V). Every layer of a uniform configuration is
-        ``full``; a paged engine has no dense cache and reports ``{}``."""
+        """The dense KV cache by layer kind: layers, rows a slot, bytes
+        (K and V) and ``row_layout`` — whether a position's KV heads lie
+        side by side in one cache row, which is the read every decode
+        step of this engine then runs (models/gpt.py:_attend_layer_cache;
+        false under a mesh, where the heads keep an axis to shard). Every
+        layer of a uniform configuration is ``full``; a paged engine has
+        no dense cache and reports ``{}``."""
         if self._k is None:
             return {}
         k, v = self._k, self._v
@@ -2014,6 +2033,7 @@ class DecodeEngine:
                 "layers": int(k[kind].shape[0]),
                 "rows_per_slot": int(k[kind].shape[2]),
                 "bytes": int(k[kind].nbytes + v[kind].nbytes),
+                "row_layout": k[kind].ndim == 4,
             }
             for kind in k
         }

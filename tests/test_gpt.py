@@ -820,6 +820,17 @@ DECODE_VARIANTS = {
         mlp_variant="swiglu", tie_word_embeddings=False,
     ),
 }
+#: and one more for the cache of rows below
+ROW_VARIANTS = {
+    **DECODE_VARIANTS,
+    # four KV heads under eight query heads, a window of three and one sink:
+    # the band mask hides rows that a wrong mask would read
+    "llama-gqa-window-sinks": dict(
+        n_head=8, n_kv_head=4, pos_embed="rope", norm_impl="rmsnorm",
+        mlp_variant="swiglu", tie_word_embeddings=False, attn_window=3,
+        attn_sinks=1,
+    ),
+}
 
 
 def _decode_step_case(variant, S=8, B=3, seed=5):
@@ -831,7 +842,7 @@ def _decode_step_case(variant, S=8, B=3, seed=5):
     import jax
     import jax.numpy as jnp
 
-    cfg = dataclasses.replace(TINY, **DECODE_VARIANTS[variant])
+    cfg = dataclasses.replace(TINY, **ROW_VARIANTS[variant])
     params = init_gpt_params(jax.random.PRNGKey(seed), cfg)
     shape = (cfg.n_layer, B, S, cfg.kv_head, cfg.head_dim)
     kk, kv, kc = jax.random.split(jax.random.PRNGKey(seed + 1), 3)
@@ -933,3 +944,117 @@ def test_decode_step_clamps_a_position_past_the_cache(
     )  # (B, S)
     assert changed[1].tolist() == [False] * (S - 1) + [True]
     assert changed[0].tolist() == [i == 2 for i in range(S)]
+
+
+# -- the decode step on a cache of rows (a position's KV heads side by side) --
+#: positions of the three slots: inside the cache, and one slot past its end
+#: (the clamp to the last row)
+ROW_POSITIONS = {"inside": [0, 3, 7], "past-the-end": [2, 8 + 3, 7]}
+
+
+def _as_rows(cache):
+    """(L, B, S, Hkv, hd) -> (L, B, S, Hkv * hd): the same values."""
+    return cache.reshape(cache.shape[:3] + (-1,))
+
+
+def _row_and_head_outputs(fn, variant, pos, **case_kw):
+    """``fn(params, cfg, cur_or_toks, pos, k, v)`` once on the 5-D cache and
+    once on the cache of rows holding the same values. Random caches: K
+    differs in every KV head, so a wrong head-to-group map cannot pass."""
+    import jax
+    import jax.numpy as jnp
+
+    cfg, params, cur, k_cache, v_cache = _decode_step_case(variant, **case_kw)
+    pos = jnp.asarray(pos, jnp.int32)
+
+    def run(k, v):
+        return jax.jit(lambda p, c, q, k, v: fn(p, cfg, c, q, k, v))(params, cur, pos, k, v)
+
+    return cfg, (k_cache, v_cache), run(k_cache, v_cache), run(_as_rows(k_cache), _as_rows(v_cache))
+
+
+def _assert_rows_equal_heads(before, heads_out, rows_out, changed_rows):
+    """Logits and written rows to 1e-5 (the first layer's rows, which no
+    attention precedes, bit for bit), and only ``changed_rows[b]`` of slot
+    b differ from the cache that went in."""
+    np.testing.assert_allclose(np.asarray(rows_out[0]), np.asarray(heads_out[0]), rtol=0, atol=1e-5)
+    for was, heads, rows in zip(before, heads_out[1:], rows_out[1:]):
+        assert rows.shape == _as_rows(was).shape and rows.dtype == was.dtype
+        np.testing.assert_array_equal(np.asarray(rows[0]), np.asarray(_as_rows(heads)[0]))
+        np.testing.assert_allclose(np.asarray(rows), np.asarray(_as_rows(heads)), rtol=0, atol=1e-5)
+        changed = np.any(np.asarray(rows != _as_rows(was)), axis=(0, 3))  # (B, S)
+        for b, want in enumerate(changed_rows):
+            assert np.flatnonzero(changed[b]).tolist() == sorted(want), (b, changed[b])
+
+
+@pytest.mark.parametrize("where", sorted(ROW_POSITIONS))
+@pytest.mark.parametrize("variant", sorted(ROW_VARIANTS))
+def test_decode_step_on_a_cache_of_rows_equals_the_step_on_the_head_axis_cache(variant, where):
+    from ray_lightning_tpu.models import gpt as G
+
+    pos = ROW_POSITIONS[where]
+    cfg, before, heads_out, rows_out = _row_and_head_outputs(G.gpt_decode_step, variant, pos)
+    _assert_rows_equal_heads(before, heads_out, rows_out, [[min(p, 7)] for p in pos])
+
+
+@pytest.mark.parametrize("variant", sorted(ROW_VARIANTS))
+def test_decode_step_on_rows_fails_when_a_query_group_reads_its_neighbours_kv_head(variant, monkeypatch):
+    """The planted fault: the block-diagonal layout built with the identity
+    shifted by one KV head. The logits then miss by ten thousand times the
+    tolerance of the test above (0.26 to 0.36 against 6e-8 read sound)."""
+    import jax.numpy as jnp
+
+    from ray_lightning_tpu.models import gpt as G
+
+    monkeypatch.setattr(G, "_kv_head_of_group", lambda n: jnp.roll(jnp.eye(n, dtype=jnp.float32), 1, axis=1))
+    _, _, heads_out, rows_out = _row_and_head_outputs(G.gpt_decode_step, variant, ROW_POSITIONS["inside"])
+    assert float(np.abs(np.asarray(rows_out[0]) - np.asarray(heads_out[0])).max()) > 0.1
+
+
+@pytest.mark.parametrize("variant", sorted(ROW_VARIANTS))
+def test_decode_verify_on_a_cache_of_rows_equals_verify_on_the_head_axis_cache(variant):
+    """Three query rows a slot (Q > 1); the last slot's rows run past the
+    cache's end and are dropped by the masked write in both layouts."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_lightning_tpu.models import gpt as G
+
+    Q, pos = 3, [0, 4, 6]
+
+    def verify(params, cfg, cur, pos, k, v):
+        toks = jnp.stack([cur, (cur + 1) % cfg.vocab_size, (cur + 5) % cfg.vocab_size], axis=1)
+        return G.gpt_decode_verify(params, cfg, toks, pos, k, v)
+
+    cfg, before, heads_out, rows_out = _row_and_head_outputs(verify, variant, pos)
+    assert rows_out[0].shape == (3, Q, cfg.vocab_size)
+    _assert_rows_equal_heads(before, heads_out, rows_out, [[r for r in range(p, p + Q) if r < 8] for p in pos])
+
+
+@pytest.mark.parametrize("variant", sorted(ROW_VARIANTS))
+def test_decode_fold_carries_a_cache_of_rows_and_changes_one_row_a_slot_a_step(variant):
+    """Three folded steps on a cache of rows: the tokens and the state are
+    those of the fold on the 5-D cache, each slot's rows ``pos .. pos + 2``
+    change (a frozen slot rewrites the row it stands on) and nothing else."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_lightning_tpu.models import gpt as G
+
+    fold, pos = 3, [0, 2, 5]
+
+    def folded(params, cfg, cur, pos, k, v):
+        B = cur.shape[0]
+        out = G.gpt_decode_fold(
+            params, cfg, cur, pos, jax.random.split(jax.random.PRNGKey(9), B), jnp.zeros((B,)),
+            jnp.zeros((B,), jnp.int32), jnp.ones((B,)), jnp.asarray([True, True, False]),
+            jnp.asarray([5, 2, 4], jnp.int32), jnp.full((B,), -1, jnp.int32), k, v, fold=fold,
+        )
+        toks, emit, cur, pos, _, active, remaining, k, v = out
+        state = [toks, emit, cur[None], pos[None], active[None], remaining[None]]
+        return jnp.concatenate([a.astype(jnp.float32) for a in state]), k, v
+
+    _, before, heads_out, rows_out = _row_and_head_outputs(folded, variant, pos)
+    # slot 0 runs all three steps, slot 1 freezes after two (then rewrites
+    # the row it stopped on), slot 2 is idle and rewrites its own row
+    _assert_rows_equal_heads(before, heads_out, rows_out, [[0, 1, 2], [2, 3, 4], [5]])
