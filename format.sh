@@ -6,8 +6,8 @@ set -euo pipefail
 cd "$(dirname "$0")"
 
 # ray_lightning_tpu covers the obs/ package; tools/ carries the obs
-# snapshot + profiling scripts the watcher runs from a bare archive.
-TARGETS=(ray_lightning_tpu tests examples tools bench.py __graft_entry__.py)
+# snapshot and the chip go/no-go scripts.
+TARGETS=(ray_lightning_tpu tests examples tools __graft_entry__.py)
 
 if ! command -v ruff >/dev/null 2>&1; then
     echo "ruff not installed; skipping lint (CI installs it)" >&2

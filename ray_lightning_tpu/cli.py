@@ -768,7 +768,8 @@ def run_serve(config: Dict[str, Any]) -> Dict[str, Any]:
         10s) under the reserved _canary tenant at floor priority;
         TTFT / decode rate / exactness land in dedicated canary.*
         series and alert on deviation from the recorded baseline
-        envelope (canary_baseline: JSON file written by bench.py).
+        envelope (canary_baseline: a JSON file in the format
+        obs.watchtower.CanaryLane documents).
         Canary traffic is excluded from organic accounting (cost
         ledger, goodput, autoscaler pressure, tenant rows).
       supervisor: drive the driver-side FleetSupervisor (default on) —

@@ -28,7 +28,8 @@ import traceback
 #: ``kill()`` SIGTERMs shortly after sending the "shutdown" message, so the
 #: signal routinely lands while atexit is already running multiprocessing
 #: manager finalizers — raising SystemExit there prints a traceback into
-#: whatever captures stderr (it half-filled BENCH_r04.json). Once exiting,
+#: whatever captures stderr (a worker's exit must not write finalizer
+#: noise into a stderr somebody records). Once exiting,
 #: further SIGTERMs are no-ops.
 _EXITING = False
 

@@ -105,8 +105,8 @@ class GPTConfig:
     num_microbatches: int = 0
     # S-chunk size for the fused LM head + cross-entropy (0 = dense path).
     # The dense loss materializes fp32 logits (B, S, V) twice (forward
-    # residual + backward cotangent) — ~1.6 GB each at the GPT-2-small
-    # bench shape; the chunked path caps live logits at (B, chunk, V) and
+    # residual + backward cotangent) — ~1.6 GB each at GPT-2 small's
+    # training shape; the chunked path caps live logits at (B, chunk, V) and
     # recomputes them in the backward. Ignored under sequence parallelism
     # (hidden states are seq-sharded; the per-rank dense logits are
     # already small).
@@ -209,7 +209,7 @@ class GPTConfig:
 
     @staticmethod
     def gpt2_small(**overrides: Any) -> "GPTConfig":
-        """GPT-2 124M: the flagship/bench configuration."""
+        """GPT-2 124M: the configuration ``chip_smoke.py`` fits and serves."""
         cfg = GPTConfig(
             vocab_size=50257,
             n_layer=12,
@@ -1010,8 +1010,8 @@ def chunked_lm_loss(
     matmul + cross-entropy over S-chunks; ``jax.checkpoint`` on the chunk
     body makes the backward *recompute* each chunk's logits instead of
     saving them, so peak logits memory is B*chunk*V fp32 on both passes
-    (vs B*S*V twice for the dense path — ~1.6 GB each at the GPT-2-small
-    bench shape). Same fp32 math as :func:`lm_loss`; equality of value and
+    (vs B*S*V twice for the dense path — ~1.6 GB each at GPT-2 small's
+    training shape). Same fp32 math as :func:`lm_loss`; equality of value and
     grads is asserted in tests/test_gpt.py.
     """
     B, S, D = x.shape

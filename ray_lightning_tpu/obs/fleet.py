@@ -24,8 +24,8 @@ control plane that would report it dead.
 
 Consumed by ``rlt serve --serve.metrics_port`` (the ``/fleet`` route),
 ``rlt top`` (the live terminal dashboard), and ``rlt doctor`` bundles
-(``fleet.json``). The observer effect of an aggressive poll cadence is
-benched as ``fleet_overhead`` next to ``obs_overhead``.
+(``fleet.json``). What an aggressive poll cadence costs a replica's loop
+on the chip is not measured (ROADMAP D5).
 """
 from __future__ import annotations
 
@@ -371,9 +371,9 @@ class FleetPoller:
     """Background fleet aggregator: pull -> condense -> ring + gauges.
 
     ``history`` bounds the ring; ``interval_s`` is the poll cadence
-    (production default seconds — the bench runs it 100x faster to
-    measure the observer effect). ``to_dict()`` is the ``/fleet``
-    payload: the latest snapshot plus the history ring.
+    (production default seconds; tests run it far faster).
+    ``to_dict()`` is the ``/fleet`` payload: the latest snapshot plus
+    the history ring.
 
     ``supervisor_fn`` (optional, zero-arg -> list of rows — typically
     ``FleetSupervisor.rows``) embeds the recovery plane's per-replica

@@ -18,9 +18,8 @@ results into:
 
 The built-in check factories only READ state the hot paths already
 publish (registry counters, gauges, heartbeat snapshots, engine slot
-counts) — the watchdog adds no instrumentation cost to the fold loop;
-the bench measures the residual observer effect as
-``watchdog_overhead`` (smoke-pinned < 5%).
+counts) — the watchdog adds no instrumentation to the fold loop; what
+its reads cost that loop on the chip is not measured (ROADMAP D5).
 
 Stall detection is flatline-based (:class:`Flatline`): a monotonically
 advancing reading (tokens emitted, admits, optimizer steps) that stops

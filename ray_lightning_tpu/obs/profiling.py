@@ -1,16 +1,14 @@
 """On-demand jax.profiler capture.
 
-Wraps ``jax.profiler`` start/stop into (1) a context manager used by the
-tools (tools/gpt_profile.py traces a known span of work) and (2)
-:func:`capture_profile` — the duration-based form behind the
-``profile(duration_s)`` RPC on serve replicas and TrainWorkers: start a
-trace, sleep while the process's OWN worker threads keep the device
-busy, stop, report the artifact files. The captured trace opens in
-Perfetto / TensorBoard's profile plugin. The profiler takes seconds to
-start and to write, so an actor that must keep answering calls runs the
-capture in a thread of its own (:class:`BackgroundCapture`). While a
-session is active, every ``obs.trace.span`` in the process is also a
-``TraceAnnotation`` in it.
+Wraps ``jax.profiler`` start/stop into :func:`capture_profile` — the
+duration-based form behind the ``profile(duration_s)`` RPC on serve
+replicas and TrainWorkers: start a trace, sleep while the process's OWN
+worker threads keep the device busy, stop, report the artifact files.
+The captured trace opens in Perfetto / TensorBoard's profile plugin.
+The profiler takes seconds to start and to write, so an actor that must
+keep answering calls runs the capture in a thread of its own
+(:class:`BackgroundCapture`). While a session is active, every
+``obs.trace.span`` in the process is also a ``TraceAnnotation`` in it.
 
 Everything degrades gracefully: when the profiler is unavailable (or a
 capture is already running — jax allows one at a time per process) the
@@ -19,12 +17,11 @@ replica must never take the replica down.
 """
 from __future__ import annotations
 
-import contextlib
 import os
 import tempfile
 import threading
 import time
-from typing import Any, Dict, Iterator, List, Optional
+from typing import Any, Dict, List, Optional
 
 #: One capture at a time per process (jax.profiler's own constraint).
 _ACTIVE = threading.Lock()
@@ -45,18 +42,6 @@ def _trace_files(outdir: str) -> List[str]:
         for f in files:
             found.append(os.path.join(root, f))
     return sorted(found)
-
-
-@contextlib.contextmanager
-def trace(outdir: str) -> Iterator[str]:
-    """``with obs.profiling.trace(dir):`` — jax.profiler.trace with the
-    one-capture lock held, so overlapping callers queue instead of
-    crashing each other."""
-    import jax
-
-    with _ACTIVE:
-        with jax.profiler.trace(outdir):
-            yield outdir
 
 
 def capture_profile(

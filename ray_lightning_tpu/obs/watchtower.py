@@ -576,7 +576,8 @@ class CanaryLane:
     the recorded baseline when one is given, else from the first
     successful probe (self-baseline).
 
-    ``baseline`` (``--serve.canary_baseline``, written by bench.py)::
+    ``baseline`` (``--serve.canary_baseline``: a JSON file of this form,
+    recorded from a healthy fleet's probe)::
 
         {"prompt": [...], "max_new_tokens": n, "tokens": [...],
          "ttft_s": f, "decode_tokens_per_s": f,

@@ -35,7 +35,7 @@ The L5 layer over the decode path (models/gpt.py: prefill + GQA KV cache
   replica; bit-exact end to end).
 - :class:`FaultInjector` — deterministic fault injection (faults.py):
   kill/delay/drop/wedge/preempt at named lifecycle points, driving the
-  chaos tests and the ``failover_blackout``/``preempt_drain`` benches.
+  chaos tests (tests/test_failover.py, tests/test_preempt.py).
 - :class:`PreemptionMonitor` (preempt.py) — the per-process preemption
   signal plane: SIGTERM, a metadata poller, and the ``preempt`` fault
   action funnel into one ``preemption_pending(deadline)`` state the

@@ -1676,7 +1676,7 @@ class ServeClient:
             timeout=self._init_timeout,
         )
 
-    # -- fault injection (chaos tests / bench) -----------------------------
+    # -- fault injection (chaos tests) -------------------------------------
     def inject_fault(self, replica: int, plan: Any) -> list:
         """Arm a deterministic fault plan (serve.faults) on ONE live
         replica; returns the armed rules."""

@@ -755,13 +755,13 @@ class DecodeEngine:
             for t in ("device", "host", "disk")
         }
         #: Host-side seconds spent refilling promoted blocks (payload
-        #: assembly + the compiled H2D dispatch) — the bench's
-        #: "what does a cold hit cost" column.
+        #: assembly + the compiled H2D dispatch): ``refill_s`` of
+        #: :meth:`prefix_stats`, hence of the replica's ``stats()``.
         self.refill_s = 0.0
         #: Cross-replica KV handoff accounting: blocks this engine
         #: serialized out for a migrating request (export) and blocks it
-        #: accepted from a dying peer (import) — the warm-handoff rate's
-        #: numerator in the preempt bench.
+        #: accepted from a dying peer (import). Read by
+        #: tests/test_preempt.py and tests/test_kvfleet.py as exact counts.
         self.prefix_handoff_exports = 0
         self.prefix_handoff_imports = 0
         #: Digests DROPPED from every tier (evicted with nowhere to
@@ -3286,7 +3286,7 @@ class DecodeEngine:
         return max(0, used)
 
     def prefix_stats(self) -> Dict[str, Any]:
-        """Pool counters for the stats endpoint / bench; with tiers on,
+        """Pool counters for the stats endpoint; with tiers on,
         a per-tier breakdown and the cumulative refill seconds ride
         along."""
         out: Dict[str, Any] = {
@@ -3777,7 +3777,7 @@ class DecodeEngine:
         return out
 
     def spec_stats(self) -> Dict[str, Any]:
-        """Speculative-decoding counters for stats/bench: accept_rate =
+        """Speculative-decoding counters for the stats endpoint: accept_rate =
         accepted draft tokens / proposed draft tokens in [0, 1];
         tokens_per_verify = emitted tokens per verify forward in
         [1, spec_depth + 1] (the per-forward multiplier spec buys)."""

@@ -45,8 +45,8 @@ die like a crash).
 
 Gating: everything is off unless a plan is supplied — via the
 ``faults=`` kwarg on ``ServeReplica``/``Scheduler``, the
-``inject_fault`` RPC on a live replica (how the chaos tests and the
-``failover_blackout`` bench arm ONE replica of a fleet), or the
+``inject_fault`` RPC on a live replica (how the chaos tests arm ONE
+replica of a fleet), or the
 ``RLT_FAULTS`` env var (JSON; applied at process start, so it rides
 ``start_replicas(env=...)``). A hit on an unarmed injector is one dict
 lookup; no injector is a ``None`` check.

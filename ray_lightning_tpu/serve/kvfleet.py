@@ -417,8 +417,8 @@ class KVFleetPlane:
         self.tracer: Optional[Any] = None
         #: Fault injector (serve.faults): the ``kvfleet_fetch`` point
         #: fires as a fetched KV payload is about to import — a delay
-        #: rule here inflates exactly the ledger's kv_fetch phase (the
-        #: bench's attribution demo).
+        #: rule here inflates exactly the ledger's kv_fetch phase
+        #: (tests/test_watchtower.py's burn-rate alert drives it).
         self.faults: Optional[Any] = None
         self._lock = threading.Lock()
         #: Layer-pipelined disagg shipping: a finished prefill's pages

@@ -52,7 +52,7 @@ class ServeMetrics:
         self._lock = threading.Lock()
         # Optional Prometheus-side mirror (obs.registry): lifecycle
         # counters, queue-depth gauge, latency histograms. None (the
-        # default for bare Scheduler construction in tests/bench) keeps
+        # default for bare Scheduler construction, as in tests) keeps
         # the hot loop free of the extra dict updates; ServeReplica
         # passes the process registry so /metrics sees the serve path.
         self._reg = None

@@ -27,7 +27,7 @@ balance exactly against the engine token counter (test-asserted), so
 goodput — emitted tokens per device-second — is a true ratio.
 
 The scheduler owns no threads: ``step()`` is driven by whoever hosts the
-engine (ServeReplica's loop thread, a test, the bench). ``submit`` /
+engine (ServeReplica's loop thread, or a test). ``submit`` /
 ``cancel`` are thread-safe so a replica's RPC surface can feed the loop.
 The lock guards ONLY the queue/bookkeeping state: ``step()`` snapshots
 its decisions under the lock and runs every engine call (prefill,
@@ -1455,7 +1455,7 @@ class Scheduler:
         return prefilled
 
     def run_until_idle(self, max_steps: int = 100_000) -> List[TokenEvent]:
-        """Drive step() until queue and slots drain (tests, bench)."""
+        """Drive step() until queue and slots drain (tests)."""
         out: List[TokenEvent] = []
         for _ in range(max_steps):
             if not self.has_work():

@@ -428,7 +428,7 @@ class ServeShardFollower:
 class ServeReplica:
     """One serving replica (designed to run as a fabric actor).
 
-    ``params`` may be passed directly (tests/bench) or loaded from
+    ``params`` may be passed directly (tests, the benchmark) or loaded from
     ``ckpt_path``; ``int8=True`` quantizes the tree at load
     (utils.quantize_params_int8), which the engine consumes directly.
     ``mesh`` ("MODELxDATA", e.g. "4x1") makes the engine mesh-sharded
@@ -1293,9 +1293,9 @@ class ServeReplica:
 
     def inject_fault(self, plan: Any) -> list:
         """Arm (or disarm with None) a deterministic fault plan on this
-        LIVE replica (serve.faults) — the chaos tests' and the
-        ``failover_blackout`` bench's way of targeting one replica of a
-        fleet; returns the armed rules. Replaces any previous plan."""
+        LIVE replica (serve.faults) — the chaos tests' way of targeting
+        one replica of a fleet; returns the armed rules. Replaces any
+        previous plan."""
         from ray_lightning_tpu.serve.faults import FaultInjector
 
         inj = FaultInjector.parse(plan, events=self.events)

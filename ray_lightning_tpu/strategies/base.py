@@ -716,8 +716,7 @@ class SingleDeviceStrategy(Strategy):
     """In-process strategy used when Trainer has no distributed strategy.
 
     Runs on the default local device set (1-chip TPU or N virtual CPU
-    devices) without any launcher — the non-distributed baseline that
-    ``bench.py`` compares distributed throughput against.
+    devices) without any launcher — the non-distributed baseline.
     """
 
     strategy_name = "single_device"

@@ -908,8 +908,8 @@ class TrainingLoop:
 
             from ray_lightning_tpu.utils.summary import summarize_params
 
-            # stderr: stdout is a data channel for CLI generate / bench
-            # JSON pipelines; diagnostics must not interleave into it.
+            # stderr: stdout is a data channel for CLI generate and JSON
+            # pipelines; diagnostics must not interleave into it.
             print(summarize_params(self.params), file=sys.stderr, flush=True)
         self.module.on_fit_start()
         self._call_callbacks("on_fit_start")

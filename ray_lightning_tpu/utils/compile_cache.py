@@ -1,6 +1,6 @@
 """Where JAX's persistent compilation cache lives.
 
-One rule for every entry point (``chip_smoke.py``, ``bench.py``,
+One rule for every entry point (``chip_smoke.py``, ``perfbench/run.py``,
 ``tools/*.py``, ``rlt``): ``JAX_COMPILATION_CACHE_DIR`` decides. When the
 operator set it, nothing here touches it. When it is unset, it is set — in
 ``os.environ``, so fabric workers inherit it through their exec

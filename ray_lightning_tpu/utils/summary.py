@@ -41,7 +41,7 @@ def summarize_params(params: Any, depth: int = 1) -> str:
 
     ``depth`` controls grouping granularity (1 = top-level keys). Returns a
     string; callers decide where to print (the loop does it rank-0 only,
-    to stderr — stdout is a data channel for CLI/bench pipelines).
+    to stderr — stdout is a data channel for CLI pipelines).
     """
     import numpy as np
 

@@ -173,7 +173,7 @@ def test_sigterm_handler_silent_once_exiting():
     """kill() SIGTERMs ~0.1s after the shutdown message, so the signal
     routinely lands while the worker is already in atexit running
     multiprocessing manager finalizers; raising SystemExit there printed a
-    traceback into bench artifacts (VERDICT r4 weak #3). The handler must
+    traceback into whatever recorded the worker's stderr. The handler must
     raise exactly once and be a no-op afterwards."""
     from ray_lightning_tpu.fabric import worker as w
 
@@ -189,7 +189,7 @@ def test_sigterm_handler_silent_once_exiting():
 
 
 class ManagerHolder:
-    """Actor whose teardown mirrors the bench workers: a multiprocessing
+    """Actor with a noisy teardown: a multiprocessing
     manager (proxy finalizers at exit) plus a slow atexit hook that widens
     the window in which kill()'s SIGTERM lands mid-shutdown."""
 
@@ -207,7 +207,8 @@ class ManagerHolder:
 
 def test_kill_mid_shutdown_leaves_clean_stderr(start_fabric, capfd):
     """A killed actor holding manager proxies must not stack-trace through
-    finalizers into stderr (the BENCH_r04.json tail pollution)."""
+    finalizers into stderr: a worker's exit writes no finalizer noise
+    into a stderr somebody captures."""
     f = start_fabric(num_cpus=1)
     actor = f.remote(ManagerHolder).options(num_cpus=1).remote()
     assert f.get(actor.ping.remote()) == "ok"

@@ -727,8 +727,7 @@ def test_gpt_window_with_sinks_decode():
 def test_chunked_lm_loss_matches_dense():
     """chunked_lm_loss == lm_loss in value AND grads (incl. padded tail).
 
-    S=15 with chunk=4 exercises the pad-and-mask path (the bench's
-    seq-1 = 511 is prime, so the real config always pads)."""
+    S=15 with chunk=4 exercises the pad-and-mask path."""
     import jax
     import jax.numpy as jnp
 
@@ -764,7 +763,8 @@ def test_chunked_lm_loss_matches_dense():
 
 def test_gptlm_fit_with_chunked_loss(start_fabric):
     """End-to-end fit with loss_chunk on, through RayShardedStrategy — the
-    exact strategy the bench's GPT config runs (chunked head + ZeRO)."""
+    strategy the benchmark's training cell and chip_smoke.py run (chunked
+    head + ZeRO)."""
     import dataclasses
 
     from ray_lightning_tpu.strategies import RayShardedStrategy

@@ -318,6 +318,62 @@ def test_scheduler_drain_finish_vs_migrate_and_queue(pt_params):
     assert migrated == set(rids2)
 
 
+def test_zero_budget_drain_resumes_on_a_survivor_bit_exact(pt_params):
+    """Drain and handoff end to end over two real engines, no fabric:
+    requests cut off mid-decode by a zero-budget drain are resubmitted
+    on a survivor under the same id and seed, after the plan's blocks
+    were imported there — none is lost, every stream is the
+    uninterrupted engine's token for token, and the survivor's admission
+    walk is warm."""
+    from ray_lightning_tpu.serve.scheduler import SamplingParams, Scheduler
+
+    rng = np.random.default_rng(5)
+    prompts = [rng.integers(0, 97, size=14).tolist() for _ in range(2)]
+    oracle = Scheduler(_engine(pt_params), max_prefills_per_step=2)
+    expected = [
+        _run_one(oracle, p, max_new_tokens=20, seed=i)
+        for i, p in enumerate(prompts)
+    ]
+
+    dying = Scheduler(_engine(pt_params), max_prefills_per_step=2)
+    rids = [
+        dying.submit(p, SamplingParams(max_new_tokens=20, seed=i))
+        for i, p in enumerate(prompts)
+    ]
+    streamed = {rid: [] for rid in rids}
+    for _ in range(8):  # both residents are decoding when the notice lands
+        for e in dying.step():
+            if e.token is not None:
+                streamed[e.request_id].append(e.token)
+    assert all(0 < len(t) < 20 for t in streamed.values()), streamed
+    dying.request_drain(0.0)
+    dying.step()
+    plan = dying.drain_result(timeout=5.0)
+    assert sorted(m["request_id"] for m in plan["migrate"]) == sorted(rids)
+    assert plan["finish"] == []
+
+    survivor = Scheduler(_engine(pt_params), max_prefills_per_step=2)
+    handed_off = sum(
+        survivor.enqueue_prefix_import(m["blocks"]) for m in plan["migrate"]
+    )
+    assert handed_off >= 2 * 3  # 14-token prompts, block 4
+    for i, (rid, p) in enumerate(zip(rids, prompts)):
+        survivor.submit(
+            p, SamplingParams(max_new_tokens=20, seed=i), request_id=rid
+        )
+    resumed = {rid: [] for rid in rids}
+    for e in survivor.run_until_idle():
+        if e.token is not None:
+            resumed[e.request_id].append(e.token)
+    for rid, want in zip(rids, expected):
+        assert resumed[rid] == want  # nothing lost, nothing changed
+        # What the client had streamed before the cut is a prefix of it
+        # (its cursor dedups those on the re-route).
+        assert want[: len(streamed[rid])] == streamed[rid]
+    assert survivor.engine.prefix_handoff_imports == handed_off
+    assert survivor.engine.prefix_hit_tokens >= 2 * 8
+
+
 # ---------------------------------------------------------------------------
 # ServeClient preempt_drain (fake replicas — no fabric processes)
 # ---------------------------------------------------------------------------

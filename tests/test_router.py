@@ -398,6 +398,67 @@ def test_client_submit_feeds_the_affinity_map(start_fabric):
     assert router.affinity_entries() > 0
 
 
+def test_affinity_routing_hits_more_prefix_tokens_than_round_robin():
+    """The router's decisions driving two REAL engines: the same sixteen
+    requests over four shared prefixes, routed round-robin and then by
+    prefix affinity. Affinity pays one cold prefill a prefix, round-robin
+    one a (prefix, replica) pair — so the fleet's prefix-hit tokens are
+    strictly higher under affinity, at identical outputs. Counts only."""
+    import jax
+
+    from ray_lightning_tpu.models.gpt import init_gpt_params
+    from ray_lightning_tpu.serve.engine import DecodeEngine
+    from ray_lightning_tpu.serve.scheduler import SamplingParams, Scheduler
+
+    cfg = _ft_cfg()
+    params = init_gpt_params(jax.random.PRNGKey(0), cfg)
+    g = np.random.default_rng(0)
+    prefixes = [g.integers(0, 97, size=8).tolist() for _ in range(4)]
+    # Every prefix visited four times, interleaved so that alternating
+    # replicas splits each prefix's visits over both.
+    order = [0, 1, 0, 2, 0, 3, 1, 0, 2, 1, 3, 2, 0, 1, 3, 2]
+    jobs = [prefixes[k] + g.integers(0, 97, size=4).tolist() for k in order]
+
+    def run(pick):
+        scheds = [
+            Scheduler(DecodeEngine(
+                params, cfg, num_slots=2, max_seq=32,
+                prefill_buckets=[16], prefill_chunk=4,
+                prefix_blocks=32, prefix_block=4, decode_fold=2,
+            ))
+            for _ in range(2)
+        ]
+        outs = []
+        for i, prompt in enumerate(jobs):
+            sched = scheds[pick(i, prompt)]
+            rid = sched.submit(prompt, SamplingParams(max_new_tokens=4))
+            outs.append([
+                e.token for e in sched.run_until_idle()
+                if e.request_id == rid and e.token is not None
+            ])
+        engines = [s.engine for s in scheds]
+        return (
+            outs,
+            sum(e.prefix_hit_tokens for e in engines),
+            sum(e.prefix_prompt_tokens for e in engines),
+        )
+
+    router, _ = _router(
+        _StatsClient([_stats(), _stats()]), prefix_block=4
+    )
+
+    def by_affinity(_, prompt):
+        replica = router.pick(prompt, alive=[0, 1])
+        router.observe_route(prompt, replica)
+        return replica
+
+    out_rr, hit_rr, tot_rr = run(lambda i, _: i % 2)
+    out_aff, hit_aff, tot_aff = run(by_affinity)
+    assert out_aff == out_rr and all(len(o) == 4 for o in out_rr)
+    assert tot_aff == tot_rr == 16 * 12
+    assert hit_aff > hit_rr > 0, (hit_aff, hit_rr)
+
+
 # ---------------------------------------------------------------------------
 # Admission control: typed rejection + retry-after
 # ---------------------------------------------------------------------------

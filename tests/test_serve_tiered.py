@@ -508,3 +508,21 @@ def test_journal_replay_rebuilds_tiers_and_replays_host_hit(params):
     # replay interleaves admissions the capture ran sequentially, so
     # WHICH tier serves a block can differ — exactness cannot).
     assert replay_sched.engine.tier_counters["device"]["spills"] > 0
+
+
+def test_tiers_serve_more_prompt_tokens_from_cache_than_the_pool_alone(
+    params, tmp_path
+):
+    """A working set larger than the device pool (five 2-block prompts
+    through a 2-block pool), tiers on against tiers off: the same
+    requests, the same tokens, and more of the prompts' tokens served
+    from cache — a spilled block survives the eviction that drops it
+    from an untiered engine. Counts, not times."""
+    tiered = _engine(params, **_tier_kw(tmp_path, "cmp"))
+    plain = _engine(params)
+    assert _run_workload(tiered) == _run_workload(plain)
+    assert tiered.prefix_prompt_tokens == plain.prefix_prompt_tokens
+    assert tiered.prefix_hit_tokens > plain.prefix_hit_tokens > 0
+    cold = plain.tier_counters
+    assert cold["host"]["hits"] == cold["disk"]["hits"] == 0, cold
+    assert cold["host"]["spills"] == cold["disk"]["spills"] == 0, cold

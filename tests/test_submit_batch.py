@@ -297,3 +297,77 @@ def test_plan_batch_size_buckets_count_batches_not_requests():
     assert plan["batches"] == 3
     assert plan["requests"] == 37
     assert plan["mean_batch"] == round(37 / 3, 2)
+
+
+# ---------------------------------------------------------------------------
+# The replica's half of the batched wire: a real engine behind it
+# ---------------------------------------------------------------------------
+def test_replica_submit_many_admits_what_serial_submits_admit():
+    """``ServeReplica.submit_many`` (the RPC the fakes above stand in
+    for) against N serial ``submit`` calls of the same requests on one
+    real replica: every request of either kind is admitted under the id
+    the caller minted, none is lost, each stream is bit-identical to
+    solo ``gpt_generate``, and serving both compiled nothing."""
+    import jax
+    import numpy as np
+
+    from ray_lightning_tpu.models.gpt import (
+        GPTConfig,
+        gpt_generate,
+        init_gpt_params,
+    )
+    from ray_lightning_tpu.serve.server import ServeReplica
+
+    cfg = GPTConfig(
+        vocab_size=97, n_layer=2, n_head=4, n_kv_head=2, d_model=32,
+        max_seq=48, attn_impl="reference", compute_dtype="float32",
+    )
+    params = init_gpt_params(jax.random.PRNGKey(0), cfg)
+    prompts = np.random.default_rng(18).integers(
+        0, 97, size=(6, 10)
+    ).tolist()
+    n = 5
+    # The references first: the replica counts every compile of the
+    # process after its own construction.
+    refs = [
+        np.asarray(
+            gpt_generate(params, cfg, np.asarray(p, np.int32)[None], n)
+        )[0, len(p):].tolist()
+        for p in prompts
+    ]
+    rep = ServeReplica(
+        params=params, model_config=cfg, num_slots=2, max_seq=48,
+        prefill_buckets=[16], decode_fold=2,
+    )
+
+    def stream(rid):
+        tokens = []
+        for _ in range(240):
+            out = rep.result(rid, cursor=len(tokens), wait_s=0.5)
+            tokens += out["tokens"]
+            if out["done"]:
+                assert out["status"] == "finished", out
+                return tokens
+        pytest.fail(f"{rid} did not finish")
+
+    finished = 'rlt_serve_requests_total{kind="finished"}'
+    try:
+        # The replica reports the process's registry: count from here.
+        before = rep.stats()["metrics"].get(finished, 0)
+        serial = [
+            rep.submit(p, max_new_tokens=n, request_id=f"s{i}")
+            for i, p in enumerate(prompts)
+        ]
+        batched = rep.submit_many([
+            {"prompt": p, "max_new_tokens": n, "request_id": f"b{i}"}
+            for i, p in enumerate(prompts)
+        ])
+        assert serial == [f"s{i}" for i in range(6)]
+        assert batched == [f"b{i}" for i in range(6)]
+        for ref, s, b in zip(refs, serial, batched):
+            assert stream(s) == stream(b) == ref
+        stats = rep.stats()
+        assert stats["compiles_since_init"] == 0
+        assert stats["metrics"][finished] - before == 12
+    finally:
+        rep.stop()
