@@ -9,6 +9,13 @@ over query blocks — so the backward never materializes (Sq, Sk) either
 (the naive recompute costs B*H*S^2*4 bytes of HBM: 400 MB at B=8, H=12,
 S=1024).
 
+The kernels multiply in the dtype of their inputs and accumulate in float32:
+bf16 q / k / v / do tiles reach the MXU as they are, and the tiles a kernel
+computes (p, ds) are rounded to that dtype once, at their product, as
+``attention_reference`` rounds p. Scores, softmax statistics (m, l, lse,
+delta), the exponentials and the acc / dq / dk / dv accumulators are float32
+whatever the inputs; float32 inputs multiply float32.
+
 On non-TPU backends the same kernels run in Pallas interpret mode (tests).
 Shapes the kernels cannot tile go to ``attention_reference``, with one
 warning per shape naming the rule that rejected it.
@@ -37,6 +44,27 @@ _NEG_INF = float("-inf")
 _warned: set = set()
 
 
+def _default_block(seq: int) -> int:
+    """The tile along a sequence of ``seq`` positions when the caller names
+    none: the largest of 512 / 256 that divides it, else 128 (which is
+    clipped to a shorter sequence, or sends a ragged one to the reference,
+    as a named block is).
+
+    Why the length alone (the chip, PERF.md §6, PR 31): a kernel's time does
+    not follow the head width (64 and 128 read the same) or the operands'
+    dtype. The VPU's work on the (block_q, block_k) score tile sets the pace,
+    and a loop trip pays the latency of its reductions and products once
+    whatever the tile: 512 x 512 is 2.8x faster than 128 x 128 at S 1024 and
+    within 5% of the best tile from S 256 to 8192, though a causal call then
+    computes 3/4 of the square, not 9/16. The chip's compiler takes 512
+    wherever it takes 128 (head width to 256, bf16 and float32, S to 32k);
+    1024 it refuses earlier."""
+    for tile in (512, 256):
+        if seq % tile == 0:
+            return tile
+    return 128
+
+
 def _warn_once(key: Any, msg: str) -> None:
     if key not in _warned:
         _warned.add(key)
@@ -55,7 +83,7 @@ def _fwd_kernel(
     seq_k = k_ref.shape[1]
     head_dim = q_ref.shape[2]
     iq = pl.program_id(1)
-    q = q_ref[0].astype(jnp.float32) * sm_scale  # (bq, d)
+    q = q_ref[0]  # (bq, d)
 
     q_offset = iq * block_q
     if causal:
@@ -76,11 +104,11 @@ def _fwd_kernel(
 
     def body(i, carry):
         m_prev, l_prev, acc_prev = carry
-        k = k_ref[0, pl.ds(i * block_k, block_k), :].astype(jnp.float32)
-        v = v_ref[0, pl.ds(i * block_k, block_k), :].astype(jnp.float32)
+        k = k_ref[0, pl.ds(i * block_k, block_k), :]
+        v = v_ref[0, pl.ds(i * block_k, block_k), :]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )  # (bq, bk)
+        ) * sm_scale  # (bq, bk)
         if causal:
             row = q_offset + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 0
@@ -100,7 +128,8 @@ def _fwd_kernel(
         p = jnp.where(s == _NEG_INF, 0.0, jnp.exp(s - m_new))
         l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
         acc_new = acc_prev * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
         )
         return m_new, l_new, acc_new
 
@@ -215,8 +244,8 @@ def _dkv_kernel(
     seq_q = q_ref.shape[1]
     block_k = k_ref.shape[1]
     ik = pl.program_id(1)
-    k = k_ref[0].astype(jnp.float32)  # (bk, d)
-    v = v_ref[0].astype(jnp.float32)
+    k = k_ref[0]  # (bk, d)
+    v = v_ref[0]
     k_offset = ik * block_k
     start_qb = k_offset // block_q if causal else 0
     end_qb = seq_q // block_q
@@ -232,8 +261,8 @@ def _dkv_kernel(
 
     def body(i, carry):
         dk, dv = carry
-        qs = q_ref[0, pl.ds(i * block_q, block_q), :].astype(jnp.float32)
-        dos = do_ref[0, pl.ds(i * block_q, block_q), :].astype(jnp.float32)
+        qs = q_ref[0, pl.ds(i * block_q, block_q), :]
+        dos = do_ref[0, pl.ds(i * block_q, block_q), :]
         lse = lse_ref[0, pl.ds(i * block_q, block_q), 0][:, None]
         delta = delta_ref[0, pl.ds(i * block_q, block_q), 0][:, None]
         s = jax.lax.dot_general(
@@ -249,14 +278,16 @@ def _dkv_kernel(
             s = jnp.where(band_allowed(row, col, window, sinks), s, _NEG_INF)
         p = jnp.exp(s - lse)  # (bq, bk), rows of the full P sum to 1
         dv2 = dv + jax.lax.dot_general(
-            p, dos, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+            p.astype(dos.dtype), dos, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
         )
         dp = jax.lax.dot_general(
             dos, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )
         ds = p * (dp - delta) * sm_scale
         dk2 = dk + jax.lax.dot_general(
-            ds, qs, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+            ds.astype(qs.dtype), qs, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
         )
         return dk2, dv2
 
@@ -277,8 +308,8 @@ def _dq_kernel(
     block_q = q_ref.shape[1]
     seq_k = k_ref.shape[1]
     iq = pl.program_id(1)
-    q = q_ref[0].astype(jnp.float32)
-    do = do_ref[0].astype(jnp.float32)
+    q = q_ref[0]
+    do = do_ref[0]
     lse = lse_ref[0, :, 0][:, None]
     delta = delta_ref[0, :, 0][:, None]
     q_offset = iq * block_q
@@ -291,8 +322,8 @@ def _dq_kernel(
     )
 
     def body(i, dq):
-        ks = k_ref[0, pl.ds(i * block_k, block_k), :].astype(jnp.float32)
-        vs = v_ref[0, pl.ds(i * block_k, block_k), :].astype(jnp.float32)
+        ks = k_ref[0, pl.ds(i * block_k, block_k), :]
+        vs = v_ref[0, pl.ds(i * block_k, block_k), :]
         s = jax.lax.dot_general(
             q, ks, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * sm_scale
@@ -310,7 +341,8 @@ def _dq_kernel(
         )
         ds = p * (dp - delta) * sm_scale
         return dq + jax.lax.dot_general(
-            ds, ks, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+            ds.astype(ks.dtype), ks, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
         )
 
     dq0 = jnp.zeros((block_q, q.shape[1]), jnp.float32)
@@ -420,14 +452,18 @@ def flash_attention(
     v: jax.Array,
     causal: bool = True,
     sm_scale: Optional[float] = None,
-    block_q: int = 128,
-    block_k: int = 128,
+    block_q: Optional[int] = None,
+    block_k: Optional[int] = None,
     interpret: Optional[bool] = None,
     window: int = 0,
     sinks: int = 0,
     mesh: Optional[jax.sharding.Mesh] = None,
 ) -> jax.Array:
     """Pallas flash attention on (B, S, H, D) tensors.
+
+    Products take their operands in the dtype of ``q`` / ``k`` / ``v`` and
+    accumulate in float32 (module docstring). ``block_q`` / ``block_k`` left
+    at ``None`` follow the sequence lengths (``_default_block``).
 
     ``interpret=None`` auto-selects: compiled kernel on TPU, interpret mode
     elsewhere (so the same code path is testable on CPU). ``window=W > 0``
@@ -456,6 +492,10 @@ def flash_attention(
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     seq_q, seq_k = q.shape[1], k.shape[1]
+    if block_q is None:
+        block_q = _default_block(seq_q)
+    if block_k is None:
+        block_k = _default_block(seq_k)
     bq, bk = min(block_q, seq_q), min(block_k, seq_k)
     # TPU tiling wants the blocks' second-minor dim 8-aligned (the kernel's
     # own lse row is padded to 8 lanes for the same reason); a clipped

@@ -1,8 +1,15 @@
-"""Compile-only guard of the chat cell's decode fold on the cache the
-single-device engine keeps — ``(L, slots, max_seq, Hkv * hd)``, a
-position's KV heads side by side in one row — for a described ``v5e:2x2``
-(no chip attached; nothing runs). With the KV heads on an axis of their own
-the chip's compiler copied a layer of the cache (268 MB) out of the stacked
+"""Compile-only guards for a described ``v5e:2x2`` (no chip attached;
+nothing runs), in one file so that one worker describes the chip once.
+
+**The flash kernels** (``ops/flash_attention.py``) with bf16 operands, forward
+and backward, at the train cell's shape and the chat prefill's: Mosaic has to
+take the packed bf16 tiles as they are, the transposed left sides of
+``flash_dkv`` (pT.do, dsT.q) among them — interpret mode on the CPU proves
+nothing about that.
+
+**The chat cell's decode fold** on the cache the single-device engine keeps
+— ``(L, slots, max_seq, Hkv * hd)``, a position's KV heads side by side in
+one row. With the KV heads on an axis of their own the chip's compiler copied a layer of the cache (268 MB) out of the stacked
 array before every attention read: temporaries of 2.38 GiB in a program of
 10.12 GiB (PERF.md §4). This is the guard that the copy does not come back.
 
@@ -87,3 +94,31 @@ def test_mistral_decode_fold_on_a_cache_of_rows_copies_no_layer(v5e, monkeypatch
           f"whole program {whole / GIB:.2f} GiB")
     assert m.temp_size_in_bytes < 2.3 * GIB  # 2.138 read; 2.383 with the KV heads on an axis of their own
     assert whole < 10.0 * GIB  # 9.88 read; 10.12
+
+
+@pytest.mark.parametrize(
+    "shape,kw",
+    [((4, 1024, 16, 64), {}), ((1, 1024, 32, 128), {"window": 4096})],
+    ids=["train_4x1024x16x64", "chat_prefill_1x1024x32x128"],
+)
+def test_flash_kernels_lower_with_bf16_operands(v5e, shape, kw):
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from ray_lightning_tpu.ops.flash_attention import flash_attention
+
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=SingleDeviceSharding(v5e))
+
+    def fwd_bwd(q, k, v, do):
+        # interpret=False: jax.default_backend() is the CPU here
+        out, vjp = jax.vjp(
+            lambda q, k, v: flash_attention(q, k, v, causal=True, interpret=False, **kw), q, k, v
+        )
+        return (out,) + vjp(do)
+
+    text = jax.jit(fwd_bwd).lower(x, x, x, x).compile().as_text()
+    mosaic = [ln for ln in text.splitlines() if 'custom_call_target="tpu_custom_call"' in ln]
+    assert len(mosaic) == 3, mosaic
+    for kernel in ("flash_fwd", "flash_dkv", "flash_dq"):
+        assert sum(kernel in ln.split(" = ")[0] for ln in mosaic) == 1, kernel

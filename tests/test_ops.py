@@ -266,7 +266,8 @@ def test_flash_backward_matches_reference_grads():
         )
         _, vjp_fl = jax.vjp(
             lambda q, k, v: flash_attention(
-                q, k, v, causal=causal, interpret=True
+                q, k, v, causal=causal, block_q=128, block_k=128,
+                interpret=True,
             ),
             q, k, v,
         )
@@ -304,7 +305,7 @@ def test_sliding_window_attention_matches_masked_reference():
         )
         fl_out, fl_vjp = jax.vjp(
             lambda q, k, v: flash_attention(
-                q, k, v, window=W, interpret=True
+                q, k, v, window=W, block_q=128, block_k=128, interpret=True
             ),
             q, k, v,
         )
@@ -355,7 +356,8 @@ def test_attention_sinks_match_masked_reference():
         )
         fl_out, fl_vjp = jax.vjp(
             lambda q, k, v: flash_attention(
-                q, k, v, window=W, sinks=N, interpret=True
+                q, k, v, window=W, sinks=N, block_q=128, block_k=128,
+                interpret=True,
             ),
             q, k, v,
         )
@@ -380,3 +382,113 @@ def test_attention_sinks_match_masked_reference():
         flash_attention(q, k, v, sinks=4)  # sinks require a window
     with pytest.raises(ValueError, match="sinks"):
         attention_reference(q, k, v, sinks=4)  # same contract on every path
+
+
+_MASK_CASES = {
+    "causal": dict(causal=True),
+    "non_causal": dict(causal=False),
+    "window_sinks": dict(causal=True, window=24, sinks=3),
+}
+
+
+@pytest.mark.parametrize("head_dim", [64, 128])
+@pytest.mark.parametrize("case", sorted(_MASK_CASES))
+def test_flash_bf16_operands_match_reference_on_the_same_inputs(case, head_dim):
+    """bf16 inputs are multiplied as bf16 (float32 accumulation; p and ds
+    rounded once at their product, as ``attention_reference`` rounds p):
+    forward and dq / dk / dv against the reference on the same bf16 inputs.
+
+    Tolerance: both sides round their bf16 outputs (half an ulp = 2**-9
+    relative) and the p / ds operands (the same); the kernels add blocks up
+    in another order and round p before the row sum divides it. 2**-6 of the
+    reference's largest magnitude is four bf16 ulps there; the readings over
+    three seeds are at most 2**-7.15 (dq; dv 2**-10.4, rounded as the
+    reference rounds), and float32 copies of the operands read 2**-7.26."""
+    kw = _MASK_CASES[case]
+    q, k, v = _make_qkv(batch=1, seq=64, heads=2, head_dim=head_dim, seed=5,
+                        dtype=jnp.bfloat16)
+    do = jax.random.normal(jax.random.PRNGKey(11), q.shape, jnp.bfloat16)
+
+    def fwd_bwd(attn, **extra):
+        out, vjp = jax.vjp(lambda q, k, v: attn(q, k, v, **kw, **extra), q, k, v)
+        return (out,) + vjp(do)
+
+    got = fwd_bwd(flash_attention, block_q=16, block_k=16, interpret=True)
+    want = fwd_bwd(attention_reference)
+    for name, a, b in zip(("out", "dq", "dk", "dv"), got, want):
+        assert a.dtype == jnp.bfloat16, name
+        a32, b32 = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        assert np.isfinite(a32).all(), name
+        err = np.abs(a32 - b32).max()
+        assert err <= 2.0 ** -6 * np.abs(b32).max(), (name, err, np.abs(b32).max())
+
+
+def _flash_kernels(x, **kw):
+    """{kernel name: (grid, [(lhs dtype, rhs dtype, result dtype) of every
+    dot_general inside it])} for the three ``pallas_call``s of
+    flash_attention's forward and backward on inputs like ``x``."""
+
+    def f(q, k, v):
+        return flash_attention(q, k, v, interpret=True, **kw)
+
+    jaxpr = jax.make_jaxpr(lambda q, k, v, do: jax.vjp(f, q, k, v)[1](do))(x, x, x, x)
+    found = {}
+
+    def walk(jp, kernel):
+        for eqn in jp.eqns:
+            inside = kernel
+            if eqn.primitive.name == "pallas_call":
+                inside = eqn.params["name"]
+                found[inside] = (tuple(eqn.params["grid_mapping"].grid), [])
+            elif eqn.primitive.name == "dot_general" and kernel:
+                found[kernel][1].append(
+                    tuple(str(var.aval.dtype) for var in (*eqn.invars, *eqn.outvars))
+                )
+            for val in eqn.params.values():
+                for sub in val if isinstance(val, (list, tuple)) else [val]:
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns"):
+                        walk(sub, inside)
+
+    walk(jaxpr.jaxpr, None)
+    return found
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("case", sorted(_MASK_CASES))
+def test_flash_kernels_multiply_in_the_dtype_of_their_inputs(dtype, case):
+    """Every product inside the three Pallas kernels takes both operands in
+    the inputs' dtype and accumulates in float32: bf16 inputs are never
+    widened for the MXU, float32 inputs still multiply float32."""
+    x = jnp.zeros((1, 64, 2, 64), jnp.dtype(dtype))
+    found = _flash_kernels(x, block_q=16, block_k=16, **_MASK_CASES[case])
+    assert sorted(found) == ["flash_dkv", "flash_dq", "flash_fwd"], found
+    # products a loop body: fwd q.kT, p.v; dkv q.kT, pT.do, do.vT, dsT.q;
+    # dq q.kT, do.vT, ds.k (a body is traced once per loop that uses it)
+    for name, per_body in (("flash_fwd", 2), ("flash_dkv", 4), ("flash_dq", 3)):
+        dots = found[name][1]
+        assert dots and len(dots) % per_body == 0, (name, dots)
+        assert set(dots) == {(dtype, dtype, "float32")}, (name, dots)
+
+
+@pytest.mark.parametrize(
+    "seq,tile",
+    [(64, 64), (128, 128), (256, 256), (384, 128), (512, 512), (768, 256),
+     (1024, 512), (1536, 512), (8192, 512)],
+)
+def test_flash_default_tile_follows_the_sequence_length(seq, tile):
+    """No block named: the largest of 512 / 256 / 128 that divides the
+    sequence, clipped to it — read off the grids of the three kernels. A
+    named block is taken as given."""
+    x = jax.ShapeDtypeStruct((1, seq, 1, 8), jnp.float32)
+
+    def grids(**blocks):
+        return {name: grid for name, (grid, _) in _flash_kernels(x, **blocks).items()}
+
+    n = seq // tile
+    assert grids() == {"flash_fwd": (1, n), "flash_dkv": (1, n), "flash_dq": (1, n)}
+    if seq >= 128:
+        m = seq // 128
+        assert grids(block_q=128, block_k=128) == {
+            "flash_fwd": (1, m), "flash_dkv": (1, m), "flash_dq": (1, m)
+        }
