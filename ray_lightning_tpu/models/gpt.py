@@ -112,12 +112,26 @@ class GPTConfig:
     # already small).
     loss_chunk: int = 0
     # A model whose layers differ in kind (models/mixed.py). ``layer_types``
-    # gives each layer's (attention kind, MLP kind): attention "full" or
-    # "window" (``attn_window`` positions), MLP "dense" (``d_ff``) or
+    # gives each layer's (mixer kind, MLP kind): mixer "full" or "window"
+    # attention (``attn_window`` positions) or "ssm" (a Mamba-2 state
+    # layer, the ``ssm_*`` sizes below), MLP "dense" (``d_ff``) or
     # "experts" (``d_ff_expert`` wide, ``n_experts`` routed over, ``moe_top_k``
-    # a token). Empty = every layer alike, the fields above say how. JSON
-    # hands these over as lists; they are held as tuples.
-    layer_types: Tuple[Tuple[str, str], ...] = ()
+    # a token). Either part may be None: a layer that is a mixer alone or
+    # an MLP alone, under its one norm. With layer_types, ``pos_embed``
+    # may be "none" (attention without positions) and ``mlp_variant``
+    # "relu2" (``down(relu(up(x))^2)``, not gated). Empty = every layer
+    # alike, the fields above say how. JSON hands these over as lists
+    # (``null`` for a missing part); they are held as tuples.
+    layer_types: Tuple[Tuple[Optional[str], Optional[str]], ...] = ()
+    # The state layers: ``ssm_heads`` heads of ``ssm_head_dim``, B and C in
+    # ``ssm_groups`` groups of ``ssm_state``, a causal depthwise conv of
+    # ``ssm_conv`` taps, the recurrence over rows in chunks of ``ssm_chunk``.
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_groups: int = 1
+    ssm_state: int = 0
+    ssm_conv: int = 4
+    ssm_chunk: int = 128
     # Head widths where q·k and v differ (0 = ``d_model // n_head``).
     qk_head_dim: int = 0
     v_head_dim: int = 0
@@ -140,6 +154,15 @@ class GPTConfig:
     # It routes over all of them and computes its own experts' part of the
     # result. Empty = all.
     experts_held: Tuple[int, ...] = ()
+    # Experts that work in a latent narrower than the residual: the
+    # layer's input is projected to ``moe_latent_dim`` once, the routed
+    # experts run there and their weighted sum is projected back (0 = the
+    # experts work at ``d_model``). ``d_ff_shared`` > 0 adds one shared
+    # expert of that width on the layer's whole input; ``moe_routed_scale``
+    # multiplies the routed experts' weights after normalisation.
+    moe_latent_dim: int = 0
+    d_ff_shared: int = 0
+    moe_routed_scale: float = 1.0
 
     def __post_init__(self) -> None:
         for name in ("layer_types", "attn_sink_logit", "experts_held"):
@@ -172,10 +195,12 @@ class GPTConfig:
         return self.d_ff or 4 * self.d_model
 
     def validate_variants(self) -> None:
-        if self.mlp_variant not in ("gelu", "swiglu"):
+        if self.mlp_variant not in ("gelu", "swiglu") and not (
+            self.mixed and self.mlp_variant == "relu2"
+        ):
             raise ValueError(
                 f"unknown mlp_variant {self.mlp_variant!r}; use 'gelu' or "
-                "'swiglu'"
+                "'swiglu' ('relu2' with layer_types)"
             )
         if self.norm_impl not in ("layernorm", "rmsnorm"):
             raise ValueError(
@@ -1878,10 +1903,12 @@ def gpt_decode_fold(
 
     With mixed layer kinds (``cfg.layer_types``) the caches are the two
     dicts of models/mixed.py, idle lanes route to no expert, and the
-    expert layers' counts leave the fold with the tokens: ``moe (4,)
-    int32`` — pairs routed, pairs on held experts, held experts hit
-    (summed over expert layers and iterations), iterations with a live
-    slot — is appended to the return tuple.
+    layers' counts leave the fold with the tokens: ``moe (6,) int32`` —
+    pairs routed, pairs on held experts, held experts hit (summed over
+    expert layers and iterations), iterations with a live slot, slot-steps
+    (every slot's state, a state layer's among them, is advanced in every
+    iteration) and the slot-steps that belonged to a live request — is
+    appended to the return tuple.
     """
     if cfg.mixed:
         if page_table is not None:
@@ -1896,9 +1923,11 @@ def gpt_decode_fold(
             logits, k_cache, v_cache, st = mixed_decode_step(
                 params, cfg, cur, pos, k_cache, v_cache, active=active
             )
-            moe = moe + jnp.concatenate(
-                [st, active.any().astype(jnp.int32)[None]]
-            )
+            moe = moe + jnp.concatenate([st, jnp.stack([
+                active.any().astype(jnp.int32),
+                jnp.asarray(active.shape[0], jnp.int32),
+                active.sum().astype(jnp.int32),
+            ])])
         elif page_table is None:
             logits, k_cache, v_cache = gpt_decode_step(
                 params, cfg, cur, pos, k_cache, v_cache
@@ -1928,7 +1957,7 @@ def gpt_decode_fold(
     carry, (tok_block, emit_block) = jax.lax.scan(
         body,
         (cur, pos, keys, active, remaining, k_cache, v_cache,
-         jnp.zeros((4,), jnp.int32)),
+         jnp.zeros((6,), jnp.int32)),
         None,
         length=int(fold),
     )

@@ -1,13 +1,19 @@
 """A decoder whose layers differ in kind, and one block function for it.
 
-``GPTConfig.layer_types`` gives each layer an attention kind — "full"
-(the whole context) or "window" (the last ``attn_window`` positions) —
-and an MLP kind — "dense" (SwiGLU, ``d_ff``) or "experts" (routed SwiGLU
-experts of ``d_ff_expert``, of which this process may hold a share:
-``experts_held``). The kinds may differ in KV heads, rotary base and in
-whether a learnable per-head sink logit joins the softmax; q·k and v may
-differ in width, and the rotation may cover only the first ``rope_dim``
-dims of a head. RMSNorm, no biases, rotary positions.
+``GPTConfig.layer_types`` gives each layer a mixer kind — attention "full"
+(the whole context) or "window" (the last ``attn_window`` positions), or
+"ssm" (a Mamba-2 state layer: models/ssm.py) — and an MLP kind — "dense"
+(``d_ff``) or "experts" (routed experts of ``d_ff_expert``, of which this
+process may hold a share: ``experts_held``). Either part may be missing
+(None): such a layer is ``x + part(RMSNorm(x))`` alone. The attention
+kinds may differ in KV heads, rotary base and in whether a learnable
+per-head sink logit joins the softmax; q·k and v may differ in width, and
+the rotation may cover only the first ``rope_dim`` dims of a head, or
+there may be none (``pos_embed="none"``: the state layers carry order).
+The MLPs are SwiGLU or, with ``mlp_variant="relu2"``, ``down(relu(up
+x)^2)``; the experts may work in a latent narrower than the residual
+(``moe_latent_dim``), beside one shared expert on the whole input
+(``d_ff_shared``). RMSNorm, no biases.
 
 One block, :func:`mixed_block`, serves the three modes the model runs in:
 
@@ -17,10 +23,17 @@ One block, :func:`mixed_block`, serves the three modes the model runs in:
 - decode (``gpt_decode_step``): one row a slot at per-slot positions, its
   K/V written in place into the caches and attention read from them.
 
-The caches are two, side by side, because the kinds need different
+The caches are one a kind, side by side, because the kinds need different
 amounts: a full layer keeps every position of a request, a window layer
-only the last ``attn_window`` of them, in a ring (row ``pos mod R``).
-Each is a dict with one stacked array an attention kind, ``{"full": ...,
+only the last ``attn_window`` of them, in a ring (row ``pos mod R``), a
+state layer one running state and the conv's last rows whatever the
+request's length. A request's state comes in two halves, each a dict by
+kind: K and V of the attention kinds, and under ``"ssm"`` the recurrent
+states in the first and the conv tails in the second — one array a state
+layer, ``(B, H, P, N)`` float32 and ``(taps - 1, B, channels)``, in a
+tuple: a step replaces each whole, so a donated one is updated where it
+lies and no layer is ever sliced out of a stack. An attention kind has
+one stacked array, ``{"full": ...,
 "window": ...}``, ``(Lk, B, rows, Hkv * d)`` with rows ``S`` or ``R`` and
 ``d`` the q·k width for K and the v width for V: a position's KV heads
 lie side by side in one row. A step's write is then one row a slot, and
@@ -33,9 +46,11 @@ size; compile-only for the v5e, PR 28).
 
 The parameter tree is top-level ``wte``, ``lm_head``, ``lnf_g`` and one
 flat ``blocks`` dict (:func:`mixed_param_shapes`): leaves of one kind of
-layer are stacked on a leading axis over the layers OF THAT KIND. Gate
-and up of a SwiGLU are two (D, F) matrices side by side (``(2, D, F)``):
-the layout the TPU's matmul takes them in, so that no step re-lays them.
+layer are stacked on a leading axis over the layers OF THAT KIND (the
+norm gains ``ln1_g`` over the layers that have a mixer, ``ln2_g`` over
+those that have an MLP). Gate and up of a SwiGLU are two (D, F) matrices
+side by side (``(2, D, F)``): the layout the TPU's matmul takes them in,
+so that no step re-lays them; a relu2 MLP has the one (``(1, D, F)``).
 """
 from __future__ import annotations
 
@@ -47,9 +62,10 @@ import jax.numpy as jnp
 import numpy as np
 
 ATTN_KINDS = ("full", "window")
+MIXER_KINDS = ATTN_KINDS + ("ssm",)
 MLP_KINDS = ("dense", "experts")
-#: Prefix of an attention kind's leaves in ``blocks``.
-_ATTN_PREFIX = {"full": "full", "window": "swa"}
+#: Prefix of a mixer kind's leaves in ``blocks``.
+_MIXER_PREFIX = {"full": "full", "window": "swa", "ssm": "ssm"}
 #: Query rows a block of the no-cache full attention takes at a time: the
 #: float32 scores of a block against its causal prefix are what is live.
 _Q_BLOCK = 512
@@ -57,28 +73,40 @@ _Q_BLOCK = 512
 
 @dataclass(frozen=True)
 class LayerSpec:
-    """One layer: its kinds and its index among the layers of each kind
-    (where its leaves and its cache rows lie)."""
+    """One layer: its kinds (None: the layer has no such part), its index
+    among the layers of each kind (where its leaves and its cache lie) and
+    among the layers that have a mixer / an MLP (where its norm gains lie)."""
 
     index: int
-    attn: str
-    mlp: str
-    attn_index: int
+    mixer: Optional[str]
+    mlp: Optional[str]
+    mixer_index: int
     mlp_index: int
+    norm1_index: int
+    norm2_index: int
 
 
 def layer_specs(cfg: Any) -> List[LayerSpec]:
-    seen: Dict[str, int] = {}
+    seen: Dict[Any, int] = {}
     out = []
-    for i, (attn, mlp) in enumerate(cfg.layer_types):
-        out.append(LayerSpec(i, attn, mlp, seen.get(attn, 0), seen.get(mlp, 0)))
-        seen[attn] = seen.get(attn, 0) + 1
-        seen[mlp] = seen.get(mlp, 0) + 1
+    for i, (mixer, mlp) in enumerate(cfg.layer_types):
+        # a missing part (None) has no index of its own: all of them read 0
+        out.append(LayerSpec(
+            i, mixer, mlp, seen.get(mixer, 0), seen.get(mlp, 0),
+            seen.get("mixers", 0), seen.get("mlps", 0),
+        ))
+        for key in ((mixer, "mixers") if mixer else ()) + ((mlp, "mlps") if mlp else ()):
+            seen[key] = seen.get(key, 0) + 1
     return out
 
 
 def count_kind(cfg: Any, kind: str) -> int:
     return sum(kind in pair for pair in cfg.layer_types)
+
+
+def count_part(cfg: Any, part: int) -> int:
+    """Layers that have a mixer (``part`` 0) or an MLP (1)."""
+    return sum(pair[part] is not None for pair in cfg.layer_types)
 
 
 def validate_mixed(cfg: Any) -> None:
@@ -90,15 +118,37 @@ def validate_mixed(cfg: Any) -> None:
             f"{cfg.n_layer}"
         )
     for pair in cfg.layer_types:
-        if len(pair) != 2 or pair[0] not in ATTN_KINDS or pair[1] not in MLP_KINDS:
+        if (
+            len(pair) != 2 or pair[0] not in MIXER_KINDS + (None,)
+            or pair[1] not in MLP_KINDS + (None,) or pair == (None, None)
+        ):
             raise ValueError(
-                f"layer_types entry {pair!r}: use (attention kind of "
-                f"{ATTN_KINDS}, MLP kind of {MLP_KINDS})"
+                f"layer_types entry {pair!r}: use (mixer kind of "
+                f"{MIXER_KINDS}, MLP kind of {MLP_KINDS}), either of them "
+                "None for a layer that is the other part alone"
             )
-    if (cfg.norm_impl, cfg.pos_embed, cfg.mlp_variant) != ("rmsnorm", "rope", "swiglu"):
+    if cfg.norm_impl != "rmsnorm" or cfg.pos_embed not in ("rope", "none") or (
+        cfg.mlp_variant not in ("swiglu", "relu2")
+    ):
         raise ValueError(
-            "layer_types runs RMSNorm, rotary positions and SwiGLU: set "
-            "norm_impl='rmsnorm', pos_embed='rope', mlp_variant='swiglu'"
+            "layer_types runs RMSNorm, rotary or no positions and SwiGLU or "
+            "relu2 MLPs: set norm_impl='rmsnorm', pos_embed='rope' or "
+            "'none', mlp_variant='swiglu' or 'relu2'"
+        )
+    if count_kind(cfg, "ssm"):
+        H, G = cfg.ssm_heads, cfg.ssm_groups
+        if min(H, cfg.ssm_head_dim, G, cfg.ssm_state, cfg.ssm_chunk) < 1 or cfg.ssm_conv < 2 or H % G:
+            raise ValueError(
+                "state layers need ssm_heads, ssm_head_dim, ssm_state and "
+                "ssm_chunk >= 1, ssm_conv >= 2 taps and ssm_heads divisible "
+                f"by ssm_groups (got heads {H}, head_dim {cfg.ssm_head_dim}, "
+                f"groups {G}, state {cfg.ssm_state}, conv {cfg.ssm_conv}, "
+                f"chunk {cfg.ssm_chunk})"
+            )
+    elif cfg.pos_embed == "none" and (count_kind(cfg, "full") or count_kind(cfg, "window")):
+        raise ValueError(
+            "pos_embed='none' needs state layers: attention without positions "
+            "sees a set, and nothing else here carries the order"
         )
     if cfg.tie_word_embeddings:
         raise ValueError("layer_types needs an untied head (tie_word_embeddings=False)")
@@ -140,16 +190,29 @@ def validate_mixed(cfg: Any) -> None:
                 f"experts_held {cfg.experts_held!r} must be (first, count) "
                 f"inside the {cfg.n_experts} experts"
             )
+        if cfg.moe_latent_dim < 0 or cfg.d_ff_shared < 0:
+            raise ValueError("moe_latent_dim and d_ff_shared must be >= 0")
+    elif cfg.moe_latent_dim or cfg.d_ff_shared or cfg.moe_routed_scale != 1.0:
+        raise ValueError(
+            "moe_latent_dim, d_ff_shared and moe_routed_scale describe "
+            "expert layers: layer_types names none"
+        )
 
 
 def refuse_mixed(cfg: Any, mechanism: str) -> None:
     """The modes that have no block for mixed layers or held experts say
     so by name; none of them computes silently."""
     if cfg.mixed:
+        state = (
+            "; a state layer keeps one running state a request, with no "
+            "rows to page, share, export, verify or enter mid-prompt: such a "
+            "mode would need a snapshot of the state"
+            if count_kind(cfg, "ssm") else ""
+        )
         raise ValueError(
             f"{mechanism} does not run a configuration with mixed layer "
             "kinds or held experts (GPTConfig.layer_types): it has the "
-            "dense engine's bucketed prefill and decode fold only"
+            f"dense engine's bucketed prefill and decode fold only{state}"
         )
 
 
@@ -195,13 +258,17 @@ def ring_rows(cfg: Any) -> int:
 
 def mixed_param_shapes(cfg: Any) -> Dict[str, Any]:
     """``name -> shape`` of the tree the block takes (``blocks`` nested)."""
+    from ray_lightning_tpu.models import ssm
+    from ray_lightning_tpu.parallel.moe import MLP_GATES
+
     D, H, V = cfg.d_model, cfg.n_head, cfg.vocab_size
-    dqk, dv = qk_dim(cfg), v_dim(cfg)
-    blocks: Dict[str, Tuple[int, ...]] = {
-        "ln1_g": (cfg.n_layer, D), "ln2_g": (cfg.n_layer, D),
-    }
+    dqk, dv, C = qk_dim(cfg), v_dim(cfg), MLP_GATES[cfg.mlp_variant]
+    blocks: Dict[str, Tuple[int, ...]] = {}
+    for name, part in (("ln1_g", 0), ("ln2_g", 1)):
+        if count_part(cfg, part):
+            blocks[name] = (count_part(cfg, part), D)
     for kind in ATTN_KINDS:
-        n, hkv, p = count_kind(cfg, kind), kv_heads(cfg, kind), _ATTN_PREFIX[kind]
+        n, hkv, p = count_kind(cfg, kind), kv_heads(cfg, kind), _MIXER_PREFIX[kind]
         if not n:
             continue
         blocks.update({
@@ -210,25 +277,36 @@ def mixed_param_shapes(cfg: Any) -> Dict[str, Any]:
         })
         if kind in cfg.attn_sink_logit:
             blocks[f"{p}_sink"] = (n, H)
+    n = count_kind(cfg, "ssm")
+    if n:
+        blocks.update(ssm.param_shapes(cfg, n))
     n = count_kind(cfg, "dense")
     if n:
-        blocks.update({"dense_wi": (n, 2, D, cfg.ff_dim), "dense_wo2": (n, cfg.ff_dim, D)})
+        blocks.update({"dense_wi": (n, C, D, cfg.ff_dim), "dense_wo2": (n, cfg.ff_dim, D)})
     n = count_kind(cfg, "experts")
     if n:
         held, F = experts_held(cfg)[1], cfg.d_ff_expert or cfg.ff_dim
+        Din = cfg.moe_latent_dim or D  # the width the routed experts work at
         blocks.update({
             "moe_router": (n, D, cfg.n_experts),
-            "moe_wi": (n, held, 2, D, F), "moe_wo2": (n, held, F, D),
+            "moe_wi": (n, held, C, Din, F), "moe_wo2": (n, held, F, Din),
         })
         if cfg.moe_scoring == "sigmoid":
             blocks["moe_router_bias"] = (n, cfg.n_experts)
+        if cfg.moe_latent_dim:
+            blocks.update({"moe_latent_down": (n, D, Din), "moe_latent_up": (n, Din, D)})
+        if cfg.d_ff_shared:
+            blocks.update({
+                "moe_shared_wi": (n, C, D, cfg.d_ff_shared), "moe_shared_wo2": (n, cfg.d_ff_shared, D),
+            })
     return {"wte": (V, D), "lm_head": (V, D), "lnf_g": (D,), "blocks": blocks}
 
 
 def init_mixed_params(rng: jax.Array, cfg: Any) -> Dict[str, Any]:
-    """Seeded float32 parameters: normal ``init_std``, the two writes into
-    the residual stream scaled by 1/sqrt(2L), gains and sink logits one,
-    the router's correction bias zero."""
+    """Seeded float32 parameters: normal ``init_std``, the writes into
+    the residual stream scaled by 1/sqrt(2L), gains, sink logits and a
+    state layer's ``D`` one, the router's correction bias and a state
+    layer's ``A_log`` and ``dt_bias`` zero (decay ``exp(-dt)``)."""
     shapes = mixed_param_shapes(cfg)
     res_std = cfg.init_std / np.sqrt(2.0 * cfg.n_layer)
     flat = [(k, v) for k, v in shapes.items() if k != "blocks"] + [
@@ -237,12 +315,12 @@ def init_mixed_params(rng: jax.Array, cfg: Any) -> Dict[str, Any]:
     out: Dict[str, Any] = {"blocks": {}}
     for i, (name, shape) in enumerate(sorted(flat)):
         leaf = name.rsplit("/", 1)[-1]
-        if leaf.endswith("_g") or leaf.endswith("_sink"):
+        if leaf.endswith(("_g", "_sink")) or leaf == "ssm_D":
             w = jnp.ones(shape, jnp.float32)
-        elif leaf == "moe_router_bias":
+        elif leaf in ("moe_router_bias", "ssm_A_log", "ssm_dt_bias"):
             w = jnp.zeros(shape, jnp.float32)
         else:
-            std = res_std if leaf.endswith(("_wo", "_wo2")) else cfg.init_std
+            std = res_std if leaf.endswith(("_wo", "_wo2", "_latent_up")) else cfg.init_std
             w = std * jax.random.normal(jax.random.fold_in(rng, i), shape, jnp.float32)
         if name.startswith("blocks/"):
             out["blocks"][leaf] = w
@@ -252,16 +330,24 @@ def init_mixed_params(rng: jax.Array, cfg: Any) -> Dict[str, Any]:
 
 
 def empty_caches(cfg: Any, slots: int, max_seq: int, dtype: Any) -> Tuple[Dict[str, Any], Dict[str, Any]]:
-    """Zeroed K and V caches for ``slots`` requests of up to ``max_seq``
-    positions (a kind the model has no layer of is left out)."""
+    """The zeroed per-request state of ``slots`` requests of up to
+    ``max_seq`` positions, in its two halves (a kind the model has no
+    layer of is left out): K and V of the attention kinds; under "ssm" a
+    tuple of recurrent states and a tuple of conv tails, one a state layer."""
+    from ray_lightning_tpu.models import ssm
+
     rows = {"full": int(max_seq), "window": ring_rows(cfg)}
-    k, v = {}, {}
+    k: Dict[str, Any] = {}
+    v: Dict[str, Any] = {}
     for kind in ATTN_KINDS:
         n = count_kind(cfg, kind)
         if n:
             lead, hkv = (n, slots, rows[kind]), kv_heads(cfg, kind)
             k[kind] = jnp.zeros(lead + (hkv * qk_dim(cfg),), dtype)
             v[kind] = jnp.zeros(lead + (hkv * v_dim(cfg),), dtype)
+    n = count_kind(cfg, "ssm")
+    if n:
+        k["ssm"], v["ssm"] = (tuple(x) for x in zip(*(ssm.empty_state(cfg, slots, dtype) for _ in range(n))))
     return k, v
 
 
@@ -397,16 +483,29 @@ def write_prefill_rows(
     k_cache: Dict[str, Any], v_cache: Dict[str, Any], pf_k: Dict[str, Any],
     pf_v: Dict[str, Any], slot: jax.Array, true_len: jax.Array,
 ) -> Tuple[Dict[str, Any], Dict[str, Any]]:
-    """An admitted prompt's K/V (``{kind: (Lk, 1, Pb, Hkv, d)}``, of
-    which the first ``true_len`` rows are real) into slot ``slot``, the
-    KV heads side by side in a row: all ``Pb`` rows of the full layers (rows past
+    """An admitted prompt's state (:func:`mixed_rows`: of its ``Pb`` rows
+    the first ``true_len`` are real) into slot ``slot``. K/V ``{kind:
+    (Lk, 1, Pb, Hkv, d)}``, the KV heads side by side in a row: all ``Pb``
+    rows of the full layers (rows past
     ``true_len`` lie behind the position mask, as in the dense engine),
     and of the window layers the prompt's last ``min(true_len, R)``
-    positions, each at its ring row."""
+    positions, each at its ring row. A state layer has no rows: its state
+    after the last real row and its conv tail are written whole, so
+    nothing of the slot's last request is left."""
     zero = jnp.zeros((), jnp.int32)
     k_cache, v_cache = dict(k_cache), dict(v_cache)
     for cache, pf in ((k_cache, pf_k), (v_cache, pf_v)):
         for kind, rows in pf.items():
+            if kind == "ssm":
+                # state (1, H, P, N) at [slot]; tail (taps - 1, 1, channels) at [:, slot]
+                cache[kind] = tuple(
+                    jax.lax.dynamic_update_slice(
+                        c, r.astype(c.dtype),
+                        (slot,) + (zero,) * 3 if c.ndim == 4 else (zero, slot, zero),
+                    )
+                    for c, r in zip(cache[kind], rows)
+                )
+                continue
             if kind == "window":
                 R, Pb = cache[kind].shape[2], rows.shape[2]
                 r = jnp.arange(R, dtype=jnp.int32)
@@ -420,57 +519,54 @@ def write_prefill_rows(
     return k_cache, v_cache
 
 
-
 # -- the block -----------------------------------------------------------------
+#: Leaves of an expert layer outside its routed experts, by mlp index.
+_MOE_OWN = ("router", "router_bias", "latent_down", "latent_up", "shared_wi", "shared_wo2")
+
+
 def _layer_leaves(blocks: Dict[str, Any], ls: LayerSpec) -> Dict[str, Any]:
     """This layer's leaves, under names without the kind's prefix (the
     experts' weights stay stacked: see ``moe_ffn_held``'s ``layer``)."""
-    p = _ATTN_PREFIX[ls.attn] + "_"
-    out = {"ln1_g": blocks["ln1_g"][ls.index], "ln2_g": blocks["ln2_g"][ls.index]}
-    for name, leaf in blocks.items():
-        if name.startswith(p):
-            out[name[len(p):]] = leaf[ls.attn_index]
-        elif name.startswith("dense_") and ls.mlp == "dense":
-            out[name[len("dense_"):]] = leaf[ls.mlp_index]
-        elif name in ("moe_router", "moe_router_bias") and ls.mlp == "experts":
-            out[name[len("moe_"):]] = leaf[ls.mlp_index]
-    if ls.mlp == "experts":
+    out: Dict[str, Any] = {}
+    if ls.mixer:
+        out["ln1_g"] = blocks["ln1_g"][ls.norm1_index]
+        p = _MIXER_PREFIX[ls.mixer] + "_"
+        out.update({k[len(p):]: w[ls.mixer_index] for k, w in blocks.items() if k.startswith(p)})
+    if ls.mlp:
+        out["ln2_g"] = blocks["ln2_g"][ls.norm2_index]
+    if ls.mlp == "dense":
+        out.update(wi=blocks["dense_wi"][ls.mlp_index], wo2=blocks["dense_wo2"][ls.mlp_index])
+    elif ls.mlp == "experts":
+        out.update({k: blocks["moe_" + k][ls.mlp_index] for k in _MOE_OWN if "moe_" + k in blocks})
         out["wi"], out["wo2"] = blocks["moe_wi"], blocks["moe_wo2"]
     return out
 
 
-def mixed_block(
-    h: jax.Array,
-    lp: Dict[str, Any],
-    ls: LayerSpec,
-    cfg: Any,
-    rope: Dict[str, Tuple[jax.Array, jax.Array]],
-    pos: Optional[jax.Array] = None,
-    caches: Optional[Tuple[Dict[str, Any], Dict[str, Any]]] = None,
-    valid: Optional[jax.Array] = None,
-) -> Tuple[jax.Array, Any, jax.Array]:
-    """One layer over h (B, S, D) -> ``(h, kv, moe_stats)``.
-
-    ``caches`` None: the S rows attend among themselves (forward,
-    prefill) and ``kv`` is their ``(k, v)`` at the layer's KV width.
-    ``caches = (k_cache, v_cache)``: decode, S = 1 and ``pos`` (B,) each
-    slot's position; the row's K/V are written in place and ``kv`` is the
-    updated pair. ``valid`` (B, S) bool marks the real tokens for the
-    expert layer. ``moe_stats`` is :func:`moe_ffn_held`'s (zeros for a
-    dense layer)."""
-    from ray_lightning_tpu.models.gpt import _rmsnorm, _write_cache_rows
-    from ray_lightning_tpu.parallel.moe import moe_ffn_held
+def _mlp(x: jax.Array, wi: jax.Array, wo: jax.Array, cfg: Any) -> jax.Array:
+    """x (..., D) through ``wi`` (gates, D, F) and ``wo`` (F, D'): SwiGLU
+    with two gates, ``relu(up x)^2`` with one."""
+    from ray_lightning_tpu.parallel.moe import mlp_act
 
     cdt = jnp.dtype(cfg.compute_dtype)
-    B, S, D = h.shape
-    G = kv_heads(cfg, ls.attn)
-    window = cfg.attn_window if ls.attn == "window" else 0
-    with jax.named_scope("attn_" + ls.attn):
+    z = jnp.einsum("...d,cdf->...cf", x, wi.astype(cdt))
+    return jnp.einsum("...f,fd->...d", mlp_act(z, cfg.mlp_variant), wo.astype(cdt))
+
+
+def _attention_part(h, lp, ls, cfg, rope, pos, caches):
+    """``(attention's write into the residual, kv)`` of one attention layer."""
+    from ray_lightning_tpu.models.gpt import _rmsnorm, _write_cache_rows
+
+    cdt = jnp.dtype(cfg.compute_dtype)
+    B, S, _ = h.shape
+    G = kv_heads(cfg, ls.mixer)
+    window = cfg.attn_window if ls.mixer == "window" else 0
+    with jax.named_scope("attn_" + ls.mixer):
         a = _rmsnorm(h, lp["ln1_g"], cfg.norm_eps)
         q = jnp.einsum("bsd,dhk->bshk", a, lp["wq"].astype(cdt))
         k = jnp.einsum("bsd,dhk->bshk", a, lp["wk"].astype(cdt))
         v = jnp.einsum("bsd,dhk->bshk", a, lp["wv"].astype(cdt))
-        q, k = _rope(q, rope[ls.attn]), _rope(k, rope[ls.attn])
+        if cfg.pos_embed == "rope":
+            q, k = _rope(q, rope[ls.mixer]), _rope(k, rope[ls.mixer])
         if cfg.attn_value_scale != 1.0:
             v = v * jnp.asarray(cfg.attn_value_scale, v.dtype)
         q = q.reshape(B, S, G, cfg.n_head // G, q.shape[-1])
@@ -484,45 +580,123 @@ def mixed_block(
             )
         else:
             k_cache, v_cache = dict(caches[0]), dict(caches[1])
-            ring = ls.attn == "window"
+            ring = ls.mixer == "window"
             # a full layer's position past the end lands on the last row
             # (frozen slots: _write_cache_rows clamps); a ring row is
             # always in bounds
-            row = pos % k_cache[ls.attn].shape[2] if ring else pos
-            k_cache[ls.attn] = _write_cache_rows(
-                k_cache[ls.attn], ls.attn_index, k[:, 0].reshape(B, -1), row
+            row = pos % k_cache[ls.mixer].shape[2] if ring else pos
+            k_cache[ls.mixer] = _write_cache_rows(
+                k_cache[ls.mixer], ls.mixer_index, k[:, 0].reshape(B, -1), row
             )
-            v_cache[ls.attn] = _write_cache_rows(
-                v_cache[ls.attn], ls.attn_index, v[:, 0].reshape(B, -1), row
+            v_cache[ls.mixer] = _write_cache_rows(
+                v_cache[ls.mixer], ls.mixer_index, v[:, 0].reshape(B, -1), row
             )
             kv = (k_cache, v_cache)
             o = _attend_cache(
-                q, k_cache[ls.attn][ls.attn_index], v_cache[ls.attn][ls.attn_index],
+                q, k_cache[ls.mixer][ls.mixer_index], v_cache[ls.mixer][ls.mixer_index],
                 pos, sink, window, ring,
             )
-        h = h + jnp.einsum("bshk,hkd->bsd", o.astype(cdt), lp["wo"].astype(cdt))
-    m = _rmsnorm(h, lp["ln2_g"], cfg.norm_eps)
-    if ls.mlp == "dense":
-        with jax.named_scope("mlp"):
-            z = jnp.einsum("bsd,cdf->bscf", m, lp["wi"].astype(cdt))
-            out = jnp.einsum(
-                "bsf,fd->bsd", jax.nn.silu(z[:, :, 0]) * z[:, :, 1], lp["wo2"].astype(cdt)
-            )
-        return h + out, kv, jnp.zeros((3,), jnp.int32)
+        return jnp.einsum("bshk,hkd->bsd", o.astype(cdt), lp["wo"].astype(cdt)), kv
+
+
+def _state_part(h, lp, ls, cfg, caches, valid):
+    """``(the state layer's write into the residual, kv)``: with no cache
+    ``kv`` is the state after the last real row and the conv tail; in
+    decode the slots' own are advanced and go back where they were."""
+    from ray_lightning_tpu.models import ssm
+    from ray_lightning_tpu.models.gpt import _rmsnorm
+
+    u = _rmsnorm(h, lp["ln1_g"], cfg.norm_eps)
+    if caches is None:
+        out, state, tail = ssm.ssm_rows(u, lp, cfg, valid)
+        return out, (state, tail)
+    k_cache, v_cache = dict(caches[0]), dict(caches[1])
+    i = ls.mixer_index
+    out, state, tail = ssm.ssm_step(u, lp, cfg, k_cache["ssm"][i], v_cache["ssm"][i])
+    k_cache["ssm"] = k_cache["ssm"][:i] + (state,) + k_cache["ssm"][i + 1:]
+    v_cache["ssm"] = v_cache["ssm"][:i] + (tail,) + v_cache["ssm"][i + 1:]
+    return out, (k_cache, v_cache)
+
+
+def _experts_part(m, lp, ls, cfg, valid):
+    """The expert layer over m (B, S, D), its normed input: the routed
+    experts this process holds, in the latent if the model has one, beside
+    the shared expert on the whole input; ``(out, moe_stats)``."""
+    from ray_lightning_tpu.parallel.moe import moe_ffn_held
+
+    cdt = jnp.dtype(cfg.compute_dtype)
+    B, S, D = m.shape
+    t = m.reshape(B * S, D)
+    a = None
+    if "latent_down" in lp:
+        with jax.named_scope("moe_latent_down"):
+            a = jnp.einsum("td,de->te", t, lp["latent_down"].astype(cdt))
     out, stats = moe_ffn_held(
         {"wo" if k == "wo2" else k: lp[k] for k in ("router", "router_bias", "wi", "wo2") if k in lp},
-        m.reshape(B * S, D), held=experts_held(cfg), top_k=cfg.moe_top_k,
-        scoring=cfg.moe_scoring, compute_dtype=cdt, layer=ls.mlp_index,
-        valid=None if valid is None else valid.reshape(B * S),
+        t, held=experts_held(cfg), top_k=cfg.moe_top_k, scoring=cfg.moe_scoring,
+        compute_dtype=cdt, layer=ls.mlp_index, expert_in=a, variant=cfg.mlp_variant,
+        scale=cfg.moe_routed_scale, valid=None if valid is None else valid.reshape(B * S),
     )
-    return h + out.reshape(B, S, D), kv, stats
+    if a is not None:
+        with jax.named_scope("moe_latent_up"):
+            out = jnp.einsum("te,ed->td", out.astype(cdt), lp["latent_up"].astype(cdt))
+    if "shared_wi" in lp:
+        with jax.named_scope("moe_shared"):
+            out = out + _mlp(t, lp["shared_wi"], lp["shared_wo2"], cfg)
+    return out.reshape(B, S, D), stats
+
+
+def mixed_block(
+    h: jax.Array,
+    lp: Dict[str, Any],
+    ls: LayerSpec,
+    cfg: Any,
+    rope: Dict[str, Tuple[jax.Array, jax.Array]],
+    pos: Optional[jax.Array] = None,
+    caches: Optional[Tuple[Dict[str, Any], Dict[str, Any]]] = None,
+    valid: Optional[jax.Array] = None,
+) -> Tuple[jax.Array, Any, jax.Array]:
+    """One layer over h (B, S, D) -> ``(h, kv, moe_stats)``: its mixer if
+    it has one, then its MLP if it has one, each under its own norm.
+
+    ``caches`` None: the S rows are a sequence from its start (forward,
+    prefill) and ``kv`` is what the mixer leaves of them: an attention
+    layer's ``(k, v)`` at its KV width, a state layer's ``(state, conv
+    tail)`` after the last real row. ``caches = (k_cache, v_cache)``:
+    decode, S = 1 and ``pos`` (B,) each slot's position; the slot's K/V
+    row, or its state and tail, are replaced in the caches and ``kv`` is
+    the updated pair. A layer without a mixer hands back ``caches`` (None
+    with no cache). ``valid`` (B, S) bool marks the real tokens for the
+    state layer and the expert layer. ``moe_stats`` is
+    :func:`moe_ffn_held`'s (zeros for any other layer)."""
+    from ray_lightning_tpu.models.gpt import _rmsnorm
+
+    kv, stats = caches, jnp.zeros((3,), jnp.int32)
+    if ls.mixer == "ssm":
+        out, kv = _state_part(h, lp, ls, cfg, caches, valid)
+        h = h + out
+    elif ls.mixer:
+        out, kv = _attention_part(h, lp, ls, cfg, rope, pos, caches)
+        h = h + out
+    if ls.mlp:
+        m = _rmsnorm(h, lp["ln2_g"], cfg.norm_eps)
+        if ls.mlp == "dense":
+            with jax.named_scope("mlp"):
+                out = _mlp(m, lp["wi"], lp["wo2"], cfg)
+        else:
+            out, stats = _experts_part(m, lp, ls, cfg, valid)
+        h = h + out
+    return h, kv, stats
 
 
 def _rope_by_kind(cfg: Any, pos: jax.Array) -> Dict[str, Tuple[jax.Array, jax.Array]]:
     """The rotation tables of positions ``pos`` (B, S), one pair a kind of
-    attention the model has: computed once, shared by its layers."""
+    attention the model has: computed once, shared by its layers (none
+    for attention without positions)."""
     from ray_lightning_tpu.models.gpt import _rope_tables
 
+    if cfg.pos_embed != "rope":
+        return {}
     return {
         kind: _rope_tables(pos, rope_theta(cfg, kind), rope_dim(cfg))
         for kind in ATTN_KINDS if count_kind(cfg, kind)
@@ -533,9 +707,13 @@ def mixed_rows(
     params: Dict[str, Any], cfg: Any, tokens: jax.Array, true_len: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, Dict[str, Any], Dict[str, Any], jax.Array]:
     """The layers over tokens (B, S) with no cache: pre-final-norm hidden
-    states, the rows' K and V by kind (``{kind: (Lk, B, S, Hkv, d)}``) and
-    the expert layers' summed ``moe_stats``. ``true_len`` (scalar): only
-    the first ``true_len`` rows are real (a right-padded prompt)."""
+    states, what the rows leave behind in its two halves by kind — K and V
+    ``{kind: (Lk, B, S, Hkv, d)}``, and under "ssm" the tuples of states
+    and conv tails (:func:`write_prefill_rows` takes both) — and ``[pairs
+    routed, pairs on held experts, held experts hit, rows, real rows]``
+    (int32; the expert layers' ``moe_stats`` summed). ``true_len``
+    (scalar): only the first ``true_len`` rows are real (a right-padded
+    prompt)."""
     from ray_lightning_tpu.utils.quantize import embed_rows
 
     B, S = tokens.shape
@@ -549,13 +727,16 @@ def mixed_rows(
     stats = jnp.zeros((3,), jnp.int32)
     for ls in layer_specs(cfg):
         lp = _layer_leaves(params["blocks"], ls)
-        h, (k, v), st = mixed_block(h, lp, ls, cfg, rope, valid=valid)
-        ks.setdefault(ls.attn, []).append(k.astype(cdt))
-        vs.setdefault(ls.attn, []).append(v.astype(cdt))
+        h, kv, st = mixed_block(h, lp, ls, cfg, rope, valid=valid)
+        if ls.mixer:
+            ks.setdefault(ls.mixer, []).append(kv[0] if ls.mixer == "ssm" else kv[0].astype(cdt))
+            vs.setdefault(ls.mixer, []).append(kv[1].astype(cdt))
         stats = stats + st
+    real = jnp.asarray(B * S, jnp.int32) if valid is None else valid.sum().astype(jnp.int32)
+    stats = jnp.concatenate([stats, jnp.stack([jnp.asarray(B * S, jnp.int32), real])])
     return (
-        h, {a: jnp.stack(x) for a, x in ks.items()},
-        {a: jnp.stack(x) for a, x in vs.items()}, stats,
+        h, {a: tuple(x) if a == "ssm" else jnp.stack(x) for a, x in ks.items()},
+        {a: tuple(x) if a == "ssm" else jnp.stack(x) for a, x in vs.items()}, stats,
     )
 
 
@@ -564,9 +745,10 @@ def mixed_decode_step(
     k_cache: Dict[str, Any], v_cache: Dict[str, Any], active: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, Dict[str, Any], Dict[str, Any], jax.Array]:
     """``gpt_decode_step`` for mixed layers: one token a slot at per-slot
-    positions through both caches; float32 logits (B, V), the caches and
+    positions through the caches; float32 logits (B, V), the caches and
     the summed ``moe_stats``. ``active`` (B,) bool: idle lanes route to
-    no expert (their logits are not read)."""
+    no expert (their logits are not read; a state layer advances their
+    own state, which the next admission into the slot overwrites)."""
     from ray_lightning_tpu.models.gpt import _lm_head, _rmsnorm
     from ray_lightning_tpu.utils.quantize import embed_rows
 

@@ -201,9 +201,11 @@ def route_top_k(
     bias: Any,
     top_k: int,
     scoring: str,
+    scale: float = 1.0,
 ) -> Tuple[jax.Array, jax.Array]:
     """tokens (T, D), router (D, E) -> gates (T, K) float32 and experts
-    (T, K) int32 over ALL ``E`` experts.
+    (T, K) int32 over ALL ``E`` experts. ``scale`` multiplies the gates
+    after their normalisation (a model's routed scaling factor).
 
     ``softmax``: :func:`_route_and_pack`'s rule (top-k of the softmax,
     renormalised over the k when k > 1). ``sigmoid``: the k largest of
@@ -232,7 +234,29 @@ def route_top_k(
         )
     if K > 1 or scoring == "sigmoid":
         top_s = top_s / jnp.clip(top_s.sum(-1, keepdims=True), 1e-9, None)
+    if scale != 1.0:
+        top_s = top_s * jnp.float32(scale)
     return top_s, top_e.astype(jnp.int32)
+
+
+#: Matrices on the input side of an MLP, by variant: gate and up, or up alone.
+MLP_GATES = {"swiglu": 2, "relu2": 1}
+
+
+def mlp_act(z: jax.Array, variant: str) -> jax.Array:
+    """What an MLP's input side z (..., gates, F) gives its output side:
+    ``swiglu`` has two gates, ``silu(gate) * up``; ``relu2`` one,
+    ``relu(up)^2``. The gate count is read from z, so a tree of the wrong
+    kind is refused and never half used."""
+    gates = MLP_GATES.get(variant)
+    if gates is None or z.shape[-2] != gates:
+        raise ValueError(
+            f"an MLP of variant {variant!r} takes {gates} input matrices, "
+            f"its weights have {z.shape[-2]}"
+        )
+    if variant == "swiglu":
+        return jax.nn.silu(z[..., 0, :]) * z[..., 1, :]
+    return jnp.square(jax.nn.relu(z[..., 0, :]))
 
 
 def held_row_tile(n_tokens: int, top_k: int, n_experts: int) -> int:
@@ -255,6 +279,9 @@ def moe_ffn_held(
     valid: Any = None,
     compute_dtype: Any = jnp.float32,
     layer: Any = None,
+    expert_in: Any = None,
+    variant: str = "swiglu",
+    scale: float = 1.0,
 ) -> Tuple[jax.Array, jax.Array]:
     """The expert layer of a process that holds ``held = (first, count)``
     of the experts: x (T, D) -> (T, D), the part of the layer's result
@@ -262,9 +289,14 @@ def moe_ffn_held(
     experts, held experts hit]`` (int32).
 
     ``params``: ``router`` (D, E) over ALL experts (with ``router_bias``
-    (E,) under sigmoid scoring), ``wi`` (count, 2, D, F) — gate and up,
-    each a (D, F) matrix as the matmul wants it — and ``wo`` (count, F, D)
-    of the held experts alone (SwiGLU). With ``layer`` (a static index)
+    (E,) under sigmoid scoring), ``wi`` (count, gates, Din, F) — gate and
+    up (SwiGLU) or up alone (``variant="relu2"``: :func:`mlp_act`), each
+    a (Din, F) matrix as the matmul wants it — and ``wo`` (count, F, Din)
+    of the held experts alone. The router reads x; the experts read
+    ``expert_in`` (T, Din) where the model's experts work at another
+    width than the residual's (a latent the caller projected x into), and
+    the result is then (T, Din) too. ``scale`` is :func:`route_top_k`'s.
+    With ``layer`` (a static index)
     ``wi`` and ``wo`` are the leaves of ALL expert layers, stacked on a
     leading axis, and the loop reads ``[layer, expert]`` out of them: a
     caller that sliced its layer out first would hand the loop a copy of
@@ -273,7 +305,7 @@ def moe_ffn_held(
     (token, expert) pairs whose expert is held are computed. They are
     grouped by expert into tiles of :func:`held_row_tile` rows, each
     group padded to whole tiles, and a loop over the tiles IN USE runs one
-    expert's SwiGLU a tile: no pair is dropped, no capacity factor, and
+    expert's MLP a tile: no pair is dropped, no capacity factor, and
     both the matmuls and the weights read follow the counts. An expert no
     token chose is never read. Nothing here stands in for the processes
     that hold the other experts: what they would add is not in the result.
@@ -289,8 +321,11 @@ def moe_ffn_held(
     wi, wo = params["wi"], params["wo"]
     with jax.named_scope("router"):
         gates, experts = route_top_k(
-            x, params["router"], params.get("router_bias"), K, scoring
+            x, params["router"], params.get("router_bias"), K, scoring, scale
         )
+    if expert_in is not None:
+        x = expert_in
+        D = x.shape[1]
     with jax.named_scope("moe_dispatch"):
         local = experts - first
         here = (local >= 0) & (local < Eh)
@@ -336,8 +371,7 @@ def moe_ffn_held(
             rows = jax.lax.dynamic_slice(row_token, (i * tile,), (tile,))
             wi_e, wo_e = one_expert(wi, e), one_expert(wo, e)
             z = jnp.einsum("td,cdf->tcf", xc[rows], wi_e.astype(cdt))
-            h = jax.nn.silu(z[:, 0]) * z[:, 1]
-            yt = jnp.einsum("tf,fd->td", h, wo_e.astype(cdt))
+            yt = jnp.einsum("tf,fd->td", mlp_act(z, variant), wo_e.astype(cdt))
             return jax.lax.dynamic_update_slice(y, yt, (i * tile, 0))
 
         y = jax.lax.fori_loop(0, n_tiles, one_tile, jnp.zeros((R, D), cdt))

@@ -656,9 +656,10 @@ class DecodeEngine:
                 (B, S // self.kv_page), jnp.int32, self._rep_sh
             )
         elif config.mixed:
-            # Two caches side by side, one an attention kind: the full
-            # layers keep max_seq rows a slot, the window layers a ring of
-            # ring_rows(cfg) (models/mixed.py).
+            # Caches side by side, one a mixer kind: the full layers keep
+            # max_seq rows a slot, the window layers a ring of
+            # ring_rows(cfg), a state layer one state and its conv tail
+            # (models/mixed.py).
             from ray_lightning_tpu.models.mixed import empty_caches
 
             self._k, self._v = empty_caches(config, B, S, cdt)
@@ -827,6 +828,19 @@ class DecodeEngine:
             "prefill": {"pairs_routed": 0, "pairs_held": 0,
                         "experts_hit": 0, "admissions": 0},
         }
+        #: The state layers' counts, fetched with the same vectors:
+        #: slot-steps a fold advanced (every slot, every iteration) and
+        #: those of live requests; rows an admission scanned (its bucket)
+        #: and those that were prompt. Zeros without state layers.
+        self.ssm_totals: Dict[str, Dict[str, int]] = {
+            "decode": {"slot_steps": 0, "slot_steps_live": 0},
+            "prefill": {"rows_scanned": 0, "rows_real": 0},
+        }
+        self._state_layers = 0
+        if config.mixed:
+            from ray_lightning_tpu.models.mixed import count_kind
+
+            self._state_layers = count_kind(config, "ssm")
         from ray_lightning_tpu.obs.registry import get_registry as _greg
 
         _reg = _greg()
@@ -964,10 +978,11 @@ class DecodeEngine:
             # deactivates itself in-graph when the request is already
             # done at its first token (n_new == 1 or eos).
             if cfg.mixed:
-                # Rows past the prompt's end route to no expert; the full
-                # layers take all Pb rows, the window layers' ring the
-                # prompt's last rows; the expert layers' counts come out
-                # with the first token.
+                # Rows past the prompt's end route to no expert and leave
+                # a state layer's state as it was; the full layers take all
+                # Pb rows, the window layers' ring the prompt's last rows, a
+                # state layer's state and conv tail are written whole; the
+                # layers' counts come out with the first token.
                 from ray_lightning_tpu.models.mixed import (
                     mixed_rows,
                     write_prefill_rows,
@@ -2016,27 +2031,38 @@ class DecodeEngine:
         return out
 
     def cache_stats(self) -> Dict[str, Dict[str, int]]:
-        """The dense KV cache by layer kind: layers, rows a slot, bytes
-        (K and V) and ``row_layout`` — whether a position's KV heads lie
-        side by side in one cache row, which is the read every decode
+        """The dense per-request state by layer kind: layers, rows a slot,
+        bytes (K and V) and ``row_layout`` — whether a position's KV heads
+        lie side by side in one cache row, which is the read every decode
         step of this engine then runs (models/gpt.py:_attend_layer_cache;
         false under a mesh, where the heads keep an axis to shard). Every
-        layer of a uniform configuration is ``full``; a paged engine has
-        no dense cache and reports ``{}``."""
+        layer of a uniform configuration is ``full``; a state layer's kind
+        is ``state``: one running state a slot whatever the request's
+        length (``rows_per_slot`` 1), its bytes the recurrent states' and
+        the conv tails'. A paged engine has no dense cache and reports
+        ``{}``."""
         if self._k is None:
             return {}
         k, v = self._k, self._v
         if not isinstance(k, dict):
             k, v = {"full": k}, {"full": v}
-        return {
+        out = {
             kind: {
                 "layers": int(k[kind].shape[0]),
                 "rows_per_slot": int(k[kind].shape[2]),
                 "bytes": int(k[kind].nbytes + v[kind].nbytes),
                 "row_layout": k[kind].ndim == 4,
             }
-            for kind in k
+            for kind in k if kind != "ssm"
         }
+        if "ssm" in k:
+            out["state"] = {
+                "layers": len(k["ssm"]),
+                "rows_per_slot": 1,
+                "bytes": sum(int(a.nbytes) for a in k["ssm"] + v["ssm"]),
+                "row_layout": False,
+            }
+        return out
 
     def moe_stats(self) -> Dict[str, Any]:
         """``stats()["moe"]``: what the expert layers of a mixed
@@ -2053,6 +2079,18 @@ class DecodeEngine:
             "expert_layers": count_kind(self.cfg, "experts"),
             "decode": dict(self.moe_totals["decode"]),
             "prefill": dict(self.moe_totals["prefill"]),
+        }
+
+    def ssm_stats(self) -> Dict[str, Any]:
+        """``stats()["ssm"]``: what the state layers of a mixed
+        configuration advanced and scanned, monotone since construction
+        (``{}`` for a configuration without state layers)."""
+        if not self._state_layers:
+            return {}
+        return {
+            "state_layers": self._state_layers,
+            "decode": dict(self.ssm_totals["decode"]),
+            "prefill": dict(self.ssm_totals["prefill"]),
         }
 
     @property
@@ -3686,10 +3724,10 @@ class DecodeEngine:
             toks = np.asarray(outs[0])
             emits = np.asarray(outs[1])
             if outs[3] is not None:
-                # the expert layers' counts of this fold: four numbers that
-                # were ready with the tokens
+                # the layers' counts of this fold: six numbers that were
+                # ready with the tokens
                 m = np.asarray(outs[3])
-                self._count_moe("decode", m[:3], int(m[3]))
+                self._count_moe("decode", m, int(m[3]))
         if self._inflight is None:
             # nothing was dispatched behind this fold: the device is
             # idle until the host enqueues again
@@ -3698,14 +3736,19 @@ class DecodeEngine:
             return self._fan_out(toks, emits, outs, snapshot, pb_finals)
 
     def _count_moe(self, phase: str, counts: np.ndarray, n: int) -> None:
-        """Add one fold's or one admission's ``[pairs routed, pairs on
-        held experts, held experts hit]`` to the totals; ``n`` is its
-        token steps (decode) or 1 (an admission)."""
+        """Add one fold's or one admission's counts to the totals:
+        ``[pairs routed, pairs on held experts, held experts hit]``, then
+        (decode) ``[live iterations, slot-steps, live slot-steps]`` or
+        (an admission) ``[rows scanned, real rows]``; ``n`` is its token
+        steps (decode) or 1 (an admission)."""
         row = self.moe_totals[phase]
         row["pairs_routed"] += int(counts[0])
         row["pairs_held"] += int(counts[1])
         row["experts_hit"] += int(counts[2])
         row["token_steps" if phase == "decode" else "admissions"] += n
+        if self._state_layers:
+            for key, c in zip(self.ssm_totals[phase], counts[-2:]):
+                self.ssm_totals[phase][key] += int(c)
 
     def _fan_out(
         self,

@@ -617,10 +617,19 @@ class ServeReplica:
                 ("token_steps", "Decode iterations with a live slot (phase=decode) and admissions (phase=prefill)"),
             )
         }
+        self._ssm_counters = {
+            key: self._registry.counter(f"rlt_serve_ssm_{key}_total", help_)
+            for key, help_ in (
+                ("slot_steps", "Slot-steps the decode folds advanced a state layer's state by (every slot, every iteration)"),
+                ("slot_steps_live", "Slot-steps of those that belonged to a live request"),
+                ("rows_scanned", "Rows the admissions' chunked scans ran over (their buckets)"),
+                ("rows_real", "Rows of those that were prompt"),
+            )
+        }
         self._moe_mirrored: Dict[Tuple[str, str], int] = {}
         kv_bytes = self._registry.gauge(
             "rlt_serve_kv_bytes",
-            "Dense KV cache bytes (K and V) by layer kind",
+            "Dense per-request state bytes (K and V; a state layer's states and conv tails) by layer kind",
         )
         for kind, row in self.engine.cache_stats().items():
             kv_bytes.set(float(row["bytes"]), kind=kind)
@@ -1125,6 +1134,12 @@ class ServeReplica:
                 if total != done:
                     self._moe_counters[name].inc(total - done, phase=phase)
                     self._moe_mirrored[(phase, key)] = total
+        for row in self.engine.ssm_totals.values():
+            for key, total in row.items():
+                done = self._moe_mirrored.get(("ssm", key), 0)
+                if total != done:
+                    self._ssm_counters[key].inc(total - done)
+                    self._moe_mirrored[("ssm", key)] = total
 
     def _spans_snapshot(self) -> Dict[str, Any]:
         """``stats()["spans"]``: what the host did, all monotone since
@@ -1197,6 +1212,11 @@ class ServeReplica:
             # experts: pairs routed, pairs on held experts, held experts
             # hit, token steps — monotone totals.
             snap["moe"] = moe
+        ssm = self.engine.ssm_stats()
+        if ssm:
+            # State layers: slot-steps advanced and live, rows scanned and
+            # real — monotone totals.
+            snap["ssm"] = ssm
         if self.kvfleet is not None:
             snap["kvfleet"] = self.kvfleet.stats()
         if self.engine.kvstore is not None:

@@ -74,6 +74,12 @@ def _serve(rep, n_requests=3, max_new_tokens=8):
     return rids
 
 
+def _idle_waits(rep):
+    """Idle waits of the loop thread that have ended (``stats()`` is the
+    ``serve.rpc.stats`` span too)."""
+    return rep.stats()["spans"]["segments"].get("serve.loop.idle", {"n": 0})["n"]
+
+
 def _events(trace_dir, prefix):
     """``{name: [stats dict, ...]}`` of the host plane's annotations
     whose name starts with ``prefix``."""
@@ -276,9 +282,16 @@ def test_serve_spans_reach_the_profiler_trace(params, tmp_path):
         _serve(rep, n_requests=1)  # warm: nothing compiles in the session
         jax.profiler.start_trace(str(tmp_path))
         try:
+            idle0 = _idle_waits(rep)
             rids = _serve(rep)
-            rep.stats()
-            time.sleep(0.25)  # one whole idle wait inside the session
+            # One whole idle wait inside the session: the one in progress
+            # when the session started is not in the trace, so wait for the
+            # second to end. (A fixed 0.25 s was too short for a loop thread
+            # that shares its cores with five other test workers.)
+            deadline = time.monotonic() + 60
+            while _idle_waits(rep) < idle0 + 2:
+                assert time.monotonic() < deadline, "the loop never idled"
+                time.sleep(0.02)
         finally:
             jax.profiler.stop_trace()
     finally:
