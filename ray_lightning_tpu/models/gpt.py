@@ -1429,22 +1429,59 @@ def _kv_head_of_group(n_kv_head: int) -> jax.Array:
     return jnp.eye(n_kv_head, dtype=jnp.float32)
 
 
+def _decode_rows_block(
+    cfg: GPTConfig,
+    q_len: int,
+    k_cache: jax.Array,
+    v_cache: jax.Array,
+    backend: Optional[str] = None,
+) -> int:
+    """Which read a cached attention takes, from what it can observe: the
+    rows of a K / V block of the decode kernel
+    (``ops/decode_attention.py``), or 0 for the XLA read. The kernel wants
+    a stacked cache of rows ``(L, B, S, Hkv * hd)``, one query row a slot,
+    ``attn_impl="flash"``, a TPU (elsewhere it would run interpreted: the
+    engine's token-identity tests compare two XLA reads in one order of
+    sums) and shapes Mosaic takes (``decode_block``: row widths a multiple
+    of 128, a block that divides S). ``serve/engine.py`` asks the same
+    question for its ``stats()["attn"]`` counters."""
+    from ray_lightning_tpu.ops.decode_attention import decode_block
+
+    if (
+        k_cache.ndim != 4
+        or q_len != 1
+        or cfg.attn_impl != "flash"
+        or (backend or jax.default_backend()) != "tpu"
+    ):
+        return 0
+    return decode_block(k_cache.shape[2], k_cache.shape[3], v_cache.shape[3])
+
+
 def _attend_layer_cache(
-    q: jax.Array, kc_l: jax.Array, vc_l: jax.Array, allowed: jax.Array
+    cfg: GPTConfig,
+    q: jax.Array,
+    k_cache: jax.Array,
+    v_cache: jax.Array,
+    li: int,
+    positions: jax.Array,
+    active: Optional[jax.Array] = None,
 ) -> jax.Array:
-    """Attention of Q query rows a slot against one layer's cache, after
-    that layer's write: ``q`` (B, Q, H, hd), ``allowed`` (B, Q, S) bool
-    (the band mask on absolute positions); float32 (B, Q, H, hd). The one
-    statement of the cached-attention math: :func:`gpt_decode_step`
+    """Attention of Q query rows a slot against layer ``li`` of the
+    stacked cache, after that layer's write: ``q`` (B, Q, H, hd),
+    ``positions`` (B, Q) int32, the absolute position each query row
+    stands on (it sees ``0 .. position``, band-limited by
+    ``cfg.attn_window`` / ``cfg.attn_sinks``); float32 (B, Q, H, hd). The
+    one statement of the cached-attention math: :func:`gpt_decode_step`
     (Q = 1) and :func:`gpt_decode_verify` both call it.
 
-    The cache's rank says which read it wants.
+    The cache's rank says which layout it reads, and
+    :func:`_decode_rows_block` which read.
 
-    - ``(B, S, Hkv, hd)``: grouped attention. The q heads fold to
+    - ``(L, B, S, Hkv, hd)``: grouped attention. The q heads fold to
       (Hkv, rep) groups (head h reads KV head h // rep, matching
       :func:`_project_qkv`'s ``jnp.repeat`` layout) and each group
       contracts with its own KV head.
-    - ``(B, S, Hkv * hd)``, a position's KV heads side by side in one
+    - ``(L, B, S, Hkv * hd)``, a position's KV heads side by side in one
       row: every query head is laid out over a whole row with zeros under
       the other KV heads' dims, so ONE matmul a slot against the cache as
       it lies gives all heads' scores — Hkv times the multiplications of
@@ -1455,13 +1492,36 @@ def _attend_layer_cache(
       matmul wants the rows minor: whichever order is stored, the TPU
       compiler copies the layer's cache out of the stacked array every
       step. Rows satisfy both (PERF.md §6, PR 28 and PR 29).
+    - rows, Q = 1, ``attn_impl="flash"``, on a TPU: the same rows read as
+      a Mosaic kernel that walks the live slots and copies only the row
+      blocks up to each slot's position, nothing for a slot that is not
+      ``active`` (``ops/decode_attention.py``; such a slot's output is
+      zeros). The XLA reads multiply against all S allocated rows and mask
+      afterwards; ``active`` does not reach them.
 
-    Either way: q scaled before the product, cache upcast to float32
+    Every read: q scaled before the product, cache upcast to float32
     (exact), float32 scores and softmax, exact ``-inf`` masking, p kept in
-    float32 for p·V.
+    float32 for p·V. The kernel sums the softmax blockwise, so its output
+    is the XLA read's to rounding, not to the bit.
     """
+    from ray_lightning_tpu.ops.attention import band_allowed
+
     B, Q, H, hd = q.shape
+    block = _decode_rows_block(cfg, Q, k_cache, v_cache)
+    if block:
+        from ray_lightning_tpu.ops.decode_attention import decode_attention
+
+        return decode_attention(
+            q[:, 0], k_cache, v_cache, li, positions[:, 0], active,
+            window=cfg.attn_window, sinks=cfg.attn_sinks, block=block,
+        )[:, None]
+    kc_l, vc_l = k_cache[li], v_cache[li]
     S = kc_l.shape[1]
+    # (B, Q, S): the band mask on absolute positions.
+    allowed = band_allowed(
+        positions[:, :, None], jnp.arange(S, dtype=jnp.int32)[None, None],
+        cfg.attn_window, cfg.attn_sinks,
+    )
     rows = kc_l.ndim == 3
     Hkv = kc_l.shape[-1] // hd if rows else kc_l.shape[2]
     rep = H // Hkv
@@ -1502,6 +1562,7 @@ def gpt_decode_step(
     pos: jax.Array,
     k_cache: jax.Array,
     v_cache: jax.Array,
+    active: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """One KV-cached decode step with PER-SLOT positions (slot masks).
 
@@ -1531,6 +1592,14 @@ def gpt_decode_step(
     rebuilds the arrays, so a caller that donates them or carries them
     through a scan (:func:`gpt_decode_fold`) has them updated in place.
 
+    ``active`` (B,) bool, default all, says which slots hold a live
+    request. Only the decode kernel looks at it (a cache of rows under
+    ``attn_impl="flash"`` on a TPU: :func:`_attend_layer_cache`): a slot
+    that is not active has none of its cache rows read and its logits are
+    whatever the rest of the step makes of a zero attention output — the
+    caller discards them, as :func:`gpt_decode_fold` does. Its cache write
+    happens all the same. The XLA reads ignore ``active``.
+
     With mixed layer kinds (``cfg.layer_types``) each cache is a dict,
     one stacked array an attention kind, the window layers' a ring
     (models/mixed.py).
@@ -1539,13 +1608,14 @@ def gpt_decode_step(
     if cfg.mixed:
         from ray_lightning_tpu.models.mixed import mixed_decode_step
 
-        return mixed_decode_step(params, cfg, cur, pos, k_cache, v_cache)[:3]
+        return mixed_decode_step(
+            params, cfg, cur, pos, k_cache, v_cache, active=active
+        )[:3]
     cdt = jnp.dtype(cfg.compute_dtype)
     norm_fn = _make_norm(cfg)
     L, H, hd = cfg.n_layer, cfg.n_head, cfg.head_dim
     Hkv = cfg.kv_head
     B = cur.shape[0]
-    S = k_cache.shape[2]
 
     x = embed_rows(params["wte"], cur)
     if cfg.pos_embed == "learned":
@@ -1593,13 +1663,6 @@ def gpt_decode_step(
             k_new = _rope_slot(k_new)
         return q, k_new, v_new
 
-    from ray_lightning_tpu.ops.attention import band_allowed
-
-    # (B, 1, S): each slot's one query row against absolute positions.
-    allowed = band_allowed(
-        pos[:, None, None], jnp.arange(S)[None, None], cfg.attn_window,
-        cfg.attn_sinks,
-    )
     # A step's K/V rows in the cache's own form: (B, Hkv, hd), or
     # (B, Hkv * hd) for a cache of rows.
     row_shape = (B,) + k_cache.shape[3:]
@@ -1637,7 +1700,7 @@ def gpt_decode_step(
             )
         with jax.named_scope("cache_attention"):
             o = _attend_layer_cache(
-                q[:, None], k_cache[li], v_cache[li], allowed
+                cfg, q[:, None], k_cache, v_cache, li, pos[:, None], active
             )[:, 0].astype(cdt)
             h = h + jnp.einsum(
                 "bhk,hkd->bd", o, dequant(lp["wo"], cdt)
@@ -1892,7 +1955,9 @@ def gpt_decode_fold(
     (Frozen slots still compute — the lanes are batched — and rewrite
     stale cache rows past their frozen position; those rows are invisible
     behind the per-slot position masks and are refreshed by the next
-    tenant's prefill/decode writes before any read.)
+    tenant's prefill/decode writes before any read. ``active`` goes to
+    :func:`gpt_decode_step` with every iteration, so the decode kernel,
+    where it is the read, fetches none of a frozen or idle slot's rows.)
 
     Returns ``(tok_block (fold, B) int32 with -1 at non-emitted lanes,
     emit_block (fold, B) bool, cur, pos, keys, active, remaining,
@@ -1930,7 +1995,7 @@ def gpt_decode_fold(
             ])])
         elif page_table is None:
             logits, k_cache, v_cache = gpt_decode_step(
-                params, cfg, cur, pos, k_cache, v_cache
+                params, cfg, cur, pos, k_cache, v_cache, active
             )
         else:
             logits, k_cache, v_cache = gpt_decode_step_paged(
@@ -2017,8 +2082,6 @@ def gpt_decode_verify(
     the slot masks hide them and the next verify's own writes refresh
     them before any read — the PR 3 masked-gather discipline.
     """
-    from ray_lightning_tpu.ops.attention import band_allowed
-
     cfg.validate_variants()
     refuse_mixed(cfg, "speculative decoding (gpt_decode_verify)")
     cdt = jnp.dtype(cfg.compute_dtype)
@@ -2061,11 +2124,6 @@ def gpt_decode_verify(
     idx = rows[None] - pos[:, None]  # (B, S): row's index into the chunk
     wvalid = (idx >= 0) & (idx < Q)
     gidx = jnp.clip(idx, 0, Q - 1)
-    # (B, Q, S): query row i of a slot sees absolute positions <= pos + i.
-    allowed = band_allowed(
-        positions[:, :, None], rows[None, None], cfg.attn_window,
-        cfg.attn_sinks,
-    )
 
     def layer(h, args):
         lp, kc_l, vc_l = args  # caches (B, S, Hkv, hd) or (B, S, Hkv * hd)
@@ -2109,7 +2167,10 @@ def gpt_decode_verify(
             ),
             vc_l,
         )
-        o = _attend_layer_cache(q, kc_l, vc_l, allowed).astype(cdt)
+        # query row i of a slot sees absolute positions <= pos + i
+        o = _attend_layer_cache(
+            cfg, q, kc_l[None], vc_l[None], 0, positions
+        ).astype(cdt)
         h = h + jnp.einsum(
             "bqhk,hkd->bqd", o, dequant(lp["wo"], cdt)
         ) + lp["bo"].astype(cdt)

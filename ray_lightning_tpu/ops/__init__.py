@@ -6,6 +6,10 @@ The reference has no kernels of its own — its hot loop is torch/NCCL
 - ``attention``: plain-XLA reference attention (ground truth + fallback).
 - ``flash_attention``: Pallas online-softmax attention kernel (TPU MXU
   tiling; interpret mode on CPU for tests).
+- ``decode_attention``: Pallas decode kernel over a cache of rows — one
+  query row a slot, only the row blocks up to each live slot's position
+  (imported from its module by ``models/gpt.py``: the function shares
+  the module's name).
 - ``ring_attention``: sequence-parallel blockwise attention over a mesh
   axis (ICI ``ppermute`` ring) for long-context training.
 - ``zigzag_attention``: load-balanced causal ring attention — zigzag chunk

@@ -1,0 +1,297 @@
+"""Decode attention over a cache of rows: a Pallas (Mosaic) kernel that
+visits only the row blocks that hold a live position.
+
+A decode token step attends ONE query row a slot against that slot's
+cached positions ``0 .. pos``. The cache is allocated for the longest
+request a slot may hold — ``(L, slots, S, Hkv * hd)``, a position's KV
+heads side by side in one row (``models/gpt.py:_attend_layer_cache`` says
+why rows) — and the plain XLA read multiplies against all ``S`` rows of
+every slot and masks afterwards: at 64 slots x 2048 rows with 5.5k live
+positions, 96% of the bytes read belong to no request (PERF.md §5).
+
+The kernel is one program that walks the live slots. The slots' positions
+reach it as prefetched scalars (-1 for a slot that is not live); the cache
+stays in HBM and the kernel copies, block by block, the rows ``0 .. pos``
+of each live slot into two VMEM buffers by turns: block ``i + 1`` — or,
+after a slot's last block, the next live slot's first — is in flight
+while block ``i`` is computed. A block past a slot's position is neither
+fetched nor computed; a slot that is not live costs one scalar compare,
+and its output is zeros. (A grid over (slot, row block) with clamped index
+maps does the same with less code and was measured first: its steps cost
+about 0.25 us each whether or not they fetch, 2.6x this form's time at a
+quarter of the slots live; PERF.md §6, PR 33.)
+
+The stacked cache goes in WHOLE, with the layer in the copies' source: a
+slice ``cache[li]`` handed to a custom call is a copy of the layer a step
+(a custom call fuses with nothing; PERF.md §6, PR 26).
+
+Arithmetic, per element, is the XLA read's: q scaled before the product,
+cache rows upcast to float32 in VMEM (exact), float32 scores, exact
+``-inf`` masking by :func:`band_allowed`, float32 p for p·V, default
+matmul precision. Only the order of the softmax's sums differs (blockwise
+running max / sum / accumulator, as the flash kernel's against
+``attention_reference``). Every query head is laid out over a whole row
+with zeros under the other KV heads' dims, so one matmul a block gives all
+heads' scores, and of p·V's ``(H, Hkv * hd_v)`` each head keeps its own KV
+head's block: every added term is an exact zero, and the read is bound by
+the cache's bytes either way. Widths come from the arguments' shapes, K's
+and V's separately.
+"""
+from __future__ import annotations
+
+import functools
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from ray_lightning_tpu.ops.attention import band_allowed
+
+_NEG_INF = float("-inf")
+
+#: Elements of one K or V block: 256 rows of 1024 (512 KiB in bf16, of
+#: which the kernel holds four, and two float32 copies while it computes).
+_BLOCK_ELEMS = 256 * 1024
+
+
+def decode_block(seq: int, k_width: int, v_width: int) -> int:
+    """Rows of a K / V block for a cache of ``seq`` rows of ``k_width`` /
+    ``v_width`` elements, or 0 where the kernel takes no such cache: a row
+    width that is not a multiple of the 128 lanes, rows so wide that 128
+    of them outgrow a block, no candidate dividing ``seq``.
+
+    256 where the rows are 1024 wide or less, from the chip (PERF.md §6,
+    PR 33; us a layer at 64 slots x 2048 x 1024, a quarter / half / all of
+    the slots live): 128 rows 46 / 228 / 741, **256: 49 / 232 / 718**,
+    512: 58 / 248 / 718, 1024: 98 / 280 / 719 — a larger block reads more
+    dead rows behind a short request, a smaller one issues more copies for
+    a long one."""
+    width = max(k_width, v_width)
+    if k_width % 128 or v_width % 128:
+        return 0
+    for block in (256, 128):
+        if seq % block == 0 and block * width <= _BLOCK_ELEMS:
+            return block
+    return 0
+
+
+def _last_block(pos, block: int, seq: int):
+    """The block that holds position ``pos`` (clamped into the cache): the
+    last one a live slot's walk fetches and computes. Its own function so
+    that a test can plant the fault."""
+    return jnp.minimum(jnp.maximum(pos, 0), seq - 1) // block
+
+
+def _kernel(
+    pos_ref, next_ref,  # prefetched scalars: (B,) and (B + 1,) int32
+    q_ref, k_hbm, v_hbm, o_ref,
+    k_buf, v_buf, sem,
+    *, layer: int, block: int, rep: int, window: int, sinks: int,
+):
+    """``q_ref`` (B, H, hd), every slot's query heads; ``k_hbm`` / ``v_hbm``
+    the stacked caches where they lie; ``o_ref`` (B, H, hd_v) float32;
+    ``k_buf`` / ``v_buf`` (2, block, width) with one copy semaphore each a
+    buffer. ``pos_ref[b]`` is -1 for a slot that is not live;
+    ``next_ref[b]`` is the first live slot at or after ``b``, -1 for none
+    (``next_ref[B]`` is -1)."""
+    B, H, hd = q_ref.shape
+    hd_v = o_ref.shape[2]
+    seq = k_hbm.shape[2]
+    k_width, v_width = k_buf.shape[2], v_buf.shape[2]
+    n_kv = k_width // hd
+
+    def own(width: int, d: int):
+        # (H, width) bool: the dims of head h's own KV head, h // rep
+        shape = (H, width)
+        head = jax.lax.div(
+            jax.lax.broadcasted_iota(jnp.int32, shape, 0), jnp.int32(rep)
+        )
+        col = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+        return (col >= head * d) & (col < (head + 1) * d)
+
+    own_k, own_v = own(k_width, hd), own(v_width, hd_v)
+
+    def copies(b, i, buf):
+        rows = pl.ds(i * block, block)
+        return (
+            pltpu.make_async_copy(
+                k_hbm.at[layer, b, rows], k_buf.at[buf], sem.at[0, buf]
+            ),
+            pltpu.make_async_copy(
+                v_hbm.at[layer, b, rows], v_buf.at[buf], sem.at[1, buf]
+            ),
+        )
+
+    def fetch(b, i, buf):
+        for c in copies(b, i, buf):
+            c.start()
+
+    o_ref[...] = jnp.zeros(o_ref.shape, jnp.float32)
+
+    @pl.when(next_ref[0] >= 0)
+    def _():
+        fetch(next_ref[0], 0, 0)
+
+    def slot(b, done):
+        # ``done``: blocks computed so far, whose parity names the buffer
+        # this slot's first block was fetched into.
+        pos = pos_ref[b]
+        n = jnp.where(pos >= 0, _last_block(pos, block, seq) + 1, 0)
+
+        @pl.when(pos >= 0)
+        def _():
+            q = q_ref[b].astype(jnp.float32) * (1.0 / (hd ** 0.5))
+            q_rows = jnp.where(own_k, jnp.concatenate([q] * n_kv, axis=1), 0.0)
+            after = next_ref[b + 1]
+
+            def step(i, carry):
+                m_prev, l_prev, acc_prev = carry
+                buf = jax.lax.rem(done + i, 2)
+
+                @pl.when(i + 1 < n)
+                def _():
+                    fetch(b, i + 1, 1 - buf)
+
+                @pl.when((i + 1 == n) & (after >= 0))
+                def _():
+                    fetch(after, 0, 1 - buf)
+
+                k_copy, v_copy = copies(b, i, buf)
+                k_copy.wait()
+                s = jax.lax.dot_general(
+                    q_rows, k_buf[buf].astype(jnp.float32),
+                    (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )  # (H, block)
+                col = i * block + jax.lax.broadcasted_iota(
+                    jnp.int32, s.shape, 1
+                )
+                s = jnp.where(
+                    band_allowed(pos, col, window, sinks), s, _NEG_INF
+                )
+                m_new = jnp.maximum(
+                    m_prev, jnp.max(s, axis=-1, keepdims=True)
+                )
+                # -inf - -inf = nan: with a window a visited block can lie
+                # wholly before the band (the flash kernel's guard).
+                alpha = jnp.where(
+                    m_prev == _NEG_INF, 0.0, jnp.exp(m_prev - m_new)
+                )
+                p = jnp.where(s == _NEG_INF, 0.0, jnp.exp(s - m_new))
+                l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+                v_copy.wait()
+                acc_new = acc_prev * alpha + jax.lax.dot_general(
+                    p, v_buf[buf].astype(jnp.float32),
+                    (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )
+                return m_new, l_new, acc_new
+
+            _, l, acc = jax.lax.fori_loop(0, n, step, (
+                jnp.full((H, 1), _NEG_INF, jnp.float32),
+                jnp.zeros((H, 1), jnp.float32),
+                jnp.zeros((H, v_width), jnp.float32),
+            ))
+            o = jnp.where(own_v, acc, 0.0) / l
+            out = o[:, :hd_v]
+            for g in range(1, v_width // hd_v):
+                out = out + o[:, g * hd_v:(g + 1) * hd_v]
+            o_ref[b] = out
+
+        return done + n
+
+    jax.lax.fori_loop(0, B, slot, jnp.int32(0))
+
+
+def decode_attention(
+    q: jax.Array,
+    k_cache: jax.Array,
+    v_cache: jax.Array,
+    layer: int,
+    pos: jax.Array,
+    live: Optional[jax.Array] = None,
+    *,
+    window: int = 0,
+    sinks: int = 0,
+    block: Optional[int] = None,
+    interpret: Optional[bool] = None,
+) -> jax.Array:
+    """Attention of one query row a slot against layer ``layer`` of a
+    stacked cache of rows, after that layer's write.
+
+    ``q`` (B, H, hd); ``k_cache`` (L, B, S, Hkv * hd) and ``v_cache``
+    (L, B, S, Hkv * hd_v), whole; ``pos`` (B,) int32, the position each
+    slot's query stands on (it sees ``0 .. pos``, band-limited by
+    ``window`` / ``sinks``); ``live`` (B,) bool, default all. Returns
+    float32 (B, H, hd_v); zeros for a slot that is not live.
+
+    ``block`` left at ``None`` follows the shapes (:func:`decode_block`);
+    ``interpret=None`` compiles on a TPU and interprets elsewhere. A
+    window shorter than the cache is masked, not skipped: blocks before
+    the band are still visited.
+    """
+    B, H, hd = q.shape
+    _, _, S, k_width = k_cache.shape
+    v_width = v_cache.shape[3]
+    n_kv = k_width // hd
+    hd_v = v_width // n_kv
+    if block is None:
+        block = decode_block(S, k_width, v_width)
+    if not block or S % block:
+        raise ValueError(
+            f"decode_attention: no row block for a cache of {S} rows of "
+            f"{k_width} / {v_width} (block {block})"
+        )
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    if live is None:
+        live = jnp.ones((B,), jnp.bool_)
+    pos_live = jnp.where(live, pos.astype(jnp.int32), -1)
+    # the first live slot at or after b, -1 for none; entry B closes it
+    slots = jnp.arange(B, dtype=jnp.int32)
+    at_or_after = jax.lax.cummin(jnp.where(live, slots, B), reverse=True)
+    next_live = jnp.concatenate([
+        jnp.where(at_or_after < B, at_or_after, -1),
+        jnp.full((1,), -1, jnp.int32),
+    ])
+    itemsize = max(k_cache.dtype.itemsize, v_cache.dtype.itemsize)
+    # two buffers each of K and V, their float32 copies, q and the output
+    # (twice: the pipeline's own two buffers), the accumulator and tiles
+    vmem = (
+        (2 * itemsize + 4) * block * (k_width + v_width)
+        + 2 * B * H * (hd * q.dtype.itemsize + hd_v * 4)
+        + 8 * H * max(k_width, v_width, block) * 4
+    )
+
+    def whole(shape):
+        return pl.BlockSpec(shape, lambda i, *_: (0,) * len(shape))
+
+    return pl.pallas_call(
+        functools.partial(
+            _kernel, layer=layer, block=block, rep=H // n_kv,
+            window=int(window), sinks=int(sinks),
+        ),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(1,),
+            in_specs=[
+                whole((B, H, hd)),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=whole((B, H, hd_v)),
+            scratch_shapes=[
+                pltpu.VMEM((2, block, k_width), k_cache.dtype),
+                pltpu.VMEM((2, block, v_width), v_cache.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((B, H, hd_v), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=max(16 * 2**20, min(2 * vmem, 100 * 2**20)),
+        ),
+        interpret=interpret,
+        name="decode_attention",
+    )(pos_live, next_live, q, k_cache, v_cache)

@@ -49,10 +49,12 @@ cache where it lies; with the KV heads on an axis of their own the
 scatter wants (slot, row, head, d), the matmul wants the rows minor, and
 the TPU compiler copies the layer (268 MB at 64 slots x 2048) out of the
 stack before every attention read (PERF.md §6, PR 28 and PR 29). The step
-decides from the rank of the cache it is given; every other program of
-this engine (admission, chunked and piggybacked prefill, the prefix
-pool's copy) converts the prompt's rows, one slot's strip or one block
-at its boundary (``cache_strip`` / ``cache_strip_put``), never the
+decides from the rank of the cache it is given, and on a TPU reads rows
+through the decode kernel, which visits only the blocks up to each live
+slot's position (``ops/decode_attention.py``; ``stats()["attn"]``). Every
+other program of this engine (admission, chunked and piggybacked prefill,
+the prefix pool's copy) converts the prompt's rows, one slot's strip or
+one block at its boundary (``cache_strip`` / ``cache_strip_put``), never the
 cache. Under a mesh the cache keeps ``(L, slots, max_seq, Hkv, hd)`` —
 the head axis is what shards — and a paged engine has no dense cache;
 pool blocks, spilled and exported blocks are ``(L, 1, block, Hkv, hd)``
@@ -204,6 +206,9 @@ class SlotInfo:
     max_new_tokens: int
     n_generated: int
     eos_token: int  # -1 = disabled
+    #: Prompt positions in the slot's cache: with ``n_generated`` the
+    #: position a decode step stands on (``stats()["attn"]``).
+    prompt_len: int = 0
     #: Host-side eviction marker: tokens an in-flight fold produced for a
     #: released tenant are dropped at harvest (the device keeps decoding a
     #: cancelled slot until its deactivate write lands).
@@ -841,6 +846,24 @@ class DecodeEngine:
             from ray_lightning_tpu.models.mixed import count_kind
 
             self._state_layers = count_kind(config, "ssm")
+        #: What the decode steps' cached attention read, in cache rows
+        #: summed over token steps and layers: the rows allocated to the
+        #: slots, those the read visited and those of live requests'
+        #: positions. Counted on the host at each harvest from the
+        #: positions the slots' records hold (no fetch, no sync).
+        self.attn_totals: Dict[str, int] = {
+            "rows_allocated": 0, "rows_visited": 0, "rows_live": 0,
+        }
+        #: Rows of the decode kernel's block when the fold's read is the
+        #: kernel (models/gpt.py:_decode_rows_block), else 0: the XLA read
+        #: visits every allocated row.
+        self._attn_block = 0
+        if self._k is not None and not config.mixed and self.spec == "off":
+            from ray_lightning_tpu.models.gpt import _decode_rows_block
+
+            self._attn_block = _decode_rows_block(
+                config, 1, self._k, self._v
+            )
         from ray_lightning_tpu.obs.registry import get_registry as _greg
 
         _reg = _greg()
@@ -2081,6 +2104,17 @@ class DecodeEngine:
             "prefill": dict(self.moe_totals["prefill"]),
         }
 
+    def attn_stats(self) -> Dict[str, int]:
+        """``stats()["attn"]``: cache rows the decode steps' attention
+        had allocated, visited and live, summed over token steps and
+        layers, monotone since construction. ``rows_visited`` equals
+        ``rows_allocated`` on the XLA read (every row of every slot is
+        multiplied and masked afterwards); under the decode kernel
+        (``ops/decode_attention.py``) it is the blocks up to each live
+        slot's position. ``{}`` for mixed layer kinds, whose caches differ
+        by kind (models/mixed.py reads every allocated row)."""
+        return {} if self.cfg.mixed else dict(self.attn_totals)
+
     def ssm_stats(self) -> Dict[str, Any]:
         """``stats()["ssm"]``: what the state layers of a mixed
         configuration advanced and scanned, monotone since construction
@@ -2400,6 +2434,7 @@ class DecodeEngine:
                     max_new_tokens=n_new,
                     n_generated=1,
                     eos_token=eos,
+                    prompt_len=int(np.size(r["prompt"])),
                 )
             out.append((slot, tok, done))
         return out
@@ -2534,6 +2569,7 @@ class DecodeEngine:
                         max_new_tokens=task.max_new_tokens,
                         n_generated=1,
                         eos_token=task.eos_token,
+                        prompt_len=len(task.tokens),
                     )
                 out.append((slot, task, tok, done))
             if not progressed:
@@ -3542,6 +3578,7 @@ class DecodeEngine:
                     max_new_tokens=task.max_new_tokens,
                     n_generated=1,
                     eos_token=task.eos_token,
+                    prompt_len=len(task.tokens),
                 )
                 self._slots[slot] = info
                 finals.append((r, slot, task, info))
@@ -3773,11 +3810,19 @@ class DecodeEngine:
         #: the accept-rate accounting (zombie tokens of released tenants
         #: are dropped above AND excluded here).
         counts: Dict[Tuple[int, int], int] = {}
+        blk, rows_live, rows_visited = self._attn_block, 0, 0
         for kk in range(toks.shape[0]):
             for slot, info in enumerate(snapshot):
                 if info is None or info.released or not emits[kk, slot]:
                     continue
                 tok = int(toks[kk, slot])
+                if kk % group == 0:
+                    # the rows 0 .. pos this token step's query saw, and
+                    # the kernel's blocks that hold them
+                    rows = info.prompt_len + info.n_generated
+                    rows_live += rows
+                    if blk:
+                        rows_visited += -(-rows // blk) * blk
                 info.n_generated += 1
                 done = (
                     info.n_generated >= info.max_new_tokens
@@ -3789,6 +3834,17 @@ class DecodeEngine:
                     counts[key] = counts.get(key, 0) + 1
                 if done:
                     self._release_synced(slot, info)
+        if not self.cfg.mixed:
+            layers = self.cfg.n_layer
+            allocated = (
+                (toks.shape[0] // group) * layers * self.num_slots
+                * self.max_seq
+            )
+            self.attn_totals["rows_allocated"] += allocated
+            self.attn_totals["rows_visited"] += (
+                layers * rows_visited if blk else allocated
+            )
+            self.attn_totals["rows_live"] += layers * rows_live
         if counts:
             # Per (verify, slot): depth tokens proposed, emitted - 1 of
             # them accepted (the final emission is the verify's own

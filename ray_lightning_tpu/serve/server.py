@@ -626,6 +626,14 @@ class ServeReplica:
                 ("rows_real", "Rows of those that were prompt"),
             )
         }
+        self._attn_counters = {
+            key: self._registry.counter(f"rlt_serve_attn_{key}_total", help_)
+            for key, help_ in (
+                ("rows_allocated", "Cache rows allocated to the slots, summed over decode token steps and layers"),
+                ("rows_visited", "Cache rows of those the decode attention read (all of them on the XLA read; the decode kernel's blocks up to each live slot's position)"),
+                ("rows_live", "Cache rows of those that held a position of a live request"),
+            )
+        }
         self._moe_mirrored: Dict[Tuple[str, str], int] = {}
         kv_bytes = self._registry.gauge(
             "rlt_serve_kv_bytes",
@@ -1134,12 +1142,16 @@ class ServeReplica:
                 if total != done:
                     self._moe_counters[name].inc(total - done, phase=phase)
                     self._moe_mirrored[(phase, key)] = total
-        for row in self.engine.ssm_totals.values():
+        for name, counters, row in (
+            ("ssm", self._ssm_counters, self.engine.ssm_totals["decode"]),
+            ("ssm", self._ssm_counters, self.engine.ssm_totals["prefill"]),
+            ("attn", self._attn_counters, self.engine.attn_totals),
+        ):
             for key, total in row.items():
-                done = self._moe_mirrored.get(("ssm", key), 0)
+                done = self._moe_mirrored.get((name, key), 0)
                 if total != done:
-                    self._ssm_counters[key].inc(total - done)
-                    self._moe_mirrored[("ssm", key)] = total
+                    counters[key].inc(total - done)
+                    self._moe_mirrored[(name, key)] = total
 
     def _spans_snapshot(self) -> Dict[str, Any]:
         """``stats()["spans"]``: what the host did, all monotone since
@@ -1217,6 +1229,11 @@ class ServeReplica:
             # State layers: slot-steps advanced and live, rows scanned and
             # real — monotone totals.
             snap["ssm"] = ssm
+        attn = self.engine.attn_stats()
+        if attn:
+            # Cache rows the decode attention had allocated, visited and
+            # live, over token steps and layers — monotone totals.
+            snap["attn"] = attn
         if self.kvfleet is not None:
             snap["kvfleet"] = self.kvfleet.stats()
         if self.engine.kvstore is not None:

@@ -1,0 +1,187 @@
+"""The decode kernel (``ops/decode_attention.py``) in interpret mode against
+the XLA rows read of ``models/gpt.py:_attend_layer_cache``: the same result
+to rounding over the dense families' head layouts, at the positions where a
+block count can be off by one, and — blocks past a slot's position filled
+with NaN — the proof that the kernel reads no row that holds no live
+position. Mosaic's own view of the kernel is ``tests/test_decode_rows_v5e.py``.
+"""
+import numpy as np
+import pytest
+
+from ray_lightning_tpu.models.gpt import GPTConfig
+
+BLOCK, S, L = 128, 384, 2  # three blocks a slot: 512 and 256 do not divide 384
+
+#: name -> config (only heads, widths, window and sinks reach the read)
+VARIANTS = {
+    "gpt2_mha_hd64": GPTConfig(vocab_size=97, n_layer=L, n_head=4, d_model=256, max_seq=S),
+    "llama_gqa_rope_hd128_8of32": GPTConfig.llama(
+        vocab_size=97, n_layer=L, n_head=32, n_kv_head=8, d_model=4096, max_seq=S),
+    "gqa_window_sinks": GPTConfig.llama(
+        vocab_size=97, n_layer=L, n_head=8, n_kv_head=2, d_model=1024, max_seq=S,
+        attn_window=150, attn_sinks=4),
+}
+
+#: where a block count is off by one first: row 0, a block's last row, the
+#: next block's first row, the cache's last row; and somewhere inside
+POSITIONS = {
+    "row0": 0, "block_last_row": BLOCK - 1, "block_first_row": BLOCK,
+    "cache_last_row": S - 1, "inside": 2 * BLOCK + 37,
+}
+
+
+def _inputs(cfg, pos, seed=0):
+    import jax
+    import jax.numpy as jnp
+
+    B = len(pos)
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    width = cfg.kv_head * cfg.head_dim
+    q = jax.random.normal(ks[0], (B, 1, cfg.n_head, cfg.head_dim), jnp.float32)
+    kc = jax.random.normal(ks[1], (L, B, S, width), jnp.float32)
+    vc = jax.random.normal(ks[2], (L, B, S, width), jnp.float32)
+    return q, kc, vc, jnp.asarray(pos, jnp.int32)[:, None]
+
+
+def _read(monkeypatch, kernel: bool):
+    """``_attend_layer_cache`` with the decode kernel in (its selection told
+    "tpu"; the kernel then interprets, as it does off the chip) or out."""
+    from ray_lightning_tpu.models import gpt as G
+    from tests.utils import force_decode_kernel
+
+    if kernel:
+        force_decode_kernel(monkeypatch)
+    return G._attend_layer_cache
+
+
+@pytest.mark.parametrize("where", sorted(POSITIONS))
+@pytest.mark.parametrize("variant", sorted(VARIANTS))
+def test_kernel_equals_the_xla_rows_read(variant, where, monkeypatch):
+    """Slot 0 at the named position, slot 1 elsewhere, slot 2 NOT live
+    (zeros from the kernel, whatever from the XLA read: nobody reads it),
+    slot 3 behind an idle one."""
+    import jax.numpy as jnp
+
+    from ray_lightning_tpu.models import gpt as G
+
+    cfg = VARIANTS[variant]
+    q, kc, vc, positions = _inputs(cfg, [POSITIONS[where], 200, 300, 5])
+    live = jnp.asarray([True, True, False, True])
+    want = G._attend_layer_cache(cfg, q, kc, vc, 1, positions)
+    assert G._decode_rows_block(cfg, 1, kc, vc) == 0, "off the TPU the engine keeps the XLA read"
+    got = _read(monkeypatch, kernel=True)(cfg, q, kc, vc, 1, positions, live)
+    assert G._decode_rows_block(cfg, 1, kc, vc) == BLOCK
+    assert got.shape == want.shape and got.dtype == want.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(got)[[0, 1, 3]], np.asarray(want)[[0, 1, 3]], atol=1e-5, rtol=0)
+    assert not np.asarray(got)[2].any()
+
+
+def _poisoned(kc, vc, positions, live):
+    """Every block that lies wholly past a slot's position, and every row of
+    a slot that is not live, set to NaN."""
+    import jax.numpy as jnp
+
+    rows = jnp.arange(S)[None, :]
+    first_dead = jnp.where(live[:, None], (positions // BLOCK + 1) * BLOCK, 0)
+    dead = (rows >= first_dead)[None, :, :, None]
+    return jnp.where(dead, jnp.nan, kc), jnp.where(dead, jnp.nan, vc)
+
+
+@pytest.mark.parametrize("variant", sorted(VARIANTS))
+def test_blocks_past_a_slots_position_are_not_read(variant, monkeypatch):
+    """p·V over a masked row is 0 x NaN = NaN: the XLA read, which multiplies
+    every allocated row, returns NaN here; the kernel returns the clean
+    cache's result."""
+    import jax.numpy as jnp
+
+    cfg = VARIANTS[variant]
+    q, kc, vc, positions = _inputs(cfg, [0, BLOCK - 1, 300, BLOCK, 2 * BLOCK - 1])
+    live = jnp.asarray([True, True, False, True, True])
+    pk, pv = _poisoned(kc, vc, positions, live)
+    assert bool(jnp.isnan(pk[:, 0, BLOCK:]).all()) and not bool(jnp.isnan(pk[:, 0, :BLOCK]).any())
+    xla = _read(monkeypatch, kernel=False)
+    assert np.isnan(np.asarray(xla(cfg, q, pk, pv, 0, positions))).any()
+    want = xla(cfg, q, kc, vc, 0, positions)
+    got = _read(monkeypatch, kernel=True)(cfg, q, pk, pv, 0, positions, live)
+    assert np.isfinite(np.asarray(got)).all()
+    keep = np.asarray(live)
+    np.testing.assert_allclose(np.asarray(got)[keep], np.asarray(want)[keep], atol=1e-5, rtol=0)
+
+
+@pytest.mark.parametrize("off", [-1, 1], ids=["one_block_short", "one_block_far"])
+def test_a_block_count_off_by_one_fails(off, monkeypatch):
+    """The planted fault. One block short, the slot's newest rows go unread
+    and the result misses; one block far, a dead block is fetched and its
+    NaN reaches the output."""
+    import jax.numpy as jnp
+
+    from ray_lightning_tpu.ops import decode_attention as D
+
+    real = D._last_block
+    monkeypatch.setattr(
+        D, "_last_block", lambda pos, block, seq: jnp.clip(real(pos, block, seq) + off, 0, seq // block - 1))
+    cfg = VARIANTS["llama_gqa_rope_hd128_8of32"]
+    q, kc, vc, positions = _inputs(cfg, [BLOCK + 3, BLOCK + 90])
+    live = jnp.asarray([True, True])
+    pk, pv = _poisoned(kc, vc, positions, live)
+    want = _read(monkeypatch, kernel=False)(cfg, q, kc, vc, 0, positions)
+    got = np.asarray(_read(monkeypatch, kernel=True)(cfg, q, pk, pv, 0, positions, live))
+    if off > 0:
+        assert np.isnan(got).any()
+    else:
+        assert np.isfinite(got).all() and np.abs(got - np.asarray(want)).max() > 1e-2
+
+
+@pytest.mark.parametrize("case,kw", [
+    ("head_axis_cache", {}), ("verify_q3", {}), ("attn_impl_reference", {"attn_impl": "reference"}),
+    ("row_width_96", {"n_head": 4, "n_kv_head": 2, "d_model": 192}), ("rows_100", {}),
+])
+def test_everything_else_keeps_the_xla_read(case, kw):
+    """What the selection observes, one condition a case, each told "tpu"."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_lightning_tpu.models import gpt as G
+
+    cfg = GPTConfig.llama(**{**dict(vocab_size=97, n_layer=L, n_head=8, n_kv_head=2, d_model=1024, max_seq=S), **kw})
+    rows = 100 if case == "rows_100" else S
+    shape = (L, 2, rows, cfg.kv_head * cfg.head_dim)
+    if case == "head_axis_cache":
+        shape = (L, 2, rows, cfg.kv_head, cfg.head_dim)
+    cache = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    q_len = 3 if case == "verify_q3" else 1
+    assert G._decode_rows_block(cfg, q_len, cache, cache, backend="tpu") == 0
+    assert G._decode_rows_block(cfg, q_len, cache, cache) == 0
+
+
+@pytest.mark.parametrize("variant", sorted(VARIANTS))
+def test_decode_step_with_the_kernel_gives_the_xla_steps_logits(variant, monkeypatch):
+    """The whole token step (projections, rotary, the cache write, the read,
+    the MLP) on a cache of rows: logits of the live slots and both caches."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_lightning_tpu.models import gpt as G
+    from tests.utils import force_decode_kernel
+
+    # the variant's kind of heads at a width the CPU builds in a second
+    cfg = VARIANTS[variant]
+    heads = dict(n_head=4, d_model=256) if cfg.kv_head == cfg.n_head else dict(
+        n_head=8, n_kv_head=2, d_model=512)
+    cfg = GPTConfig(**{**cfg.__dict__, **heads, "compute_dtype": "float32"})
+    assert cfg.kv_head * cfg.head_dim % 128 == 0
+    params = G.init_gpt_params(jax.random.PRNGKey(1), cfg)
+    B, width = 3, cfg.kv_head * cfg.head_dim
+    ks = jax.random.split(jax.random.PRNGKey(2), 2)
+    kc = 0.3 * jax.random.normal(ks[0], (L, B, S, width), jnp.float32)
+    vc = 0.3 * jax.random.normal(ks[1], (L, B, S, width), jnp.float32)
+    cur = jnp.asarray([5, 17, 44], jnp.int32)
+    pos = jnp.asarray([BLOCK, 2 * BLOCK - 1, 9], jnp.int32)
+    active = jnp.asarray([True, True, False])
+    want = G.gpt_decode_step(params, cfg, cur, pos, kc, vc, active)
+    force_decode_kernel(monkeypatch)
+    got = G.gpt_decode_step(params, cfg, cur, pos, kc, vc, active)
+    np.testing.assert_allclose(np.asarray(got[0])[:2], np.asarray(want[0])[:2], atol=2e-4, rtol=0)
+    for a, b in zip(got[1:], want[1:]):
+        # layer 0's write is the same; layer 1's differs by the read's rounding, and only in the live slots' rows
+        np.testing.assert_allclose(np.asarray(a)[:, :2], np.asarray(b)[:, :2], atol=2e-4, rtol=0)

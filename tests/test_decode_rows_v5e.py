@@ -11,7 +11,9 @@ nothing about that.
 — ``(L, slots, max_seq, Hkv * hd)``, a position's KV heads side by side in
 one row. With the KV heads on an axis of their own the chip's compiler copied a layer of the cache (268 MB) out of the stacked
 array before every attention read: temporaries of 2.38 GiB in a program of
-10.12 GiB (PERF.md §4). This is the guard that the copy does not come back.
+10.12 GiB (PERF.md §4). This is the guard that the copy does not come back — under the XLA read and
+under the decode kernel (``ops/decode_attention.py``), which takes the stacked
+cache whole for the same reason.
 
 Built as ``tests/perfbench/test_compile_v5e.py`` builds its programs, from
 the cell's own files; the topology is described in a fixture, never at
@@ -51,7 +53,9 @@ def v5e():
     cc.reset_cache()
 
 
-def test_mistral_decode_fold_on_a_cache_of_rows_copies_no_layer(v5e, monkeypatch):
+def _compile_chat_fold(v5e, monkeypatch):
+    """The chat cell's decode fold, from the cell's own files, compiled for
+    the described chip: (compiled, slots, rows, layers, fold)."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import SingleDeviceSharding
@@ -79,21 +83,71 @@ def test_mistral_decode_fold_on_a_cache_of_rows_copies_no_layer(v5e, monkeypatch
     assert (B, S) == (64, 2048), "the sizes below are this cell's"
     cache = sds((dims["layers"], B, S, dims["kv_heads"] * dims["head_dim"]), jnp.bfloat16)
     i32, f32 = (lambda: sds((B,), jnp.int32)), (lambda: sds((B,), jnp.float32))
+    fold = int(rep["decode_fold"])
 
     def step(params, k_cache, v_cache, cur, pos, temps, top_ks, top_ps, keys, active, remaining, eos):
         return gpt_decode_fold(params, pc, cur, pos, keys, temps, top_ks, top_ps, active, remaining, eos,
-                               k_cache, v_cache, fold=int(rep["decode_fold"]))
+                               k_cache, v_cache, fold=fold)
 
     # donated as serve/engine.py donates them: caches and the state the fold moves
-    m = jax.jit(step, donate_argnums=(1, 2, 3, 4, 8, 9, 10)).lower(
+    compiled = jax.jit(step, donate_argnums=(1, 2, 3, 4, 8, 9, 10)).lower(
         params, cache, cache, i32(), i32(), f32(), i32(), f32(), sds((B, 2), jnp.uint32),
         sds((B,), jnp.bool_), i32(), i32(),
-    ).compile().memory_analysis()
+    ).compile()
+    return compiled, B, S, dims["layers"], fold
+
+
+@pytest.mark.parametrize("read", ["xla", "kernel"])
+def test_mistral_decode_fold_on_a_cache_of_rows_copies_no_layer(v5e, monkeypatch, read):
+    """``xla``: the read the engine takes off the TPU (``jax.default_backend()``
+    is the CPU here). ``kernel``: the read it takes on the chip — the test says
+    "tpu" where the program asks, so the decode kernel is in and Mosaic compiles
+    it: one custom call a layer of the fold's one traced token step, the stacked
+    cache handed to it whole (a copy of a layer, or of the cache, would show in
+    the temporaries)."""
+    import jax
+
+    if read == "kernel":
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled, B, S, layers, fold = _compile_chat_fold(v5e, monkeypatch)
+    m = compiled.memory_analysis()
     whole = m.argument_size_in_bytes + m.output_size_in_bytes - m.alias_size_in_bytes + m.temp_size_in_bytes
-    print(f"decode fold on rows at {B} x {S}: temporaries {m.temp_size_in_bytes / GIB:.3f} GiB, "
+    print(f"decode fold on rows at {B} x {S}, {read} read: temporaries {m.temp_size_in_bytes / GIB:.3f} GiB, "
           f"whole program {whole / GIB:.2f} GiB")
-    assert m.temp_size_in_bytes < 2.3 * GIB  # 2.138 read; 2.383 with the KV heads on an axis of their own
+    assert m.temp_size_in_bytes < 2.3 * GIB  # 2.138 read (xla); 2.383 with the KV heads on an axis of their own
     assert whole < 10.0 * GIB  # 9.88 read; 10.12
+    mosaic = [ln for ln in compiled.as_text().splitlines() if 'custom_call_target="tpu_custom_call"' in ln]
+    # the fold is a scan: its body, one token step, is in the program once
+    assert len(mosaic) == (layers if read == "kernel" else 0), mosaic
+    assert all("decode_attention" in ln.split(" = ")[0] for ln in mosaic), mosaic
+
+
+@pytest.mark.parametrize(
+    "shape",
+    [(8, 64, 2048, 32, 8, 128), (24, 16, 1024, 16, 16, 64)],
+    ids=["chat_8x64x2048_32q8kvx128", "gpt2_medium_24x16x1024_16x64"],
+)
+def test_decode_kernel_lowers_at_the_rows_of_both_dense_families(v5e, shape):
+    """The kernel alone, with the block its shapes choose: Llama's grouped
+    rows (lane-aligned heads of 128) and GPT-2's (heads of 64, two to a lane
+    tile: the query layout is concatenated and the output's own blocks are
+    sliced across the tile)."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from ray_lightning_tpu.ops.decode_attention import decode_attention
+
+    L, B, S, H, Hkv, hd = shape
+    one = SingleDeviceSharding(v5e)
+    cache = jax.ShapeDtypeStruct((L, B, S, Hkv * hd), jnp.bfloat16, sharding=one)
+    text = jax.jit(
+        lambda q, k, v, pos, live: decode_attention(q, k, v, L - 1, pos, live, interpret=False)
+    ).lower(
+        jax.ShapeDtypeStruct((B, H, hd), jnp.bfloat16, sharding=one), cache, cache,
+        jax.ShapeDtypeStruct((B,), jnp.int32, sharding=one), jax.ShapeDtypeStruct((B,), jnp.bool_, sharding=one),
+    ).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
 
 
 @pytest.mark.parametrize(

@@ -304,7 +304,7 @@ def _drive_mixed_join(eng, rng):
     return reqs, outs
 
 
-def _drive_budget_freeze(eng, rng):
+def _drive_budget_freeze(eng, rng, max_seq=64):
     """Slots at three depths (``budget_freeze_requests``). The deepest
     runs out of its token budget inside a fold and stays frozen under its
     batchmates for the folds that follow, rewriting the stale row at its
@@ -313,7 +313,7 @@ def _drive_budget_freeze(eng, rng):
     and the last request decodes up to the cache's last row."""
     from tests.utils import budget_freeze_requests
 
-    reqs, late = budget_freeze_requests(rng)
+    reqs, late = budget_freeze_requests(rng, max_seq)
     outs, slot_of = {}, {}
     for i, (p, n) in enumerate(reqs):
         slot_of[f"r{i}"], tok, done = eng.admit(
@@ -322,7 +322,7 @@ def _drive_budget_freeze(eng, rng):
         outs[f"r{i}"] = [tok]
         assert not done
     frozen_folds = 0
-    for _ in range(200):
+    for _ in range(200 + max_seq):
         if not eng.num_active:
             break
         for _, rid, tok, _ in eng.step():
@@ -374,6 +374,47 @@ def test_engine_folded_matches_sequential_generate(
     for i, (p, n) in enumerate(reqs):
         assert p + outs[f"r{i}"] == _reference(serve_params, p, n), f"r{i}"
     assert eng.compiled_count == compiles
+
+
+@pytest.mark.parametrize("fold", [1, 4])
+def test_engine_with_the_decode_kernel_serves_the_xla_reads_tokens(fold, monkeypatch):
+    """The decode kernel (ops/decode_attention.py) under the engine, in
+    interpret mode: the one selection function is told "tpu", nothing else.
+    ``budget_freeze`` traffic on a cache of three blocks a slot — a frozen
+    slot under live ones, a slot re-let over a longer tenant's stale rows, a
+    request that decodes to the cache's last row — gives the tokens of the
+    same engine on the XLA read, with no compile after construction. (Not
+    gpt_generate's to the bit: the kernel sums the softmax blockwise.)"""
+    import jax
+
+    from ray_lightning_tpu.serve.engine import DecodeEngine
+    from tests.utils import force_decode_kernel
+
+    cfg = GPTConfig.llama(
+        vocab_size=97, n_layer=2, n_head=4, n_kv_head=2, d_model=256, max_seq=384,
+        compute_dtype="float32",
+    )
+    params = init_gpt_params(jax.random.PRNGKey(0), cfg)
+    kw = dict(num_slots=3, max_seq=384, prefill_buckets=[8, 16], decode_fold=fold)
+
+    def serve():
+        eng = DecodeEngine(params, cfg, **kw)
+        compiles = eng.compiled_count
+        reqs, outs = _drive_budget_freeze(eng, np.random.default_rng(0), max_seq=384)
+        assert eng.num_active == 0 and eng.compiled_count == compiles
+        assert len(outs["r4"]) == 384 - 16  # to the last row
+        return eng, outs
+
+    xla, want = serve()
+    assert xla._attn_block == 0
+    force_decode_kernel(monkeypatch)
+    kernel, got = serve()
+    assert kernel._attn_block == 128
+    assert got == want
+    a, b = kernel.attn_stats(), xla.attn_stats()
+    assert a["rows_live"] == b["rows_live"] and a["rows_allocated"] == b["rows_allocated"]
+    assert b["rows_visited"] == b["rows_allocated"]
+    assert a["rows_live"] <= a["rows_visited"] < a["rows_allocated"]
 
 
 def test_engine_fold_eos_truncates_mid_fold(serve_params):

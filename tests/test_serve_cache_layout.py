@@ -72,3 +72,58 @@ def test_engine_cache_layout_and_what_cache_stats_says(kind, params):
         got += [tok for _, _, tok, _ in eng.step()]
     want = np.asarray(gpt_generate(params, CFG, np.asarray(prompt, np.int32)[None], 6))[0].tolist()
     assert got == want[len(prompt):]
+
+
+# -- the read that goes with the rows, and what stats()["attn"] says of it ------------
+KERNEL_CFG = GPTConfig.llama(
+    vocab_size=97, n_layer=2, n_head=4, n_kv_head=2, d_model=256, max_seq=384, compute_dtype="float32",
+)  # rows of 2 x 64 = 128 lanes, three blocks of 128 a slot: shapes the decode kernel takes
+
+
+@pytest.mark.parametrize("read", ["xla", "kernel"])
+def test_replica_counts_the_cache_rows_its_decode_attention_visits(read, monkeypatch):
+    """``stats()["attn"]`` and the three ``rlt_serve_attn_rows_*_total``
+    series: every allocated row on the XLA read; under the decode kernel
+    (its selection told "tpu": it interprets here) the blocks up to each live
+    slot's position, and nothing for the idle slot. Counted on the host from
+    the slots' records: no program is added."""
+    import time
+
+    import jax
+
+    from ray_lightning_tpu.serve.server import ServeReplica
+    from tests.utils import force_decode_kernel
+
+    if read == "kernel":
+        force_decode_kernel(monkeypatch)
+    params = init_gpt_params(jax.random.PRNGKey(0), KERNEL_CFG)
+    rep = ServeReplica(params=params, model_config=KERNEL_CFG.__dict__.copy(), num_slots=3, max_seq=384,
+                       prefill_buckets=[16, 256], decode_fold=4, watchdog=False)
+    try:
+        rng = np.random.default_rng(1)
+        # one slot idle throughout; a request inside block 0, one that crosses into block 1 and 2
+        work = [(10, 20), (250, 12)]
+        rids = [rep.submit(rng.integers(0, 96, size=p).tolist(), max_new_tokens=n) for p, n in work]
+        deadline = time.monotonic() + 120
+        for rid in rids:
+            while not rep.result(rid, wait_s=0.2)["done"]:
+                assert time.monotonic() < deadline, "request did not finish"
+        st = rep.stats()
+        attn, layers = st["attn"], KERNEL_CFG.n_layer
+        # a token step at position pos sees rows 0 .. pos; the first token came from the prefill
+        assert attn["rows_live"] == layers * sum(p + g for p, n in work for g in range(1, n))
+        assert attn["rows_allocated"] % (layers * 3 * 384 * 4) == 0  # whole folds of 4 steps, 3 slots
+        assert attn["rows_live"] <= attn["rows_visited"] <= attn["rows_allocated"]
+        if read == "xla":
+            assert attn["rows_visited"] == attn["rows_allocated"]
+        else:
+            assert attn["rows_visited"] == layers * 128 * sum(-(-(p + g) // 128) for p, n in work for g in range(1, n))
+            assert attn["rows_visited"] < 0.45 * attn["rows_allocated"]
+        assert st["compiles_since_init"] == 0
+        text = rep.metrics_text()
+        for key in ("rows_allocated", "rows_visited", "rows_live"):
+            # the registry is the process's: a replica before this one has counted into it too
+            line = next(ln for ln in text.splitlines() if ln.startswith(f"rlt_serve_attn_{key}_total "))
+            assert float(line.split()[1]) >= attn[key] > 0
+    finally:
+        rep.stop()

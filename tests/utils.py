@@ -70,3 +70,16 @@ def budget_freeze_requests(rng: Any, max_seq: int = 64) -> tuple:
     first = [(prompt(3), 30), (prompt(14), 3), (prompt(9), 11)]
     late = [(prompt(4), 8), (prompt(16), max_seq - 16)]
     return first, late
+
+
+def force_decode_kernel(monkeypatch: Any) -> None:
+    """Tell the one selection function (``models/gpt.py:_decode_rows_block``)
+    "tpu": the decode kernel is then the read wherever its other conditions
+    hold, and off the chip it runs interpreted. No option does this."""
+    import functools
+
+    from ray_lightning_tpu.models import gpt as G
+
+    monkeypatch.setattr(
+        G, "_decode_rows_block", functools.partial(G._decode_rows_block, backend="tpu")
+    )
