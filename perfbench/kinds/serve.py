@@ -148,19 +148,80 @@ def start(ctx: Dict[str, Any], replica_cls: type = BenchReplica) -> Any:
     return client, actor, t_spawn
 
 
+def next_poll_in(rec: Dict[str, Any], now: float, fine: float, coarse: float, burst: int = 1) -> float:
+    """Seconds from ``now``, the return of a poll, to the request's next one.
+
+    Until the first token has been seen: ``fine``, so ``recv_s[0]`` is the
+    first token's time to within ``fine`` and one call. From then on the
+    step doubles from ``fine`` up to ``coarse``: a poll in mid-stream times
+    no token, it only says at what rate this request's tokens come. From
+    that rate and the tokens still due (``want`` less those held) the
+    harness reckons when the last will come and goes half of the way
+    there, never by less than ``fine``. Tokens come ``burst`` at a time
+    (the replica's decode fold), so the last ``burst`` may come with the
+    next: they count as due now. The last polls are then ``fine`` apart,
+    and ``recv_s[-1]`` is the last token's time to within ``fine`` and one
+    call. With ``fine`` equal to ``coarse`` every step is that one."""
+    held = len(rec["tokens"])
+    if not held:
+        return fine
+    rec["step"] = step = min(coarse, 2.0 * rec.get("step", fine / 2.0))
+    seen_over = rec["recv_s"][-1] - rec["recv_s"][0]
+    if seen_over > 0.0:
+        per_s = (held - rec["recv_n"][0]) / seen_over
+        last_due_in = rec["recv_s"][-1] + max(0, rec["want"] - held - burst) / per_s - now
+        step = min(step, last_due_in / 2.0)
+    return max(fine, step)
+
+
+def poll_steps(mix: Dict[str, Any]) -> Any:
+    """``(fine, coarse)`` in seconds: the mix's ``fine_poll_ms`` and
+    ``poll_ms``. A serve mix names both."""
+    from pb.spec import SpecError
+
+    for key in ("fine_poll_ms", "poll_ms"):
+        if key not in mix:
+            raise SpecError(f"a serve mix names {key!r}: the client's poll has a fine and a coarse step")
+    coarse = float(mix["poll_ms"]) / 1000.0
+    return min(coarse, float(mix["fine_poll_ms"]) / 1000.0), coarse
+
+
+def poll_phases(mix: Dict[str, Any], n: int) -> List[float]:
+    """For each of ``n`` requests the share of ``fine_poll_ms``, in (0, 1],
+    after which its first poll comes once ``submit`` has returned; the
+    later ones follow ``fine_poll_ms`` apart. Drawn from the mix's
+    ``arrangement_seed``, so every seed and every run polls request ``i``
+    in the same phase. Without it every request is polled on one lattice
+    of ``fine_poll_ms`` from its ``submit``, every first-token time is a
+    point of that lattice, and the 95th percentile of the window steps from
+    one point to the next (10 ms of 150: PERF.md, PR 37); with it the times
+    are as spread as the tokens' own."""
+    import numpy as np
+
+    return (1.0 - np.random.default_rng([int(mix.get("arrangement_seed", 0)), 0xF1A5E]).random(n)).tolist()
+
+
 def drive(ctx: Dict[str, Any], client: Any, actor: Any, schedule: List[Dict[str, Any]]) -> Dict[str, Any]:
-    """Offer ``schedule`` at its due times and read every stream back.
-    Times in the result are seconds relative to the window's start."""
+    """Offer ``schedule`` at its due times and read every stream back, each
+    request by its own ``client.result`` on the schedule of
+    ``next_poll_in``, from the phase ``poll_phases`` gives it. Times in
+    the result are seconds relative to the window's start. A request that
+    is due goes before a poll that is due, so a generator with more polls
+    than it can make is late with polls (``poll_late_s``) before it is
+    late with requests."""
     from ray_lightning_tpu import fabric
 
     mix = ctx["mix"]
     seconds = float(ctx["seconds"])
     lead = float(mix.get("lead_in_s", 0.0))
     drain = float(mix.get("drain_s", 30.0))
-    poll = float(mix.get("poll_ms", 50.0)) / 1000.0
+    fine, coarse = poll_steps(mix)
+    burst = int(mix["replica"].get("decode_fold", 1))  # the replica's own default
+    phases = poll_phases(mix, len(schedule))
     trace_s = float(mix.get("trace_s", 3.0))
     recs: List[Dict[str, Any]] = []
     heap: List[Any] = []  # (next_poll, idx)
+    poll_late: List[float] = []
     marks: Dict[str, Any] = {}
     stats0 = stats1 = None
     tracing = False
@@ -190,16 +251,18 @@ def drive(ctx: Dict[str, Any], client: Any, actor: Any, schedule: List[Dict[str,
             recs.append({
                 "due_s": r["due_s"], "counted": r["counted"], "prompt_len": len(r["prompt"]),
                 "want": r["max_new_tokens"], "submit_s": t_call - t0, "rpc_s": t_ret - t_call,
-                "handle": handle, "tokens": [], "recv_s": [], "recv_n": [],
+                "handle": handle, "tokens": [], "recv_s": [], "recv_n": [], "polls": 0,
                 "done": False, "status": "open",
             })
-            heapq.heappush(heap, (t_ret - t0 + poll, i))
+            heapq.heappush(heap, (t_ret - t0 + fine * phases[i], i))
             open_count += 1
             i += 1
             continue
         if heap and heap[0][0] <= now:
-            _, idx = heapq.heappop(heap)
+            due, idx = heapq.heappop(heap)
             rec = recs[idx]
+            poll_late.append(now - due)
+            rec["polls"] += 1
             try:
                 res = client.result(rec["handle"], len(rec["tokens"]))
             except Exception as exc:  # noqa: BLE001 - a lost request is a failed request
@@ -216,7 +279,7 @@ def drive(ctx: Dict[str, Any], client: Any, actor: Any, schedule: List[Dict[str,
                 rec["done"], rec["status"] = True, str(res["status"])
                 open_count -= 1
             else:
-                heapq.heappush(heap, (t_poll + poll, idx))
+                heapq.heappush(heap, (t_poll + next_poll_in(rec, t_poll, fine, coarse, burst), idx))
             continue
         if i >= n and open_count == 0 and stats1 is not None:
             break
@@ -234,11 +297,43 @@ def drive(ctx: Dict[str, Any], client: Any, actor: Any, schedule: List[Dict[str,
     end_s = time.monotonic() - t0
     for rec in recs:
         rec.pop("handle")
+        rec.pop("step", None)
     return {
         "records": recs, "stats0": stats0, "stats1": stats1 or client.stats()[0],
         "marks": marks, "end_s": end_s, "trace": trace_info,
-        "offered": n, "submitted": i,
+        "offered": n, "submitted": i, "poll_late_s": poll_late,
     }
+
+
+def end_to_end(recs: List[Dict[str, Any]], seconds: float, drain_s: float) -> Any:
+    """The window's three end-to-end metrics from the client's records:
+    ``(e2e, counted, done_ok, tokens_in_window)``. A request that failed,
+    was lost in mid-stream or did not finish whole by the drain limit
+    enters both latency tails at that limit, the longest it could have
+    waited; it is never dropped from the sample."""
+    from pb import stats
+
+    counted = [r for r in recs if r["counted"]]
+    done_ok = [r for r in counted if r["done"] and r["status"] == "finished" and len(r["tokens"]) == r["want"]]
+    missing = seconds + drain_s
+    ok_ids = {id(r) for r in done_ok}
+    ttft = stats.latency_with_missing(
+        [(r["recv_s"][0] - r["due_s"]) if id(r) in ok_ids else None for r in counted], missing)
+    # time per output token: (last token - first token) / (tokens - 1), at the client
+    tpot = stats.latency_with_missing(
+        [((r["recv_s"][-1] - r["recv_s"][0]) / (len(r["tokens"]) - 1)) if id(r) in ok_ids and len(r["tokens"]) > 1
+         else None for r in counted if r["want"] > 1], missing)
+    # every output token the client received inside the window, whichever
+    # request it belongs to (lead-in requests that are still decoding too):
+    # all the work of the window over all its time. A token lands in the
+    # window by the time of the poll that brought it.
+    in_window = sum(n for r in recs for t, n in zip(r["recv_s"], r["recv_n"]) if 0.0 <= t < seconds)
+    e2e = {
+        "ttft_p95_ms": 1000.0 * stats.percentile(ttft, 95),
+        "tpot_p95_ms": 1000.0 * stats.percentile(tpot, 95),
+        "serve_tokens_per_s": in_window / seconds,
+    }
+    return e2e, counted, done_ok, in_window
 
 
 def run(ctx: Dict[str, Any], replica_cls: type = BenchReplica) -> Dict[str, Any]:
@@ -251,6 +346,7 @@ def run(ctx: Dict[str, Any], replica_cls: type = BenchReplica) -> Dict[str, Any]
 
     mix, dims = ctx["mix"], ctx["dims"]
     seconds = float(ctx["seconds"])
+    poll_steps(mix)  # a mix that leaves a step out is refused before anything is started
     schedule = traffic.serve_schedule(mix, ctx["seed"], seconds, dims["vocab"])
     client, actor, t_spawn = start(ctx, replica_cls)
     try:
@@ -263,26 +359,9 @@ def run(ctx: Dict[str, Any], replica_cls: type = BenchReplica) -> Dict[str, Any]
         client.shutdown()
     leftovers = teardown()
     recs = out["records"]
-    counted = [r for r in recs if r["counted"]]
-    done_ok = [r for r in counted if r["done"] and r["status"] == "finished" and len(r["tokens"]) == r["want"]]
-    missing = seconds + float(mix.get("drain_s", 30.0))
-    ok_ids = {id(r) for r in done_ok}
-    ttft = stats.latency_with_missing(
-        [(r["recv_s"][0] - r["due_s"]) if id(r) in ok_ids else None for r in counted], missing)
-    # time per output token: (last token - first token) / (tokens - 1), at the client
-    tpot = stats.latency_with_missing(
-        [((r["recv_s"][-1] - r["recv_s"][0]) / (len(r["tokens"]) - 1)) if id(r) in ok_ids and len(r["tokens"]) > 1
-         else None for r in counted if r["want"] > 1], missing)
-    # every output token the client received inside the window, whichever
-    # request it belongs to (lead-in requests that are still decoding too):
-    # all the work of the window over all its time
-    in_window = sum(n for r in recs for t, n in zip(r["recv_s"], r["recv_n"]) if 0.0 <= t < seconds)
-    e2e = {
-        "ttft_p95_ms": 1000.0 * stats.percentile(ttft, 95),
-        "tpot_p95_ms": 1000.0 * stats.percentile(tpot, 95),
-        "serve_tokens_per_s": in_window / seconds,
-        "setup_s": ctx["window_open_wall"] - ctx["t_start"],
-    }
+    drain_s = float(mix.get("drain_s", 30.0))
+    e2e, counted, done_ok, in_window = end_to_end(recs, seconds, drain_s)
+    e2e["setup_s"] = ctx["window_open_wall"] - ctx["t_start"]
     if not stats.tail_supported(len(counted), 95):
         say(f"note: {len(counted)} requests leave fewer than ten beyond the 95th percentile")
     # -- the check: a seeded sample of finished requests, the longest among them
@@ -294,7 +373,13 @@ def run(ctx: Dict[str, Any], replica_cls: type = BenchReplica) -> Dict[str, Any]
     by_due = {r["due_s"]: s for r, s in zip(recs, schedule)}
     pad_to = -(-(int(mix["prompt_tokens"]["max"]) + int(mix["output_tokens"]["max"])) // 128) * 128
     ref: Dict[str, Any] = {"reference": {}, "wall_s": 0.0}
-    numbers: Dict[str, float] = {}
+    # printed in every result line, compared with nothing: a run that reads over 50 ms in the
+    # first offered other traffic than its mix describes, and one that reads over the mix's
+    # fine_poll_ms in the second timed its tokens more coarsely than that (perfbench/README.md)
+    numbers: Dict[str, float] = {
+        "gen_late_p95_ms": 1000.0 * stats.percentile([r["submit_s"] - r["due_s"] for r in counted], 95),
+        "poll_late_p95_ms": 1000.0 * stats.percentile(out["poll_late_s"] or [0.0], 95),
+    }
     if sample:
         ref = run_reference(ctx, {
             "kind": "serve", "max_seq": int(mix["replica"]["max_seq"]),

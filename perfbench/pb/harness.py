@@ -16,10 +16,15 @@ def say(msg: str) -> None:
     print(msg, flush=True)
 
 
+def check_text(c: Dict[str, Any]) -> str:
+    """A compared number beside its limit, as a line of the run."""
+    return f"check {c['check']}: value={c['value']} limit={c['limit']} -> {'ok' if c['ok'] else 'FAILED'}"
+
+
 def check_line(results: List[Dict[str, Any]], name: str, value: Any, limit: Any, ok: bool) -> None:
     """One number compared, printed beside its limit, and kept."""
     results.append({"check": name, "value": value, "limit": limit, "ok": bool(ok)})
-    say(f"check {name}: value={value} limit={limit} -> {'ok' if ok else 'FAILED'}")
+    say(check_text(results[-1]))
 
 
 def run_reference(ctx: Dict[str, Any], ref_in: Dict[str, Any]) -> Dict[str, Any]:

@@ -4,8 +4,8 @@ A cell is ``{name, config, traffic, chips}``. Its configuration is the
 ``file`` of the ``configs`` entry; its traffic mix is
 ``<path>/traffic/<traffic>.json``, its check limits
 ``<path>/limits/<cell>.json`` and each per-layer metric's reader
-``<path>/metrics/<metric>.py``, looked for under every directory in
-``paths``. The configuration's ``model_type`` names its family
+``<path>/metrics/<metric>.py`` (or the one its ``<metric>.json`` names),
+looked for under every directory in ``paths``. The configuration's ``model_type`` names its family
 (``families/<model_type>.py``), the mix's ``kind`` the driver of the cell
 (``kinds/<kind>.py``), and a size distribution, an arrival process or a
 way of sharing that the generator does not have built in a piece of it
@@ -122,8 +122,11 @@ class Spec:
         return out
 
     def reader(self, metric: str) -> Callable[[Dict[str, Any]], Optional[float]]:
-        """``read(ctx) -> value or None`` from ``metrics/<metric>.py``."""
-        path = self._find(f"metrics/{metric}.py")
+        """``read(ctx) -> value or None`` from ``metrics/<metric>.py``, or from
+        the ``metrics/<reader>.py`` that ``metrics/<metric>.json`` names: two
+        entries that read one quantity (in cells that report different
+        end-to-end metrics, or at two settings of a parameter) share a reader."""
+        path = self._find(f"metrics/{self.metric_params(metric).get('reader', metric)}.py")
         if path is None:
             raise SpecError(f"per-layer metric {metric!r} has no reader file")
         mod_spec = importlib.util.spec_from_file_location(

@@ -44,7 +44,7 @@ ROOT = os.path.dirname(HERE)
 sys.path.insert(0, HERE)
 sys.path.insert(0, ROOT)
 
-from pb.harness import say  # noqa: E402
+from pb.harness import check_text, say  # noqa: E402
 
 
 class NoChip(RuntimeError):
@@ -207,6 +207,12 @@ def report(spec: Any, args: Any, run: Dict[str, Any]) -> int:
     }
     if breakdown:
         result["breakdown"] = breakdown
+    # what the run printed and compared with nothing (how late the generator ran is among them,
+    # traced or not), then, last, each number compared beside its limit
+    result["numbers"] = run["numbers"]
+    result["checks"] = {c["check"]: {"value": c["value"], "limit": c["limit"]} for c in run["checks"]}
+    for c in run["checks"]:
+        print(check_text(c), file=sys.stderr, flush=True)
     if args.rehearse:
         say("REHEARSAL finished: correct=%s attempted=%s failed=%s (not a chip result; no metrics)"
             % (correct, run["attempted"], run["failed"]))

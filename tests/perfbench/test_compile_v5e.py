@@ -185,30 +185,75 @@ def _mistral(topo):
     return pc, dims, mix, params, sds
 
 
-def test_mistral_decode_fold_fits_one_chip(topo):
+def _mistral_fold_and_admission(topo, slots):
+    """The chat cell's two largest programs on the cache the engine runs,
+    rows ``(L, slots, S, Hkv * hd)`` (PR 29): the decode fold, and the
+    admission of the largest prefill bucket (prefill, its rows written into
+    one slot of the donated caches, the head over the last position), built
+    as ``serve/engine.py`` builds ``step_impl`` and ``admit_impl``. They share
+    weights and caches, so what the replica needs is the arguments once and
+    the larger of the two programs' temporaries."""
     import jax
     import jax.numpy as jnp
 
-    from ray_lightning_tpu.models.gpt import gpt_decode_fold
+    from ray_lightning_tpu.models.gpt import cache_strip_put, gpt_decode_fold, gpt_prefill
 
     pc, dims, mix, params, sds = _mistral(topo)
     rep = mix["replica"]
-    B, S = int(rep["num_slots"]), int(rep["max_seq"])
-    cache = sds((dims["layers"], B, S, dims["kv_heads"], dims["head_dim"]), jnp.bfloat16)
+    B, S = int(slots), int(rep["max_seq"])
+    cache = sds((dims["layers"], B, S, dims["kv_heads"] * dims["head_dim"]), jnp.bfloat16)
     i32, f32 = (lambda: sds((B,), jnp.int32)), (lambda: sds((B,), jnp.float32))
 
     def step(params, k_cache, v_cache, cur, pos, temps, top_ks, top_ps, keys, active, remaining, eos):
         return gpt_decode_fold(params, pc, cur, pos, keys, temps, top_ks, top_ps, active, remaining, eos,
                                k_cache, v_cache, fold=int(rep["decode_fold"]))
 
-    compiled = jax.jit(step, donate_argnums=(1, 2)).lower(
+    def admit(params, k_cache, v_cache, prompt, last_idx, slot):
+        h, pf_k, pf_v = gpt_prefill(params, pc, prompt)
+        k_cache, v_cache = cache_strip_put(k_cache, pf_k, slot, 0), cache_strip_put(v_cache, pf_v, slot, 0)
+        h_last = jax.lax.dynamic_slice_in_dim(h, last_idx, 1, axis=1)[:, 0]
+        return k_cache, v_cache, jnp.argmax(h_last @ params["lm_head"].T, axis=-1)
+
+    fold = jax.jit(step, donate_argnums=(1, 2, 3, 4, 8, 9, 10)).lower(
         params, cache, cache, i32(), i32(), f32(), i32(), f32(), sds((B, 2), jnp.uint32),
         sds((B,), jnp.bool_), i32(), i32(),
     ).compile()
-    used = _device_bytes(compiled)
-    print(f"mistral-d8 decode fold at {B} slots x {S}: {used / 1e9:.2f} GB on the device")
-    assert used < HBM
-    assert used > 0.5 * HBM, "weights and cache should hold most of the chip"
+    bucket = max(rep["prefill_buckets"])
+    scalar = sds((), jnp.int32)
+    admission = jax.jit(admit, donate_argnums=(1, 2)).lower(
+        params, cache, cache, sds((1, bucket), jnp.int32), scalar, scalar).compile()
+    return fold, admission, B, S, bucket
+
+
+def _program_gib(compiled):
+    m = compiled.memory_analysis()
+    return _device_bytes(compiled) / 2**30, m.temp_size_in_bytes / 2**30
+
+
+def test_mistral_decode_fold_fits_one_chip(topo):
+    """At the cell's own slot count, on the layout the engine runs, with the
+    1024 admission beside the fold."""
+    _, _, mix, _, _ = _mistral(topo)
+    fold, admission, B, S, bucket = _mistral_fold_and_admission(topo, mix["replica"]["num_slots"])
+    (f_all, f_tmp), (a_all, a_tmp) = _program_gib(fold), _program_gib(admission)
+    print(f"mistral-d8 at {B} slots x {S} on rows: decode fold {f_all:.2f} GiB (temporaries {f_tmp:.3f}), "
+          f"{bucket} admission {a_all:.2f} GiB (temporaries {a_tmp:.3f})")
+    assert max(f_all, a_all) * 2**30 < HBM - 2**30, "the cell keeps 1 GiB free"
+    assert f_all * 2**30 > 0.5 * HBM, "weights and cache should hold most of the chip"
+    assert len(_mosaic_calls(admission.as_text())) >= 1, "the admission's prefill holds the flash kernel"
+
+
+@pytest.mark.parametrize("slots", [96, 128])
+def test_mistral_programs_at_more_slots_say_what_is_left(topo, slots):
+    """Sizes, not times, for the PR that moves the cell's slot count (PERF.md
+    section 4 has the readings): what the fold and the 1024 admission take at
+    96 and 128 slots on the rows layout, and that both still compile."""
+    fold, admission, B, S, bucket = _mistral_fold_and_admission(topo, slots)
+    (f_all, f_tmp), (a_all, a_tmp) = _program_gib(fold), _program_gib(admission)
+    free = HBM / 2**30 - max(f_all, a_all)
+    print(f"mistral-d8 at {B} slots x {S} on rows: decode fold {f_all:.2f} GiB (temporaries {f_tmp:.3f}), "
+          f"{bucket} admission {a_all:.2f} GiB (temporaries {a_tmp:.3f}); {free:.2f} GiB of {HBM / 2**30:.2f} free")
+    assert max(f_all, a_all) * 2**30 < HBM
 
 
 def test_mistral_prefill_bucket_holds_flash_kernel(topo):

@@ -225,36 +225,89 @@ def test_benchmark_json_names_files_that_exist():
         spec.cell("no-such-cell")
 
 
+def _counted(k, expert_layers):
+    """``stats()["moe"]``, ``["ssm"]`` and ``["attn"]`` after ``k`` units of everything: monotone totals,
+    so that the window is the difference of two. A unit is 500 decode token steps of 128 slots."""
+    return {
+        "moe": {"expert_layers": expert_layers,
+                "decode": {"token_steps": 500 * k, "experts_hit": 500 * k * expert_layers * 10,
+                           "pairs_routed": 80_000 * k, "pairs_held": 5_000 * k},
+                "prefill": {"pairs_routed": 420_000 * k, "pairs_held": 26_250 * k}},
+        "ssm": {"decode": {"slot_steps": 64_000 * k, "slot_steps_live": 38_400 * k},
+                "prefill": {"rows_scanned": 12_800 * k, "rows_real": 9_600 * k}},
+        "attn": {"rows_allocated": 500_000_000 * k, "rows_visited": 15_500_000 * k, "rows_live": 11_450_000 * k},
+    }
+
+
 def test_every_reader_of_every_cell_reads_a_hand_made_run_at_the_chips_peaks():
     """On the CPU a rehearsal has no peaks and the roofline readers return
     early; here each reader runs on a run written by hand, with the v5e's
-    peaks and the counts of the cell's own family."""
+    peaks and the counts of the cell's own family. The two ``stats`` carry
+    every block a reader of some cell reads (``spans``, ``moe``, ``ssm``,
+    ``attn``) after one unit and after three: the window is two units."""
+    from test_pb_span_metrics import _spans
+
     spec = Spec(ROOT)
     trace = {"devices": 1, "busy_s": 2.9, "window_s": 3.0, "op_seconds": {"tpu_custom_call/x": 0.79},
-             "modules": {"jit_step_impl(1)": [0.1654, 0.1654]}, "collective_exposed_s": 0.0}
-    program = {
-        "window": {"data_wait_s": 0.1, "dispatch_s": 0.1, "drain_s": 29.0, "chunks": 44, "compiles": 0,
-                   "steps": 176, "dispatches": 44},
-        "trace": {"dispatches": 3}, "worker_ready_wall": 10.0, "fit_call_wall": 1.0,
-        "records": [{"counted": True, "rpc_s": 0.001, "submit_s": 0.1, "due_s": 0.09, "recv_s": [0.5, 20.0],
-                     "recv_n": [1, 99], "prompt_len": 100, "tokens": [1] * 100}],
-        "stats0": {"compiles_since_init": 0},
-        "stats1": {"compiles_since_init": 0, "ttft_queue_p95_s": 0.2, "occupancy": 0.6},
-        "info1": {"ready_wall": 5.0}, "spawn_wall": 1.0,
-    }
+             "modules": {"jit_step_impl(1)": [0.1654, 0.1654], "jit_admit_impl(2)": [0.0130, 0.0174, 0.0397]},
+             "collective_exposed_s": 0.0}
     seen = {}
     for cell in spec.bench["workloads"]:
         mix = spec.traffic(cell["traffic"])
+        dims = spec.dims(spec.config(cell["config"]))
+        # the dense layer + one period of MiMo-V2-Flash has six expert layers, a period of Nemotron-3 five
+        expert_layers = {"mimo-v2-flash-d7-ep16": 6, "nemotron-3-super-d11-ep4": 5}.get(cell["config"], 0)
+        program = {
+            "window": {"data_wait_s": 0.1, "dispatch_s": 0.1, "drain_s": 29.0, "chunks": 44, "compiles": 0,
+                       "steps": 176, "dispatches": 44},
+            "trace": {"dispatches": 3}, "worker_ready_wall": 10.0, "fit_call_wall": 1.0,
+            "records": [{"counted": True, "rpc_s": 0.001, "submit_s": 0.1, "due_s": 0.09, "recv_s": [0.5, 20.0],
+                         "recv_n": [1, 99], "prompt_len": 100, "tokens": [1] * 100}],
+            "stats0": {"compiles_since_init": 0, "spans": _spans(1), **_counted(1, expert_layers)},
+            "stats1": {"compiles_since_init": 0, "ttft_queue_p95_s": 0.2, "occupancy": 0.6, "spans": _spans(3),
+                       **_counted(3, expert_layers)},
+            "info1": {"ready_wall": 5.0}, "spawn_wall": 1.0,
+        }
         e2e = ({"train_tokens_per_s_per_chip": 24078.0} if mix["kind"] == "train"
                else {"ttft_p95_ms": 459.0, "tpot_p95_ms": 53.0, "serve_tokens_per_s": 938.0})
-        ctx = {"cell": cell["name"], "chips": 1, "dims": spec.dims(spec.config(cell["config"])), "mix": mix,
+        ctx = {"cell": cell["name"], "chips": 1, "dims": dims, "mix": mix,
                "config": spec.config(cell["config"]), "program": program, "e2e": e2e, "trace": trace,
                "seconds": 30.0, "costs": costs, "peaks": costs.peaks("TPU v5 lite")}
         for m in spec.per_layer(cell["name"], list(e2e)):
             ctx["params"] = spec.metric_params(m["name"])
-            seen[m["name"]] = spec.reader(m["name"])(ctx)
+            seen[cell["name"], m["name"]] = spec.reader(m["name"])(ctx)
     assert all(v is not None for v in seen.values()), seen
+    assert {name for _, name in seen} == {m["name"] for m in spec.bench["per_layer"]}
+    train, chat = "gpt2-medium.train-1chip", "mistral-7b-v0.1-d8.serve-chat"
+    mimo, nemo = "mimo-v2-flash-d7-ep16.serve-mixedlen", "nemotron-3-super-d11-ep4.serve-shortchat"
     # 24,078 tokens/s/chip x 2.27 GFLOP a token over 197 TFLOP/s
-    assert seen["mfu_pct"] == pytest.approx(100 * 24078.0 * 3 * (2 * 353_453_056 + 50_380_800) / 197e12)
-    assert seen["decode_step_ms"] == pytest.approx(165.4 / 4)
-    assert 0 < seen["flash_roofline_pct.train"] < 100 and 0 < seen["decode_hbm_roofline_pct"] < 100
+    assert seen[train, "mfu_pct"] == pytest.approx(100 * 24078.0 * 3 * (2 * 353_453_056 + 50_380_800) / 197e12)
+    assert seen[chat, "decode_step_ms"] == pytest.approx(165.4 / 4)
+    assert 0 < seen[train, "flash_roofline_pct.train"] < 100
+    # the one request holds 100 + 100 / 2 positions from 0.5 s to 20 s of the 30: 97.5 live positions a step
+    want = 100.0 * (3_751_804_928 + 97.5 * 32768) / 819e9 / (0.1654 / 4)
+    assert seen[chat, "decode_hbm_roofline_pct"] == pytest.approx(want) and 0 < want < 100
+    # the counters, over the window's two units: 31.0 M rows visited of 1,000 M allocated
+    assert seen[chat, "attn_visited_pct"] == pytest.approx(3.1)
+    for cell in (mimo, nemo):
+        # 10 experts hit a layer and step; (10,000 + 52,500) of 1,000,000 pairs landed on held experts
+        assert seen[cell, "experts_hit_per_step"] == pytest.approx(10.0)
+        assert seen[cell, "routed_here_pct"] == pytest.approx(6.25)
+    assert seen[nemo, "state_live_pct"] == pytest.approx(60.0)  # 76,800 of 128,000 slot-steps
+    assert seen[nemo, "admit_device_ms"] == pytest.approx(17.4)  # the median of the three admissions
+    assert 0 < seen[mimo, "sparse_decode_hbm_roofline_pct"] < 100
+    assert 0 < seen[nemo, "hybrid_decode_hbm_roofline_pct"] < 100
+    # the spans' readers on the window between the two stats() calls (no marks: the run's 30 s)
+    # the chat cell reads the quantities that move the first tokens through twins of their own (the same
+    # readers: ``metrics/<name>.chat.json`` names them), since its first tokens' tail is no end-to-end metric
+    for cell, twin in ((chat, ".chat"), (mimo, ""), (nemo, "")):
+        assert seen[cell, "result_rpcs_per_s" + twin] == pytest.approx(1600 / 30.0)
+        assert seen[cell, "admit_ms" + twin] == pytest.approx(1000.0 * (0.160 + 0.800) / 8)
+        assert seen[cell, "gen_late_p95_ms" + twin] == pytest.approx(10.0)
+        assert seen[cell, "client_rpc_ms" + twin] == pytest.approx(1.0)
+    # the one request saw its first token at 0.5 s and was due at 0.09 s: both percentiles are that one
+    assert seen[chat, "first_token_p95_ms"] == seen[chat, "first_token_p50_ms"] == pytest.approx(410.0)
+    # the benchmark's contract: every cell an entry lists reports the end-to-end metric the entry moves
+    e2e_of = {c["name"]: {m["name"] for m in spec.end_to_end(c["name"])} for c in spec.bench["workloads"]}
+    moves = {m["name"]: m["moves"] for m in spec.bench["per_layer"]}
+    assert all(moves[name] in e2e_of[cell] for cell, name in seen)
