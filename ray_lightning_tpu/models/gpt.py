@@ -113,8 +113,9 @@ class GPTConfig:
     loss_chunk: int = 0
     # A model whose layers differ in kind (models/mixed.py). ``layer_types``
     # gives each layer's (mixer kind, MLP kind): mixer "full" or "window"
-    # attention (``attn_window`` positions) or "ssm" (a Mamba-2 state
-    # layer, the ``ssm_*`` sizes below), MLP "dense" (``d_ff``) or
+    # attention (``attn_window`` positions), "latent" attention (one
+    # compressed row a position, ``kv_lora_rank`` below) or "ssm" (a Mamba-2
+    # state layer, the ``ssm_*`` sizes below), MLP "dense" (``d_ff``) or
     # "experts" (``d_ff_expert`` wide, ``n_experts`` routed over, ``moe_top_k``
     # a token). Either part may be None: a layer that is a mixer alone or
     # an MLP alone, under its one norm. With layer_types, ``pos_embed``
@@ -141,6 +142,16 @@ class GPTConfig:
     # it); ``rope_theta_window`` is the window layers' base (0 = ``rope_theta``).
     rope_dim: int = 0
     rope_theta_window: float = 0.0
+    # The rotated pairs are neighbours ``(2i, 2i + 1)`` instead of the
+    # half-split ``(i, i + rope_dim / 2)`` (layer_types only).
+    rope_interleave: bool = False
+    # Latent attention: keys and values of all heads are projections of one
+    # normed latent of ``kv_lora_rank`` values a position, beside one rotary
+    # key of ``rope_dim`` that the heads share; a q·k head is
+    # ``qk_head_dim - rope_dim`` dims against the latent's keys, then
+    # ``rope_dim`` rotated ones. The cache keeps the latent and the rotated
+    # key, ``kv_lora_rank + rope_dim`` values a position, and no K or V.
+    kv_lora_rank: int = 0
     # Attention kinds with a learnable per-head sink logit: it takes
     # probability in the softmax and contributes no value.
     attn_sink_logit: Tuple[str, ...] = ()
@@ -216,6 +227,12 @@ class GPTConfig:
                 "experts_held and moe_scoring='sigmoid' need layer_types: "
                 "the expert layer that is told which experts it holds runs "
                 "in the block of models/mixed.py"
+            )
+        elif self.kv_lora_rank or self.rope_interleave:
+            raise ValueError(
+                "kv_lora_rank and rope_interleave need layer_types: latent "
+                "attention and the rotation of neighbouring pairs run in "
+                "the block of models/mixed.py"
             )
 
     @staticmethod

@@ -1,8 +1,10 @@
 """A decoder whose layers differ in kind, and one block function for it.
 
 ``GPTConfig.layer_types`` gives each layer a mixer kind — attention "full"
-(the whole context) or "window" (the last ``attn_window`` positions), or
-"ssm" (a Mamba-2 state layer: models/ssm.py) — and an MLP kind — "dense"
+(the whole context), "window" (the last ``attn_window`` positions) or
+"latent" (the whole context through one compressed row a position:
+:func:`_latent_part`), or "ssm" (a Mamba-2 state layer: models/ssm.py) —
+and an MLP kind — "dense"
 (``d_ff``) or "experts" (routed experts of ``d_ff_expert``, of which this
 process may hold a share: ``experts_held``). Either part may be missing
 (None): such a layer is ``x + part(RMSNorm(x))`` alone. The attention
@@ -32,7 +34,15 @@ kind: K and V of the attention kinds, and under ``"ssm"`` the recurrent
 states in the first and the conv tails in the second — one array a state
 layer, ``(B, H, P, N)`` float32 and ``(taps - 1, B, channels)``, in a
 tuple: a step replaces each whole, so a donated one is updated where it
-lies and no layer is ever sliced out of a stack. An attention kind has
+lies and no layer is ever sliced out of a stack. The latent kind keeps
+a position's normed latent in the first half, ``(Lk, B, S,
+kv_lora_rank)`` — keys and values of every head are read out of it —
+and in the second the rotary key all heads share, already rotated,
+``(Lk, B, S, rope_dim)``: ``kv_lora_rank + rope_dim`` values a position
+where K and V would be ``n_head * (qk + v)``. (As ONE array of rows
+``[latent; key]`` the chip's compiler re-laid a layer out for the second
+of the two matmuls that read it and kept a padded copy of the whole:
+compile-only for the v5e, PR 38.) A K/V attention kind has
 one stacked array, ``{"full": ...,
 "window": ...}``, ``(Lk, B, rows, Hkv * d)`` with rows ``S`` or ``R`` and
 ``d`` the q·k width for K and the v width for V: a position's KV heads
@@ -61,11 +71,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-ATTN_KINDS = ("full", "window")
+#: Attention kinds that keep a K and a V row a position.
+KV_KINDS = ("full", "window")
+ATTN_KINDS = KV_KINDS + ("latent",)
 MIXER_KINDS = ATTN_KINDS + ("ssm",)
 MLP_KINDS = ("dense", "experts")
 #: Prefix of a mixer kind's leaves in ``blocks``.
-_MIXER_PREFIX = {"full": "full", "window": "swa", "ssm": "ssm"}
+_MIXER_PREFIX = {"full": "full", "window": "swa", "latent": "lat", "ssm": "ssm"}
 #: Query rows a block of the no-cache full attention takes at a time: the
 #: float32 scores of a block against its causal prefix are what is live.
 _Q_BLOCK = 512
@@ -145,7 +157,7 @@ def validate_mixed(cfg: Any) -> None:
                 f"groups {G}, state {cfg.ssm_state}, conv {cfg.ssm_conv}, "
                 f"chunk {cfg.ssm_chunk})"
             )
-    elif cfg.pos_embed == "none" and (count_kind(cfg, "full") or count_kind(cfg, "window")):
+    elif cfg.pos_embed == "none" and any(count_kind(cfg, kind) for kind in ATTN_KINDS):
         raise ValueError(
             "pos_embed='none' needs state layers: attention without positions "
             "sees a set, and nothing else here carries the order"
@@ -160,9 +172,20 @@ def validate_mixed(cfg: Any) -> None:
             "learnable sink logit is attn_sink_logit"
         )
     for kind in cfg.attn_sink_logit:
-        if kind not in ATTN_KINDS:
-            raise ValueError(f"attn_sink_logit names {kind!r}, not one of {ATTN_KINDS}")
-    for kind in ATTN_KINDS:
+        if kind not in KV_KINDS:
+            raise ValueError(f"attn_sink_logit names {kind!r}, not one of {KV_KINDS}")
+    if count_kind(cfg, "latent"):
+        if cfg.kv_lora_rank < 1 or cfg.pos_embed != "rope" or not 0 < rope_dim(cfg) < qk_dim(cfg):
+            raise ValueError(
+                "latent layers need kv_lora_rank >= 1, pos_embed='rope' and "
+                "0 < rope_dim < qk_head_dim: a q·k head is its dims against "
+                "the latent's keys, then rope_dim rotated ones (got "
+                f"kv_lora_rank {cfg.kv_lora_rank}, rope_dim {rope_dim(cfg)}, "
+                f"q·k head {qk_dim(cfg)})"
+            )
+    elif cfg.kv_lora_rank:
+        raise ValueError("kv_lora_rank describes latent layers: layer_types names none")
+    for kind in KV_KINDS:
         if count_kind(cfg, kind) and cfg.n_head % kv_heads(cfg, kind):
             raise ValueError(
                 f"n_head ({cfg.n_head}) must be divisible by the {kind} "
@@ -209,10 +232,16 @@ def refuse_mixed(cfg: Any, mechanism: str) -> None:
             "mode would need a snapshot of the state"
             if count_kind(cfg, "ssm") else ""
         )
+        latent = (
+            "; a latent layer keeps one row of kv_lora_rank + rope_dim values "
+            "a position and no K / V pair: the page, pool, export and wire "
+            "formats (serve/kvstore.py, serve/kvfleet.py) have no such row"
+            if count_kind(cfg, "latent") else ""
+        )
         raise ValueError(
             f"{mechanism} does not run a configuration with mixed layer "
             "kinds or held experts (GPTConfig.layer_types): it has the "
-            f"dense engine's bucketed prefill and decode fold only{state}"
+            f"dense engine's bucketed prefill and decode fold only{state}{latent}"
         )
 
 
@@ -267,7 +296,7 @@ def mixed_param_shapes(cfg: Any) -> Dict[str, Any]:
     for name, part in (("ln1_g", 0), ("ln2_g", 1)):
         if count_part(cfg, part):
             blocks[name] = (count_part(cfg, part), D)
-    for kind in ATTN_KINDS:
+    for kind in KV_KINDS:
         n, hkv, p = count_kind(cfg, kind), kv_heads(cfg, kind), _MIXER_PREFIX[kind]
         if not n:
             continue
@@ -277,6 +306,16 @@ def mixed_param_shapes(cfg: Any) -> Dict[str, Any]:
         })
         if kind in cfg.attn_sink_logit:
             blocks[f"{p}_sink"] = (n, H)
+    n = count_kind(cfg, "latent")
+    if n:
+        # wkv_a: the stream -> [latent; rotary key]; wkv_b: the normed latent ->
+        # each head's [keys of its no-position dims; values], the heads leading: the
+        # order both of its uses multiply in, so that no fold re-lays the stack out
+        r, dn = cfg.kv_lora_rank, dqk - rope_dim(cfg)
+        blocks.update({
+            "lat_wq": (n, D, H, dqk), "lat_wkv_a": (n, D, r + rope_dim(cfg)), "lat_kv_g": (n, r),
+            "lat_wkv_b": (n, H, r, dn + dv), "lat_wo": (n, H, dv, D),
+        })
     n = count_kind(cfg, "ssm")
     if n:
         blocks.update(ssm.param_shapes(cfg, n))
@@ -332,19 +371,24 @@ def init_mixed_params(rng: jax.Array, cfg: Any) -> Dict[str, Any]:
 def empty_caches(cfg: Any, slots: int, max_seq: int, dtype: Any) -> Tuple[Dict[str, Any], Dict[str, Any]]:
     """The zeroed per-request state of ``slots`` requests of up to
     ``max_seq`` positions, in its two halves (a kind the model has no
-    layer of is left out): K and V of the attention kinds; under "ssm" a
-    tuple of recurrent states and a tuple of conv tails, one a state layer."""
+    layer of is left out): K and V of the attention kinds (of the latent
+    kind the latents and the rotary keys); under "ssm" a tuple of recurrent
+    states and a tuple of conv tails, one a state layer."""
     from ray_lightning_tpu.models import ssm
 
     rows = {"full": int(max_seq), "window": ring_rows(cfg)}
     k: Dict[str, Any] = {}
     v: Dict[str, Any] = {}
-    for kind in ATTN_KINDS:
+    for kind in KV_KINDS:
         n = count_kind(cfg, kind)
         if n:
             lead, hkv = (n, slots, rows[kind]), kv_heads(cfg, kind)
             k[kind] = jnp.zeros(lead + (hkv * qk_dim(cfg),), dtype)
             v[kind] = jnp.zeros(lead + (hkv * v_dim(cfg),), dtype)
+    n = count_kind(cfg, "latent")
+    if n:
+        k["latent"] = jnp.zeros((n, slots, int(max_seq), cfg.kv_lora_rank), dtype)
+        v["latent"] = jnp.zeros((n, slots, int(max_seq), rope_dim(cfg)), dtype)
     n = count_kind(cfg, "ssm")
     if n:
         k["ssm"], v["ssm"] = (tuple(x) for x in zip(*(ssm.empty_state(cfg, slots, dtype) for _ in range(n))))
@@ -352,14 +396,23 @@ def empty_caches(cfg: Any, slots: int, max_seq: int, dtype: Any) -> Tuple[Dict[s
 
 
 # -- pieces --------------------------------------------------------------------
-def _rope(x: jax.Array, tables: Tuple[jax.Array, jax.Array]) -> jax.Array:
+def _rope(x: jax.Array, tables: Tuple[jax.Array, jax.Array], interleave: bool = False) -> jax.Array:
     """Rotate the first ``2 * half`` dims of x (B, S, H, d) by position
-    (half-split pairs ``(i, i + half)``); the dims after them pass."""
+    (half-split pairs ``(i, i + half)``); the dims after them pass. With
+    ``interleave`` pair ``i`` is the neighbours ``(2i, 2i + 1)``, and the
+    rotated dims come out half-split (every first member, then every
+    second): a permutation that queries and keys share, so no score sees
+    it."""
     cos, sin = tables  # (B, S, half)
     half = cos.shape[-1]
     cos, sin = cos[:, :, None, :], sin[:, :, None, :]
     x32 = x.astype(jnp.float32)
-    x1, x2, rest = x32[..., :half], x32[..., half:2 * half], x32[..., 2 * half:]
+    rest = x32[..., 2 * half:]
+    if interleave:
+        pairs = x32[..., :2 * half].reshape(x.shape[:-1] + (half, 2))
+        x1, x2 = pairs[..., 0], pairs[..., 1]
+    else:
+        x1, x2 = x32[..., :half], x32[..., half:2 * half]
     return jnp.concatenate(
         [x1 * cos - x2 * sin, x1 * sin + x2 * cos, rest], axis=-1
     ).astype(x.dtype)
@@ -489,7 +542,8 @@ def write_prefill_rows(
     rows of the full layers (rows past
     ``true_len`` lie behind the position mask, as in the dense engine),
     and of the window layers the prompt's last ``min(true_len, R)``
-    positions, each at its ring row. A state layer has no rows: its state
+    positions, each at its ring row; a latent layer's latents and rotary
+    keys as the full layers' K and V. A state layer has no rows: its state
     after the last real row and its conv tail are written whole, so
     nothing of the slot's last request is left."""
     zero = jnp.zeros((), jnp.int32)
@@ -566,7 +620,8 @@ def _attention_part(h, lp, ls, cfg, rope, pos, caches):
         k = jnp.einsum("bsd,dhk->bshk", a, lp["wk"].astype(cdt))
         v = jnp.einsum("bsd,dhk->bshk", a, lp["wv"].astype(cdt))
         if cfg.pos_embed == "rope":
-            q, k = _rope(q, rope[ls.mixer]), _rope(k, rope[ls.mixer])
+            q = _rope(q, rope[ls.mixer], cfg.rope_interleave)
+            k = _rope(k, rope[ls.mixer], cfg.rope_interleave)
         if cfg.attn_value_scale != 1.0:
             v = v * jnp.asarray(cfg.attn_value_scale, v.dtype)
         q = q.reshape(B, S, G, cfg.n_head // G, q.shape[-1])
@@ -596,6 +651,58 @@ def _attention_part(h, lp, ls, cfg, rope, pos, caches):
                 q, k_cache[ls.mixer][ls.mixer_index], v_cache[ls.mixer][ls.mixer_index],
                 pos, sink, window, ring,
             )
+        return jnp.einsum("bshk,hkd->bsd", o.astype(cdt), lp["wo"].astype(cdt)), kv
+
+
+def _latent_part(h, lp, ls, cfg, rope, pos, caches):
+    """``(a latent layer's write into the residual, kv)``. Every head's keys
+    and values are projections ``wkv_b`` of one normed latent ``c`` a
+    position, beside one rotary key the heads share: ``s_h = (q_nope,h ·
+    wkv_b[K,h]^T c + rope(q_rope,h) · rope(k_r)) / sqrt(dqk)``.
+
+    With no cache (forward, prefill) the keys and values are built from
+    the latent, head by head, and the rows attend as the full kind's do;
+    ``kv`` is ``(c, rope(k_r))`` of every row, what the cache keeps. In
+    decode ``wkv_b`` moves onto the query and the output instead (the same
+    sums in another order): ``q^_h = wkv_b[K,h] q_nope,h`` scores against
+    the cached latents as they lie, ``p`` weighs the latents themselves,
+    and ``wkv_b[V,h]`` takes each head's weighted latent to its values. No
+    key or value of a cached position is ever built."""
+    from ray_lightning_tpu.models.gpt import _rmsnorm, _write_cache_rows
+
+    cdt = jnp.dtype(cfg.compute_dtype)
+    B, S, _ = h.shape
+    H, r, dn = cfg.n_head, cfg.kv_lora_rank, qk_dim(cfg) - rope_dim(cfg)
+    wkv_b = lp["wkv_b"].astype(cdt)
+    with jax.named_scope("attn_latent_prefill" if caches is None else "attn_latent_decode"):
+        a = _rmsnorm(h, lp["ln1_g"], cfg.norm_eps)
+        q = jnp.einsum("bsd,dhk->bshk", a, lp["wq"].astype(cdt))
+        ckr = jnp.einsum("bsd,dc->bsc", a, lp["wkv_a"].astype(cdt))
+        c = _rmsnorm(ckr[..., :r], lp["kv_g"], cfg.norm_eps)
+        k_rope = _rope(ckr[:, :, None, r:], rope["latent"], cfg.rope_interleave)  # (B, S, 1, rope_dim)
+        q_nope, q_rope = q[..., :dn], _rope(q[..., dn:], rope["latent"], cfg.rope_interleave)
+        if caches is None:
+            kv: Any = (c, k_rope[:, :, 0])
+            kv_h = jnp.einsum("bsc,hck->bshk", c, wkv_b)
+            k = jnp.concatenate([kv_h[..., :dn], jnp.broadcast_to(k_rope, (B, S, H, k_rope.shape[-1]))], axis=-1)
+            qf = jnp.concatenate([q_nope, q_rope], axis=-1)[:, :, :, None]  # every head a KV head of its own
+            o = _attend_rows_full(qf, k, kv_h[..., dn:], None)
+        else:
+            c_cache, r_cache = dict(caches[0]), dict(caches[1])
+            c_cache["latent"] = _write_cache_rows(c_cache["latent"], ls.mixer_index, c[:, 0], pos)
+            r_cache["latent"] = _write_cache_rows(r_cache["latent"], ls.mixer_index, k_rope[:, 0, 0], pos)
+            kv = (c_cache, r_cache)
+            cc, rc = c_cache["latent"][ls.mixer_index], r_cache["latent"][ls.mixer_index]  # (B, rows, r), (B, rows, rope_dim)
+            q_lat = jnp.einsum("bhk,hck->bhc", q_nope[:, 0], wkv_b[..., :dn])
+            s = (
+                jnp.einsum("bhc,bsc->bhs", q_lat, cc, preferred_element_type=jnp.float32)
+                + jnp.einsum("bhc,bsc->bhs", q_rope[:, 0], rc, preferred_element_type=jnp.float32)
+            ) * (1.0 / np.sqrt(qk_dim(cfg)))
+            ok = jnp.arange(cc.shape[1], dtype=jnp.int32)[None, :] <= pos.astype(jnp.int32)[:, None]
+            s = jnp.where(ok[:, None, :], s, -jnp.inf)
+            p = jax.nn.softmax(s, axis=-1)  # row 0 is always allowed: no row is all -inf
+            o_lat = jnp.einsum("bhs,bsc->bhc", p.astype(cc.dtype), cc)
+            o = jnp.einsum("bhc,hck->bhk", o_lat, wkv_b[..., dn:])[:, None]
         return jnp.einsum("bshk,hkd->bsd", o.astype(cdt), lp["wo"].astype(cdt)), kv
 
 
@@ -661,8 +768,9 @@ def mixed_block(
 
     ``caches`` None: the S rows are a sequence from its start (forward,
     prefill) and ``kv`` is what the mixer leaves of them: an attention
-    layer's ``(k, v)`` at its KV width, a state layer's ``(state, conv
-    tail)`` after the last real row. ``caches = (k_cache, v_cache)``:
+    layer's ``(k, v)`` at its KV width (a latent layer's ``(latents,
+    rotary keys)``), a state layer's ``(state, conv tail)`` after the last
+    real row. ``caches = (k_cache, v_cache)``:
     decode, S = 1 and ``pos`` (B,) each slot's position; the slot's K/V
     row, or its state and tail, are replaced in the caches and ``kv`` is
     the updated pair. A layer without a mixer hands back ``caches`` (None
@@ -674,6 +782,9 @@ def mixed_block(
     kv, stats = caches, jnp.zeros((3,), jnp.int32)
     if ls.mixer == "ssm":
         out, kv = _state_part(h, lp, ls, cfg, caches, valid)
+        h = h + out
+    elif ls.mixer == "latent":
+        out, kv = _latent_part(h, lp, ls, cfg, rope, pos, caches)
         h = h + out
     elif ls.mixer:
         out, kv = _attention_part(h, lp, ls, cfg, rope, pos, caches)
@@ -708,7 +819,8 @@ def mixed_rows(
 ) -> Tuple[jax.Array, Dict[str, Any], Dict[str, Any], jax.Array]:
     """The layers over tokens (B, S) with no cache: pre-final-norm hidden
     states, what the rows leave behind in its two halves by kind — K and V
-    ``{kind: (Lk, B, S, Hkv, d)}``, and under "ssm" the tuples of states
+    ``{kind: (Lk, B, S, Hkv, d)}`` (the latent kind's latents and rotary
+    keys ``(Lk, B, S, width)``), and under "ssm" the tuples of states
     and conv tails (:func:`write_prefill_rows` takes both) — and ``[pairs
     routed, pairs on held experts, held experts hit, rows, real rows]``
     (int32; the expert layers' ``moe_stats`` summed). ``true_len``

@@ -663,7 +663,8 @@ class DecodeEngine:
         elif config.mixed:
             # Caches side by side, one a mixer kind: the full layers keep
             # max_seq rows a slot, the window layers a ring of
-            # ring_rows(cfg), a state layer one state and its conv tail
+            # ring_rows(cfg), the latent layers max_seq latents and
+            # rotary keys, a state layer one state and its conv tail
             # (models/mixed.py).
             from ray_lightning_tpu.models.mixed import empty_caches
 
@@ -842,10 +843,14 @@ class DecodeEngine:
             "prefill": {"rows_scanned": 0, "rows_real": 0},
         }
         self._state_layers = 0
+        #: Layers whose decode read ``attn_totals`` counts: every layer of
+        #: a uniform configuration; of mixed layer kinds the latent ones.
+        self._attn_layers = config.n_layer
         if config.mixed:
             from ray_lightning_tpu.models.mixed import count_kind
 
             self._state_layers = count_kind(config, "ssm")
+            self._attn_layers = count_kind(config, "latent")
         #: What the decode steps' cached attention read, in cache rows
         #: summed over token steps and layers: the rows allocated to the
         #: slots, those the read visited and those of live requests'
@@ -2055,10 +2060,11 @@ class DecodeEngine:
 
     def cache_stats(self) -> Dict[str, Dict[str, int]]:
         """The dense per-request state by layer kind: layers, rows a slot,
-        bytes (K and V) and ``row_layout`` — whether a position's KV heads
-        lie side by side in one cache row, which is the read every decode
-        step of this engine then runs (models/gpt.py:_attend_layer_cache;
-        false under a mesh, where the heads keep an axis to shard). Every
+        bytes (K and V; a latent layer's latents and rotary keys) and
+        ``row_layout`` — whether a position's KV heads lie side by side
+        in one cache row, which is the read every decode step of this
+        engine then runs (models/gpt.py:_attend_layer_cache; false under
+        a mesh, where the heads keep an axis to shard). Every
         layer of a uniform configuration is ``full``; a state layer's kind
         is ``state``: one running state a slot whatever the request's
         length (``rows_per_slot`` 1), its bytes the recurrent states' and
@@ -2111,9 +2117,10 @@ class DecodeEngine:
         ``rows_allocated`` on the XLA read (every row of every slot is
         multiplied and masked afterwards); under the decode kernel
         (``ops/decode_attention.py``) it is the blocks up to each live
-        slot's position. ``{}`` for mixed layer kinds, whose caches differ
-        by kind (models/mixed.py reads every allocated row)."""
-        return {} if self.cfg.mixed else dict(self.attn_totals)
+        slot's position. Of mixed layer kinds, whose caches differ by
+        kind, the latent layers' rows are counted (models/mixed.py reads
+        every allocated row of them); ``{}`` without such a layer."""
+        return dict(self.attn_totals) if self._attn_layers else {}
 
     def ssm_stats(self) -> Dict[str, Any]:
         """``stats()["ssm"]``: what the state layers of a mixed
@@ -3834,8 +3841,8 @@ class DecodeEngine:
                     counts[key] = counts.get(key, 0) + 1
                 if done:
                     self._release_synced(slot, info)
-        if not self.cfg.mixed:
-            layers = self.cfg.n_layer
+        if self._attn_layers:
+            layers = self._attn_layers
             allocated = (
                 (toks.shape[0] // group) * layers * self.num_slots
                 * self.max_seq

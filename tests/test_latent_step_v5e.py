@@ -1,0 +1,122 @@
+"""Compile-only guard for a described ``v5e:2x2`` (no chip attached; nothing
+runs), beside ``tests/test_state_step_v5e.py`` and built as it builds its
+program, from the cell's own files.
+
+**The latent cell's decode fold** (``kanana-2-30b-a3b-d16-ep8.serve-docqa``:
+64 slots x 6656 positions, sixteen latent layers): a layer's latents are one
+array of 436 MB that a token step reads twice (scores, then the weighted
+sum) and writes one row a slot into. The fold donates the caches, the
+latents and the shared rotary keys are two arrays that each matmul takes
+where they lie, and ``lat_wkv_b`` is stored in the order both of its uses
+multiply in: temporaries of 0.03 GiB in a program of 10.7 GiB (PERF.md §4).
+As one array of rows ``[latent; key]`` the same fold did not fit the chip:
+the compiler kept a padded copy of the whole cache (8.1 GiB) and re-laid
+one layer out for the second matmul (0.46 GiB a layer and step). This is
+the guard that no such copy comes back.
+"""
+import os
+import re
+import time
+
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+GIB = 2**30
+CELL = "kanana-2-30b-a3b-d16-ep8.serve-docqa"
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    # see tests/test_decode_rows_v5e.py: this file asks for no lock of the TPU's library
+    os.environ.setdefault("ALLOW_MULTIPLE_LIBTPU_LOAD", "1")
+    import jax
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # A compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep it out.
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield topo.devices[0]
+    jax.config.update("jax_enable_compilation_cache", True)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def fold(v5e):
+    """The cell's decode fold, lowered as ``serve/engine.py`` lowers
+    ``step_impl``: ``(compiled, slots, positions, seconds it took)``."""
+    import sys
+
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    sys.path.insert(0, os.path.join(ROOT, "perfbench"))
+    try:
+        from pb import weights
+        from pb.spec import Spec
+    finally:
+        sys.path.remove(os.path.join(ROOT, "perfbench"))
+
+    from ray_lightning_tpu.models.gpt import GPTConfig, gpt_decode_fold
+    from ray_lightning_tpu.models.mixed import empty_caches
+
+    t0 = time.monotonic()
+    spec = Spec(ROOT)
+    cell = spec.cell(CELL)
+    cfg, rep = spec.config(cell["config"]), spec.traffic(cell["traffic"])["replica"]
+    dims = spec.dims(cfg)
+    pc = GPTConfig(**cfg["program_config"])
+    one, dt = SingleDeviceSharding(v5e), jnp.dtype(cfg["weights_dtype"])
+
+    def sds(shape, d):
+        return jax.ShapeDtypeStruct(shape, d, sharding=one)
+
+    shapes = weights.param_shapes(dims, pc.max_seq)
+    params = {k: sds(v[0], dt) for k, v in shapes.items() if k != "blocks"}
+    params["blocks"] = {k: sds(v[0], dt) for k, v in shapes["blocks"].items()}
+    B, S = int(rep["num_slots"]), int(rep["max_seq"])
+    assert (B, S) == (64, 6656), "the sizes below are this cell's"
+    k_cache, v_cache = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype), jax.eval_shape(lambda: empty_caches(pc, B, S, dt)))
+    assert k_cache["latent"].shape == (16, B, S, 512) and v_cache["latent"].shape == (16, B, S, 64)
+    i32, f32 = (lambda: sds((B,), jnp.int32)), (lambda: sds((B,), jnp.float32))
+
+    def step(params, k_cache, v_cache, cur, pos, temps, top_ks, top_ps, keys, active, remaining, eos):
+        return gpt_decode_fold(params, pc, cur, pos, keys, temps, top_ks, top_ps, active, remaining, eos,
+                               k_cache, v_cache, fold=int(rep["decode_fold"]))
+
+    # donated as serve/engine.py donates them: caches and the state the fold moves
+    compiled = jax.jit(step, donate_argnums=(1, 2, 3, 4, 8, 9, 10)).lower(
+        params, k_cache, v_cache, i32(), i32(), f32(), i32(), f32(), sds((B, 2), jnp.uint32),
+        sds((B,), jnp.bool_), i32(), i32(),
+    ).compile()
+    return compiled, B, S, time.monotonic() - t0
+
+
+def test_the_latent_cells_decode_fold_keeps_its_sizes(fold):
+    compiled, B, S, took = fold
+    m = compiled.memory_analysis()
+    whole = m.argument_size_in_bytes + m.output_size_in_bytes - m.alias_size_in_bytes + m.temp_size_in_bytes
+    cache = 16 * B * S * (512 + 64) * 2
+    print(f"latent cell's decode fold at {B} x {S}: temporaries {m.temp_size_in_bytes / GIB:.3f} GiB, "
+          f"whole program {whole / GIB:.2f} GiB, built in {took:.0f} s")
+    assert m.alias_size_in_bytes >= cache  # the caches are updated where they lie
+    assert m.temp_size_in_bytes < 0.3 * GIB  # 0.027 read; one layer's latents copied would be 0.41 more
+    assert whole < 11.2 * GIB  # 10.70 read: 3.36 of weights, 7.31 of latents and keys
+    assert took < 300, "the guard's own time limit: 35 s read"
+
+
+def test_the_fold_copies_no_latent_layers_cache(fold):
+    """No instruction of the compiled fold copies or transposes an array the
+    size of one layer's latents or keys, or of the stack of them."""
+    compiled, B, S, _ = fold
+    size = re.compile(rf"= bf16\[(16,)?{B},{S},(512|64)\]\S* (copy|transpose)\(")
+    hits = [ln.strip()[:160] for ln in compiled.as_text().splitlines() if size.search(ln)]
+    assert not hits, hits
