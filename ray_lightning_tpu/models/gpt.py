@@ -1449,21 +1449,30 @@ def _kv_head_of_group(n_kv_head: int) -> jax.Array:
 def _decode_rows_block(
     cfg: GPTConfig,
     q_len: int,
-    k_cache: jax.Array,
-    v_cache: jax.Array,
+    k_cache: Any,
+    v_cache: Any,
     backend: Optional[str] = None,
 ) -> int:
     """Which read a cached attention takes, from what it can observe: the
-    rows of a K / V block of the decode kernel
-    (``ops/decode_attention.py``), or 0 for the XLA read. The kernel wants
-    a stacked cache of rows ``(L, B, S, Hkv * hd)``, one query row a slot,
-    ``attn_impl="flash"``, a TPU (elsewhere it would run interpreted: the
-    engine's token-identity tests compare two XLA reads in one order of
-    sums) and shapes Mosaic takes (``decode_block``: row widths a multiple
-    of 128, a block that divides S). ``serve/engine.py`` asks the same
+    rows of a block of the decode kernel (``ops/decode_attention.py``), or
+    0 for the XLA read. The kernel wants a stacked cache of rows
+    ``(L, B, S, Hkv * hd)`` — or, the caches being the dicts by kind of
+    mixed layers (models/mixed.py), the ``"latent"`` kind's pair: latents
+    ``(L, B, S, rank)`` and rotary keys ``(L, B, S, rope)`` —, one query
+    row a slot, ``attn_impl="flash"``, a TPU (elsewhere it would run
+    interpreted: the engine's token-identity tests compare two XLA reads
+    in one order of sums) and shapes Mosaic takes (``decode_block``: row
+    widths a multiple of 128, a block that divides S). A mixed
+    configuration's K/V kinds keep their XLA read
+    (models/mixed.py:_attend_cache). ``serve/engine.py`` asks the same
     question for its ``stats()["attn"]`` counters."""
     from ray_lightning_tpu.ops.decode_attention import decode_block
 
+    latent = isinstance(k_cache, dict)
+    if latent:
+        if "latent" not in k_cache:
+            return 0
+        k_cache, v_cache = k_cache["latent"], v_cache["latent"]
     if (
         k_cache.ndim != 4
         or q_len != 1
@@ -1471,7 +1480,7 @@ def _decode_rows_block(
         or (backend or jax.default_backend()) != "tpu"
     ):
         return 0
-    return decode_block(k_cache.shape[2], k_cache.shape[3], v_cache.shape[3])
+    return decode_block(k_cache.shape[2], k_cache.shape[3], v_cache.shape[3], latent=latent)
 
 
 def _attend_layer_cache(
@@ -1610,8 +1619,9 @@ def gpt_decode_step(
     through a scan (:func:`gpt_decode_fold`) has them updated in place.
 
     ``active`` (B,) bool, default all, says which slots hold a live
-    request. Only the decode kernel looks at it (a cache of rows under
-    ``attn_impl="flash"`` on a TPU: :func:`_attend_layer_cache`): a slot
+    request. Only the decode kernel looks at it (a cache of rows, or a
+    latent layer's pair, under ``attn_impl="flash"`` on a TPU:
+    :func:`_attend_layer_cache`, models/mixed.py:_attend_latent_cache): a slot
     that is not active has none of its cache rows read and its logits are
     whatever the rest of the step makes of a zero attention output — the
     caller discards them, as :func:`gpt_decode_fold` does. Its cache write

@@ -42,7 +42,13 @@ and in the second the rotary key all heads share, already rotated,
 where K and V would be ``n_head * (qk + v)``. (As ONE array of rows
 ``[latent; key]`` the chip's compiler re-laid a layer out for the second
 of the two matmuls that read it and kept a padded copy of the whole:
-compile-only for the v5e, PR 38.) A K/V attention kind has
+compile-only for the v5e, PR 38. Keys of 64, half a lane tile, the
+chip's compiler keeps with the positions minor, ``{2,3,1,0}``, to pad
+nothing: the decode kernel, whose operands are row-major, is handed
+``swapaxes(keys, 2, 3)``, which is those bytes as they lie and no copy,
+and copies ``(rope_dim, block)``. PR 39;
+``tests/test_latent_step_v5e.py`` guards it.)
+A K/V attention kind has
 one stacked array, ``{"full": ...,
 "window": ...}``, ``(Lk, B, rows, Hkv * d)`` with rows ``S`` or ``R`` and
 ``d`` the q·k width for K and the v width for V: a position's KV heads
@@ -235,7 +241,9 @@ def refuse_mixed(cfg: Any, mechanism: str) -> None:
         latent = (
             "; a latent layer keeps one row of kv_lora_rank + rope_dim values "
             "a position and no K / V pair: the page, pool, export and wire "
-            "formats (serve/kvstore.py, serve/kvfleet.py) have no such row"
+            "formats (serve/kvstore.py, serve/kvfleet.py) have no such row, "
+            "and its decode read (ops/decode_attention.py) takes one query "
+            "row a slot against whole slots' rows"
             if count_kind(cfg, "latent") else ""
         )
         raise ValueError(
@@ -654,7 +662,51 @@ def _attention_part(h, lp, ls, cfg, rope, pos, caches):
         return jnp.einsum("bshk,hkd->bsd", o.astype(cdt), lp["wo"].astype(cdt)), kv
 
 
-def _latent_part(h, lp, ls, cfg, rope, pos, caches):
+def _attend_latent_cache(cfg, q_lat, q_rope, c_cache, r_cache, li, pos, live=None):
+    """One query row a slot against latent layer ``li`` of the caches (the
+    dicts by kind) after this step's write: ``q_lat`` (B, H, rank) the
+    queries taken into the latent, ``q_rope`` (B, H, rope_dim) their
+    rotated parts, ``pos`` (B,) the queries' positions; the weighted
+    latents ``softmax((q_lat · c + q_rope · k_r) / sqrt(qk)) · c`` over each
+    slot's positions ``0 .. pos``, (B, H, rank) in the compute dtype.
+    ``models/gpt.py:_decode_rows_block`` says which read:
+
+    - the XLA read: two matmuls over all ``S`` allocated rows of every
+      slot, the scores, then the weighted sum — every latent is read
+      twice whatever is live —, masked in between; ``live`` does not
+      reach it;
+    - one query row, ``attn_impl="flash"``, on a TPU: the decode kernel
+      (``ops/decode_attention.py:latent_decode_attention``), which walks
+      the slots that are ``live``, copies only the row blocks up to each
+      one's position, each once for both products, and gives zeros for a
+      slot that is not live. The same sums, the softmax's blockwise; p is
+      float32 up to the product, whose operands the MXU takes rounded to
+      bfloat16 (Mosaic's default contraction: PERF.md §6, PR 39) — what
+      the XLA read's cast of p to the cache's dtype does."""
+    from ray_lightning_tpu.models.gpt import _decode_rows_block
+
+    scale = 1.0 / np.sqrt(qk_dim(cfg))
+    block = _decode_rows_block(cfg, 1, c_cache, r_cache)
+    if block:
+        from ray_lightning_tpu.ops.decode_attention import latent_decode_attention
+
+        # the keys with the positions minor: the bytes as the chip's compiler keeps 64-wide rows, so no copy
+        return latent_decode_attention(
+            q_lat, q_rope, c_cache["latent"], jnp.swapaxes(r_cache["latent"], 2, 3), li, pos, live,
+            scale=scale, block=block,
+        ).astype(q_lat.dtype)
+    cc, rc = c_cache["latent"][li], r_cache["latent"][li]  # (B, rows, rank), (B, rows, rope_dim)
+    s = (
+        jnp.einsum("bhc,bsc->bhs", q_lat, cc, preferred_element_type=jnp.float32)
+        + jnp.einsum("bhc,bsc->bhs", q_rope, rc, preferred_element_type=jnp.float32)
+    ) * scale
+    ok = jnp.arange(cc.shape[1], dtype=jnp.int32)[None, :] <= pos.astype(jnp.int32)[:, None]
+    s = jnp.where(ok[:, None, :], s, -jnp.inf)
+    p = jax.nn.softmax(s, axis=-1)  # row 0 is always allowed: no row is all -inf
+    return jnp.einsum("bhs,bsc->bhc", p.astype(cc.dtype), cc)
+
+
+def _latent_part(h, lp, ls, cfg, rope, pos, caches, live=None):
     """``(a latent layer's write into the residual, kv)``. Every head's keys
     and values are projections ``wkv_b`` of one normed latent ``c`` a
     position, beside one rotary key the heads share: ``s_h = (q_nope,h ·
@@ -667,7 +719,10 @@ def _latent_part(h, lp, ls, cfg, rope, pos, caches):
     sums in another order): ``q^_h = wkv_b[K,h] q_nope,h`` scores against
     the cached latents as they lie, ``p`` weighs the latents themselves,
     and ``wkv_b[V,h]`` takes each head's weighted latent to its values. No
-    key or value of a cached position is ever built."""
+    key or value of a cached position is ever built. The read between the
+    two is :func:`_attend_latent_cache`'s: on a TPU the decode kernel over
+    the ``live`` slots' positions, elsewhere XLA's over every allocated
+    row."""
     from ray_lightning_tpu.models.gpt import _rmsnorm, _write_cache_rows
 
     cdt = jnp.dtype(cfg.compute_dtype)
@@ -692,16 +747,8 @@ def _latent_part(h, lp, ls, cfg, rope, pos, caches):
             c_cache["latent"] = _write_cache_rows(c_cache["latent"], ls.mixer_index, c[:, 0], pos)
             r_cache["latent"] = _write_cache_rows(r_cache["latent"], ls.mixer_index, k_rope[:, 0, 0], pos)
             kv = (c_cache, r_cache)
-            cc, rc = c_cache["latent"][ls.mixer_index], r_cache["latent"][ls.mixer_index]  # (B, rows, r), (B, rows, rope_dim)
             q_lat = jnp.einsum("bhk,hck->bhc", q_nope[:, 0], wkv_b[..., :dn])
-            s = (
-                jnp.einsum("bhc,bsc->bhs", q_lat, cc, preferred_element_type=jnp.float32)
-                + jnp.einsum("bhc,bsc->bhs", q_rope[:, 0], rc, preferred_element_type=jnp.float32)
-            ) * (1.0 / np.sqrt(qk_dim(cfg)))
-            ok = jnp.arange(cc.shape[1], dtype=jnp.int32)[None, :] <= pos.astype(jnp.int32)[:, None]
-            s = jnp.where(ok[:, None, :], s, -jnp.inf)
-            p = jax.nn.softmax(s, axis=-1)  # row 0 is always allowed: no row is all -inf
-            o_lat = jnp.einsum("bhs,bsc->bhc", p.astype(cc.dtype), cc)
+            o_lat = _attend_latent_cache(cfg, q_lat, q_rope[:, 0], c_cache, r_cache, ls.mixer_index, pos, live)
             o = jnp.einsum("bhc,hck->bhk", o_lat, wkv_b[..., dn:])[:, None]
         return jnp.einsum("bshk,hkd->bsd", o.astype(cdt), lp["wo"].astype(cdt)), kv
 
@@ -784,7 +831,7 @@ def mixed_block(
         out, kv = _state_part(h, lp, ls, cfg, caches, valid)
         h = h + out
     elif ls.mixer == "latent":
-        out, kv = _latent_part(h, lp, ls, cfg, rope, pos, caches)
+        out, kv = _latent_part(h, lp, ls, cfg, rope, pos, caches, None if valid is None else valid[:, 0])
         h = h + out
     elif ls.mixer:
         out, kv = _attention_part(h, lp, ls, cfg, rope, pos, caches)

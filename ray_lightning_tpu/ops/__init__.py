@@ -9,7 +9,8 @@ The reference has no kernels of its own — its hot loop is torch/NCCL
 - ``decode_attention``: Pallas decode kernel over a cache of rows — one
   query row a slot, only the row blocks up to each live slot's position
   (imported from its module by ``models/gpt.py``: the function shares
-  the module's name).
+  the module's name) — and its twin over a latent layer's latents and
+  shared rotary keys, ``latent_decode_attention`` (``models/mixed.py``).
 - ``ring_attention``: sequence-parallel blockwise attention over a mesh
   axis (ICI ``ppermute`` ring) for long-context training.
 - ``zigzag_attention``: load-balanced causal ring attention — zigzag chunk

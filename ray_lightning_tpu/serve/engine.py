@@ -860,10 +860,11 @@ class DecodeEngine:
             "rows_allocated": 0, "rows_visited": 0, "rows_live": 0,
         }
         #: Rows of the decode kernel's block when the fold's read is the
-        #: kernel (models/gpt.py:_decode_rows_block), else 0: the XLA read
-        #: visits every allocated row.
+        #: kernel (models/gpt.py:_decode_rows_block: a uniform cache of
+        #: rows, or the latent layers' of mixed kinds), else 0: the XLA
+        #: read visits every allocated row.
         self._attn_block = 0
-        if self._k is not None and not config.mixed and self.spec == "off":
+        if self._k is not None and self.spec == "off":
             from ray_lightning_tpu.models.gpt import _decode_rows_block
 
             self._attn_block = _decode_rows_block(
@@ -2118,8 +2119,9 @@ class DecodeEngine:
         multiplied and masked afterwards); under the decode kernel
         (``ops/decode_attention.py``) it is the blocks up to each live
         slot's position. Of mixed layer kinds, whose caches differ by
-        kind, the latent layers' rows are counted (models/mixed.py reads
-        every allocated row of them); ``{}`` without such a layer."""
+        kind, the latent layers' rows are counted (read by the same
+        kernel on a TPU: models/mixed.py:_latent_part); ``{}`` without
+        such a layer."""
         return dict(self.attn_totals) if self._attn_layers else {}
 
     def ssm_stats(self) -> Dict[str, Any]:

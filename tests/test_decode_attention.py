@@ -4,6 +4,9 @@ to rounding over the dense families' head layouts, at the positions where a
 block count can be off by one, and — blocks past a slot's position filled
 with NaN — the proof that the kernel reads no row that holds no live
 position. Mosaic's own view of the kernel is ``tests/test_decode_rows_v5e.py``.
+The second half does the same for the latent kind's call
+(``latent_decode_attention`` against ``models/mixed.py:_attend_latent_cache``'s
+XLA read), whose view by Mosaic is ``tests/test_latent_step_v5e.py``.
 """
 import numpy as np
 import pytest
@@ -185,3 +188,201 @@ def test_decode_step_with_the_kernel_gives_the_xla_steps_logits(variant, monkeyp
     for a, b in zip(got[1:], want[1:]):
         # layer 0's write is the same; layer 1's differs by the read's rounding, and only in the live slots' rows
         np.testing.assert_allclose(np.asarray(a)[:, :2], np.asarray(b)[:, :2], atol=2e-4, rtol=0)
+
+
+# -- the latent kind: one row a position is keys and values of every head --------------------
+#: name -> (layers, rows, latent width, rotary width, block): a cut shape, and the docqa cell's rows and widths
+LATENT_SHAPES = {"cut_384x128+64": (2, 384, 128, 64, 128), "cell_6656x512+64": (1, 6656, 512, 64, 512)}
+
+
+def _latent_cfg(attn_impl="flash"):
+    from ray_lightning_tpu.models.gpt import GPTConfig
+
+    # only the scale (1 / sqrt(192)) and attn_impl reach the read
+    return GPTConfig(qk_head_dim=192, attn_impl=attn_impl)
+
+
+def _latent_positions(S, block):
+    return {"row0": 0, "block_last_row": block - 1, "block_first_row": block, "cache_last_row": S - 1,
+            "inside": 2 * block + 37}
+
+
+def _latent_inputs(shape, pos, seed=0, heads=4):
+    import jax
+    import jax.numpy as jnp
+
+    L, S, rank, rope, _ = LATENT_SHAPES[shape]
+    B = len(pos)
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    q_lat = jax.random.normal(ks[0], (B, heads, rank), jnp.float32) / np.sqrt(rank)
+    q_rope = jax.random.normal(ks[1], (B, heads, rope), jnp.float32)
+    cc = {"latent": jax.random.normal(ks[2], (L, B, S, rank), jnp.float32)}
+    rc = {"latent": jax.random.normal(ks[3], (L, B, S, rope), jnp.float32)}
+    return q_lat, q_rope, cc, rc, jnp.asarray(pos, jnp.int32)
+
+
+def _latent_read(monkeypatch, kernel: bool):
+    from ray_lightning_tpu.models import mixed as M
+    from tests.utils import force_decode_kernel
+
+    if kernel:
+        force_decode_kernel(monkeypatch)
+    return M._attend_latent_cache
+
+
+@pytest.mark.parametrize("where", ["row0", "block_last_row", "block_first_row", "cache_last_row", "inside"])
+@pytest.mark.parametrize("shape", sorted(LATENT_SHAPES))
+def test_latent_kernel_equals_the_xla_read_of_latents(shape, where, monkeypatch):
+    """``models/mixed.py:_attend_latent_cache``: slot 0 at the named position,
+    slot 1 elsewhere, slot 2 NOT live (zeros from the kernel), slot 3 behind
+    an idle one."""
+    import jax.numpy as jnp
+
+    from ray_lightning_tpu.models import gpt as G
+
+    L, S, rank, _, block = LATENT_SHAPES[shape]
+    cfg = _latent_cfg()
+    q_lat, q_rope, cc, rc, pos = _latent_inputs(shape, [_latent_positions(S, block)[where], 200, 300, 5])
+    live = jnp.asarray([True, True, False, True])
+    want = _latent_read(monkeypatch, kernel=False)(cfg, q_lat, q_rope, cc, rc, L - 1, pos)
+    assert G._decode_rows_block(cfg, 1, cc, rc) == 0, "off the TPU the engine keeps the XLA read"
+    got = _latent_read(monkeypatch, kernel=True)(cfg, q_lat, q_rope, cc, rc, L - 1, pos, live)
+    assert G._decode_rows_block(cfg, 1, cc, rc) == block
+    assert got.shape == want.shape == (4, 4, rank) and got.dtype == want.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(got)[[0, 1, 3]], np.asarray(want)[[0, 1, 3]], atol=1e-5, rtol=0)
+    assert not np.asarray(got)[2].any()
+
+
+def _latent_poisoned(cc, rc, pos, live, block):
+    """Every block that lies wholly past a slot's position, and every
+    position of a slot that is not live, NaN in the latents and the keys."""
+    import jax.numpy as jnp
+
+    S = cc["latent"].shape[2]
+    first_dead = jnp.where(live, (pos // block + 1) * block, 0)
+    dead = jnp.arange(S)[None, :] >= first_dead[:, None]  # (B, S)
+    return (
+        {"latent": jnp.where(dead[None, :, :, None], jnp.nan, cc["latent"])},
+        {"latent": jnp.where(dead[None, :, :, None], jnp.nan, rc["latent"])},
+    )
+
+
+def test_latent_blocks_past_a_slots_position_are_not_read(monkeypatch):
+    """The XLA read multiplies every allocated latent, so a NaN behind a
+    slot's position reaches its output (0 x NaN); the kernel returns the
+    clean cache's result."""
+    import jax.numpy as jnp
+
+    shape, block = "cut_384x128+64", 128
+    cfg = _latent_cfg()
+    q_lat, q_rope, cc, rc, pos = _latent_inputs(shape, [0, block - 1, 300, block, 2 * block - 1])
+    live = jnp.asarray([True, True, False, True, True])
+    pc, pr = _latent_poisoned(cc, rc, pos, live, block)
+    assert bool(jnp.isnan(pc["latent"][:, 0, block:]).all()) and not bool(jnp.isnan(pc["latent"][:, 0, :block]).any())
+    assert bool(jnp.isnan(pr["latent"][:, 0, block:]).all()) and not bool(jnp.isnan(pr["latent"][:, 0, :block]).any())
+    xla = _latent_read(monkeypatch, kernel=False)
+    assert np.isnan(np.asarray(xla(cfg, q_lat, q_rope, pc, pr, 0, pos))).any()
+    want = xla(cfg, q_lat, q_rope, cc, rc, 0, pos)
+    got = _latent_read(monkeypatch, kernel=True)(cfg, q_lat, q_rope, pc, pr, 0, pos, live)
+    assert np.isfinite(np.asarray(got)).all()
+    keep = np.asarray(live)
+    np.testing.assert_allclose(np.asarray(got)[keep], np.asarray(want)[keep], atol=1e-5, rtol=0)
+
+
+@pytest.mark.parametrize("off", [-1, 1], ids=["one_block_short", "one_block_far"])
+def test_a_latent_block_count_off_by_one_fails(off, monkeypatch):
+    """The planted fault of the walk both kernels share, seen through the
+    latent one: a block short, the newest latents go unread; a block far, a
+    dead block's NaN reaches the output."""
+    import jax.numpy as jnp
+
+    from ray_lightning_tpu.ops import decode_attention as D
+
+    shape, block = "cut_384x128+64", 128
+    real = D._last_block
+    monkeypatch.setattr(
+        D, "_last_block", lambda pos, block, seq: jnp.clip(real(pos, block, seq) + off, 0, seq // block - 1))
+    cfg = _latent_cfg()
+    q_lat, q_rope, cc, rc, pos = _latent_inputs(shape, [block + 3, block + 90])
+    live = jnp.asarray([True, True])
+    pc, pr = _latent_poisoned(cc, rc, pos, live, block)
+    want = _latent_read(monkeypatch, kernel=False)(cfg, q_lat, q_rope, cc, rc, 0, pos)
+    got = np.asarray(_latent_read(monkeypatch, kernel=True)(cfg, q_lat, q_rope, pc, pr, 0, pos, live))
+    if off > 0:
+        assert np.isnan(got).any()
+    else:
+        assert np.isfinite(got).all() and np.abs(got - np.asarray(want)).max() > 1e-2
+
+
+def test_a_latent_block_is_copied_once_for_both_products(monkeypatch):
+    """The kernel's structure, counted while it is traced: each of the three
+    places that fetch a block (the first live slot's first, a slot's next,
+    the next live slot's first — one of them runs a block) starts ONE copy
+    of the latents and one of the keys, a block's step waits for each once,
+    and the latents' buffer is loaded once for the scores and ``p · c``."""
+    import jax.numpy as jnp
+
+    from ray_lightning_tpu.ops import decode_attention as D
+
+    started, waited, loaded = [], [], []
+    real = D.pltpu.make_async_copy
+
+    class Counted:
+        def __init__(self, src, dst, sem):
+            self.copy = real(src, dst, sem)
+
+        def start(self):
+            started.append(self)
+            self.copy.start()
+
+        def wait(self):
+            waited.append(self)
+            self.copy.wait()
+
+    monkeypatch.setattr(D.pltpu, "make_async_copy", Counted)
+    kernel = D._latent_kernel
+
+    def spy(pos_ref, next_ref, ql_ref, qr_ref, c_hbm, r_hbm, o_ref, c_buf, r_buf, sem, **kw):
+        class Loads:  # the latents' buffer, its loads counted
+            shape, dtype = c_buf.shape, c_buf.dtype
+
+            def __getitem__(self, i):
+                loaded.append(i)
+                return c_buf[i]
+
+            at = c_buf.at
+
+        return kernel(pos_ref, next_ref, ql_ref, qr_ref, c_hbm, r_hbm, o_ref, Loads(), r_buf, sem, **kw)
+
+    monkeypatch.setattr(D, "_latent_kernel", spy)
+    shape, block = "cut_384x128+64", 128
+    q_lat, q_rope, cc, rc, pos = _latent_inputs(shape, [block + 3, 5])
+    D.latent_decode_attention(q_lat, q_rope, cc["latent"], jnp.swapaxes(rc["latent"], 2, 3), 0, pos,
+                              jnp.asarray([True, True]), scale=0.1, interpret=True)
+    # a pair a place: the latents' copy and the keys'
+    assert len(started) == 2 * 3 and len(waited) == 2 * 1
+    assert len(loaded) == 1
+
+
+@pytest.mark.parametrize("case", [
+    "cpu", "attn_impl_reference", "verify_q3", "latent_width_96", "rope_width_48", "rows_100", "no_latent_kind",
+])
+def test_everything_else_keeps_the_xla_read_of_latents(case):
+    """What the selection observes of a mixed configuration's caches, one
+    condition a case; ``"tpu"`` and the cell's shapes give the block."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_lightning_tpu.models import gpt as G
+
+    cfg = _latent_cfg("reference" if case == "attn_impl_reference" else "flash")
+    rows = 100 if case == "rows_100" else 6656
+    rank, rope = (96 if case == "latent_width_96" else 512), (48 if case == "rope_width_48" else 64)
+    kind = "full" if case == "no_latent_kind" else "latent"
+    cc = {kind: jax.ShapeDtypeStruct((16, 64, rows, rank), jnp.bfloat16)}
+    rc = {kind: jax.ShapeDtypeStruct((16, 64, rows, rope), jnp.bfloat16)}
+    q_len = 3 if case == "verify_q3" else 1
+    assert G._decode_rows_block(cfg, q_len, cc, rc, backend=None if case == "cpu" else "tpu") == 0
+    sound = {"latent": jax.ShapeDtypeStruct((16, 64, 6656, 512), jnp.bfloat16)}, {
+        "latent": jax.ShapeDtypeStruct((16, 64, 6656, 64), jnp.bfloat16)}
+    assert G._decode_rows_block(_latent_cfg(), 1, *sound, backend="tpu") == 512

@@ -163,3 +163,80 @@ def test_the_replica_serves_it_and_reports_the_latent_rows(params, monkeypatch):
         assert f'rlt_serve_kv_bytes{{kind="latent"}} {cache["latent"]["bytes"]}\n' in text
     finally:
         rep.stop()
+
+
+# -- the decode kernel under the latent layers (ops/decode_attention.py) ---------------------------------
+#: LATENT at widths Mosaic takes: latents of 128, rotary keys of 64 (half a lane tile), three blocks of 128 positions a slot
+KERNEL = dict(LATENT, kv_lora_rank=128, rope_dim=64, qk_head_dim=72, max_seq=384)
+
+
+def test_a_decode_step_through_the_kernel_gives_the_xla_steps_logits(monkeypatch):
+    """The whole token step (projections, rotary, the two cache writes, the
+    read, the experts): logits of the live slots and both caches, to the
+    tolerance of the GPT twin (tests/test_decode_attention.py)."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_lightning_tpu.models import gpt as G
+    from ray_lightning_tpu.models.mixed import empty_caches
+    from tests.utils import force_decode_kernel
+
+    cfg = GPTConfig(**dict(KERNEL, compute_dtype="float32"))
+    params = init_gpt_params(jax.random.PRNGKey(1), cfg)
+    B = 3
+    ks = jax.random.split(jax.random.PRNGKey(2), 2)
+    kc, vc = empty_caches(cfg, B, 384, jnp.float32)  # three blocks of 128: 256 does not divide it
+    kc = {"latent": 0.3 * jax.random.normal(ks[0], kc["latent"].shape, jnp.float32)}
+    vc = {"latent": 0.3 * jax.random.normal(ks[1], vc["latent"].shape, jnp.float32)}
+    cur = jnp.asarray([5, 17, 44], jnp.int32)
+    pos = jnp.asarray([128, 383, 9], jnp.int32)  # a block's first row, the cache's last, and a slot that is not live
+    active = jnp.asarray([True, True, False])
+    want = G.gpt_decode_step(params, cfg, cur, pos, kc, vc, active)
+    assert G._decode_rows_block(cfg, 1, kc, vc) == 0
+    force_decode_kernel(monkeypatch)
+    assert G._decode_rows_block(cfg, 1, kc, vc) == 128
+    got = G.gpt_decode_step(params, cfg, cur, pos, kc, vc, active)
+    np.testing.assert_allclose(np.asarray(got[0])[:2], np.asarray(want[0])[:2], atol=2e-4, rtol=0)
+    for a, b in zip(got[1:], want[1:]):
+        # layer 0's write is the same; the later layers' differ by the read's rounding, in the live slots' positions
+        np.testing.assert_allclose(np.asarray(a["latent"])[:, :2], np.asarray(b["latent"])[:, :2], atol=2e-4, rtol=0)
+
+
+@pytest.mark.parametrize("read", ["xla", "kernel"])
+def test_the_replicas_attn_counter_says_which_read_the_fold_takes(read, monkeypatch):
+    """``stats()["attn"]``: under the kernel ``rows_visited`` is the blocks
+    up to each live slot's position, under the XLA read every allocated
+    row. The engine asks the selection the fold asks."""
+    import jax
+
+    from ray_lightning_tpu.obs import registry
+    from ray_lightning_tpu.serve.server import ServeReplica
+    from tests.utils import force_decode_kernel
+
+    own = registry.MetricsRegistry()
+    monkeypatch.setattr(registry, "get_registry", lambda: own)
+    if read == "kernel":
+        force_decode_kernel(monkeypatch)
+    params = init_gpt_params(jax.random.PRNGKey(0), GPTConfig(**KERNEL))
+    rep = ServeReplica(params=params, model_config=dict(KERNEL), num_slots=3, max_seq=384,
+                       prefill_buckets=[16, 128], decode_fold=4, watchdog=False)
+    try:
+        rng = np.random.default_rng(1)
+        sizes = (10, 120, 3)  # the second crosses the first block's end while it decodes
+        rids = [rep.submit(rng.integers(0, 96, size=n).tolist(), max_new_tokens=20) for n in sizes]
+        deadline = time.monotonic() + 240
+        for rid in rids:
+            while not rep.result(rid, wait_s=0.2)["done"]:
+                assert time.monotonic() < deadline, "request did not finish"
+        attn = rep.stats()["attn"]
+        steps = [n + j for n in sizes for j in range(1, 20)]  # rows 0 .. pos each decode step's query saw
+        assert attn["rows_live"] == 3 * sum(steps)
+        assert attn["rows_allocated"] % (3 * 3 * 384 * 4) == 0 and attn["rows_allocated"] > 0
+        if read == "kernel":
+            assert attn["rows_visited"] == 3 * sum(-(-rows // 128) * 128 for rows in steps)
+            assert attn["rows_live"] < attn["rows_visited"] < attn["rows_allocated"]
+        else:
+            assert attn["rows_visited"] == attn["rows_allocated"]
+        assert rep.stats()["compiles_since_init"] == 0
+    finally:
+        rep.stop()

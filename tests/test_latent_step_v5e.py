@@ -12,7 +12,12 @@ multiply in: temporaries of 0.03 GiB in a program of 10.7 GiB (PERF.md §4).
 As one array of rows ``[latent; key]`` the same fold did not fit the chip:
 the compiler kept a padded copy of the whole cache (8.1 GiB) and re-laid
 one layer out for the second matmul (0.46 GiB a layer and step). This is
-the guard that no such copy comes back.
+the guard that no such copy comes back — under the XLA read and under the
+decode kernel (``ops/decode_attention.py:latent_decode_attention``), which
+takes both stacked caches whole and the rotary keys with the positions
+minor (as rows of 64 Mosaic refuses the slice): the compiler keeps the
+64-wide rows ``{2,3,1,0}``, so the ``swapaxes`` that hands them over has to
+stay a bitcast of the bytes and not 0.87 GB re-laid out a call.
 """
 import os
 import re
@@ -47,10 +52,14 @@ def v5e():
     cc.reset_cache()
 
 
-@pytest.fixture(scope="module")
-def fold(v5e):
+@pytest.fixture(scope="module", params=["xla", "kernel"])
+def fold(v5e, request):
     """The cell's decode fold, lowered as ``serve/engine.py`` lowers
-    ``step_impl``: ``(compiled, slots, positions, seconds it took)``."""
+    ``step_impl``: ``(compiled, slots, positions, seconds it took, read)``.
+    ``xla``: the read the engine takes off the TPU (``jax.default_backend()``
+    is the CPU here); ``kernel``: the read it takes on the chip — the
+    fixture says "tpu" where the program asks, as
+    ``tests/test_decode_rows_v5e.py`` does."""
     import sys
 
     import jax
@@ -67,6 +76,9 @@ def fold(v5e):
     from ray_lightning_tpu.models.gpt import GPTConfig, gpt_decode_fold
     from ray_lightning_tpu.models.mixed import empty_caches
 
+    mp = pytest.MonkeyPatch()
+    if request.param == "kernel":
+        mp.setattr(jax, "default_backend", lambda: "tpu")
     t0 = time.monotonic()
     spec = Spec(ROOT)
     cell = spec.cell(CELL)
@@ -93,30 +105,41 @@ def fold(v5e):
                                k_cache, v_cache, fold=int(rep["decode_fold"]))
 
     # donated as serve/engine.py donates them: caches and the state the fold moves
-    compiled = jax.jit(step, donate_argnums=(1, 2, 3, 4, 8, 9, 10)).lower(
-        params, k_cache, v_cache, i32(), i32(), f32(), i32(), f32(), sds((B, 2), jnp.uint32),
-        sds((B,), jnp.bool_), i32(), i32(),
-    ).compile()
-    return compiled, B, S, time.monotonic() - t0
+    try:
+        compiled = jax.jit(step, donate_argnums=(1, 2, 3, 4, 8, 9, 10)).lower(
+            params, k_cache, v_cache, i32(), i32(), f32(), i32(), f32(), sds((B, 2), jnp.uint32),
+            sds((B,), jnp.bool_), i32(), i32(),
+        ).compile()
+    finally:
+        mp.undo()
+    return compiled, B, S, time.monotonic() - t0, request.param
 
 
 def test_the_latent_cells_decode_fold_keeps_its_sizes(fold):
-    compiled, B, S, took = fold
+    compiled, B, S, took, read = fold
     m = compiled.memory_analysis()
     whole = m.argument_size_in_bytes + m.output_size_in_bytes - m.alias_size_in_bytes + m.temp_size_in_bytes
     cache = 16 * B * S * (512 + 64) * 2
-    print(f"latent cell's decode fold at {B} x {S}: temporaries {m.temp_size_in_bytes / GIB:.3f} GiB, "
+    print(f"latent cell's decode fold at {B} x {S}, {read} read: temporaries {m.temp_size_in_bytes / GIB:.3f} GiB, "
           f"whole program {whole / GIB:.2f} GiB, built in {took:.0f} s")
     assert m.alias_size_in_bytes >= cache  # the caches are updated where they lie
     assert m.temp_size_in_bytes < 0.3 * GIB  # 0.027 read; one layer's latents copied would be 0.41 more
     assert whole < 11.2 * GIB  # 10.70 read: 3.36 of weights, 7.31 of latents and keys
     assert took < 300, "the guard's own time limit: 35 s read"
+    mosaic = [ln for ln in compiled.as_text().splitlines() if 'custom_call_target="tpu_custom_call"' in ln]
+    # the fold is a scan: its body, one token step, is in the program once, a call a latent layer
+    assert len(mosaic) == (16 if read == "kernel" else 0), mosaic
+    assert all("decode_attention" in ln.split(" = ")[0] for ln in mosaic), mosaic
 
 
 def test_the_fold_copies_no_latent_layers_cache(fold):
     """No instruction of the compiled fold copies or transposes an array the
     size of one layer's latents or keys, or of the stack of them."""
-    compiled, B, S, _ = fold
-    size = re.compile(rf"= bf16\[(16,)?{B},{S},(512|64)\]\S* (copy|transpose)\(")
-    hits = [ln.strip()[:160] for ln in compiled.as_text().splitlines() if size.search(ln)]
+    compiled, B, S, _, read = fold
+    text = compiled.as_text().splitlines()
+    size = re.compile(rf"= bf16\[(16,)?{B},({S},512|{S},64|64,{S})\]\S* (copy|transpose)\(")
+    hits = [ln.strip()[:160] for ln in text if size.search(ln)]
     assert not hits, hits
+    # the keys with the positions minor, as the kernel takes them: the cache's own bytes, a call a layer
+    turned = [ln.strip()[:160] for ln in text if re.search(rf"= bf16\[16,{B},64,{S}\]", ln)]
+    assert len(turned) == (16 if read == "kernel" else 0) and all(" bitcast(" in ln for ln in turned), turned

@@ -1,17 +1,19 @@
 """On-chip decode-attention check: does the kernel compile at a family's
 rows, is it the XLA read's result, and what does a block size cost?
 
-For each shape (the chat cell's Llama rows, GPT-2 medium's) and each
-occupancy (``cell``: a quarter of the slots live at chat lengths; ``idle``:
-none, which is the call's own cost; ``half``; ``full``: every slot at the
-last row, where kernel and XLA read move the same bytes) runs
-``ops.decode_attention`` over every layer of a stacked bf16 cache of rows at
-each candidate block and ``models/gpt.py:_attend_layer_cache``'s XLA rows
-read on the same inputs, and records: compiled or refused with Mosaic's
-message, the max abs error against the XLA read over the live slots, and
-the time of one layer's call. In-process on the real chip; fails off-chip
-(interpret mode proves nothing about Mosaic, and a CPU time is no device
-number). Prints one JSON line per row and writes them all to ``--out``.
+For each shape (the chat cell's Llama rows, GPT-2 medium's, the docqa
+cell's latents and rotary keys) and each occupancy (``cell``: a quarter of
+the slots live at the cell's lengths; ``idle``: none, which is the call's
+own cost; ``half``; ``full``: every slot at the last row, where kernel and
+XLA read move the same bytes — the latent XLA read moves the latents twice)
+runs ``ops.decode_attention`` over every layer of a stacked bf16 cache at
+each candidate block and the XLA read on the same inputs
+(``models/gpt.py:_attend_layer_cache``, ``models/mixed.py:_attend_latent_cache``),
+and records: compiled or refused with Mosaic's message, the max abs error
+against the XLA read over the live slots, and the time of one layer's call.
+In-process on the real chip; fails off-chip (interpret mode proves nothing
+about Mosaic, and a CPU time is no device number). Prints one JSON line per
+row and writes them all to ``--out``.
 """
 import argparse
 import json
@@ -23,19 +25,26 @@ SHAPES = {
     # name: layers, slots, rows, query heads, KV heads, head width
     "chat_8x64x2048_32q8kvx128": (8, 64, 2048, 32, 8, 128),
     "gpt2_medium_24x16x1024_16x64": (24, 16, 1024, 16, 16, 64),
+    # a latent layer's: layers, slots, rows, query heads, latent width, rotary width
+    "docqa_16x64x6656_32q_512+64": (16, 64, 6656, 32, 512, 64),
 }
+#: the ``cell`` occupancy's lengths by shape: the prompts' lognormal median and sigma, their least and
+#: largest (None: half the cache) and how far into its answer a request may be; chat lengths by default
+CHAT_LENGTHS = (192, 0.6, 32, None, 96)
+CELL_LENGTHS = {"docqa_16x64x6656_32q_512+64": (2048, 0.7, 512, 6144, 256)}
 
 
-def occupancy(name: str, B: int, S: int, rng):
+def occupancy(name: str, B: int, S: int, rng, lengths=CHAT_LENGTHS):
     """(pos, live) of one token step."""
     import numpy as np
 
+    median, sigma, low, high, answer = lengths
     live = np.zeros((B,), bool)
     pos = rng.integers(0, S, size=(B,))
     if name == "cell":
         live[rng.permutation(B)[: B // 4]] = True
-        prompt = np.clip(np.exp(rng.normal(np.log(192), 0.6, size=(B,))), 32, S // 2)
-        pos = np.minimum(prompt + rng.integers(0, 96, size=(B,)), S - 1).astype(np.int64)
+        prompt = np.clip(np.exp(rng.normal(np.log(median), sigma, size=(B,))), low, high or S // 2)
+        pos = np.minimum(prompt + rng.integers(0, answer, size=(B,)), S - 1).astype(np.int64)
     elif name == "half":
         live[rng.permutation(B)[: B // 2]] = True
     elif name == "full":
@@ -47,9 +56,12 @@ def occupancy(name: str, B: int, S: int, rng):
 def main() -> int:
     here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     p = argparse.ArgumentParser()
+    p.add_argument("--shapes", default=",".join(SHAPES))
     p.add_argument("--blocks", default="128,256,512,1024")
     p.add_argument("--occupancies", default="cell,idle,half,full")
     p.add_argument("--calls", type=int, default=20)
+    p.add_argument("--layers", type=int, default=0, help="time this many of a shape's layers, evenly spaced (0: all; "
+                   "a program holds one Mosaic compile a layer)")
     p.add_argument(
         "--out", default=os.path.join(here, "chiprun_out", "decode_attention_check.json")
     )
@@ -81,29 +93,65 @@ def main() -> int:
         jax.block_until_ready(last)
         return out, (time.perf_counter() - t) / args.calls
 
+    def filled(shape, seed):
+        """A bf16 normal array filled a layer at a time where it lies (the
+        docqa shape's latents are 7 GB: no second copy fits beside them)."""
+        put = jax.jit(
+            lambda buf, li: buf.at[li].set(
+                jax.random.normal(jax.random.fold_in(jax.random.PRNGKey(seed), li), shape[1:], jnp.bfloat16)),
+            donate_argnums=0,
+        )
+        buf = jnp.zeros(shape, jnp.bfloat16)
+        for li in range(shape[0]):
+            buf = put(buf, li)
+        return buf
+
+    def dense(L, B, S, H, Hkv, hd):
+        """(queries, caches, the XLA read a layer, the kernel a layer by block)"""
+        q = jax.random.normal(jax.random.PRNGKey(hd), (B, H, hd), jnp.bfloat16)
+        caches = (filled((L, B, S, Hkv * hd), 1), filled((L, B, S, Hkv * hd), 2))
+        xla = lambda q, kc, vc, li, pos, live: _attend_layer_cache(xla_cfg, q[:, None], kc, vc, li, pos[:, None])[:, 0]
+        kern = lambda q, kc, vc, li, pos, live, block: decode_attention(q, kc, vc, li, pos, live, block=block)
+        return (q,), caches, xla, kern
+
+    def latent(L, B, S, H, rank, rope):
+        from ray_lightning_tpu.models.mixed import _attend_latent_cache
+        from ray_lightning_tpu.ops.decode_attention import latent_decode_attention
+
+        cfg = GPTConfig(attn_impl="reference", qk_head_dim=rank // 4 + rope)  # 192: the scale is all it gives
+        qs = (
+            jax.random.normal(jax.random.PRNGKey(3), (B, H, rank), jnp.bfloat16),
+            jax.random.normal(jax.random.PRNGKey(4), (B, H, rope), jnp.bfloat16),
+        )
+        # the latents are RMS-normed rows (norm sqrt(rank)); the rotary keys as the cache keeps them, handed to
+        # the kernel with the positions minor as models/mixed.py hands them: no copy where the chip keeps them so
+        caches = (filled((L, B, S, rank), 1), filled((L, B, S, rope), 2))
+        xla = lambda ql, qr, cc, rc, li, pos, live: _attend_latent_cache(
+            cfg, ql, qr, {"latent": cc}, {"latent": rc}, li, pos).astype(jnp.float32)
+        kern = lambda ql, qr, cc, rc, li, pos, live, block: latent_decode_attention(
+            ql, qr, cc, jnp.swapaxes(rc, 2, 3), li, pos, live, scale=(rank // 4 + rope) ** -0.5, block=block)
+        return qs, caches, xla, kern
+
     rows = []
-    for shape_name, (L, B, S, H, Hkv, hd) in SHAPES.items():
-        ks = jax.random.split(jax.random.PRNGKey(L * S + hd), 3)
-        q = jax.random.normal(ks[0], (B, H, hd), jnp.bfloat16)
-        kc = jax.random.normal(ks[1], (L, B, S, Hkv * hd), jnp.bfloat16)
-        vc = jax.random.normal(ks[2], (L, B, S, Hkv * hd), jnp.bfloat16)
+    for shape_name in args.shapes.split(","):
+        L, B, S, H = SHAPES[shape_name][:4]
+        qs, caches, xla_layer, kern_layer = (latent if "+" in shape_name else dense)(*SHAPES[shape_name])
+        layers = sorted({round(i * (L - 1) / max(1, args.layers - 1)) for i in range(args.layers)}) or list(range(L))
 
         @jax.jit
-        def xla(q, kc, vc, pos, live):
-            return jnp.stack([
-                _attend_layer_cache(xla_cfg, q[:, None], kc, vc, li, pos[:, None])[:, 0]
-                for li in range(L)
-            ])
+        def xla(qs, caches, pos, live):
+            return jnp.stack([xla_layer(*qs, *caches, li, pos, live) for li in layers])
 
         for occ in args.occupancies.split(","):
-            pos_np, live_np = occupancy(occ, B, S, np.random.default_rng(len(occ)))
+            pos_np, live_np = occupancy(
+                occ, B, S, np.random.default_rng(len(occ)), CELL_LENGTHS.get(shape_name, CHAT_LENGTHS))
             pos, live = jnp.asarray(pos_np), jnp.asarray(live_np)
-            want, t_xla = timed(xla, q, kc, vc, pos, live)
+            want, t_xla = timed(xla, qs, caches, pos, live)
             base = {
                 "shape": shape_name, "occupancy": occ, "live_slots": int(live_np.sum()),
                 "live_rows": int((pos_np[live_np] + 1).sum()), "device": dev.device_kind,
             }
-            rows.append(dict(base, form="xla", us_per_layer=t_xla / L * 1e6))
+            rows.append(dict(base, form="xla", us_per_layer=t_xla / len(layers) * 1e6))
             print(json.dumps(rows[-1]), flush=True)
             for block in (int(b) for b in args.blocks.split(",")):
                 if S % block:
@@ -111,19 +159,17 @@ def main() -> int:
                 row = dict(base, form="kernel", block=block)
 
                 @jax.jit
-                def kern(q, kc, vc, pos, live, block=block):
-                    return jnp.stack([
-                        decode_attention(q, kc, vc, li, pos, live, block=block) for li in range(L)
-                    ])
+                def kern(qs, caches, pos, live, block=block):
+                    return jnp.stack([kern_layer(*qs, *caches, li, pos, live, block) for li in layers])
 
                 try:
-                    got, t = timed(kern, q, kc, vc, pos, live)
+                    got, t = timed(kern, qs, caches, pos, live)
                 except Exception as exc:  # noqa: BLE001 - the refusal IS the record
                     row["status"] = "refused"
                     row["error"] = f"{type(exc).__name__}: {exc}"[:2000]
                 else:
                     row["status"] = "compiled"
-                    row["us_per_layer"] = t / L * 1e6
+                    row["us_per_layer"] = t / len(layers) * 1e6
                     if live_np.any():
                         err = jnp.abs(got - want)[:, live_np]
                         row["max_abs_err"] = float(err.max())
@@ -131,6 +177,7 @@ def main() -> int:
                     row["finite"] = bool(jnp.isfinite(got).all())
                 rows.append(row)
                 print(json.dumps(row), flush=True)
+        del qs, caches
     os.makedirs(os.path.dirname(args.out), exist_ok=True)
     with open(args.out, "w") as f:
         json.dump(rows, f, indent=1)
