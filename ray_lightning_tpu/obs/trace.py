@@ -239,6 +239,9 @@ class RequestTracer:
 
 _ANNOTATION: Any = None
 
+#: where :class:`SpanTotals` puts the riders' time outside every span
+NO_SPAN = "(no span)"
+
 
 def _annotation_cls() -> Any:
     """``jax.profiler.TraceAnnotation``, imported on first use: this
@@ -268,9 +271,21 @@ class SpanTotals:
     is open. A lower bound: a device that runs dry before the host's
     next sync is seen only by the profiler.
 
-    Spans and marks come from the owning thread; :meth:`snapshot` and
-    :meth:`mirror` may be called from any: the lock guards what they
-    read (the two dictionaries and the working time), nothing else.
+    And it reckons WHOSE time a span takes: :meth:`riders` says how many
+    requests wait for a first token and how many slots decode, and every
+    span boundary charges the time since the last mark, times each
+    count, to the innermost open span (``riders_s``) — the seconds the
+    requests sat behind that span, as ``exposed_s`` is the seconds the
+    device did. Nothing is kept per request; a boundary costs two
+    multiply-adds. Time outside every span goes to :data:`NO_SPAN`.
+
+    Spans and marks come from the owning thread; :meth:`snapshot`,
+    :meth:`mirror` and :meth:`riders` (a submit arrives on the RPC
+    thread) may be called from any: the lock guards what they read and
+    write (the dictionaries, the counts, the working time). They read
+    the innermost open span's name without owning the stack: the owner
+    appends to it outside the lock, and a reader that comes a name early
+    or late misplaces the microseconds in between.
     """
 
     def __init__(self) -> None:
@@ -289,6 +304,14 @@ class SpanTotals:
         self._mark = 0
         #: (seconds, spans) already mirrored into registry counters
         self._mirrored: Dict[str, Tuple[float, int]] = {}
+        #: requests without a first token / slots decoding, as last told
+        self._waiting = 0
+        self._decoding = 0
+        #: name -> nanosecond-requests that [waited, decoded] behind it
+        self._rode: Dict[str, List[int]] = {}
+        #: the riders are charged up to here
+        self._rmark = 0
+        self._rmirrored: Dict[Tuple[str, str], float] = {}
 
     # -- the owning thread ------------------------------------------------
     def _charge(self, now: int) -> None:
@@ -299,16 +322,38 @@ class SpanTotals:
             )
         self._mark = now
 
+    def _ride(self, now: int) -> None:
+        """Under the lock: the time since the last mark, times each
+        count, to the innermost open span. A clock read before another
+        thread's mark is behind it, and charges nothing."""
+        dt = now - self._rmark
+        if dt <= 0:
+            return
+        self._rmark = now
+        if self._waiting or self._decoding:
+            name = self._open[-1][0] if self._open else NO_SPAN
+            rode = self._rode.get(name)
+            if rode is None:
+                rode = self._rode[name] = [0, 0]
+            rode[0] += dt * self._waiting
+            rode[1] += dt * self._decoding
+
     def _enter(self, name: str, now: int) -> None:
-        if self._working:
-            with self._lock:  # may add a name to _exposed
-                self._charge(now)
+        riding = self._waiting or self._decoding
+        if self._working or riding:
+            with self._lock:  # may add a name to a dictionary
+                if self._working:
+                    self._charge(now)
+                if riding:
+                    self._ride(now)
         self._open.append([name, 0])
 
     def _exit(self, name: str, t0: int, now: int) -> None:
         with self._lock:
             if self._working:
                 self._charge(now)
+            if self._waiting or self._decoding:
+                self._ride(now)
             dur = now - t0
             own = dur - self._open.pop()[1]
             if self._open:
@@ -350,15 +395,29 @@ class SpanTotals:
                 self._work_ns += now - t0
 
     # -- any thread --------------------------------------------------------
+    def riders(self, waiting: int, decoding: int) -> None:
+        """From now on ``waiting`` requests have no first token yet
+        (queued, parked or in an admission) and ``decoding`` slots hold
+        a request between its first token and its end. The span that is
+        open is charged with the old counts up to this instant."""
+        with self._lock:
+            self._ride(time.perf_counter_ns())
+            self._waiting = waiting
+            self._decoding = decoding
+
     def snapshot(self) -> Dict[str, Any]:
         """``{"segments": {name: {"n", "s", "max_s"}}, "exposed_s":
-        {name: s}, "work_s"}`` (``s`` and ``max_s`` self time), all
+        {name: s}, "work_s", "riders_s": {"waiting": {name: s},
+        "decoding": {name: s}}}`` (``s`` and ``max_s`` self time;
+        ``riders_s`` request-seconds, charged up to this call), all
         since construction and never decreasing: the difference of two
         snapshots is exactly the time between them."""
         with self._lock:
+            self._ride(time.perf_counter_ns())
             seg = {k: list(v) for k, v in self._seg.items()}
             exposed = dict(self._exposed)
             work_ns = self._work_ns
+            rode = sorted((k, list(v)) for k, v in self._rode.items())
         return {
             "segments": {
                 k: {"n": n, "s": ns * 1e-9, "max_s": mx * 1e-9}
@@ -368,19 +427,33 @@ class SpanTotals:
                 k: ns * 1e-9 for k, ns in sorted(exposed.items())
             },
             "work_s": work_ns * 1e-9,
+            "riders_s": {
+                kind: {k: ns[i] * 1e-9 for k, ns in rode if ns[i]}
+                for i, kind in enumerate(("waiting", "decoding"))
+            },
         }
 
-    def mirror(self, seconds: Any, spans: Any) -> None:
+    def mirror(self, seconds: Any, spans: Any, riders: Any = None) -> None:
         """Bring two registry counters (labelled ``segment``) up to
-        these totals. Called where the registry is read, not per span:
-        the hot path pays for one sink only."""
-        snap = self.snapshot()["segments"]
+        these totals, and ``riders`` (labelled ``segment`` and ``kind``)
+        up to the request-seconds. Called where the registry is read,
+        not per span: the hot path pays for one sink only."""
+        snap = self.snapshot()
         with self._lock:
-            for name, row in snap.items():
+            for name, row in snap["segments"].items():
                 s0, n0 = self._mirrored.get(name, (0.0, 0))
                 seconds.inc(row["s"] - s0, segment=name)
                 spans.inc(row["n"] - n0, segment=name)
                 self._mirrored[name] = (row["s"], row["n"])
+            if riders is None:
+                return
+            for kind, by_name in snap["riders_s"].items():
+                for name, s in by_name.items():
+                    riders.inc(
+                        s - self._rmirrored.get((kind, name), 0.0),
+                        segment=name, kind=kind,
+                    )
+                    self._rmirrored[(kind, name)] = s
 
 
 class span:  # noqa: N801 - used as ``with span(totals, name, **attrs):``

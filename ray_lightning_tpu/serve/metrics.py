@@ -16,6 +16,8 @@ from typing import TYPE_CHECKING, Any, Dict, Optional
 
 from ray_lightning_tpu.utils.rank_zero import rank_zero_info
 
+from ray_lightning_tpu.obs.registry import LATENCY_BUCKETS
+
 if TYPE_CHECKING:  # registry import is cheap, but keep the seam explicit
     from ray_lightning_tpu.obs.registry import MetricsRegistry
 
@@ -72,8 +74,17 @@ class ServeMetrics:
                 "queue": registry.gauge(
                     "rlt_serve_queue_depth", "Requests waiting for a slot"
                 ),
+                # The three latency series a tail is read from
+                # (``latency()``) have buckets 4.8% apart.
                 "ttft": registry.histogram(
-                    "rlt_serve_ttft_seconds", "Submit-to-first-token latency"
+                    "rlt_serve_ttft_seconds", "Submit-to-first-token latency",
+                    buckets=LATENCY_BUCKETS,
+                ),
+                "tpot": registry.histogram(
+                    "rlt_serve_tpot_seconds",
+                    "Time per output token after the first, of requests "
+                    "that finished with at least two",
+                    buckets=LATENCY_BUCKETS,
                 ),
                 "step_time": registry.histogram(
                     "rlt_serve_step_seconds", "Scheduler step wall time"
@@ -184,6 +195,7 @@ class ServeMetrics:
                     "rlt_serve_phase_seconds",
                     "Per-request phase durations from the anatomy "
                     "ledger, by phase and replica role",
+                    buckets=LATENCY_BUCKETS,
                 ),
                 # Canary probes: counted here (by outcome) INSTEAD of
                 # in the cost ledger families — synthetic traffic must
@@ -293,6 +305,12 @@ class ServeMetrics:
             )
         if self._reg is not None:
             self._reg["ttft"].observe(float(ttft_s))
+
+    def record_tpot(self, tpot_s: float) -> None:
+        """A request finished with at least two tokens: the time after
+        its first token over the tokens after it."""
+        if self._reg is not None:
+            self._reg["tpot"].observe(float(tpot_s))
 
     def record_finish(
         self, n: int = 1, queue_depth: Optional[int] = None
@@ -524,6 +542,23 @@ class ServeMetrics:
                 )
 
     # -- aggregates ------------------------------------------------------
+    def latency(self) -> Dict[str, Any]:
+        """``stats()["latency"]``: the per-bucket counts of the time to
+        first token, the time per output token and the queue phase
+        (``Histogram.row``), all since the registry was made: two calls
+        differ by exactly the requests between them, so a tail of that
+        window can be read from the difference, to a bucket's 4.8%.
+        {} without a registry."""
+        if self._reg is None:
+            return {}
+        return {
+            "ttft": self._reg["ttft"].row(),
+            "tpot": self._reg["tpot"].row(),
+            "queue": self._reg["phase_seconds"].row(
+                phase="queue", role=self.role
+            ),
+        }
+
     def snapshot(self) -> Dict[str, Any]:
         """Aggregate view over the sliding window (the stats payload)."""
         with self._lock:

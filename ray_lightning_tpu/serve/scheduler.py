@@ -262,6 +262,17 @@ class Scheduler:
         #: estimated device-seconds share) and emitted as ONE record at
         #: finish/cancel/expire via metrics.record_cost + a typed event.
         self._acct: Dict[str, Dict[str, Any]] = {}
+        #: Whose seconds the loop spends (``SpanTotals.riders``): open
+        #: ledger records without a first token, and with one. Each
+        #: moves where its record does — opened, first token, closed —
+        #: so the spans' request-seconds add up to the ledger's phases.
+        #: ``_since_*`` is the sum of the instants at which the open
+        #: requests began to wait / to decode: what they have accrued
+        #: by ``t`` is ``count * t - sum`` (:meth:`riders_open`).
+        self._n_waiting = 0
+        self._n_decoding = 0
+        self._since_waiting = 0.0
+        self._since_decoding = 0.0
         #: Preemption drain: a pending ``request_drain`` budget (s) the
         #: next step() consumes, and the plan it produced — engine work
         #: (prefix-block export) must run on the loop thread, so the RPC
@@ -292,7 +303,43 @@ class Scheduler:
         self._park_cv = threading.Condition()
 
     # -- cost ledger ------------------------------------------------------
+    def _riders(
+        self, waiting: int = 0, since_w: float = 0.0,
+        decoding: int = 0, since_d: float = 0.0,
+    ) -> None:
+        """A ledger record was opened, got its first token or was
+        closed: move the counts, and tell the spans. A submit comes on
+        the RPC thread, hence the lock."""
+        with self._lock:
+            self._n_waiting += waiting
+            self._since_waiting += waiting * since_w
+            self._n_decoding += decoding
+            self._since_decoding += decoding * since_d
+            self.spans.riders(self._n_waiting, self._n_decoding)
+
+    def riders_open(self) -> Dict[str, float]:
+        """Request-seconds the requests still open have accrued: those
+        without a first token since their submit, the others since their
+        first token. With the ledger's phases of the requests already
+        closed it is what ``riders_s`` adds up to (any thread)."""
+        with self._lock:
+            now = time.monotonic()
+            return {
+                "waiting": self._n_waiting * now - self._since_waiting,
+                "decoding": self._n_decoding * now - self._since_decoding,
+            }
+
+    def _acct_first_token(self, acct: Dict[str, Any], ttft: float) -> None:
+        """The record's request has its first token, ``ttft`` seconds
+        after its submit: it waits no longer, it decodes."""
+        if "_ttft_s" in acct:
+            return
+        acct["_ttft_s"] = ttft
+        t_sub = acct["submitted_at"]
+        self._riders(-1, t_sub, +1, t_sub + ttft)
+
     def _acct_open(self, req: Request) -> None:
+        self._riders(+1, req.submitted_at)
         self._acct[req.request_id] = {
             "request_id": req.request_id,
             "tenant": req.tenant,
@@ -316,9 +363,12 @@ class Scheduler:
         if rec is None:
             return
         rec["outcome"] = outcome
-        rec["total_s"] = round(
-            time.monotonic() - rec.pop("submitted_at"), 6
-        )
+        t_sub = rec.pop("submitted_at")
+        if "_ttft_s" in rec:
+            self._riders(decoding=-1, since_d=t_sub + rec["_ttft_s"])
+        else:
+            self._riders(-1, t_sub)
+        rec["total_s"] = round(time.monotonic() - t_sub, 6)
         rec["queue_s"] = round(rec["queue_s"], 6)
         rec["device_s"] = round(rec["device_s"], 6)
         rec["spec_verifies"] = round(rec["spec_verifies"], 3)
@@ -362,6 +412,12 @@ class Scheduler:
             }
             self.metrics.record_phases(
                 phases, tenant=rec["tenant"], outcome=outcome
+            )
+        if outcome == "finished" and rec["emitted_tokens"] > 1 and ttft is not None:
+            # Time per output token as the client reckons it: the tokens
+            # after the first over the time after the first.
+            self.metrics.record_tpot(
+                (rec["total_s"] - ttft) / (rec["emitted_tokens"] - 1)
             )
         self.metrics.record_cost(rec)
         self._event(
@@ -1070,7 +1126,7 @@ class Scheduler:
                     )
                     if acct is not None:
                         acct["emitted_tokens"] += 1
-                        acct["_ttft_s"] = now - req.submitted_at
+                        self._acct_first_token(acct, now - req.submitted_at)
                     if self.journal is not None:
                         self._jr_tokens[req.request_id] = [int(first_tok)]
                         self._jr_ttft[req.request_id] = (
@@ -1353,7 +1409,7 @@ class Scheduler:
                 acct["prefix_hit_tokens"] = task.matched_tokens
                 acct["emitted_tokens"] += 1
                 if req is not None:
-                    acct.setdefault("_ttft_s", now - req.submitted_at)
+                    self._acct_first_token(acct, now - req.submitted_at)
             if self.journal is not None and tok is not None:
                 self._jr_tokens.setdefault(
                     task.request_id, []

@@ -600,6 +600,11 @@ class ServeReplica:
             "rlt_serve_loop_spans_total",
             "Replica host spans completed, by span",
         )
+        self._rider_seconds = self._registry.counter(
+            "rlt_serve_loop_rider_seconds_total",
+            "Request-seconds spent behind each loop span, by span and by "
+            "kind (waiting for a first token / decoding)",
+        )
         self._gc_seconds = self._registry.counter(
             "rlt_gc_pause_seconds_total",
             "Seconds the cyclic collector paused this process, by generation",
@@ -1132,8 +1137,10 @@ class ServeReplica:
     def _mirror_spans(self) -> None:
         """Bring the registry's span and collector counters up to the
         totals (the hot paths feed the totals only)."""
-        for totals in (self.spans, self._rpc_spans):
-            totals.mirror(self._span_seconds, self._span_count)
+        self.spans.mirror(
+            self._span_seconds, self._span_count, self._rider_seconds
+        )
+        self._rpc_spans.mirror(self._span_seconds, self._span_count)
         self._gc.mirror(self._gc_seconds)
         for phase, row in self.engine.moe_totals.items():
             for key, total in row.items():
@@ -1157,8 +1164,13 @@ class ServeReplica:
         """``stats()["spans"]``: what the host did, all monotone since
         construction, so the difference of two calls is exactly the time
         between them. ``segments`` holds both threads' spans; the loop
-        thread's are those not named ``serve.rpc.*``."""
+        thread's are those not named ``serve.rpc.*``. ``riders_s`` is
+        the loop thread's: request-seconds behind each of its spans, by
+        kind; ``riders_open_s`` what the requests still open have
+        accrued of them (with the closed requests' phases, what
+        ``riders_s`` adds up to)."""
         out = self.spans.snapshot()
+        out["riders_open_s"] = self.scheduler.riders_open()
         out["segments"].update(self._rpc_spans.snapshot()["segments"])
         out["folds"] = int(sum(self.engine.fold_dispatches.values()))
         out["gc"] = self._gc.snapshot()
@@ -1287,6 +1299,7 @@ class ServeReplica:
         snap["health"] = self.health()["verdict"]
         snap["preempt"] = self.preempt.state()
         snap["spans"] = self._spans_snapshot()
+        snap["latency"] = self.metrics.latency()
         return snap
 
     # -- health / forensics RPCs ------------------------------------------

@@ -126,7 +126,10 @@ def test_spans_nest_and_sum_to_wall():
 def test_span_totals_are_monotone():
     totals = SpanTotals()
     before = totals.snapshot()
-    assert before == {"segments": {}, "exposed_s": {}, "work_s": 0.0}
+    assert before == {
+        "segments": {}, "exposed_s": {}, "work_s": 0.0,
+        "riders_s": {"waiting": {}, "decoding": {}},
+    }
     snaps = []
     for _ in range(3):
         with totals.work(), span(totals, "a"):
