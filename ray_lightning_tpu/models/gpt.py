@@ -613,18 +613,93 @@ def _make_norm(cfg: GPTConfig):
     return lambda x, g, b: _layernorm(x, g, b, cfg.norm_eps)
 
 
+#: The projections an engine holds flat: (L, D, *out) -> (L, D, prod(out)).
+_ENGINE_FLAT = ("wq", "wkv")
+
+
+def engine_weights(params: Dict[str, Any], cfg: GPTConfig) -> Dict[str, Any]:
+    """The tree a serving engine multiplies, from the tree it is handed.
+
+    The STORED layout (:func:`init_gpt_params`) is the interface to
+    checkpoints, ``hf_import``, the sharded trainer and the benchmark. It
+    names its axes for those readers — SwiGLU's gate and up stacked in
+    ``wi`` (L, D, 2, F) so that tensor parallelism on F keeps both shards
+    on one rank, the GQA projections ``wq`` (L, D, H, hd) and ``wkv``
+    (L, D, 2, Hkv, hd) with their heads apart — and a matmul over D reads
+    none of the three where it lies: the TPU keeps an array in tiles of
+    its two minor axes, (2, F) and (H, hd) here, where ``x @ w`` wants
+    tiles of (D, out). The chip's compiler therefore re-lays the leaves
+    out, whole at the entry of every program that multiplies them (2.1 GiB
+    of temporaries and 7 ms a decode fold at Mistral-7B's widths,
+    PERF.md §6) or a layer at a time inside it. An engine re-forms them
+    ONCE instead, when it is built:
+
+    - ``wi`` becomes two leaves ``wi_gate`` and ``wi_up`` (L, D, F), which
+      :func:`_dense_mlp` tells from the stored form by their names;
+    - ``wq`` and ``wkv`` become (L, D, H * hd) and (L, D, 2 * Hkv * hd),
+      the same values in the same row-major order, which
+      :func:`_project_gqa` tells by their rank.
+
+    An int8 node (``{"q", "s"}``) re-forms alike: its scales carry the
+    same axes. ``wo`` (L, H, hd, D) and ``wo2`` are read where they lie
+    (their minor axes are the matmul's already).
+
+    Only the dense GQA / SwiGLU leaves are touched: a fused ``wqkv``, a
+    gelu ``wi``, expert layers (``parallel/moe.py`` reads their ``wi``)
+    and mixed layer kinds (their own leaves, models/mixed.py) come back
+    as they went in, and re-forming a re-formed tree changes nothing.
+    """
+    if cfg.mixed:
+        return params
+    blocks = dict(params["blocks"])
+    for name in _ENGINE_FLAT:
+        if name in blocks:
+            blocks[name] = _flatten_out(blocks[name])
+    if "wi" in blocks and cfg.n_experts == 0 and cfg.mlp_variant == "swiglu":
+        blocks["wi_gate"], blocks["wi_up"] = _split_gate_up(blocks.pop("wi"))
+    return {**params, "blocks": blocks}
+
+
+@jax.jit
+def _split_gate_up(wi: Any) -> Tuple[Any, Any]:
+    """(L, D, 2, F) -> gate, up (L, D, F), each leaf of a quantized node."""
+    return tuple(
+        jax.tree_util.tree_map(lambda a: a[:, :, c], wi) for c in (0, 1)
+    )
+
+
+@jax.jit
+def _flatten_out(w: Any) -> Any:
+    """(L, D, *out) -> (L, D, prod(out)), each leaf of a quantized node
+    (its scales are (L, 1, *out))."""
+    return jax.tree_util.tree_map(
+        lambda a: a.reshape(a.shape[:2] + (-1,)), w
+    )
+
+
 def _dense_mlp(
     m: jax.Array, lp: Dict[str, jax.Array], cfg: GPTConfig, cdt: Any
 ) -> jax.Array:
     """The dense (non-MoE) feed-forward on normed input (..., D): GPT-2
-    gelu or Llama-style SwiGLU (gate/up stacked in ``wi`` (D, 2, F) so
-    tensor parallelism on F keeps both shards co-located). One definition
-    serves the training forward and the KV-cached decode."""
+    gelu or Llama-style SwiGLU. One definition serves the training
+    forward and the KV-cached decode.
+
+    SwiGLU's weights come in one of two forms, told apart by the leaves'
+    names: the stored ``wi`` (D, 2, F) — gate/up stacked so tensor
+    parallelism on F keeps both shards co-located; what training, a
+    checkpoint and ``gpt_generate`` on a stored tree hand in — or an
+    engine's ``wi_gate`` / ``wi_up`` (D, F) each (:func:`engine_weights`).
+    The arithmetic is the same: every gate and up element is the same dot
+    product over D, the same bias added after it."""
     if cfg.mlp_variant == "swiglu":
-        z = jnp.einsum("...d,dcf->...cf", m, dequant(lp["wi"], cdt)) + lp[
-            "bi"
-        ].astype(cdt)
-        h = jax.nn.silu(z[..., 0, :]) * z[..., 1, :]
+        bi = lp["bi"].astype(cdt)
+        if "wi_gate" in lp:
+            gate = m @ dequant(lp["wi_gate"], cdt) + bi[0]
+            up = m @ dequant(lp["wi_up"], cdt) + bi[1]
+        else:
+            z = jnp.einsum("...d,dcf->...cf", m, dequant(lp["wi"], cdt)) + bi
+            gate, up = z[..., 0, :], z[..., 1, :]
+        h = jax.nn.silu(gate) * up
     else:
         z = jnp.einsum("...d,df->...f", m, dequant(lp["wi"], cdt)) + lp[
             "bi"
@@ -672,6 +747,35 @@ def _rope(x: jax.Array, tables: Tuple[jax.Array, jax.Array]) -> jax.Array:
     ).astype(x.dtype)
 
 
+def _project_gqa(
+    a: jax.Array, lp: Dict[str, jax.Array], cfg: GPTConfig, cdt: Any
+) -> Tuple[jax.Array, jax.Array]:
+    """Grouped-query projections of normed rows ``a`` (..., D): ``q``
+    (..., H, hd) and ``kv`` (..., 2, Hkv, hd), biases added. One
+    definition for the prefill, the decode step and the verify.
+
+    A layer's ``wq`` / ``wkv`` come stored, (D, H, hd) and
+    (D, 2, Hkv, hd), or in an engine's flat form, (D, H * hd) and
+    (D, 2 * Hkv * hd) (:func:`engine_weights`); the rank says which. The
+    sums and their order are the same. The flat form is multiplied as
+    the plain matrix it is and the product reshaped; the barrier between
+    the two keeps the TPU's compiler from folding the reshape back into
+    the dot, whose output would then carry the head axes and have the
+    compiler turn the whole stacked leaf to suit it at every program's
+    entry (0.375 GiB and a millisecond a decode fold at Mistral-7B's
+    widths: PERF.md §6). It costs nothing at run time."""
+    H, Hkv, hd = cfg.n_head, cfg.kv_head, cfg.head_dim
+    wq, wkv = dequant(lp["wq"], cdt), dequant(lp["wkv"], cdt)
+    if wq.ndim == 2:
+        q, kv = jax.lax.optimization_barrier((a @ wq, a @ wkv))
+        q = q.reshape(a.shape[:-1] + (H, hd))
+        kv = kv.reshape(a.shape[:-1] + (2, Hkv, hd))
+    else:
+        q = jnp.einsum("...d,dhk->...hk", a, wq)
+        kv = jnp.einsum("...d,dthk->...thk", a, wkv)
+    return q + lp["bq"].astype(cdt), kv + lp["bkv"].astype(cdt)
+
+
 def _project_qkv(
     a: jax.Array,
     lp: Dict[str, jax.Array],
@@ -689,6 +793,10 @@ def _project_qkv(
     ``repeat_kv=False`` returns k/v at their native Hkv width (what the
     decode cache stores — the prefill path repeats locally for attention
     but caches the grouped heads).
+
+    The GQA leaves come stored or in an engine's flat form
+    (:func:`_project_gqa`, which the decode step and the verify share);
+    the fused ``wqkv`` has one form.
     """
     if cfg.kv_head == cfg.n_head:
         qkv = (
@@ -697,14 +805,7 @@ def _project_qkv(
         )
         q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
     else:
-        q = (
-            jnp.einsum("bsd,dhk->bshk", a, dequant(lp["wq"], cdt))
-            + lp["bq"].astype(cdt)
-        )
-        kv = (
-            jnp.einsum("bsd,dthk->bsthk", a, dequant(lp["wkv"], cdt))
-            + lp["bkv"].astype(cdt)
-        )
+        q, kv = _project_gqa(a, lp, cfg, cdt)
         k, v = kv[:, :, 0], kv[:, :, 1]
     if rope_tables is not None:
         q = _rope(q, rope_tables)
@@ -1173,9 +1274,24 @@ def gpt_prefill(
     real rows. MoE configs dispatch with capacity set to never drop tokens
     (see :func:`gpt_generate`), so padding cannot displace real tokens.
     ``params`` must already be device arrays (quantized int8 trees are
-    consumed directly). ``mesh`` is the serving mesh when the caller's
+    consumed directly), in the stored layout or as an engine holds them
+    (:func:`engine_weights`). ``mesh`` is the serving mesh when the caller's
     program is partitioned over one (the flash kernel then runs per head
     shard; see ``ops.flash_attention``).
+
+    The layers are walked by a ``lax.scan`` over the stacked leaves, and
+    that stays: each step slices its layer out of the stack by a
+    loop-carried index, which the TPU's compiler reads as a view wherever
+    the leaf's layout already suits the matmul. On the STORED tree it
+    does not for ``wi``, ``wq`` and ``wkv``, and every step of the scan
+    then materialises a re-laid-out copy of those three
+    (``constant_dynamic-slice_fusion``: 0.22 GiB of temporaries and about
+    6 ms a prefill at Mistral-7B's widths); on an engine's re-formed tree
+    no leaf is copied (PERF.md §6, PR 43: compile-only temporaries of the
+    1024-row admission 0.305 -> 0.059 GiB). A Python loop over static
+    indices copies nothing either, but is ``n_layer`` times the program to
+    trace, lower, compile and load, a bucket: 8 s more of set-up in the
+    chat cell for no device time.
 
     A configuration with mixed layer kinds (``cfg.layer_types``) returns
     ``pf_k``/``pf_v`` as ``{kind: (Lk, B, P, Hkv, d)}``, one entry an
@@ -1676,14 +1792,7 @@ def gpt_decode_step(
             )
             q, k_new, v_new = qkv[:, 0], qkv[:, 1], qkv[:, 2]  # (B,H,hd)
         else:
-            q = (
-                jnp.einsum("bd,dhk->bhk", a, dequant(lp["wq"], cdt))
-                + lp["bq"].astype(cdt)
-            )
-            kv = (
-                jnp.einsum("bd,dthk->bthk", a, dequant(lp["wkv"], cdt))
-                + lp["bkv"].astype(cdt)
-            )
+            q, kv = _project_gqa(a, lp, cfg, cdt)
             k_new, v_new = kv[:, 0], kv[:, 1]  # (B, Hkv, hd)
         if rope_tables is not None:
             q = _rope_slot(q)
@@ -2162,14 +2271,7 @@ def gpt_decode_verify(
             )
             q, k_new, v_new = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
         else:
-            q = (
-                jnp.einsum("bqd,dhk->bqhk", a, dequant(lp["wq"], cdt))
-                + lp["bq"].astype(cdt)
-            )
-            kv = (
-                jnp.einsum("bqd,dthk->bqthk", a, dequant(lp["wkv"], cdt))
-                + lp["bkv"].astype(cdt)
-            )
+            q, kv = _project_gqa(a, lp, cfg, cdt)
             k_new, v_new = kv[:, :, 0], kv[:, :, 1]
         if rope_tables is not None:
             q = _rope_rows(q)

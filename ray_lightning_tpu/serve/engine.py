@@ -194,7 +194,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from ray_lightning_tpu.models.gpt import GPTConfig
+from ray_lightning_tpu.models.gpt import GPTConfig, engine_weights
 from ray_lightning_tpu.obs.trace import SpanTotals, span
 
 
@@ -304,6 +304,21 @@ class DecodeEngine:
         spec_window: int = 32,
         mesh: Any = None,
     ) -> None:
+        """``params`` (and ``spec_params``) come in the STORED layout of
+        ``models/gpt.py:init_gpt_params`` — the interface to checkpoints,
+        ``hf_import``, the trainer and the benchmark, int8 nodes
+        included — as host or device arrays. The engine places them as
+        stored and then holds, in ``self.params``, the tree
+        ``models/gpt.py:engine_weights`` makes of that ONCE: SwiGLU's
+        gate and up as two matrices, the GQA projections flat, so that
+        none of the programs compiled below re-lays its weights out at
+        its entry (PERF.md §6, PR 43). The model code takes either form
+        and says which by the leaves it is given; no argument here
+        chooses. Stored copies the engine placed itself are freed at the
+        re-forming, before any cache is allocated; arrays the CALLER
+        placed are never donated or deleted (the same tree may feed a
+        solo ``gpt_generate`` or another engine), so a caller that wants
+        their memory back drops its own reference."""
         import jax
         import jax.numpy as jnp
 
@@ -625,28 +640,38 @@ class DecodeEngine:
             # Draft weights stay REPLICATED under a mesh: the drafter is
             # small by design, and a replicated draft keeps its proposals
             # (and therefore the accept scan) a pure per-device SPMD
-            # computation with zero collective traffic.
-            self._spec_params = jax.tree_util.tree_map(
-                (
-                    (lambda a: jax.device_put(jnp.asarray(a), self._rep_sh))
-                    if mesh is not None
-                    else jnp.asarray
+            # computation with zero collective traffic. Re-formed like
+            # the main tree (below): the draft runs the same prefill and
+            # decode step.
+            self._spec_params = engine_weights(
+                jax.tree_util.tree_map(
+                    (
+                        (lambda a: jax.device_put(
+                            jnp.asarray(a), self._rep_sh))
+                        if mesh is not None
+                        else jnp.asarray
+                    ),
+                    spec_params,
                 ),
-                spec_params,
+                spec_config,
             )
         # Host accept accounting (read by spec_stats / the scheduler).
         self.spec_verifies = 0
         self.spec_drafted_tokens = 0
         self.spec_accepted_tokens = 0
         self.spec_emitted_tokens = 0
+        # Placed as stored, held re-formed (the docstring); what was
+        # placed here dies with ``placed``.
         if mesh is not None:
-            self.params = jax.tree_util.tree_map(
+            placed = jax.tree_util.tree_map(
                 lambda a, s: jax.device_put(jnp.asarray(a), s),
                 params,
                 self._params_sh,
             )
         else:
-            self.params = jax.tree_util.tree_map(jnp.asarray, params)
+            placed = jax.tree_util.tree_map(jnp.asarray, params)
+        self.params = engine_weights(placed, config)
+        del placed
 
         cdt = jnp.dtype(config.compute_dtype)
         L, Hkv, hd = config.n_layer, config.kv_head, config.head_dim

@@ -13,7 +13,13 @@ one row. With the KV heads on an axis of their own the chip's compiler copied a 
 array before every attention read: temporaries of 2.38 GiB in a program of
 10.12 GiB (PERF.md §4). This is the guard that the copy does not come back — under the XLA read and
 under the decode kernel (``ops/decode_attention.py``), which takes the stacked
-cache whole for the same reason.
+cache whole for the same reason. **And on the weights the engine holds**
+(``models/gpt.py:engine_weights``, PR 43): multiplied as stored, ``wi``
+(L, D, 2, F), ``wq`` and ``wkv`` were re-laid-out whole at the fold's entry,
+2.13 GiB of the fold's 2.138 GiB of temporaries; re-formed once at engine build
+the fold reads 0.012 / 7.75 GiB. **The cell's 1024-row admission** on the
+same tree: its ``lax.scan`` over the stored leaves copied a layer's ``wi``,
+``wq`` and ``wkv`` out of the stack, re-laid-out, at every step.
 
 Built as ``tests/perfbench/test_compile_v5e.py`` builds its programs, from
 the cell's own files; the topology is described in a fixture, never at
@@ -53,9 +59,11 @@ def v5e():
     cc.reset_cache()
 
 
-def _compile_chat_fold(v5e, monkeypatch):
-    """The chat cell's decode fold, from the cell's own files, compiled for
-    the described chip: (compiled, slots, rows, layers, fold)."""
+def _chat_cell(v5e, monkeypatch):
+    """The chat cell from its own files, described for the chip:
+    ``(program config, dims, replica group, weights, sds)``. The weights are
+    the tree the engine holds — the stored tree (``pb/weights.py``) through
+    ``engine_weights``, as ``DecodeEngine.__init__`` takes it."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import SingleDeviceSharding
@@ -64,7 +72,7 @@ def _compile_chat_fold(v5e, monkeypatch):
     from pb import weights
     from pb.spec import Spec
 
-    from ray_lightning_tpu.models.gpt import GPTConfig, gpt_decode_fold
+    from ray_lightning_tpu.models.gpt import GPTConfig, engine_weights
 
     spec = Spec(ROOT)
     cell = spec.cell("mistral-7b-v0.1-d8.serve-chat")
@@ -77,10 +85,25 @@ def _compile_chat_fold(v5e, monkeypatch):
         return jax.ShapeDtypeStruct(shape, d, sharding=one)
 
     shapes = weights.param_shapes(dims, pc.max_seq)
-    params = {k: sds(v[0], dt) for k, v in shapes.items() if k != "blocks"}
-    params["blocks"] = {k: sds(v[0], dt) for k, v in shapes["blocks"].items()}
+    stored = {k: sds(v[0], dt) for k, v in shapes.items() if k != "blocks"}
+    stored["blocks"] = {k: sds(v[0], dt) for k, v in shapes["blocks"].items()}
+    assert stored["blocks"]["wi"].shape == (dims["layers"], dims["d"], 2, dims["ff"]), "the stored layout"
+    params = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype), jax.eval_shape(lambda p: engine_weights(p, pc), stored))
+    assert (int(rep["num_slots"]), int(rep["max_seq"])) == (64, 2048), "the sizes below are this cell's"
+    return pc, dims, rep, params, sds
+
+
+def _compile_chat_fold(v5e, monkeypatch):
+    """The chat cell's decode fold, compiled for the described chip:
+    (compiled, slots, rows, layers, fold)."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_lightning_tpu.models.gpt import gpt_decode_fold
+
+    pc, dims, rep, params, sds = _chat_cell(v5e, monkeypatch)
     B, S = int(rep["num_slots"]), int(rep["max_seq"])
-    assert (B, S) == (64, 2048), "the sizes below are this cell's"
     cache = sds((dims["layers"], B, S, dims["kv_heads"] * dims["head_dim"]), jnp.bfloat16)
     i32, f32 = (lambda: sds((B,), jnp.int32)), (lambda: sds((B,), jnp.float32))
     fold = int(rep["decode_fold"])
@@ -114,12 +137,80 @@ def test_mistral_decode_fold_on_a_cache_of_rows_copies_no_layer(v5e, monkeypatch
     whole = m.argument_size_in_bytes + m.output_size_in_bytes - m.alias_size_in_bytes + m.temp_size_in_bytes
     print(f"decode fold on rows at {B} x {S}, {read} read: temporaries {m.temp_size_in_bytes / GIB:.3f} GiB, "
           f"whole program {whole / GIB:.2f} GiB")
-    assert m.temp_size_in_bytes < 2.3 * GIB  # 2.138 read (xla); 2.383 with the KV heads on an axis of their own
-    assert whole < 10.0 * GIB  # 9.88 read; 10.12
+    # 2.138 / 9.88 read while the fold multiplied the stored tree: wi, wq and wkv re-laid-out at its entry
+    assert m.temp_size_in_bytes < 0.3 * GIB
+    assert whole < 8.2 * GIB
     mosaic = [ln for ln in compiled.as_text().splitlines() if 'custom_call_target="tpu_custom_call"' in ln]
     # the fold is a scan: its body, one token step, is in the program once
     assert len(mosaic) == (layers if read == "kernel" else 0), mosaic
     assert all("decode_attention" in ln.split(" = ")[0] for ln in mosaic), mosaic
+
+
+def test_mistral_admission_copies_no_layer_of_weights_out_of_the_stack(v5e, monkeypatch):
+    """The cell's largest admission (the 1024 bucket: prefill, its rows
+    into one slot of the donated caches, the head over the last position),
+    as ``serve/engine.py`` builds ``admit_impl``. ``gpt_prefill`` scans the
+    stacked leaves, and on the STORED tree every step of the scan
+    materialised a re-laid-out copy of its layer's ``wi``, ``wq`` and
+    ``wkv`` (three ``constant_dynamic-slice_fusion``s whose results are
+    those leaves; temporaries 0.305 GiB, a layer's ``wi`` 0.219 of them). On
+    the tree the engine holds no such fusion yields a weight, and all the
+    temporaries together (0.059 GiB read: activations) are under ONE of a
+    layer's gate and up matrices."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_lightning_tpu.models.gpt import cache_strip_put, gpt_prefill
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the flash kernel, as on the chip
+    pc, dims, rep, params, sds = _chat_cell(v5e, monkeypatch)
+    B, S, bucket = int(rep["num_slots"]), int(rep["max_seq"]), max(rep["prefill_buckets"])
+    cache = sds((dims["layers"], B, S, dims["kv_heads"] * dims["head_dim"]), jnp.bfloat16)
+
+    def admit(params, k_cache, v_cache, prompt, last_idx, slot):
+        h, pf_k, pf_v = gpt_prefill(params, pc, prompt)
+        k_cache, v_cache = cache_strip_put(k_cache, pf_k, slot, 0), cache_strip_put(v_cache, pf_v, slot, 0)
+        h_last = jax.lax.dynamic_slice_in_dim(h, last_idx, 1, axis=1)[:, 0]
+        return k_cache, v_cache, jnp.argmax(h_last @ params["lm_head"].T, axis=-1)
+
+    scalar = sds((), jnp.int32)
+    compiled = jax.jit(admit, donate_argnums=(1, 2)).lower(
+        params, cache, cache, sds((1, bucket), jnp.int32), scalar, scalar).compile()
+    m = compiled.memory_analysis()
+    print(f"{bucket} admission at {B} x {S}: temporaries {m.temp_size_in_bytes / GIB:.3f} GiB")
+    assert m.temp_size_in_bytes < dims["d"] * dims["ff"] * 2  # 0.109 GiB
+    assert _weights_sliced_out(compiled.as_text(), dims) == []
+
+
+def _weights_sliced_out(text, dims):
+    """The instructions of a compiled program that MAKE a copy of one layer's
+    weight leaf out of the stack: a ``dynamic-slice``, or a fusion named after
+    one, whose result has a leaf's shape (stored, flat or split, with or
+    without the leading 1 of the layer axis) and is materialised — it stands
+    in the entry computation or a loop's body, not inside a fusion, where a
+    slice is a view that the consuming matmul reads through."""
+    import re
+
+    D, F, H, Hkv, hd = (dims[k] for k in ("d", "ff", "heads", "kv_heads", "head_dim"))
+    leaves = {(D, F), (F, D), (D, 2, F), (D, H, hd), (D, H * hd), (D, 2, Hkv, hd), (D, 2 * Hkv * hd),
+              (H, hd, D), (H * hd, D)}
+    fused = set(re.findall(r" fusion\(.*calls=%([\w.\-]+)", text))
+    found, inside = [], None
+    for ln in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) \(.*\{$", ln)
+        if head:
+            inside = head.group(1)
+            continue
+        name, _, rest = ln.strip().partition(" = ")
+        name = name.replace("ROOT ", "").replace("_", "-")
+        sliced = " dynamic-slice(" in rest or ("dynamic-slice" in name and " fusion(" in rest)
+        if inside in fused or not sliced or "update" in name:
+            continue
+        got = re.match(r"\w+\[([\d,]*)\]", rest)
+        shape = tuple(int(x) for x in got.group(1).split(",") if x) if got else ()
+        if shape in leaves or (shape[:1] == (1,) and shape[1:] in leaves):
+            found.append(ln.strip()[:160])
+    return found
 
 
 @pytest.mark.parametrize(

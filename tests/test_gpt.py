@@ -522,23 +522,59 @@ def test_generate_with_sampling_filters():
     np.testing.assert_array_equal(np.asarray(greedy), np.asarray(greedy_filtered))
 
 
-def test_gqa_rope_shapes_and_kv_cache_equality():
+@pytest.mark.parametrize("family", ["gelu-stored", "swiglu-engine"])
+def test_gqa_rope_shapes_and_kv_cache_equality(family):
     """GQA (n_kv_head < n_head) + RoPE: params carry Hkv-headed kv and no
     wpe; greedy KV-cached decode (grouped Hkv cache) agrees with the full
-    forward at every position."""
+    forward at every position.
+
+    ``swiglu-engine``: a llama-style block (three layers, RMSNorm, SwiGLU)
+    whose decode runs on the tree an engine holds (``engine_weights``) while
+    the full forward keeps the stored one; and the re-formed tree's prefill
+    and first decode step give the stored tree's hidden states, K/V and
+    logits bit for bit (float32 on the CPU: the same sums in the same
+    order)."""
     import dataclasses
 
     import jax
+    import jax.numpy as jnp
 
-    from ray_lightning_tpu.models.gpt import gpt_generate
+    from ray_lightning_tpu.models.gpt import engine_weights, gpt_decode_step, gpt_generate, gpt_prefill
 
     cfg = dataclasses.replace(TINY, n_head=4, n_kv_head=2, pos_embed="rope")
-    params = init_gpt_params(jax.random.PRNGKey(3), cfg)
-    assert "wpe" not in params
-    assert params["blocks"]["wkv"].shape == (
+    if family == "swiglu-engine":
+        cfg = dataclasses.replace(
+            cfg, n_layer=3, norm_impl="rmsnorm", mlp_variant="swiglu", tie_word_embeddings=False
+        )
+    stored = init_gpt_params(jax.random.PRNGKey(3), cfg)
+    params = stored
+    if family == "swiglu-engine":
+        params = engine_weights(stored, cfg)
+        assert stored["blocks"]["wi"].shape == (3, cfg.d_model, 2, cfg.ff_dim)  # the interface, as it was
+        assert sorted(set(params["blocks"]) - set(stored["blocks"])) == ["wi_gate", "wi_up"]
+        assert params["blocks"]["wi_gate"].shape == (3, cfg.d_model, cfg.ff_dim)
+        assert params["blocks"]["wq"].shape == (3, cfg.d_model, 4 * cfg.head_dim)
+        assert params["blocks"]["wkv"].shape == (3, cfg.d_model, 2 * 2 * cfg.head_dim)
+        assert engine_weights(params, cfg)["blocks"].keys() == params["blocks"].keys()  # re-forming twice: nothing
+        toks = jax.random.randint(jax.random.PRNGKey(5), (2, 7), 0, cfg.vocab_size)
+
+        def prefill_then_step(p):
+            h, k, v = gpt_prefill(p, cfg, toks)
+            pad = ((0, 0), (0, 0), (0, 1), (0, 0), (0, 0))
+            return (h, k, v) + gpt_decode_step(
+                p, cfg, toks[:, 0], jnp.full((2,), 7, jnp.int32), jnp.pad(k, pad), jnp.pad(v, pad)
+            )
+
+        for got, want in zip(jax.jit(prefill_then_step)(params), jax.jit(prefill_then_step)(stored)):
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        params_fwd = stored
+    else:
+        params_fwd = params
+    assert "wpe" not in stored
+    assert stored["blocks"]["wkv"].shape == (
         cfg.n_layer, cfg.d_model, 2, 2, cfg.head_dim
     )
-    assert params["blocks"]["wq"].shape == (
+    assert stored["blocks"]["wq"].shape == (
         cfg.n_layer, cfg.d_model, 4, cfg.head_dim
     )
 
@@ -553,7 +589,7 @@ def test_gqa_rope_shapes_and_kv_cache_equality():
     )
     assert out.shape == (2, 13)
     for p in range(4, 12):
-        logits = gpt_forward(params, out[:, : p + 1], cfg)
+        logits = gpt_forward(params_fwd, out[:, : p + 1], cfg)
         np.testing.assert_array_equal(
             np.argmax(np.asarray(logits[:, -1]), -1), out[:, p + 1]
         )
@@ -957,20 +993,28 @@ def _as_rows(cache):
     return cache.reshape(cache.shape[:3] + (-1,))
 
 
-def _row_and_head_outputs(fn, variant, pos, **case_kw):
+def _row_and_head_outputs(fn, variant, pos, weights="stored", **case_kw):
     """``fn(params, cfg, cur_or_toks, pos, k, v)`` once on the 5-D cache and
     once on the cache of rows holding the same values. Random caches: K
-    differs in every KV head, so a wrong head-to-group map cannot pass."""
+    differs in every KV head, so a wrong head-to-group map cannot pass.
+    ``weights="engine"`` gives the run on rows the tree a single-device
+    engine holds (``engine_weights``: gate and up apart, ``wq`` / ``wkv``
+    flat) and leaves the head-axis run the stored tree."""
     import jax
     import jax.numpy as jnp
 
+    from ray_lightning_tpu.models.gpt import engine_weights
+
     cfg, params, cur, k_cache, v_cache = _decode_step_case(variant, **case_kw)
     pos = jnp.asarray(pos, jnp.int32)
+    held = {"stored": params, "engine": engine_weights(params, cfg)}[weights]
+    if weights == "engine":
+        assert "wi" not in held["blocks"] and held["blocks"]["wq"].ndim == 3
 
-    def run(k, v):
-        return jax.jit(lambda p, c, q, k, v: fn(p, cfg, c, q, k, v))(params, cur, pos, k, v)
+    def run(p, k, v):
+        return jax.jit(lambda p, c, q, k, v: fn(p, cfg, c, q, k, v))(p, cur, pos, k, v)
 
-    return cfg, (k_cache, v_cache), run(k_cache, v_cache), run(_as_rows(k_cache), _as_rows(v_cache))
+    return cfg, (k_cache, v_cache), run(params, k_cache, v_cache), run(held, _as_rows(k_cache), _as_rows(v_cache))
 
 
 def _assert_rows_equal_heads(before, heads_out, rows_out, changed_rows):
@@ -987,13 +1031,23 @@ def _assert_rows_equal_heads(before, heads_out, rows_out, changed_rows):
             assert np.flatnonzero(changed[b]).tolist() == sorted(want), (b, changed[b])
 
 
-@pytest.mark.parametrize("where", sorted(ROW_POSITIONS))
-@pytest.mark.parametrize("variant", sorted(ROW_VARIANTS))
-def test_decode_step_on_a_cache_of_rows_equals_the_step_on_the_head_axis_cache(variant, where):
+#: the re-formed tree beside the stored one, where a GQA / SwiGLU variant has leaves to re-form
+ENGINE_WEIGHTS_CASES = [("llama-gqa-rope", "inside", "engine"), ("llama-gqa-window-sinks", "past-the-end", "engine")]
+
+
+@pytest.mark.parametrize(
+    "variant,where,weights",
+    [(v, w, "stored") for v in sorted(ROW_VARIANTS) for w in sorted(ROW_POSITIONS)] + ENGINE_WEIGHTS_CASES,
+)
+def test_decode_step_on_a_cache_of_rows_equals_the_step_on_the_head_axis_cache(variant, where, weights):
+    """``weights="engine"``: the step as the single-device engine runs it (a
+    cache of rows AND the re-formed tree) against the step on the stored
+    tree: the first layer's rows bit for bit — the flat projections sum in
+    the einsum's order — and the logits to the file's tolerance."""
     from ray_lightning_tpu.models import gpt as G
 
     pos = ROW_POSITIONS[where]
-    cfg, before, heads_out, rows_out = _row_and_head_outputs(G.gpt_decode_step, variant, pos)
+    cfg, before, heads_out, rows_out = _row_and_head_outputs(G.gpt_decode_step, variant, pos, weights=weights)
     _assert_rows_equal_heads(before, heads_out, rows_out, [[min(p, 7)] for p in pos])
 
 
@@ -1011,8 +1065,10 @@ def test_decode_step_on_rows_fails_when_a_query_group_reads_its_neighbours_kv_he
     assert float(np.abs(np.asarray(rows_out[0]) - np.asarray(heads_out[0])).max()) > 0.1
 
 
-@pytest.mark.parametrize("variant", sorted(ROW_VARIANTS))
-def test_decode_verify_on_a_cache_of_rows_equals_verify_on_the_head_axis_cache(variant):
+@pytest.mark.parametrize(
+    "variant,weights", [(v, "stored") for v in sorted(ROW_VARIANTS)] + [("llama-gqa-rope", "engine")]
+)
+def test_decode_verify_on_a_cache_of_rows_equals_verify_on_the_head_axis_cache(variant, weights):
     """Three query rows a slot (Q > 1); the last slot's rows run past the
     cache's end and are dropped by the masked write in both layouts."""
     import jax
@@ -1026,7 +1082,7 @@ def test_decode_verify_on_a_cache_of_rows_equals_verify_on_the_head_axis_cache(v
         toks = jnp.stack([cur, (cur + 1) % cfg.vocab_size, (cur + 5) % cfg.vocab_size], axis=1)
         return G.gpt_decode_verify(params, cfg, toks, pos, k, v)
 
-    cfg, before, heads_out, rows_out = _row_and_head_outputs(verify, variant, pos)
+    cfg, before, heads_out, rows_out = _row_and_head_outputs(verify, variant, pos, weights=weights)
     assert rows_out[0].shape == (3, Q, cfg.vocab_size)
     _assert_rows_equal_heads(before, heads_out, rows_out, [[r for r in range(p, p + Q) if r < 8] for p in pos])
 

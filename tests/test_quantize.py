@@ -104,18 +104,33 @@ def test_quantized_path_equals_dequantized_oracle(cfg):
     ],
     ids=["gpt2-fused", "llama-gqa"],
 )
-def test_quantized_decode_matches_quantized_forward(cfg):
+@pytest.mark.parametrize("weights", ["stored", "engine"])
+def test_quantized_decode_matches_quantized_forward(cfg, weights):
     """Greedy decode from the quantized tree (prefill + cached scan)
     agrees with argmax over the quantized parallel forward — the decode
     consumers (embedding gather, fused AND grouped qkv, wo/mlp/head
-    dequants) all line up."""
+    dequants) all line up. ``engine``: the decode runs on the tree an
+    engine makes of the quantized one (``engine_weights``: int8 nodes
+    re-form with their scales; a fused gelu tree comes back as it is)."""
     import jax
     import jax.numpy as jnp
 
+    from ray_lightning_tpu.models.gpt import engine_weights
+
     params = quantize_params_int8(init_gpt_params(jax.random.PRNGKey(3), cfg))
+    held = params
+    if weights == "engine":
+        held = engine_weights(params, cfg)
+        if cfg.mlp_variant == "swiglu":
+            gate, wq = held["blocks"]["wi_gate"], held["blocks"]["wq"]
+            assert is_quantized(gate) and is_quantized(wq) and "wi" not in held["blocks"]
+            assert gate["q"].shape == (2, 32, 48) and gate["s"].shape == (2, 1, 48)
+            assert wq["q"].shape == (2, 32, 32) and wq["s"].shape == (2, 1, 32)
+        else:
+            assert held["blocks"].keys() == params["blocks"].keys()
     prompt = np.asarray([[5, 2, 7, 1]], np.int32)
     out = np.asarray(
-        gpt_generate(params, cfg, jnp.asarray(prompt), max_new_tokens=6)
+        gpt_generate(held, cfg, jnp.asarray(prompt), max_new_tokens=6)
     )
     assert out.shape == (1, 10)
     for p in range(3, 9):

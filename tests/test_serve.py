@@ -57,9 +57,9 @@ def engine(serve_params):
     )
 
 
-def _reference(params, prompt, n):
+def _reference(params, prompt, n, cfg=SERVE_CFG):
     out = gpt_generate(
-        params, SERVE_CFG, np.asarray(prompt, np.int32)[None], n
+        params, cfg, np.asarray(prompt, np.int32)[None], n
     )
     return np.asarray(out)[0].tolist()
 
@@ -82,10 +82,41 @@ def _prompt_whose_greedy(params, want, n, tries=64):
     pytest.fail(f"none of {tries} prompts has the continuation wanted")
 
 
-def test_engine_concurrent_matches_sequential_generate(engine, serve_params):
+#: Llama-style: three layers of RMSNorm, rotary GQA and SwiGLU — every leaf
+#: the engine re-forms when it is built (``engine_weights``).
+LLAMA_CFG = GPTConfig.llama(
+    vocab_size=97, n_layer=3, n_head=4, n_kv_head=2, d_model=32, d_ff=48,
+    max_seq=64, attn_impl="reference", compute_dtype="float32",
+)
+
+
+@pytest.mark.parametrize("family", ["gqa-gelu", "llama"])
+def test_engine_concurrent_matches_sequential_generate(family, request):
     """Different prompt/output lengths admitted together, a request joining
     mid-flight as another leaves: every output token-identical to solo
-    gpt_generate, with ZERO compiles after construction."""
+    gpt_generate, with ZERO compiles after construction.
+
+    ``llama``: the engine holds the re-formed tree (gate and up apart, the
+    GQA projections flat) and solo ``gpt_generate`` runs on the STORED tree
+    the engine was handed, which the engine left as it was."""
+    if family == "llama":
+        import jax
+
+        from ray_lightning_tpu.serve.engine import DecodeEngine
+
+        cfg = LLAMA_CFG
+        serve_params = init_gpt_params(jax.random.PRNGKey(0), cfg)
+        engine = DecodeEngine(
+            serve_params, cfg, num_slots=3, max_seq=64, prefill_buckets=[8, 16]
+        )
+        held, given = engine.params["blocks"], serve_params["blocks"]
+        assert "wi" not in held and held["wi_gate"].shape == (3, 32, 48)
+        assert held["wq"].shape == (3, 32, 32) and given["wq"].shape == (3, 32, 4, 8)
+        assert given["wi"].shape == (3, 32, 2, 48) and not given["wi"].is_deleted()
+    else:
+        cfg = SERVE_CFG
+        engine = request.getfixturevalue("engine")
+        serve_params = request.getfixturevalue("serve_params")
     compiles_before = engine.compiled_count
     rng = np.random.default_rng(0)
     reqs = [
@@ -116,7 +147,7 @@ def test_engine_concurrent_matches_sequential_generate(engine, serve_params):
             joined = True
     assert joined and engine.num_active == 0
     for i, (p, n) in enumerate(reqs):
-        assert p + outs[f"r{i}"] == _reference(serve_params, p, n), f"r{i}"
+        assert p + outs[f"r{i}"] == _reference(serve_params, p, n, cfg), f"r{i}"
     # No per-request recompilation: the count is frozen at construction.
     assert engine.compiled_count == compiles_before
 
