@@ -1567,28 +1567,41 @@ def _decode_rows_block(
     q_len: int,
     k_cache: Any,
     v_cache: Any,
+    kind: Optional[str] = None,
     backend: Optional[str] = None,
 ) -> int:
     """Which read a cached attention takes, from what it can observe: the
     rows of a block of the decode kernel (``ops/decode_attention.py``), or
     0 for the XLA read. The kernel wants a stacked cache of rows
-    ``(L, B, S, Hkv * hd)`` — or, the caches being the dicts by kind of
-    mixed layers (models/mixed.py), the ``"latent"`` kind's pair: latents
-    ``(L, B, S, rank)`` and rotary keys ``(L, B, S, rope)`` —, one query
-    row a slot, ``attn_impl="flash"``, a TPU (elsewhere it would run
-    interpreted: the engine's token-identity tests compare two XLA reads
-    in one order of sums) and shapes Mosaic takes (``decode_block``: row
-    widths a multiple of 128, a block that divides S). A mixed
-    configuration's K/V kinds keep their XLA read
-    (models/mixed.py:_attend_cache). ``serve/engine.py`` asks the same
-    question for its ``stats()["attn"]`` counters."""
+    ``(L, B, S, Hkv * hd)``, one query row a slot, ``attn_impl="flash"``,
+    a TPU (elsewhere it would run interpreted: the engine's token-identity
+    tests compare two XLA reads in one order of sums) and shapes Mosaic
+    takes (``decode_block``: row widths a multiple of 128, a block that
+    divides S).
+
+    The caches being the dicts by kind of mixed layers (models/mixed.py),
+    ``kind`` names the one asked about, and the answer is that kind's:
+
+    - ``"latent"``: the pair of latents ``(L, B, S, rank)`` and rotary
+      keys ``(L, B, S, rope)`` (``latent_decode_attention``);
+    - ``"full"``: K rows ``(L, B, S, Hkv * qk)`` and V rows ``(L, B, S,
+      Hkv * v)``, row ``r`` position ``r`` (``decode_attention``; the two
+      widths may differ) — unless a learnable sink logit joins that kind's
+      softmax (``cfg.attn_sink_logit``), which the kernel's sums do not
+      know;
+    - ``"window"``: 0. Its rows are a ring (row ``pos mod R``), not
+      positions ``0 .. pos``, and ``R`` rows a slot are all there is to
+      read (models/mixed.py:_attend_cache);
+    - a kind the model has no layer of, or one with no rows: 0.
+
+    ``serve/engine.py`` asks the same question, kind by kind, for its
+    ``stats()["attn"]`` counters."""
     from ray_lightning_tpu.ops.decode_attention import decode_block
 
-    latent = isinstance(k_cache, dict)
-    if latent:
-        if "latent" not in k_cache:
+    if isinstance(k_cache, dict):
+        if kind not in k_cache or kind not in ("full", "latent") or kind in cfg.attn_sink_logit:
             return 0
-        k_cache, v_cache = k_cache["latent"], v_cache["latent"]
+        k_cache, v_cache = k_cache[kind], v_cache[kind]
     if (
         k_cache.ndim != 4
         or q_len != 1
@@ -1596,7 +1609,9 @@ def _decode_rows_block(
         or (backend or jax.default_backend()) != "tpu"
     ):
         return 0
-    return decode_block(k_cache.shape[2], k_cache.shape[3], v_cache.shape[3], latent=latent)
+    return decode_block(
+        k_cache.shape[2], k_cache.shape[3], v_cache.shape[3], latent=kind == "latent"
+    )
 
 
 def _attend_layer_cache(

@@ -614,9 +614,16 @@ def _mlp(x: jax.Array, wi: jax.Array, wo: jax.Array, cfg: Any) -> jax.Array:
     return jnp.einsum("...f,fd->...d", mlp_act(z, cfg.mlp_variant), wo.astype(cdt))
 
 
-def _attention_part(h, lp, ls, cfg, rope, pos, caches):
-    """``(attention's write into the residual, kv)`` of one attention layer."""
-    from ray_lightning_tpu.models.gpt import _rmsnorm, _write_cache_rows
+def _attention_part(h, lp, ls, cfg, rope, pos, caches, live=None):
+    """``(attention's write into the residual, kv)`` of one attention layer.
+    In decode the read after the row's write is the one
+    ``models/gpt.py:_decode_rows_block`` names for the layer's kind: the
+    decode kernel (``ops/decode_attention.py:decode_attention``) over the
+    ``live`` slots' row blocks ``0 .. pos`` — a full kind without a sink
+    logit, on a TPU; a slot that is not live reads zeros — or
+    :func:`_attend_cache` over every allocated row, which ``live`` does
+    not reach: the ring, a kind with a sink, the CPU."""
+    from ray_lightning_tpu.models.gpt import _decode_rows_block, _rmsnorm, _write_cache_rows
 
     cdt = jnp.dtype(cfg.compute_dtype)
     B, S, _ = h.shape
@@ -655,10 +662,20 @@ def _attention_part(h, lp, ls, cfg, rope, pos, caches):
                 v_cache[ls.mixer], ls.mixer_index, v[:, 0].reshape(B, -1), row
             )
             kv = (k_cache, v_cache)
-            o = _attend_cache(
-                q, k_cache[ls.mixer][ls.mixer_index], v_cache[ls.mixer][ls.mixer_index],
-                pos, sink, window, ring,
-            )
+            block = _decode_rows_block(cfg, S, k_cache, v_cache, ls.mixer)
+            if block:
+                from ray_lightning_tpu.ops.decode_attention import decode_attention
+
+                # the stacked caches whole: a layer sliced out for a custom call is a copy of it a step
+                o = decode_attention(
+                    q[:, 0].reshape(B, cfg.n_head, -1), k_cache[ls.mixer], v_cache[ls.mixer],
+                    ls.mixer_index, pos, live, block=block,
+                )[:, None]
+            else:
+                o = _attend_cache(
+                    q, k_cache[ls.mixer][ls.mixer_index], v_cache[ls.mixer][ls.mixer_index],
+                    pos, sink, window, ring,
+                )
         return jnp.einsum("bshk,hkd->bsd", o.astype(cdt), lp["wo"].astype(cdt)), kv
 
 
@@ -686,7 +703,7 @@ def _attend_latent_cache(cfg, q_lat, q_rope, c_cache, r_cache, li, pos, live=Non
     from ray_lightning_tpu.models.gpt import _decode_rows_block
 
     scale = 1.0 / np.sqrt(qk_dim(cfg))
-    block = _decode_rows_block(cfg, 1, c_cache, r_cache)
+    block = _decode_rows_block(cfg, 1, c_cache, r_cache, "latent")
     if block:
         from ray_lightning_tpu.ops.decode_attention import latent_decode_attention
 
@@ -830,11 +847,9 @@ def mixed_block(
     if ls.mixer == "ssm":
         out, kv = _state_part(h, lp, ls, cfg, caches, valid)
         h = h + out
-    elif ls.mixer == "latent":
-        out, kv = _latent_part(h, lp, ls, cfg, rope, pos, caches, None if valid is None else valid[:, 0])
-        h = h + out
     elif ls.mixer:
-        out, kv = _attention_part(h, lp, ls, cfg, rope, pos, caches)
+        part = _latent_part if ls.mixer == "latent" else _attention_part
+        out, kv = part(h, lp, ls, cfg, rope, pos, caches, None if valid is None else valid[:, 0])
         h = h + out
     if ls.mlp:
         m = _rmsnorm(h, lp["ln2_g"], cfg.norm_eps)

@@ -37,9 +37,17 @@ head's block: every added term is an exact zero, and the read is bound by
 the cache's bytes either way. Widths come from the arguments' shapes, K's
 and V's separately.
 
-**Two kinds of cache, one walk** (:func:`_walk`: the slots, the blocks, the
+**Three callers, one walk** (:func:`_walk`: the slots, the blocks, the
 copies in flight, the softmax's running sums). :func:`decode_attention`
-reads K and V rows of ``Hkv`` heads side by side, as above.
+reads K and V rows of ``Hkv`` heads side by side, as above: the dense
+engine's uniform cache (``models/gpt.py:_attend_layer_cache``), and a mixed
+configuration's full kind (``models/mixed.py:_attention_part``), whose K
+rows and V rows differ in width — 4 KV heads of 192 against 128 are rows
+of 768 and 512; a head of 192, one and a half lane tiles, is laid over
+the row by the same concatenation along the lanes, which Mosaic takes
+(``tests/test_latent_step_v5e.py``). A window kind's ring (row ``pos mod
+R``) and a kind whose softmax a learnable sink logit joins keep the XLA
+read (``models/gpt.py:_decode_rows_block`` says which, kind by kind).
 :func:`latent_decode_attention` reads a latent layer's pair
 (``models/mixed.py:_latent_part``'s absorbed decode): one latent row a
 position, ``(L, B, S, rank)``, is the keys AND the values of every head, so
@@ -83,21 +91,30 @@ def decode_block(seq: int, k_width: int, v_width: int, latent: bool = False) -> 
     shared rotary keys of ``v_width``, which the kernel takes with the
     positions minor, so they may be half a lane tile of sublanes.
 
-    256 where the rows are 1024 wide or less, from the chip (PERF.md §6,
-    PR 33; us a layer at 64 slots x 2048 x 1024, a quarter / half / all of
-    the slots live): 128 rows 46 / 228 / 741, **256: 49 / 232 / 718**,
-    512: 58 / 248 / 718, 1024: 98 / 280 / 719 — a larger block reads more
-    dead rows behind a short request, a smaller one issues more copies for
-    a long one. A latent pair, whose position is 1,152 B where those rows'
-    is 4,096, takes 512 first (PERF.md §6, PR 39; us a layer at 64 slots x
-    6656 x (512 + 64), the docqa mix / half / all): 128 rows 226 / 489 /
-    1,648, 256: 157 / 331 / 1,081, **512: 124 / 253 / 785** — a block's
-    step costs about 0.35 us beside its bytes, which two buffers do not
-    hide."""
+    The largest of 512, 256 and 128 rows whose wider block stays within
+    ``_BLOCK_ELEMS``: the narrower the rows, the more rows a block, since a
+    block's step costs about 0.35 us beside its bytes, which two buffers do
+    not hide, while a larger block reads more dead rows behind a short
+    request. From the chip, us a layer's call:
+
+    - rows 1024 wide (4,096 B a position in K and V: the chat cell, PERF.md
+      §6, PR 33; 64 slots x 2048, a quarter / half / all of the slots
+      live): 128 rows 46 / 228 / 741, **256: 49 / 232 / 718**, 512: 58 /
+      248 / 718, 1024: 98 / 280 / 719;
+    - K rows 768 and V rows 512 wide (2,560 B: the mixedlen cell's full
+      layers, PR 46; 64 slots x 5120, a quarter at the mix's lengths /
+      half / all): 128 rows 175 / 471 / 1,602, **256: 130 / 340 / 1,133**,
+      512: 135 / 349 / 1,119 — the XLA read 1,169 whatever is live;
+    - K and V rows 256 wide (1,024 B: the shortchat cell's one attention
+      layer, PR 46; 128 slots x 2048): 128 rows 85 / 294 / 985, 256: 59 /
+      181 / 565, **512: 47 / 134 / 381** — the XLA read 399;
+    - a latent pair (1,152 B: the docqa cell, PR 39; 64 slots x 6656 x
+      (512 + 64)): 128 rows 226 / 489 / 1,648, 256: 157 / 331 / 1,081,
+      **512: 124 / 253 / 785**."""
     width = max(k_width, v_width)
     if k_width % 128 or v_width % (64 if latent else 128):
         return 0
-    for block in ((512,) if latent else ()) + (256, 128):
+    for block in (512, 256, 128):
         if seq % block == 0 and block * width <= _BLOCK_ELEMS:
             return block
     return 0

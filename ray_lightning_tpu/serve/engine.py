@@ -868,14 +868,20 @@ class DecodeEngine:
             "prefill": {"rows_scanned": 0, "rows_real": 0},
         }
         self._state_layers = 0
-        #: Layers whose decode read ``attn_totals`` counts: every layer of
-        #: a uniform configuration; of mixed layer kinds the latent ones.
-        self._attn_layers = config.n_layer
+        #: Layers whose decode read ``attn_totals`` counts, by the kind of
+        #: cache they read: every layer of a uniform configuration (under
+        #: None); of mixed layer kinds the full and the latent ones, whose
+        #: rows are a request's positions (a window kind's ring is read
+        #: whole, a slot's last ``attn_window`` positions, and not counted).
+        self._attn_layers: Dict[Optional[str], int] = {None: config.n_layer}
         if config.mixed:
             from ray_lightning_tpu.models.mixed import count_kind
 
             self._state_layers = count_kind(config, "ssm")
-            self._attn_layers = count_kind(config, "latent")
+            self._attn_layers = {
+                kind: count_kind(config, kind)
+                for kind in ("full", "latent") if count_kind(config, kind)
+            }
         #: What the decode steps' cached attention read, in cache rows
         #: summed over token steps and layers: the rows allocated to the
         #: slots, those the read visited and those of live requests'
@@ -884,17 +890,20 @@ class DecodeEngine:
         self.attn_totals: Dict[str, int] = {
             "rows_allocated": 0, "rows_visited": 0, "rows_live": 0,
         }
-        #: Rows of the decode kernel's block when the fold's read is the
-        #: kernel (models/gpt.py:_decode_rows_block: a uniform cache of
-        #: rows, or the latent layers' of mixed kinds), else 0: the XLA
-        #: read visits every allocated row.
-        self._attn_block = 0
+        #: By the same kinds, the rows of the decode kernel's block when
+        #: the fold's read of that kind is the kernel — the model's own
+        #: answer (models/gpt.py:_decode_rows_block: a uniform cache of
+        #: rows, the latent layers' pair, a full kind's K and V without a
+        #: sink logit; on a TPU) —, else 0: the XLA read visits every
+        #: allocated row.
+        self._attn_block: Dict[Optional[str], int] = dict.fromkeys(self._attn_layers, 0)
         if self._k is not None and self.spec == "off":
             from ray_lightning_tpu.models.gpt import _decode_rows_block
 
-            self._attn_block = _decode_rows_block(
-                config, 1, self._k, self._v
-            )
+            for kind in self._attn_block:
+                self._attn_block[kind] = _decode_rows_block(
+                    config, 1, self._k, self._v, kind
+                )
         from ray_lightning_tpu.obs.registry import get_registry as _greg
 
         _reg = _greg()
@@ -2144,9 +2153,11 @@ class DecodeEngine:
         multiplied and masked afterwards); under the decode kernel
         (``ops/decode_attention.py``) it is the blocks up to each live
         slot's position. Of mixed layer kinds, whose caches differ by
-        kind, the latent layers' rows are counted (read by the same
-        kernel on a TPU: models/mixed.py:_latent_part); ``{}`` without
-        such a layer."""
+        kind, the rows of the kinds that keep a request's every position
+        are counted, the full and the latent layers' (read by the same
+        kernel on a TPU: models/mixed.py:_attention_part, _latent_part),
+        each kind by the read its layers take; ``{}`` without such a
+        layer."""
         return dict(self.attn_totals) if self._attn_layers else {}
 
     def ssm_stats(self) -> Dict[str, Any]:
@@ -3844,7 +3855,9 @@ class DecodeEngine:
         #: the accept-rate accounting (zombie tokens of released tenants
         #: are dropped above AND excluded here).
         counts: Dict[Tuple[int, int], int] = {}
-        blk, rows_live, rows_visited = self._attn_block, 0, 0
+        # rows the kernel visits, by the block it walks in (a block a
+        # counted kind at most: one of them in every configuration so far)
+        rows_live, rows_visited = 0, {b: 0 for b in self._attn_block.values() if b}
         for kk in range(toks.shape[0]):
             for slot, info in enumerate(snapshot):
                 if info is None or info.released or not emits[kk, slot]:
@@ -3855,8 +3868,8 @@ class DecodeEngine:
                     # the kernel's blocks that hold them
                     rows = info.prompt_len + info.n_generated
                     rows_live += rows
-                    if blk:
-                        rows_visited += -(-rows // blk) * blk
+                    for blk in rows_visited:
+                        rows_visited[blk] += -(-rows // blk) * blk
                 info.n_generated += 1
                 done = (
                     info.n_generated >= info.max_new_tokens
@@ -3868,15 +3881,15 @@ class DecodeEngine:
                     counts[key] = counts.get(key, 0) + 1
                 if done:
                     self._release_synced(slot, info)
-        if self._attn_layers:
-            layers = self._attn_layers
+        for kind, layers in self._attn_layers.items():
+            blk = self._attn_block[kind]
             allocated = (
                 (toks.shape[0] // group) * layers * self.num_slots
                 * self.max_seq
             )
             self.attn_totals["rows_allocated"] += allocated
             self.attn_totals["rows_visited"] += (
-                layers * rows_visited if blk else allocated
+                layers * rows_visited[blk] if blk else allocated
             )
             self.attn_totals["rows_live"] += layers * rows_live
         if counts:

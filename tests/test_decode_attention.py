@@ -6,7 +6,10 @@ with NaN — the proof that the kernel reads no row that holds no live
 position. Mosaic's own view of the kernel is ``tests/test_decode_rows_v5e.py``.
 The second half does the same for the latent kind's call
 (``latent_decode_attention`` against ``models/mixed.py:_attend_latent_cache``'s
-XLA read), whose view by Mosaic is ``tests/test_latent_step_v5e.py``.
+XLA read), whose view by Mosaic is ``tests/test_latent_step_v5e.py``. The
+third for a mixed configuration's full K/V kind, whose K and V rows differ
+in width (heads of 192 against 128), against ``models/mixed.py:_attend_cache``;
+Mosaic's view of it is ``tests/test_latent_step_v5e.py`` too.
 """
 import numpy as np
 import pytest
@@ -245,9 +248,9 @@ def test_latent_kernel_equals_the_xla_read_of_latents(shape, where, monkeypatch)
     q_lat, q_rope, cc, rc, pos = _latent_inputs(shape, [_latent_positions(S, block)[where], 200, 300, 5])
     live = jnp.asarray([True, True, False, True])
     want = _latent_read(monkeypatch, kernel=False)(cfg, q_lat, q_rope, cc, rc, L - 1, pos)
-    assert G._decode_rows_block(cfg, 1, cc, rc) == 0, "off the TPU the engine keeps the XLA read"
+    assert G._decode_rows_block(cfg, 1, cc, rc, "latent") == 0, "off the TPU the engine keeps the XLA read"
     got = _latent_read(monkeypatch, kernel=True)(cfg, q_lat, q_rope, cc, rc, L - 1, pos, live)
-    assert G._decode_rows_block(cfg, 1, cc, rc) == block
+    assert G._decode_rows_block(cfg, 1, cc, rc, "latent") == block
     assert got.shape == want.shape == (4, 4, rank) and got.dtype == want.dtype == jnp.float32
     np.testing.assert_allclose(np.asarray(got)[[0, 1, 3]], np.asarray(want)[[0, 1, 3]], atol=1e-5, rtol=0)
     assert not np.asarray(got)[2].any()
@@ -382,7 +385,176 @@ def test_everything_else_keeps_the_xla_read_of_latents(case):
     cc = {kind: jax.ShapeDtypeStruct((16, 64, rows, rank), jnp.bfloat16)}
     rc = {kind: jax.ShapeDtypeStruct((16, 64, rows, rope), jnp.bfloat16)}
     q_len = 3 if case == "verify_q3" else 1
-    assert G._decode_rows_block(cfg, q_len, cc, rc, backend=None if case == "cpu" else "tpu") == 0
+    assert G._decode_rows_block(cfg, q_len, cc, rc, "latent", backend=None if case == "cpu" else "tpu") == 0
     sound = {"latent": jax.ShapeDtypeStruct((16, 64, 6656, 512), jnp.bfloat16)}, {
         "latent": jax.ShapeDtypeStruct((16, 64, 6656, 64), jnp.bfloat16)}
-    assert G._decode_rows_block(_latent_cfg(), 1, *sound, backend="tpu") == 512
+    assert G._decode_rows_block(_latent_cfg(), 1, *sound, "latent", backend="tpu") == 512
+
+
+# -- a mixed configuration's full kind: K rows and V rows of different widths ---------------
+#: 8 query heads on 2 KV heads, q·k heads of 192 (one and a half lane tiles) and v heads of 128: K rows of 384,
+#: V rows of 256, three blocks of 128 positions a slot. Only heads, widths, the sink and attn_impl reach the read.
+KV = dict(
+    vocab_size=96, n_layer=2, n_head=8, n_kv_head=2, n_kv_head_window=2, d_model=64, d_ff=64, qk_head_dim=192,
+    v_head_dim=128, max_seq=S, pos_embed="rope", rope_dim=64, norm_impl="rmsnorm", mlp_variant="swiglu",
+    tie_word_embeddings=False, attn_window=128, attn_sink_logit=["window"], attn_value_scale=0.707,
+    layer_types=[["full", "dense"], ["window", "dense"]], compute_dtype="float32",
+)
+
+
+def _kv_inputs(pos, seed=0, layers=L):
+    import jax
+    import jax.numpy as jnp
+
+    B, (H, G, dk, dv) = len(pos), (KV[k] for k in ("n_head", "n_kv_head", "qk_head_dim", "v_head_dim"))
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    q = jax.random.normal(ks[0], (B, 1, G, H // G, dk), jnp.float32)
+    kc = jax.random.normal(ks[1], (layers, B, S, G * dk), jnp.float32)
+    vc = jax.random.normal(ks[2], (layers, B, S, G * dv), jnp.float32)
+    return q, kc, vc, jnp.asarray(pos, jnp.int32)
+
+
+def _kv_xla(q, kc, vc, li, pos):
+    """The read the kernel takes the place of, on the same inputs: (B, H, dv)."""
+    from ray_lightning_tpu.models.mixed import _attend_cache
+
+    return np.asarray(_attend_cache(q, kc[li], vc[li], pos, None, 0, False))[:, 0]
+
+
+def _kv_kernel(q, kc, vc, li, pos, live):
+    from ray_lightning_tpu.ops.decode_attention import decode_attention
+
+    B = q.shape[0]
+    return np.asarray(decode_attention(q[:, 0].reshape(B, KV["n_head"], -1), kc, vc, li, pos, live, block=BLOCK))
+
+
+@pytest.mark.parametrize("where", sorted(POSITIONS))
+def test_kernel_equals_the_xla_read_of_k_and_v_rows_of_different_widths(where):
+    """Slot 0 at the named position, slot 1 elsewhere, slot 2 NOT live
+    (zeros), slot 3 behind an idle one."""
+    import jax.numpy as jnp
+
+    q, kc, vc, pos = _kv_inputs([POSITIONS[where], 200, 300, 5])
+    want = _kv_xla(q, kc, vc, 1, pos)
+    got = _kv_kernel(q, kc, vc, 1, pos, jnp.asarray([True, True, False, True]))
+    assert got.shape == want.shape == (4, 8, 128) and got.dtype == np.float32
+    np.testing.assert_allclose(got[[0, 1, 3]], want[[0, 1, 3]], atol=1e-5, rtol=0)
+    assert not got[2].any()
+
+
+def test_k_and_v_blocks_past_a_slots_position_are_not_read():
+    import jax.numpy as jnp
+
+    q, kc, vc, pos = _kv_inputs([0, BLOCK - 1, 300, BLOCK, 2 * BLOCK - 1])
+    live = jnp.asarray([True, True, False, True, True])
+    pk, pv = _poisoned(kc, vc, pos[:, None], live)
+    assert np.isnan(_kv_xla(q, pk, pv, 0, pos)).any()  # 0 x NaN behind the mask
+    got = _kv_kernel(q, pk, pv, 0, pos, live)
+    assert np.isfinite(got).all()
+    keep = np.asarray(live)
+    np.testing.assert_allclose(got[keep], _kv_xla(q, kc, vc, 0, pos)[keep], atol=1e-5, rtol=0)
+
+
+@pytest.mark.parametrize("off", [-1, 1], ids=["one_block_short", "one_block_far"])
+def test_a_k_and_v_block_count_off_by_one_fails(off, monkeypatch):
+    """The planted fault of the walk the three callers share, seen through
+    rows of two widths."""
+    import jax.numpy as jnp
+
+    from ray_lightning_tpu.ops import decode_attention as D
+
+    real = D._last_block
+    monkeypatch.setattr(
+        D, "_last_block", lambda pos, block, seq: jnp.clip(real(pos, block, seq) + off, 0, seq // block - 1))
+    q, kc, vc, pos = _kv_inputs([BLOCK + 3, BLOCK + 90])
+    live = jnp.asarray([True, True])
+    pk, pv = _poisoned(kc, vc, pos[:, None], live)
+    got = _kv_kernel(q, pk, pv, 0, pos, live)
+    if off > 0:
+        assert np.isnan(got).any()
+    else:
+        assert np.isfinite(got).all() and np.abs(got - _kv_xla(q, kc, vc, 0, pos)).max() > 1e-2
+
+
+@pytest.mark.parametrize("case,kind,want", [
+    ("full_without_a_sink", "full", BLOCK), ("the_window_ring", "window", 0), ("full_with_a_sink", "full", 0),
+    ("verify_q3", "full", 0), ("cpu", "full", 0), ("attn_impl_reference", "full", 0), ("v_rows_of_96", "full", 0),
+    ("a_kind_the_model_has_no_layer_of", "latent", 0), ("the_state_layers_tuples", "ssm", 0),
+])
+def test_the_selection_answers_kind_by_kind(case, kind, want):
+    """``_decode_rows_block`` on a mixed configuration's caches: the full
+    kind's rows walk where no sink joins its softmax; the ring, whose rows
+    are not positions ``0 .. pos``, and everything the uniform selection
+    refuses keep the XLA read."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_lightning_tpu.models import gpt as G
+
+    cfg = GPTConfig(**dict(
+        KV, attn_sink_logit=["window", "full"] if case == "full_with_a_sink" else ["window"],
+        attn_impl="reference" if case == "attn_impl_reference" else "flash"))
+    dv = 48 if case == "v_rows_of_96" else 128
+
+    def rows(n, width):
+        return jax.ShapeDtypeStruct((1, 4, n, width), jnp.bfloat16)
+
+    kc = {"full": rows(S, 2 * 192), "window": rows(128, 2 * 192), "ssm": (rows(S, 384),)}
+    vc = {"full": rows(S, 2 * dv), "window": rows(128, 2 * dv), "ssm": (rows(S, 256),)}
+    q_len = 3 if case == "verify_q3" else 1
+    assert G._decode_rows_block(cfg, q_len, kc, vc, kind, backend=None if case == "cpu" else "tpu") == want
+    assert G._decode_rows_block(cfg, q_len, kc, vc, kind) == 0
+
+
+def test_a_mixed_decode_step_with_the_kernel_gives_the_xla_steps_logits(monkeypatch):
+    """The whole token step of a full layer and a window layer (projections,
+    rotary, the value scale, the row writes, the reads, the MLPs): the full
+    layer's read through the kernel, the ring's through XLA in both."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_lightning_tpu.models import gpt as G
+    from ray_lightning_tpu.models.mixed import empty_caches
+    from ray_lightning_tpu.ops import decode_attention as D
+    from tests.utils import force_decode_kernel
+
+    cfg = GPTConfig(**KV)
+    params = G.init_gpt_params(jax.random.PRNGKey(1), cfg)
+    B = 3
+    kc, vc = empty_caches(cfg, B, S, jnp.float32)
+    ks = iter(jax.random.split(jax.random.PRNGKey(2), 4))
+    kc = {kind: 0.3 * jax.random.normal(next(ks), a.shape, jnp.float32) for kind, a in kc.items()}
+    vc = {kind: 0.3 * jax.random.normal(next(ks), a.shape, jnp.float32) for kind, a in vc.items()}
+    cur = jnp.asarray([5, 17, 44], jnp.int32)
+    pos = jnp.asarray([BLOCK, S - 1, 9], jnp.int32)  # a block's first row, the cache's last, a slot that is not live
+    active = jnp.asarray([True, True, False])
+    want = G.gpt_decode_step(params, cfg, cur, pos, kc, vc, active)
+    force_decode_kernel(monkeypatch)
+    real, calls = D.decode_attention, []
+
+    def spy(*a, **kw):
+        calls.append(a[3])
+        return real(*a, **kw)
+
+    monkeypatch.setattr(D, "decode_attention", spy)
+    got = G.gpt_decode_step(params, cfg, cur, pos, kc, vc, active)
+    assert calls == [0], "the full kind's one layer, by its index in the stack; the ring keeps the XLA read"
+    np.testing.assert_allclose(np.asarray(got[0])[:2], np.asarray(want[0])[:2], atol=2e-4, rtol=0)
+    for a, b in zip(got[1:], want[1:]):
+        for kind in ("full", "window"):
+            np.testing.assert_allclose(np.asarray(a[kind])[:, :2], np.asarray(b[kind])[:, :2], atol=2e-4, rtol=0)
+
+
+@pytest.mark.parametrize("seq,k_width,v_width,latent,want", [
+    (2048, 1024, 1024, False, 256), (1024, 1024, 1024, False, 256), (5120, 768, 512, False, 256),
+    (2048, 256, 256, False, 512), (6656, 512, 64, True, 512), (384, 384, 256, False, 128), (2048, 2048, 2048, False, 128),
+    (2048, 4096, 4096, False, 0), (2048, 192, 128, False, 0), (100, 256, 256, False, 0),
+], ids=["chat_rows", "gpt2_rows", "mixedlen_k768_v512", "shortchat_rows_256", "docqa_latent_pair", "three_blocks_of_128",
+        "rows_2048_wide", "rows_too_wide", "a_width_off_the_lanes", "no_block_divides_the_rows"])
+def test_the_block_follows_the_rows_width(seq, k_width, v_width, latent, want):
+    """``decode_block``: the largest of 512 / 256 / 128 rows whose wider
+    block stays within 256K elements — what the chip's tables chose for each
+    cell's rows (its docstring has them)."""
+    from ray_lightning_tpu.ops.decode_attention import decode_block
+
+    assert decode_block(seq, k_width, v_width, latent=latent) == want

@@ -192,9 +192,9 @@ def test_a_decode_step_through_the_kernel_gives_the_xla_steps_logits(monkeypatch
     pos = jnp.asarray([128, 383, 9], jnp.int32)  # a block's first row, the cache's last, and a slot that is not live
     active = jnp.asarray([True, True, False])
     want = G.gpt_decode_step(params, cfg, cur, pos, kc, vc, active)
-    assert G._decode_rows_block(cfg, 1, kc, vc) == 0
+    assert G._decode_rows_block(cfg, 1, kc, vc, "latent") == 0
     force_decode_kernel(monkeypatch)
-    assert G._decode_rows_block(cfg, 1, kc, vc) == 128
+    assert G._decode_rows_block(cfg, 1, kc, vc, "latent") == 128
     got = G.gpt_decode_step(params, cfg, cur, pos, kc, vc, active)
     np.testing.assert_allclose(np.asarray(got[0])[:2], np.asarray(want[0])[:2], atol=2e-4, rtol=0)
     for a, b in zip(got[1:], want[1:]):

@@ -52,14 +52,14 @@ def v5e():
     cc.reset_cache()
 
 
-@pytest.fixture(scope="module", params=["xla", "kernel"])
-def fold(v5e, request):
-    """The cell's decode fold, lowered as ``serve/engine.py`` lowers
+def _decode_fold(v5e, cell_name, read, sizes, k_shapes, v_shapes):
+    """A cell's decode fold, lowered as ``serve/engine.py`` lowers
     ``step_impl``: ``(compiled, slots, positions, seconds it took, read)``.
     ``xla``: the read the engine takes off the TPU (``jax.default_backend()``
-    is the CPU here); ``kernel``: the read it takes on the chip — the
-    fixture says "tpu" where the program asks, as
-    ``tests/test_decode_rows_v5e.py`` does."""
+    is the CPU here); ``kernel``: the read it takes on the chip — said
+    "tpu" where the program asks, as ``tests/test_decode_rows_v5e.py``
+    does. ``sizes`` (slots, positions) and the caches' shapes by kind are
+    what the caller's numbers were read at."""
     import sys
 
     import jax
@@ -77,11 +77,11 @@ def fold(v5e, request):
     from ray_lightning_tpu.models.mixed import empty_caches
 
     mp = pytest.MonkeyPatch()
-    if request.param == "kernel":
+    if read == "kernel":
         mp.setattr(jax, "default_backend", lambda: "tpu")
     t0 = time.monotonic()
     spec = Spec(ROOT)
-    cell = spec.cell(CELL)
+    cell = spec.cell(cell_name)
     cfg, rep = spec.config(cell["config"]), spec.traffic(cell["traffic"])["replica"]
     dims = spec.dims(cfg)
     pc = GPTConfig(**cfg["program_config"])
@@ -94,10 +94,11 @@ def fold(v5e, request):
     params = {k: sds(v[0], dt) for k, v in shapes.items() if k != "blocks"}
     params["blocks"] = {k: sds(v[0], dt) for k, v in shapes["blocks"].items()}
     B, S = int(rep["num_slots"]), int(rep["max_seq"])
-    assert (B, S) == (64, 6656), "the sizes below are this cell's"
+    assert (B, S) == sizes, "the sizes below are this cell's"
     k_cache, v_cache = jax.tree_util.tree_map(
         lambda a: sds(a.shape, a.dtype), jax.eval_shape(lambda: empty_caches(pc, B, S, dt)))
-    assert k_cache["latent"].shape == (16, B, S, 512) and v_cache["latent"].shape == (16, B, S, 64)
+    assert {k: a.shape for k, a in k_cache.items()} == k_shapes
+    assert {k: a.shape for k, a in v_cache.items()} == v_shapes
     i32, f32 = (lambda: sds((B,), jnp.int32)), (lambda: sds((B,), jnp.float32))
 
     def step(params, k_cache, v_cache, cur, pos, temps, top_ks, top_ps, keys, active, remaining, eos):
@@ -112,7 +113,14 @@ def fold(v5e, request):
         ).compile()
     finally:
         mp.undo()
-    return compiled, B, S, time.monotonic() - t0, request.param
+    return compiled, B, S, time.monotonic() - t0, read
+
+
+@pytest.fixture(scope="module", params=["xla", "kernel"])
+def fold(v5e, request):
+    """The latent cell's decode fold."""
+    return _decode_fold(v5e, CELL, request.param, (64, 6656),
+                        {"latent": (16, 64, 6656, 512)}, {"latent": (16, 64, 6656, 64)})
 
 
 def test_the_latent_cells_decode_fold_keeps_its_sizes(fold):
@@ -143,3 +151,44 @@ def test_the_fold_copies_no_latent_layers_cache(fold):
     # the keys with the positions minor, as the kernel takes them: the cache's own bytes, a call a layer
     turned = [ln.strip()[:160] for ln in text if re.search(rf"= bf16\[16,{B},64,{S}\]", ln)]
     assert len(turned) == (16 if read == "kernel" else 0) and all(" bitcast(" in ln for ln in turned), turned
+
+
+# -- the mixed cell's full layers: K rows of 768, V rows of 512 -----------------------------------------
+MIMO = "mimo-v2-flash-d7-ep16.serve-mixedlen"
+
+
+@pytest.fixture(scope="module", params=["xla", "kernel"])
+def mimo_fold(v5e, request):
+    """``mimo-v2-flash-d7-ep16.serve-mixedlen``'s decode fold: 64 slots x
+    5,120 positions, two full layers (4 KV heads of 192 / 128: a layer's K
+    and V are 503 + 336 MB) and five window layers on rings of 128 rows."""
+    return _decode_fold(
+        v5e, MIMO, request.param, (64, 5120),
+        {"full": (2, 64, 5120, 4 * 192), "window": (5, 64, 128, 8 * 192)},
+        {"full": (2, 64, 5120, 4 * 128), "window": (5, 64, 128, 8 * 128)})
+
+
+def test_the_mixed_cells_decode_fold_walks_its_full_layers_and_copies_none(mimo_fold):
+    """Under the kernel the token step holds two ``decode_attention`` custom
+    calls, one a full layer (the five window layers keep the XLA read of
+    their rings, sink and all), handed the stacked K and V whole: no
+    instruction copies or transposes an array the size of a layer's K or V
+    (503 / 336 MB), or of the stack of them, under either read."""
+    compiled, B, S, took, read = mimo_fold
+    m = compiled.memory_analysis()
+    whole = m.argument_size_in_bytes + m.output_size_in_bytes - m.alias_size_in_bytes + m.temp_size_in_bytes
+    cache = 2 * B * S * (768 + 512) * 2 + 5 * B * 128 * (1536 + 1024) * 2
+    print(f"mixed cell's decode fold at {B} x {S}, {read} read: temporaries {m.temp_size_in_bytes / GIB:.3f} GiB, "
+          f"whole program {whole / GIB:.2f} GiB, built in {took:.0f} s")
+    assert m.alias_size_in_bytes >= cache  # the caches are updated where they lie
+    assert m.temp_size_in_bytes < 0.3 * GIB  # 0.054 / 0.044 read; one layer's V copied would be 0.31 more
+    assert whole < 8.6 * GIB  # 8.20 / 8.19 read: 6.4 of weights, 1.76 of K, V and rings; the chip has 16
+    assert took < 300, "the guard's own time limit: 30 s read"
+    text = compiled.as_text().splitlines()
+    mosaic = [ln for ln in text if 'custom_call_target="tpu_custom_call"' in ln]
+    # the fold is a scan: its body, one token step, is in the program once, a call a full layer
+    assert len(mosaic) == (2 if read == "kernel" else 0), mosaic
+    assert all("decode_attention" in ln.split(" = ")[0] for ln in mosaic), mosaic
+    size = re.compile(rf"= bf16\[(2,)?{B},{S},(768|512)\]\S* (copy|transpose)\(")
+    hits = [ln.strip()[:160] for ln in text if size.search(ln)]
+    assert not hits, hits

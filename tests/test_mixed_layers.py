@@ -230,3 +230,87 @@ def test_the_replica_serves_it_and_reports_the_expert_layers_and_both_caches(par
         assert f'rlt_serve_kv_bytes{{kind="window"}} {cache["window"]["bytes"]}\n' in text
     finally:
         rep.stop()
+
+
+# -- the full layers' decode read and its counter (ops/decode_attention.py) ----------------------------
+#: MIXED at widths Mosaic takes: full K rows of 2 x 192, V rows of 2 x 128, three blocks of 128 positions a slot
+KERNEL = dict(MIXED, n_head=8, n_kv_head=2, qk_head_dim=192, v_head_dim=128, rope_dim=64, max_seq=384, attn_window=128)
+
+
+def _serve(rep, sizes, new_tokens=20):
+    rng = np.random.default_rng(1)
+    rids = [rep.submit(rng.integers(0, 96, size=n).tolist(), max_new_tokens=new_tokens) for n in sizes]
+    deadline = time.monotonic() + 240
+    out = []
+    for rid in rids:
+        while not (res := rep.result(rid, wait_s=0.2))["done"]:
+            assert time.monotonic() < deadline, "request did not finish"
+        out.append(res["tokens"])
+    return out
+
+
+@pytest.mark.parametrize("read", ["xla", "planted_block"])
+def test_the_replicas_attn_counter_counts_the_full_layers(params, read, monkeypatch):
+    """``stats()["attn"]`` of the toy mimo configuration: its two full layers'
+    rows (the window layer's ring is not counted). On the CPU the read is
+    XLA's, every allocated row; with a block planted in the engine's
+    ``_attn_block`` the counter rounds each step's rows up to whole blocks."""
+    from ray_lightning_tpu.obs import registry
+    from ray_lightning_tpu.serve.server import ServeReplica
+
+    own = registry.MetricsRegistry()
+    monkeypatch.setattr(registry, "get_registry", lambda: own)
+    rep = ServeReplica(params=params, model_config=dict(MIXED), num_slots=2, max_seq=64,
+                       prefill_buckets=[16], decode_fold=4, watchdog=False)
+    try:
+        assert rep.engine._attn_layers == {"full": 2} and rep.engine._attn_block == {"full": 0}
+        if read == "planted_block":
+            rep.engine._attn_block["full"] = 16
+        sizes = (10, 3, 12)
+        _serve(rep, sizes)
+        attn = rep.stats()["attn"]
+        steps = [n + j for n in sizes for j in range(1, 20)]  # rows 0 .. pos each decode step's query saw
+        assert attn["rows_live"] == 2 * sum(steps)
+        assert attn["rows_allocated"] % (2 * 2 * 64 * 4) == 0 and attn["rows_allocated"] > 0
+        if read == "xla":
+            assert attn["rows_visited"] == attn["rows_allocated"]
+        else:
+            assert attn["rows_visited"] == 2 * sum(-(-rows // 16) * 16 for rows in steps)
+            assert attn["rows_live"] < attn["rows_visited"] < attn["rows_allocated"]
+        assert f'rlt_serve_attn_rows_visited_total {attn["rows_visited"]}\n' in rep.metrics_text()
+    finally:
+        rep.stop()
+
+
+def test_the_full_layers_read_through_the_kernel_serves_the_xla_reads_tokens(monkeypatch):
+    """The selection told "tpu": the fold's full layers read through the
+    decode kernel (interpreted here), the engine's counter takes the block
+    from the same answer, and the tokens are the XLA read's."""
+    import jax
+
+    from ray_lightning_tpu.obs import registry
+    from ray_lightning_tpu.serve.server import ServeReplica
+    from tests.utils import force_decode_kernel
+
+    own = registry.MetricsRegistry()
+    monkeypatch.setattr(registry, "get_registry", lambda: own)
+    kernel_params = init_gpt_params(jax.random.PRNGKey(0), GPTConfig(**KERNEL))
+    sizes = (10, 120, 3)  # the second crosses the first block's end while it decodes
+    seen = {}
+    for read in ("xla", "kernel"):
+        if read == "kernel":
+            force_decode_kernel(monkeypatch)
+        rep = ServeReplica(params=kernel_params, model_config=dict(KERNEL), num_slots=3, max_seq=384,
+                           prefill_buckets=[16, 128], decode_fold=4, watchdog=False)
+        try:
+            assert rep.engine._attn_block == {"full": 128 if read == "kernel" else 0}
+            seen[read] = (_serve(rep, sizes), rep.stats()["attn"])
+            assert rep.stats()["compiles_since_init"] == 0
+        finally:
+            rep.stop()
+    (want, xla), (got, kernel) = seen["xla"], seen["kernel"]
+    assert got == want
+    steps = [n + j for n in sizes for j in range(1, 20)]
+    assert kernel["rows_live"] == xla["rows_live"] == 2 * sum(steps)
+    assert xla["rows_visited"] == xla["rows_allocated"] == kernel["rows_allocated"]
+    assert kernel["rows_visited"] == 2 * sum(-(-rows // 128) * 128 for rows in steps)
