@@ -174,9 +174,25 @@ class GPTConfig:
     moe_latent_dim: int = 0
     d_ff_shared: int = 0
     moe_routed_scale: float = 1.0
+    # The muP scalars of a model whose parts are scaled where they meet
+    # the residual stream (layer_types only), one value a name of
+    # ``MULTIPLIERS`` and in its order: on the embedding's rows, the
+    # head's logits, the attention's input, its output and its keys, a
+    # state layer's input and output and the five parts of its
+    # in-projection (z, x, B, C before the conv; dt before ``dt_bias``),
+    # a dense MLP's gate before its activation and its output. Empty =
+    # every one is 1 and nothing is multiplied.
+    multipliers: Tuple[float, ...] = ()
+
+    #: The names of ``multipliers``, in its order.
+    MULTIPLIERS = (
+        "embedding", "lm_head", "attn_in", "attn_out", "key", "ssm_in",
+        "ssm_out", "ssm_z", "ssm_x", "ssm_b", "ssm_c", "ssm_dt",
+        "mlp_gate", "mlp_out",
+    )
 
     def __post_init__(self) -> None:
-        for name in ("layer_types", "attn_sink_logit", "experts_held"):
+        for name in ("layer_types", "attn_sink_logit", "experts_held", "multipliers"):
             v = getattr(self, name)
             t = tuple(tuple(x) if isinstance(x, list) else x for x in v)
             if t != v:
@@ -187,6 +203,14 @@ class GPTConfig:
         """Layers of more than one kind, or a share of the experts: the
         block of models/mixed.py runs this configuration."""
         return bool(self.layer_types)
+
+    def multiplier(self, name: str) -> float:
+        """The muP scalar ``name`` of ``multipliers`` (1.0 without them): a
+        Python float, so that a caller decides while it is traced whether
+        anything is multiplied."""
+        if not self.multipliers:
+            return 1.0
+        return float(self.multipliers[self.MULTIPLIERS.index(name)])
 
     @property
     def head_dim(self) -> int:
@@ -233,6 +257,11 @@ class GPTConfig:
                 "kv_lora_rank and rope_interleave need layer_types: latent "
                 "attention and the rotation of neighbouring pairs run in "
                 "the block of models/mixed.py"
+            )
+        elif self.multipliers:
+            raise ValueError(
+                "multipliers (the muP scalars) need layer_types: they are "
+                "applied in the block of models/mixed.py"
             )
 
     @staticmethod
@@ -844,13 +873,13 @@ def gpt_forward(
     cfg.validate_variants()
     if cfg.mixed:
         # Layers of more than one kind: the block of models/mixed.py.
-        from ray_lightning_tpu.models.mixed import mixed_rows
+        from ray_lightning_tpu.models.mixed import mixed_logits, mixed_rows
 
         if not _mesh_is_one_device(mesh):
             refuse_mixed(cfg, "a forward pass over a mesh of more than one device")
         x = mixed_rows(params, cfg, tokens)[0]
         x = _rmsnorm(x, params["lnf_g"], cfg.norm_eps)
-        out = x if return_hidden else _lm_head(x, params["lm_head"])
+        out = x if return_hidden else mixed_logits(x, params, cfg)
         return (out, jnp.zeros((), jnp.float32)) if return_aux else out
     cdt = jnp.dtype(cfg.compute_dtype)
     norm_fn = _make_norm(cfg)

@@ -17,6 +17,20 @@ x)^2)``; the experts may work in a latent narrower than the residual
 (``moe_latent_dim``), beside one shared expert on the whole input
 (``d_ff_shared``). RMSNorm, no biases.
 
+A layer's mixer may also be TWO mixers side by side, spelled
+``"full+ssm"`` (:data:`PARALLEL`): full attention and a state layer read
+the one normed input ``u = RMSNorm(x; ln1_g)`` and both write into the
+residual in one step, ``x + A(u) + M(u)``, before the layer's MLP. Such a
+layer is a layer of BOTH kinds — it has an index among the full layers
+(its K/V rows) and one among the state layers (its state and conv tail),
+one admission writes both and one decode step advances both — and a
+single ``ln1_g``. With ``multipliers`` (``GPTConfig.MULTIPLIERS``: a
+model's muP scalars) the embedding's rows, the head's logits, each
+mixer's input and output, the keys, the five parts of a state layer's
+in-projection, a dense MLP's gate and its output are each multiplied by
+a constant, decided while the program is traced: a configuration
+without them compiles to what it compiled to before they existed.
+
 One block, :func:`mixed_block`, serves the three modes the model runs in:
 
 - no cache (``gpt_forward``): attention among the rows given;
@@ -81,6 +95,8 @@ import numpy as np
 KV_KINDS = ("full", "window")
 ATTN_KINDS = KV_KINDS + ("latent",)
 MIXER_KINDS = ATTN_KINDS + ("ssm",)
+#: Two mixers side by side on one normed input: full attention and a state layer.
+PARALLEL = "full+ssm"
 MLP_KINDS = ("dense", "experts")
 #: Prefix of a mixer kind's leaves in ``blocks``.
 _MIXER_PREFIX = {"full": "full", "window": "swa", "latent": "lat", "ssm": "ssm"}
@@ -93,7 +109,9 @@ _Q_BLOCK = 512
 class LayerSpec:
     """One layer: its kinds (None: the layer has no such part), its index
     among the layers of each kind (where its leaves and its cache lie) and
-    among the layers that have a mixer / an MLP (where its norm gains lie)."""
+    among the layers that have a mixer / an MLP (where its norm gains lie).
+    A parallel layer (:data:`PARALLEL`) has ``mixer`` "full" and, beside it
+    on the same normed input, ``side`` "ssm" with an index of its own."""
 
     index: int
     mixer: Optional[str]
@@ -102,24 +120,36 @@ class LayerSpec:
     mlp_index: int
     norm1_index: int
     norm2_index: int
+    side: Optional[str] = None
+    side_index: int = 0
+
+
+def mixer_kinds(mixer: Optional[str]) -> Tuple[str, ...]:
+    """The kinds a ``layer_types`` mixer entry names: none, one, or a
+    parallel layer's two."""
+    return tuple(mixer.split("+")) if mixer else ()
 
 
 def layer_specs(cfg: Any) -> List[LayerSpec]:
     seen: Dict[Any, int] = {}
     out = []
     for i, (mixer, mlp) in enumerate(cfg.layer_types):
+        kinds = mixer_kinds(mixer)
+        first, side = (kinds + (None, None))[:2]
         # a missing part (None) has no index of its own: all of them read 0
         out.append(LayerSpec(
-            i, mixer, mlp, seen.get(mixer, 0), seen.get(mlp, 0),
-            seen.get("mixers", 0), seen.get("mlps", 0),
+            i, first, mlp, seen.get(first, 0), seen.get(mlp, 0),
+            seen.get("mixers", 0), seen.get("mlps", 0), side, seen.get(side, 0),
         ))
-        for key in ((mixer, "mixers") if mixer else ()) + ((mlp, "mlps") if mlp else ()):
+        for key in kinds + (("mixers",) if kinds else ()) + ((mlp, "mlps") if mlp else ()):
             seen[key] = seen.get(key, 0) + 1
     return out
 
 
 def count_kind(cfg: Any, kind: str) -> int:
-    return sum(kind in pair for pair in cfg.layer_types)
+    """Layers that have a part of ``kind``; a parallel layer counts under
+    each of its mixers."""
+    return sum(kind in mixer_kinds(pair[0]) + (pair[1],) for pair in cfg.layer_types)
 
 
 def count_part(cfg: Any, part: int) -> int:
@@ -137,12 +167,13 @@ def validate_mixed(cfg: Any) -> None:
         )
     for pair in cfg.layer_types:
         if (
-            len(pair) != 2 or pair[0] not in MIXER_KINDS + (None,)
+            len(pair) != 2 or pair[0] not in MIXER_KINDS + (PARALLEL, None)
             or pair[1] not in MLP_KINDS + (None,) or pair == (None, None)
         ):
             raise ValueError(
                 f"layer_types entry {pair!r}: use (mixer kind of "
-                f"{MIXER_KINDS}, MLP kind of {MLP_KINDS}), either of them "
+                f"{MIXER_KINDS} or {PARALLEL!r}, two mixers side by side on "
+                f"one normed input, MLP kind of {MLP_KINDS}), either of them "
                 "None for a layer that is the other part alone"
             )
     if cfg.norm_impl != "rmsnorm" or cfg.pos_embed not in ("rope", "none") or (
@@ -161,7 +192,8 @@ def validate_mixed(cfg: Any) -> None:
                 "ssm_chunk >= 1, ssm_conv >= 2 taps and ssm_heads divisible "
                 f"by ssm_groups (got heads {H}, head_dim {cfg.ssm_head_dim}, "
                 f"groups {G}, state {cfg.ssm_state}, conv {cfg.ssm_conv}, "
-                f"chunk {cfg.ssm_chunk})"
+                f"chunk {cfg.ssm_chunk}); the state half of a {PARALLEL!r} "
+                "layer is one"
             )
     elif cfg.pos_embed == "none" and any(count_kind(cfg, kind) for kind in ATTN_KINDS):
         raise ValueError(
@@ -192,11 +224,22 @@ def validate_mixed(cfg: Any) -> None:
     elif cfg.kv_lora_rank:
         raise ValueError("kv_lora_rank describes latent layers: layer_types names none")
     for kind in KV_KINDS:
-        if count_kind(cfg, kind) and cfg.n_head % kv_heads(cfg, kind):
+        if count_kind(cfg, kind) and (kv_heads(cfg, kind) < 1 or cfg.n_head % kv_heads(cfg, kind)):
             raise ValueError(
                 f"n_head ({cfg.n_head}) must be divisible by the {kind} "
-                f"layers' KV heads ({kv_heads(cfg, kind)})"
+                f"layers' KV heads ({kv_heads(cfg, kind)}: n_kv_head, or "
+                f"n_head without it; the attention half of {PARALLEL!r} is a "
+                "full layer)"
             )
+    if cfg.multipliers and (
+        len(cfg.multipliers) != len(cfg.MULTIPLIERS)
+        or not all(np.isfinite(m) and m != 0.0 for m in cfg.multipliers)
+    ):
+        raise ValueError(
+            f"multipliers has {len(cfg.multipliers)} values "
+            f"{cfg.multipliers!r}: give one finite, non-zero scalar a name of "
+            f"{cfg.MULTIPLIERS}, in that order, or none at all"
+        )
     if rope_dim(cfg) % 2 or rope_dim(cfg) > qk_dim(cfg):
         raise ValueError(
             f"rope_dim {rope_dim(cfg)} must be even and at most the q·k "
@@ -594,6 +637,10 @@ def _layer_leaves(blocks: Dict[str, Any], ls: LayerSpec) -> Dict[str, Any]:
         out["ln1_g"] = blocks["ln1_g"][ls.norm1_index]
         p = _MIXER_PREFIX[ls.mixer] + "_"
         out.update({k[len(p):]: w[ls.mixer_index] for k, w in blocks.items() if k.startswith(p)})
+    if ls.side:
+        # the second mixer's leaves under a key of their own: both kinds have a ``wo``
+        p = _MIXER_PREFIX[ls.side] + "_"
+        out["side"] = {k[len(p):]: w[ls.side_index] for k, w in blocks.items() if k.startswith(p)}
     if ls.mlp:
         out["ln2_g"] = blocks["ln2_g"][ls.norm2_index]
     if ls.mlp == "dense":
@@ -604,18 +651,31 @@ def _layer_leaves(blocks: Dict[str, Any], ls: LayerSpec) -> Dict[str, Any]:
     return out
 
 
-def _mlp(x: jax.Array, wi: jax.Array, wo: jax.Array, cfg: Any) -> jax.Array:
+def _scaled(x: jax.Array, scale: float) -> jax.Array:
+    """``x * scale`` in x's dtype; x itself, and no multiply in the
+    program, where the scale is 1 (``GPTConfig.multiplier``: a Python
+    float, read while the program is traced)."""
+    return x if scale == 1.0 else x * jnp.asarray(scale, x.dtype)
+
+
+def _mlp(x: jax.Array, wi: jax.Array, wo: jax.Array, cfg: Any, gate: float = 1.0, out: float = 1.0) -> jax.Array:
     """x (..., D) through ``wi`` (gates, D, F) and ``wo`` (F, D'): SwiGLU
-    with two gates, ``relu(up x)^2`` with one."""
+    with two gates, ``relu(up x)^2`` with one. ``gate`` multiplies the
+    first input matrix's result before the activation, ``out`` the
+    result (a dense layer's muP scalars)."""
     from ray_lightning_tpu.parallel.moe import mlp_act
 
     cdt = jnp.dtype(cfg.compute_dtype)
     z = jnp.einsum("...d,cdf->...cf", x, wi.astype(cdt))
-    return jnp.einsum("...f,fd->...d", mlp_act(z, cfg.mlp_variant), wo.astype(cdt))
+    if gate != 1.0:
+        z = z * jnp.asarray([gate] + [1.0] * (z.shape[-2] - 1), z.dtype)[:, None]
+    return _scaled(jnp.einsum("...f,fd->...d", mlp_act(z, cfg.mlp_variant), wo.astype(cdt)), out)
 
 
-def _attention_part(h, lp, ls, cfg, rope, pos, caches, live=None):
-    """``(attention's write into the residual, kv)`` of one attention layer.
+def _attention_part(h, lp, ls, cfg, rope, pos, caches, live=None, u=None):
+    """``(attention's write into the residual, kv)`` of one attention layer
+    (``u``: the layer's normed input where the caller has it already, a
+    parallel layer's; else it is ``RMSNorm(h; ln1_g)``).
     In decode the read after the row's write is the one
     ``models/gpt.py:_decode_rows_block`` names for the layer's kind: the
     decode kernel (``ops/decode_attention.py:decode_attention``) over the
@@ -630,9 +690,9 @@ def _attention_part(h, lp, ls, cfg, rope, pos, caches, live=None):
     G = kv_heads(cfg, ls.mixer)
     window = cfg.attn_window if ls.mixer == "window" else 0
     with jax.named_scope("attn_" + ls.mixer):
-        a = _rmsnorm(h, lp["ln1_g"], cfg.norm_eps)
+        a = _scaled(_rmsnorm(h, lp["ln1_g"], cfg.norm_eps) if u is None else u, cfg.multiplier("attn_in"))
         q = jnp.einsum("bsd,dhk->bshk", a, lp["wq"].astype(cdt))
-        k = jnp.einsum("bsd,dhk->bshk", a, lp["wk"].astype(cdt))
+        k = _scaled(jnp.einsum("bsd,dhk->bshk", a, lp["wk"].astype(cdt)), cfg.multiplier("key"))
         v = jnp.einsum("bsd,dhk->bshk", a, lp["wv"].astype(cdt))
         if cfg.pos_embed == "rope":
             q = _rope(q, rope[ls.mixer], cfg.rope_interleave)
@@ -676,7 +736,8 @@ def _attention_part(h, lp, ls, cfg, rope, pos, caches, live=None):
                     q, k_cache[ls.mixer][ls.mixer_index], v_cache[ls.mixer][ls.mixer_index],
                     pos, sink, window, ring,
                 )
-        return jnp.einsum("bshk,hkd->bsd", o.astype(cdt), lp["wo"].astype(cdt)), kv
+        out = jnp.einsum("bshk,hkd->bsd", o.astype(cdt), lp["wo"].astype(cdt))
+        return _scaled(out, cfg.multiplier("attn_out")), kv
 
 
 def _attend_latent_cache(cfg, q_lat, q_rope, c_cache, r_cache, li, pos, live=None):
@@ -770,14 +831,16 @@ def _latent_part(h, lp, ls, cfg, rope, pos, caches, live=None):
         return jnp.einsum("bshk,hkd->bsd", o.astype(cdt), lp["wo"].astype(cdt)), kv
 
 
-def _state_part(h, lp, ls, cfg, caches, valid):
+def _state_part(h, lp, ls, cfg, caches, valid, u=None):
     """``(the state layer's write into the residual, kv)``: with no cache
     ``kv`` is the state after the last real row and the conv tail; in
-    decode the slots' own are advanced and go back where they were."""
+    decode the slots' own are advanced and go back where they were
+    (``u``: the layer's normed input where the caller has it already)."""
     from ray_lightning_tpu.models import ssm
     from ray_lightning_tpu.models.gpt import _rmsnorm
 
-    u = _rmsnorm(h, lp["ln1_g"], cfg.norm_eps)
+    if u is None:
+        u = _rmsnorm(h, lp["ln1_g"], cfg.norm_eps)
     if caches is None:
         out, state, tail = ssm.ssm_rows(u, lp, cfg, valid)
         return out, (state, tail)
@@ -787,6 +850,25 @@ def _state_part(h, lp, ls, cfg, caches, valid):
     k_cache["ssm"] = k_cache["ssm"][:i] + (state,) + k_cache["ssm"][i + 1:]
     v_cache["ssm"] = v_cache["ssm"][:i] + (tail,) + v_cache["ssm"][i + 1:]
     return out, (k_cache, v_cache)
+
+
+def _parallel_part(h, lp, ls, cfg, rope, pos, caches, valid):
+    """``(A(u) + M(u), kv)`` of a parallel layer: full attention and a state
+    layer on the one normed input ``u = RMSNorm(h; ln1_g)``. With no cache
+    ``kv`` is ``{"full": (k, v), "ssm": (state, tail)}``; in decode the
+    attention half writes its row into the caches it is given and the
+    state half advances its state in what comes back, so ``kv`` is the
+    pair of dicts with both kinds replaced."""
+    from dataclasses import replace
+
+    from ray_lightning_tpu.models.gpt import _rmsnorm
+
+    with jax.named_scope("parallel"):
+        u = _rmsnorm(h, lp["ln1_g"], cfg.norm_eps)
+        a, kv_a = _attention_part(h, lp, ls, cfg, rope, pos, caches, None if valid is None else valid[:, 0], u=u)
+        side = replace(ls, mixer=ls.side, mixer_index=ls.side_index, side=None)
+        m, kv_m = _state_part(h, lp["side"], side, cfg, caches and kv_a, valid, u=u)
+    return a + m, (kv_m if caches else {ls.mixer: kv_a, ls.side: kv_m})
 
 
 def _experts_part(m, lp, ls, cfg, valid):
@@ -828,13 +910,15 @@ def mixed_block(
     valid: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, Any, jax.Array]:
     """One layer over h (B, S, D) -> ``(h, kv, moe_stats)``: its mixer if
-    it has one, then its MLP if it has one, each under its own norm.
+    it has one (a parallel layer's two, summed), then its MLP if it has
+    one, each under its own norm.
 
     ``caches`` None: the S rows are a sequence from its start (forward,
     prefill) and ``kv`` is what the mixer leaves of them: an attention
     layer's ``(k, v)`` at its KV width (a latent layer's ``(latents,
     rotary keys)``), a state layer's ``(state, conv tail)`` after the last
-    real row. ``caches = (k_cache, v_cache)``:
+    real row, a parallel layer's both by kind, ``{"full": (k, v), "ssm":
+    (state, conv tail)}``. ``caches = (k_cache, v_cache)``:
     decode, S = 1 and ``pos`` (B,) each slot's position; the slot's K/V
     row, or its state and tail, are replaced in the caches and ``kv`` is
     the updated pair. A layer without a mixer hands back ``caches`` (None
@@ -844,7 +928,10 @@ def mixed_block(
     from ray_lightning_tpu.models.gpt import _rmsnorm
 
     kv, stats = caches, jnp.zeros((3,), jnp.int32)
-    if ls.mixer == "ssm":
+    if ls.side:
+        out, kv = _parallel_part(h, lp, ls, cfg, rope, pos, caches, valid)
+        h = h + out
+    elif ls.mixer == "ssm":
         out, kv = _state_part(h, lp, ls, cfg, caches, valid)
         h = h + out
     elif ls.mixer:
@@ -855,7 +942,7 @@ def mixed_block(
         m = _rmsnorm(h, lp["ln2_g"], cfg.norm_eps)
         if ls.mlp == "dense":
             with jax.named_scope("mlp"):
-                out = _mlp(m, lp["wi"], lp["wo2"], cfg)
+                out = _mlp(m, lp["wi"], lp["wo2"], cfg, cfg.multiplier("mlp_gate"), cfg.multiplier("mlp_out"))
         else:
             out, stats = _experts_part(m, lp, ls, cfg, valid)
         h = h + out
@@ -876,6 +963,14 @@ def _rope_by_kind(cfg: Any, pos: jax.Array) -> Dict[str, Tuple[jax.Array, jax.Ar
     }
 
 
+def mixed_logits(h: jax.Array, params: Dict[str, Any], cfg: Any) -> jax.Array:
+    """Float32 logits of final-normed hidden states h (..., D): the untied
+    head's, times the ``lm_head`` multiplier where the model has one."""
+    from ray_lightning_tpu.models.gpt import _lm_head
+
+    return _scaled(_lm_head(h, params["lm_head"]), cfg.multiplier("lm_head"))
+
+
 def mixed_rows(
     params: Dict[str, Any], cfg: Any, tokens: jax.Array, true_len: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, Dict[str, Any], Dict[str, Any], jax.Array]:
@@ -892,7 +987,7 @@ def mixed_rows(
 
     B, S = tokens.shape
     cdt = jnp.dtype(cfg.compute_dtype)
-    h = embed_rows(params["wte"], tokens).astype(cdt)
+    h = _scaled(embed_rows(params["wte"], tokens).astype(cdt), cfg.multiplier("embedding"))
     pos = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
     rope = _rope_by_kind(cfg, pos)
     valid = None if true_len is None else pos < true_len
@@ -902,9 +997,9 @@ def mixed_rows(
     for ls in layer_specs(cfg):
         lp = _layer_leaves(params["blocks"], ls)
         h, kv, st = mixed_block(h, lp, ls, cfg, rope, valid=valid)
-        if ls.mixer:
-            ks.setdefault(ls.mixer, []).append(kv[0] if ls.mixer == "ssm" else kv[0].astype(cdt))
-            vs.setdefault(ls.mixer, []).append(kv[1].astype(cdt))
+        for kind, (k, v) in (kv if ls.side else {ls.mixer: kv} if ls.mixer else {}).items():
+            ks.setdefault(kind, []).append(k if kind == "ssm" else k.astype(cdt))
+            vs.setdefault(kind, []).append(v.astype(cdt))
         stats = stats + st
     real = jnp.asarray(B * S, jnp.int32) if valid is None else valid.sum().astype(jnp.int32)
     stats = jnp.concatenate([stats, jnp.stack([jnp.asarray(B * S, jnp.int32), real])])
@@ -923,11 +1018,11 @@ def mixed_decode_step(
     the summed ``moe_stats``. ``active`` (B,) bool: idle lanes route to
     no expert (their logits are not read; a state layer advances their
     own state, which the next admission into the slot overwrites)."""
-    from ray_lightning_tpu.models.gpt import _lm_head, _rmsnorm
+    from ray_lightning_tpu.models.gpt import _rmsnorm
     from ray_lightning_tpu.utils.quantize import embed_rows
 
     cdt = jnp.dtype(cfg.compute_dtype)
-    h = embed_rows(params["wte"], cur).astype(cdt)[:, None]  # (B, 1, D)
+    h = _scaled(embed_rows(params["wte"], cur).astype(cdt), cfg.multiplier("embedding"))[:, None]  # (B, 1, D)
     rope = _rope_by_kind(cfg, pos[:, None])
     valid = None if active is None else active[:, None]
     stats = jnp.zeros((3,), jnp.int32)
@@ -939,5 +1034,5 @@ def mixed_decode_step(
         stats = stats + st
     with jax.named_scope("lm_head"):
         h = _rmsnorm(h[:, 0], params["lnf_g"], cfg.norm_eps)
-        logits = _lm_head(h, params["lm_head"])
+        logits = mixed_logits(h, params, cfg)
     return logits, k_cache, v_cache, stats

@@ -10,6 +10,11 @@ groups of N (head h reads group ``h // (H / G)``) and K conv taps::
     S_t = exp(dt_t A) S_{t-1} + dt_t x_t B_t^T,  y_t = S_t C_t + D x_t
     out = RMSNorm_per_group(y_t * silu(z_t); g) W_o
 
+A model with muP scalars (``GPTConfig.multipliers``) takes ``u`` times
+``ssm_in``, multiplies z, x, B, C and dt by a scalar each before the conv
+and before ``dt_bias``, and ``out`` by ``ssm_out`` (:func:`_in_scales`; the
+one recurrence below serves both).
+
 Two evaluations of the one recurrence:
 
 - :func:`ssm_rows` (forward, prefill): over S rows in chunks of
@@ -32,6 +37,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 F32 = jnp.float32
 
@@ -68,11 +74,29 @@ def empty_state(cfg: Any, slots: int, dtype: Any) -> Tuple[jax.Array, jax.Array]
 
 
 # -- pieces shared by the two modes ------------------------------------------------
-def _in_proj(u: jax.Array, lp: Dict[str, Any], cdt: Any):
+def _in_scales(cfg: Any):
+    """What each part of the in-projection is multiplied by where the
+    model has muP scalars (``GPTConfig.multipliers``): the input's
+    ``ssm_in`` folded into the part's own, ``(u s_in) W s_part = (u W)
+    (s_in s_part)``. ``(z, xBC, dt)``: a float, or for xBC — whose x, B
+    and C differ — one value a channel; None where nothing is multiplied,
+    decided while the program is traced."""
+    s_in = cfg.multiplier("ssm_in")
+    z, x, b, c, dt = (s_in * cfg.multiplier("ssm_" + part) for part in ("z", "x", "b", "c", "dt"))
+    gn = cfg.ssm_groups * cfg.ssm_state
+    xbc = None if x == b == c == 1.0 else np.repeat(np.float32([x, b, c]), [d_inner(cfg), gn, gn])
+    return (None if z == 1.0 else z), xbc, (None if dt == 1.0 else dt)
+
+
+def _in_proj(u: jax.Array, lp: Dict[str, Any], cfg: Any, cdt: Any):
     with jax.named_scope("ssm_in_proj"):
         z = jnp.einsum("bsd,de->bse", u, lp["wz"].astype(cdt))
         xbc = jnp.einsum("bsd,de->bse", u, lp["wx"].astype(cdt))
         dt = jnp.einsum("bsd,dh->bsh", u, lp["wdt"].astype(cdt))
+        z, xbc, dt = (
+            a if s is None else a * jnp.asarray(s, a.dtype)
+            for a, s in zip((z, xbc, dt), _in_scales(cfg))
+        )
     return z, xbc, dt
 
 
@@ -106,7 +130,9 @@ def _gate_out(y: jax.Array, z: jax.Array, lp: Dict[str, Any], cfg: Any, cdt: Any
         y = y.reshape(B, S, G, -1) * jax.nn.silu(z.astype(F32)).reshape(B, S, G, -1)
         y = y * jax.lax.rsqrt(jnp.mean(y * y, -1, keepdims=True) + cfg.norm_eps)
         y = y.reshape(B, S, -1) * lp["norm_g"].astype(F32)
-        return jnp.einsum("bse,ed->bsd", y.astype(cdt), lp["wo"].astype(cdt))
+        out = jnp.einsum("bse,ed->bsd", y.astype(cdt), lp["wo"].astype(cdt))
+        s_out = cfg.multiplier("ssm_out")
+        return out if s_out == 1.0 else out * jnp.asarray(s_out, out.dtype)
 
 
 # -- rows: forward and prefill -------------------------------------------------------
@@ -163,7 +189,7 @@ def ssm_rows(
     cdt = jnp.dtype(cfg.compute_dtype)
     B, S, _ = u.shape
     K = cfg.ssm_conv
-    z, xbc, dt = _in_proj(u, lp, cdt)
+    z, xbc, dt = _in_proj(u, lp, cfg, cdt)
     with jax.named_scope("ssm_conv"):
         n_real = jnp.full((B,), S, jnp.int32) if valid is None else valid.sum(-1).astype(jnp.int32)
         front = jnp.pad(xbc, ((0, 0), (K - 1, 0), (0, 0)))  # row t of xbc is row t + K - 1
@@ -195,7 +221,7 @@ def ssm_step(
     place."""
     cdt = jnp.dtype(cfg.compute_dtype)
     B = u.shape[0]
-    z, xbc, dt = _in_proj(u, lp, cdt)
+    z, xbc, dt = _in_proj(u, lp, cfg, cdt)
     with jax.named_scope("ssm_conv"):
         rows = jnp.concatenate([tail, xbc[:, 0][None].astype(tail.dtype)], axis=0)  # (K, B, channels)
         conv = lp["conv_b"].astype(F32) + jnp.sum(lp["conv_w"].astype(F32)[:, None] * rows.astype(F32), 0)

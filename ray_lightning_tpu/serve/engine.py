@@ -1047,6 +1047,7 @@ class DecodeEngine:
                 # state layer's state and conv tail are written whole; the
                 # layers' counts come out with the first token.
                 from ray_lightning_tpu.models.mixed import (
+                    mixed_logits,
                     mixed_rows,
                     write_prefill_rows,
                 )
@@ -1067,7 +1068,10 @@ class DecodeEngine:
             h_last = norm_fn(
                 h_last, params["lnf_g"], params.get("lnf_b")
             )[:, 0]
-            logits = _lm_head(h_last, _head_weight(params, cfg))
+            logits = (
+                mixed_logits(h_last, params, cfg) if cfg.mixed
+                else _lm_head(h_last, _head_weight(params, cfg))
+            )
             key, sub = jax.random.split(key0)
             tok = sample_logits_batched(
                 sub[None], logits, temp[None], tk[None], tp[None]
