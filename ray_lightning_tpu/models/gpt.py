@@ -2151,9 +2151,13 @@ def gpt_decode_fold(
     layers' counts leave the fold with the tokens: ``moe (6,) int32`` —
     pairs routed, pairs on held experts, held experts hit (summed over
     expert layers and iterations), iterations with a live slot, slot-steps
-    (every slot's state, a state layer's among them, is advanced in every
-    iteration) and the slot-steps that belonged to a live request — is
-    appended to the return tuple.
+    (slots times iterations: every slot is a lane of every iteration) and
+    the slot-steps that belonged to a live request — is appended to the
+    return tuple. A configuration with state layers counts a seventh: the
+    slot-steps whose running state the step read and wrote — the live
+    ones where the update walks the live slots (``models/ssm.py:
+    _step_heads``: a TPU), all of them where one XLA pass moves every
+    slot's state.
     """
     if cfg.mixed:
         if page_table is not None:
@@ -2161,6 +2165,10 @@ def gpt_decode_fold(
         if piggyback is not None:
             refuse_mixed(cfg, "piggybacked prefill chunks in the decode fold")
         from ray_lightning_tpu.models.mixed import mixed_decode_step
+        from ray_lightning_tpu.models.ssm import _step_heads
+
+        states = k_cache.get("ssm", ())
+        visits_live = bool(states) and _step_heads(states[0], cfg.ssm_groups) > 0
 
     def body(carry, _):
         cur, pos, keys, active, remaining, k_cache, v_cache, moe = carry
@@ -2168,11 +2176,14 @@ def gpt_decode_fold(
             logits, k_cache, v_cache, st = mixed_decode_step(
                 params, cfg, cur, pos, k_cache, v_cache, active=active
             )
-            moe = moe + jnp.concatenate([st, jnp.stack([
+            counts = [
                 active.any().astype(jnp.int32),
                 jnp.asarray(active.shape[0], jnp.int32),
                 active.sum().astype(jnp.int32),
-            ])])
+            ]
+            if states:  # visited: the live slot-steps or all of them
+                counts.append(counts[2] if visits_live else counts[1])
+            moe = moe + jnp.concatenate([st, jnp.stack(counts)])
         elif page_table is None:
             logits, k_cache, v_cache = gpt_decode_step(
                 params, cfg, cur, pos, k_cache, v_cache, active
@@ -2202,7 +2213,7 @@ def gpt_decode_fold(
     carry, (tok_block, emit_block) = jax.lax.scan(
         body,
         (cur, pos, keys, active, remaining, k_cache, v_cache,
-         jnp.zeros((6,), jnp.int32)),
+         jnp.zeros((7 if cfg.mixed and states else 6,), jnp.int32)),
         None,
         length=int(fold),
     )

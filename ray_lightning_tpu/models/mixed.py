@@ -834,8 +834,10 @@ def _latent_part(h, lp, ls, cfg, rope, pos, caches, live=None):
 def _state_part(h, lp, ls, cfg, caches, valid, u=None):
     """``(the state layer's write into the residual, kv)``: with no cache
     ``kv`` is the state after the last real row and the conv tail; in
-    decode the slots' own are advanced and go back where they were
-    (``u``: the layer's normed input where the caller has it already)."""
+    decode the live slots' own (``valid[:, 0]``) are advanced and go back
+    where they were, and a slot that is not live keeps its state bit for
+    bit (``models/ssm.py:ssm_step``: on a TPU its state is not even read).
+    ``u``: the layer's normed input where the caller has it already."""
     from ray_lightning_tpu.models import ssm
     from ray_lightning_tpu.models.gpt import _rmsnorm
 
@@ -846,7 +848,8 @@ def _state_part(h, lp, ls, cfg, caches, valid, u=None):
         return out, (state, tail)
     k_cache, v_cache = dict(caches[0]), dict(caches[1])
     i = ls.mixer_index
-    out, state, tail = ssm.ssm_step(u, lp, cfg, k_cache["ssm"][i], v_cache["ssm"][i])
+    out, state, tail = ssm.ssm_step(
+        u, lp, cfg, k_cache["ssm"][i], v_cache["ssm"][i], None if valid is None else valid[:, 0])
     k_cache["ssm"] = k_cache["ssm"][:i] + (state,) + k_cache["ssm"][i + 1:]
     v_cache["ssm"] = v_cache["ssm"][:i] + (tail,) + v_cache["ssm"][i + 1:]
     return out, (k_cache, v_cache)

@@ -23,9 +23,12 @@ Two evaluations of the one recurrence:
   rows come out (``valid`` marks the real rows of a right-padded prompt:
   a row that is not real has ``dt = 0``, which is decay one and input
   zero, so it leaves the state as it was).
-- :func:`ssm_step` (decode): one row a slot; the slot's state and conv
+- :func:`ssm_step` (decode): one row a slot; the slots' state and conv
   tail go in and come out, each a whole array that a donating caller has
-  updated in place.
+  updated in place. A slot that is not live keeps its state bit for bit:
+  on a TPU the update is a kernel that walks the live slots and reads and
+  writes no other's state (``ops/ssm_step.py``), elsewhere one XLA pass
+  over every slot's.
 
 The state is float32 whatever the compute dtype (it is summed into for
 every token of a request); the matmuls take their operands in the compute
@@ -211,14 +214,50 @@ def ssm_rows(
 
 
 # -- one row a slot: decode ------------------------------------------------------------
+def _step_heads(state: jax.Array, groups: int, backend: Optional[str] = None) -> int:
+    """Which update a decode token step takes, from what it can observe: the
+    heads of a block of the kernel that walks the live slots
+    (``ops/ssm_step.py``), or 0 for the XLA lines — off a TPU (there the
+    kernel would run interpreted) and at a shape the kernel takes no block
+    of (``ops/ssm_step.py:step_heads``)."""
+    from ray_lightning_tpu.ops.ssm_step import step_heads
+
+    if (backend or jax.default_backend()) != "tpu":
+        return 0
+    return step_heads(*state.shape[1:], groups)
+
+
+def _update_all(state, decay, dtx, bm, cm, active=None):
+    """The recurrence's one row in XLA, over every slot: ``state`` (B, G,
+    R, P, N), ``decay`` (B, G, R), ``dtx`` (B, G, R, P), ``bm`` and ``cm``
+    (B, G, N) -> ``(state, y (B, G, R, P))``. One elementwise pass reads
+    and writes the state of all B slots; a slot that is not ``active``
+    keeps its state bit for bit and has zeros for y."""
+    s = decay[..., None, None] * state + dtx[..., None] * bm[:, :, None, None, :]
+    y = jnp.sum(s * cm[:, :, None, None, :], -1)
+    if active is not None:
+        at = active[:, None, None, None]
+        s, y = jnp.where(at[..., None], s, state), jnp.where(at, y, 0.0)
+    return s, y
+
+
 def ssm_step(
     u: jax.Array, lp: Dict[str, Any], cfg: Any, state: jax.Array, tail: jax.Array,
+    active: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """One token a slot, u (B, 1, D), through the slots' ``state`` (B, H,
     P, N) and conv ``tail`` (K - 1, B, channels) -> (out (B, 1, D), state,
-    tail), each slot advanced by its row. The state is read once and
-    written once, elementwise: a caller that donates it has it updated in
-    place."""
+    tail), each slot advanced by its row. ``active`` (B,) bool, default
+    all: **a slot that is not active keeps its state bit for bit** (its
+    ``out`` is not read; its tail moves on, and the next admission into the
+    slot overwrites both).
+
+    The state's update is chosen while the program is traced
+    (:func:`_step_heads`): on a TPU the kernel that walks the active slots
+    and neither reads nor writes another slot's state, in place in the
+    donated array (``ops/ssm_step.py``); elsewhere one elementwise XLA pass
+    over every slot's state, which a donating caller has updated in place
+    too. Same products in the same order; the sum over N in another."""
     cdt = jnp.dtype(cfg.compute_dtype)
     B = u.shape[0]
     z, xbc, dt = _in_proj(u, lp, cfg, cdt)
@@ -228,7 +267,18 @@ def ssm_step(
         x, bm, cm = _split(jax.nn.silu(conv), cfg)  # (B, G, R, P), (B, G, N)
     with jax.named_scope("ssm_scan"):
         dt, A = _step_sizes(dt[:, 0], lp, cfg)  # (B, G, R)
-        s = state.reshape((B,) + x.shape[1:] + (cfg.ssm_state,))
-        s = jnp.exp(dt * A)[..., None, None] * s + (dt[..., None] * x)[..., None] * bm[:, :, None, None, :]
-        y = jnp.sum(s * cm[:, :, None, None, :], -1) + _per_head(lp["D"], cfg)[..., None] * x
+        decay, dtx = jnp.exp(dt * A), dt[..., None] * x
+        heads = _step_heads(state, cfg.ssm_groups)
+        if heads:
+            from ray_lightning_tpu.ops.ssm_step import ssm_step_update
+
+            H, P = state.shape[1:3]
+            s, y = ssm_step_update(
+                state, decay.reshape(B, H), dtx.reshape(B, H, P), bm, cm,
+                jnp.ones((B,), jnp.bool_) if active is None else active, heads=heads,
+            )
+            y = y.reshape(x.shape)
+        else:
+            s, y = _update_all(state.reshape((B,) + x.shape[1:] + (cfg.ssm_state,)), decay, dtx, bm, cm, active)
+        y = y + _per_head(lp["D"], cfg)[..., None] * x
     return _gate_out(y[:, None], z, lp, cfg, cdt), s.reshape(state.shape), rows[1:]

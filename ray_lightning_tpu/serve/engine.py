@@ -860,11 +860,13 @@ class DecodeEngine:
                         "experts_hit": 0, "admissions": 0},
         }
         #: The state layers' counts, fetched with the same vectors:
-        #: slot-steps a fold advanced (every slot, every iteration) and
-        #: those of live requests; rows an admission scanned (its bucket)
-        #: and those that were prompt. Zeros without state layers.
+        #: slot-steps of the folds (every slot, every iteration), those of
+        #: live requests and those whose state the step read and wrote
+        #: (the live ones under the kernel that walks them, else all);
+        #: rows an admission scanned (its bucket) and those that were
+        #: prompt. Zeros without state layers.
         self.ssm_totals: Dict[str, Dict[str, int]] = {
-            "decode": {"slot_steps": 0, "slot_steps_live": 0},
+            "decode": {"slot_steps": 0, "slot_steps_live": 0, "slot_steps_visited": 0},
             "prefill": {"rows_scanned": 0, "rows_real": 0},
         }
         self._state_layers = 0
@@ -3810,8 +3812,8 @@ class DecodeEngine:
             toks = np.asarray(outs[0])
             emits = np.asarray(outs[1])
             if outs[3] is not None:
-                # the layers' counts of this fold: six numbers that were
-                # ready with the tokens
+                # the layers' counts of this fold: six or seven numbers
+                # that were ready with the tokens
                 m = np.asarray(outs[3])
                 self._count_moe("decode", m, int(m[3]))
         if self._inflight is None:
@@ -3824,17 +3826,19 @@ class DecodeEngine:
     def _count_moe(self, phase: str, counts: np.ndarray, n: int) -> None:
         """Add one fold's or one admission's counts to the totals:
         ``[pairs routed, pairs on held experts, held experts hit]``, then
-        (decode) ``[live iterations, slot-steps, live slot-steps]`` or
-        (an admission) ``[rows scanned, real rows]``; ``n`` is its token
-        steps (decode) or 1 (an admission)."""
+        (decode) ``[live iterations, slot-steps, live slot-steps]`` — with
+        state layers a fourth, the slot-steps visited — or (an admission)
+        ``[rows scanned, real rows]``; ``n`` is its token steps (decode)
+        or 1 (an admission)."""
         row = self.moe_totals[phase]
         row["pairs_routed"] += int(counts[0])
         row["pairs_held"] += int(counts[1])
         row["experts_hit"] += int(counts[2])
         row["token_steps" if phase == "decode" else "admissions"] += n
         if self._state_layers:
-            for key, c in zip(self.ssm_totals[phase], counts[-2:]):
-                self.ssm_totals[phase][key] += int(c)
+            row = self.ssm_totals[phase]  # the vector's last entries, in the row's order
+            for key, c in zip(row, counts[-len(row):]):
+                row[key] += int(c)
 
     def _fan_out(
         self,

@@ -625,8 +625,9 @@ class ServeReplica:
         self._ssm_counters = {
             key: self._registry.counter(f"rlt_serve_ssm_{key}_total", help_)
             for key, help_ in (
-                ("slot_steps", "Slot-steps the decode folds advanced a state layer's state by (every slot, every iteration)"),
+                ("slot_steps", "Slot-steps of the decode folds of a model with state layers (every slot, every iteration)"),
                 ("slot_steps_live", "Slot-steps of those that belonged to a live request"),
+                ("slot_steps_visited", "Slot-steps of those whose running state the step read and wrote (the live ones on a TPU, else all)"),
                 ("rows_scanned", "Rows the admissions' chunked scans ran over (their buckets)"),
                 ("rows_real", "Rows of those that were prompt"),
             )
@@ -1238,8 +1239,8 @@ class ServeReplica:
             snap["moe"] = moe
         ssm = self.engine.ssm_stats()
         if ssm:
-            # State layers: slot-steps advanced and live, rows scanned and
-            # real — monotone totals.
+            # State layers: slot-steps, those live and those visited, rows
+            # scanned and real — monotone totals.
             snap["ssm"] = ssm
         attn = self.engine.attn_stats()
         if attn:

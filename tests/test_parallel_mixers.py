@@ -9,6 +9,7 @@ already runs have the trees and the counters they had (a snapshot taken on
 the commit before this one: ``tests/data/mixed_trees_pr46.json``). The
 comparison with the plain reference is ``tests/perfbench/test_falcon_h1.py``."""
 import dataclasses
+import functools
 import glob
 import json
 import os
@@ -267,8 +268,9 @@ def test_the_engine_counts_three_state_parts_and_three_attention_parts_a_token_s
     assert got == {"r0": 7, "r1": 4}
     ssm, attn, cache = eng.ssm_stats(), eng.attn_stats(), eng.cache_stats()
     assert ssm["state_layers"] == 3 and ssm["prefill"] == {"rows_scanned": 8 + 32, "rows_real": 25}
-    steps = ssm["decode"]["slot_steps"] // 2  # token steps: both slots advance in each
+    steps = ssm["decode"]["slot_steps"] // 2  # token steps: both slots are lanes of each
     assert ssm["decode"]["slot_steps_live"] == 6 + 3 and steps >= 6 and ssm["decode"]["slot_steps"] % 4 == 0
+    assert ssm["decode"]["slot_steps_visited"] == ssm["decode"]["slot_steps"]  # the XLA pass, here on the CPU
     assert attn["rows_allocated"] == steps * 3 * 2 * 64  # three full layers' rows a token step
     assert attn["rows_visited"] == attn["rows_allocated"]  # the XLA read, here on the CPU
     # the rows a live slot's step stands on: positions 0 .. pos, a layer
@@ -276,6 +278,53 @@ def test_the_engine_counts_three_state_parts_and_three_attention_parts_a_token_s
     assert set(cache) == {"full", "state"} and cache["full"]["layers"] == cache["state"]["layers"] == 3
     assert cache["state"]["bytes"] == 2 * 3 * (32 * 2 * 8 * 4 + 3 * 96 * 4)
     assert eng.moe_stats() == {}
+
+
+@pytest.fixture(scope="module")
+def under_both_updates():
+    """Two parallel layers whose state is wide enough for the kernel
+    (``ssm_state`` 128, heads of 8 rows) through the engine's fold, twice:
+    as it runs here (the XLA pass) and told a TPU (the kernel, interpreted).
+    ``{update: (tokens by request, stats()["ssm"]["decode"])}``."""
+    import jax
+
+    from ray_lightning_tpu.models import ssm
+    from ray_lightning_tpu.serve.engine import DecodeEngine
+
+    cfg = GPTConfig(**dict(PARALLEL, n_layer=2, layer_types=[["full+ssm", "dense"]] * 2, ssm_heads=4, ssm_head_dim=8,
+                           ssm_state=128))
+    params = init_gpt_params(jax.random.PRNGKey(0), cfg)
+    out = {}
+    for update in ("xla", "kernel"):
+        with pytest.MonkeyPatch.context() as mp:
+            if update == "kernel":
+                mp.setattr(ssm, "_step_heads", functools.partial(ssm._step_heads, backend="tpu"))
+            eng = DecodeEngine(params, cfg, num_slots=3, max_seq=64, prefill_buckets=[8], decode_fold=2)
+            rng = np.random.default_rng(5)
+            reqs = [dict(prompt=rng.integers(0, 96, n).tolist(), request_id=f"r{i}", max_new_tokens=m)
+                    for i, (n, m) in enumerate([(5, 7), (3, 4)])]
+            got = {r["request_id"]: [] for r in reqs}
+            eng.admit_many(reqs)
+            for _ in range(20):
+                for _, rid, tok, _ in eng.step():
+                    got[rid].append(tok)
+                if eng.num_active == 0:
+                    break
+            out[update] = (got, eng.ssm_stats()["decode"])
+    return out
+
+
+@pytest.mark.parametrize("update", ["xla", "kernel"])
+def test_a_parallel_layers_state_half_visits_the_live_slots_under_the_kernel(under_both_updates, update):
+    """Under the kernel the state half visits the live slot-steps and no
+    other, on the XLA pass every one; ``slot_steps`` and ``slot_steps_live``
+    read the same under both, to the number, and so do the tokens."""
+    tokens, d = under_both_updates[update]
+    assert d["slot_steps_live"] == 6 + 3 and d["slot_steps"] % 6 == 0 and d["slot_steps"] >= 3 * 6
+    assert d["slot_steps_visited"] == (d["slot_steps_live"] if update == "kernel" else d["slot_steps"])
+    other_tokens, other = under_both_updates["xla" if update == "kernel" else "kernel"]
+    assert tokens == other_tokens and [len(v) for v in tokens.values()] == [6, 3]
+    assert (d["slot_steps"], d["slot_steps_live"]) == (other["slot_steps"], other["slot_steps_live"])
 
 
 # -- the configurations the benchmark already runs ------------------------------------------------
@@ -316,5 +365,31 @@ def test_a_mixed_configuration_of_the_benchmark_has_the_trees_and_counters_it_ha
     for _ in range(4):
         eng.step()
     stats = {"moe": eng.moe_stats(), "ssm": eng.ssm_stats(), "attn": eng.attn_stats(), "cache": eng.cache_stats()}
+    if stats["ssm"]:
+        # the one key since the snapshot (PR 48): off a TPU the XLA pass moves every slot's state
+        assert stats["ssm"]["decode"].pop("slot_steps_visited") == stats["ssm"]["decode"]["slot_steps"]
     assert {k: keys(v) for k, v in stats.items()} == want["stats"]
     assert {"ssm": stats["ssm"], "attn": stats["attn"]} == want["counts"]
+
+
+@pytest.mark.parametrize("toy,program", [
+    ("toy_mimo", "fold"), ("toy_mimo", "admission"), ("toy_kanana", "fold"), ("toy_kanana", "admission"),
+    ("toy_nemotron", "admission"), ("toy_falcon_h1", "admission"),
+])
+def test_a_program_without_a_state_layers_decode_step_is_the_one_it_was(toy, program):
+    """The jaxpr of the toy roots' decode fold and admission, to the byte
+    (sha256 of its text), against what the commit before the state
+    layers' decode kernel traced with this same code (PR 47's tree:
+    ``tests/data/mixed_jaxprs_pr47.json``): the kernel, the live mask it
+    takes and the seventh count are in the fold of a configuration WITH
+    state layers and in no other program — the mimo and kanana cells'
+    folds and every configuration's admission are the parent's."""
+    from tests.utils import mixed_program_hashes
+
+    with open(os.path.join(HERE, "data", "mixed_jaxprs_pr47.json")) as f:
+        want = json.load(f)[toy][program]
+    path, = glob.glob(os.path.join(HERE, "perfbench", toy, "configs", "*.json"))
+    with open(path) as f:
+        cfg = GPTConfig(**json.load(f)["program_config"])
+    assert mixed_program_hashes(cfg)[program] == want
+

@@ -13,10 +13,15 @@ there is no room for a copy: one layer's state copied would be 0.25 GiB of
 temporaries, one layer's K or V 0.125. The configuration's depth was set
 from this build (six layers if it leaves 1 GiB of the 15.75 free, else
 five: ``perfbench/configs/falcon-h1-34b-d6.json``); this is the guard
-that it still does, and that no such copy comes in — under the decode
-kernel, the read the chip takes (``jax.default_backend()`` said "tpu"
-where the program asks, as ``tests/test_latent_step_v5e.py`` does; under
-the XLA read the fold read the same sizes: 0.224 GiB / 13.02 GiB).
+that it still does, and that no such copy comes in — under the two
+kernels the chip takes, the decode attention's read and the state's update
+over the live slots (``jax.default_backend()`` said "tpu" where the program
+asks, as ``tests/test_latent_step_v5e.py`` does; under XLA's read and
+update the fold read the same sizes: 0.224 GiB / 13.02 GiB). The state's
+update is the repo's first custom call whose output IS an argument
+(``ops/ssm_step.py``: ``input_output_aliases``): were the alias lost, or
+the state handed over as anything but the leaf the fold was given, each
+layer's call would bring a copy of 0.25 GiB.
 """
 import os
 import re
@@ -120,7 +125,7 @@ def test_the_parallel_cells_decode_fold_copies_no_state_and_no_rows(cell):
 
     t0 = time.monotonic()
     with pytest.MonkeyPatch.context() as mp:
-        mp.setattr(jax, "default_backend", lambda: "tpu")  # the read the chip takes: the decode kernel
+        mp.setattr(jax, "default_backend", lambda: "tpu")  # what the chip takes: the decode kernel, the state's walk
         # donated as serve/engine.py donates them: caches and the state the fold moves
         compiled = jax.jit(step, donate_argnums=(1, 2, 3, 4, 8, 9, 10)).lower(
             params, k_cache, v_cache, i32(), i32(), f32(), i32(), f32(), sds((B, 2), jnp.uint32),
@@ -136,9 +141,10 @@ def test_the_parallel_cells_decode_fold_copies_no_state_and_no_rows(cell):
     assert whole < 14.75 * GIB  # 13.02 read: 1 GiB of the chip's 15.75 stays free, and 1.7 more
     text = compiled.as_text().splitlines()
     mosaic = [ln for ln in text if 'custom_call_target="tpu_custom_call"' in ln]
-    # the fold is a scan: its body, one token step, is in the program once, a call a layer
-    assert len(mosaic) == LAYERS, mosaic
-    assert all("decode_attention" in ln.split(" = ")[0] for ln in mosaic), mosaic
+    # the fold is a scan: its body, one token step, is in the program once, two calls a layer — Mosaic took the
+    # state's update at 64 x 32 x 128 x 256 in blocks of 8 heads
+    names = sorted(ln.split(" = ")[0].strip().lstrip("%").split(".")[0] for ln in mosaic)
+    assert names == ["decode_attention"] * LAYERS + ["ssm_step"] * LAYERS, mosaic
     hits = [ln.strip()[:160] for ln in text if _COPIES.search(ln)]
     assert not hits, hits
     assert took < 300, "the guard's own time limit"

@@ -202,7 +202,18 @@ def test_a_configuration_without_state_layers_is_refused_as_before():
 
 
 # -- what the replica says -----------------------------------------------------------
-def test_the_replica_serves_it_and_reports_the_state_beside_the_cache(params, monkeypatch):
+@pytest.mark.parametrize("update", ["xla", "kernel"])
+def test_the_replica_serves_it_and_reports_the_state_beside_the_cache(update, monkeypatch):
+    """``update`` "kernel": the selection function is told a TPU, so the
+    state's update is the kernel that walks the live slots (interpreted
+    here) wherever the state is wide enough for it — a state of 128, where
+    the configuration above keeps 16: the counts below do not depend on it
+    but for the bytes and ``slot_steps_visited``."""
+    import functools
+
+    import jax
+
+    from ray_lightning_tpu.models import ssm as ssm_mod
     from ray_lightning_tpu.obs import registry
     from ray_lightning_tpu.serve.server import ServeReplica
 
@@ -210,7 +221,14 @@ def test_the_replica_serves_it_and_reports_the_state_beside_the_cache(params, mo
     # tests/test_mixed_layers.py reads exact expert-layer totals out of it
     own = registry.MetricsRegistry()
     monkeypatch.setattr(registry, "get_registry", lambda: own)
-    rep = ServeReplica(params=params, model_config=dict(STATE), num_slots=3, max_seq=64,
+    N = 16
+    if update == "kernel":
+        N = 128
+        monkeypatch.setattr(ssm_mod, "_step_heads", functools.partial(ssm_mod._step_heads, backend="tpu"))
+    wide = dict(STATE, ssm_state=N, ssm_head_dim=8)
+    assert ssm_mod._step_heads(jax.ShapeDtypeStruct((3, 8, 8, N), "float32"), 2) == (4 if update == "kernel" else 0)
+    params = init_gpt_params(jax.random.PRNGKey(0), GPTConfig(**wide))
+    rep = ServeReplica(params=params, model_config=wide, num_slots=3, max_seq=64,
                        prefill_buckets=[4, 16], decode_fold=4, watchdog=False)
     try:
         rng = np.random.default_rng(1)
@@ -225,13 +243,19 @@ def test_the_replica_serves_it_and_reports_the_state_beside_the_cache(params, mo
         assert ssm["prefill"] == {"rows_scanned": 16 + 4 + 16 + 4, "rows_real": 10 + 3 + 12 + 2}
         assert ssm["decode"]["slot_steps_live"] == 4 * 19 == moe["decode"]["pairs_routed"] // (2 * 3)
         assert ssm["decode"]["slot_steps"] >= ssm["decode"]["slot_steps_live"] and ssm["decode"]["slot_steps"] % 12 == 0
-        per_slot = 2 * (8 * 4 * 16 * 4 + 3 * 96 * 4)
+        # the slot-steps whose state was read and written: the live ones under the kernel, all under the XLA pass
+        assert ssm["decode"]["slot_steps_visited"] == ssm["decode"][
+            "slot_steps_live" if update == "kernel" else "slot_steps"]
+        assert list(ssm["decode"]) == ["slot_steps", "slot_steps_live", "slot_steps_visited"]
+        per_slot = 2 * (8 * 8 * N * 4 + 3 * (64 + 4 * N) * 4)
         assert cache["state"] == {"layers": 2, "rows_per_slot": 1, "bytes": 3 * per_slot, "row_layout": False}
         assert cache["full"]["layers"] == 1 and set(cache) == {"full", "state"}
         assert st["memory"]["kv_cache"]["bytes"] == cache["full"]["bytes"] + cache["state"]["bytes"]
         assert st["compiles_since_init"] == 0
         text = rep.metrics_text()
         assert f'rlt_serve_ssm_slot_steps_live_total {ssm["decode"]["slot_steps_live"]}\n' in text
+        assert f'rlt_serve_ssm_slot_steps_total {ssm["decode"]["slot_steps"]}\n' in text
+        assert f'rlt_serve_ssm_slot_steps_visited_total {ssm["decode"]["slot_steps_visited"]}\n' in text
         assert f'rlt_serve_ssm_rows_scanned_total {ssm["prefill"]["rows_scanned"]}\n' in text
         assert f'rlt_serve_kv_bytes{{kind="state"}} {cache["state"]["bytes"]}\n' in text
     finally:

@@ -10,9 +10,15 @@ whole, so the compiler updates it where it lies: temporaries of 0.08 GiB
 in a program of 11.5 GiB (PERF.md §4). One copy of one layer's state would
 be 0.5 GiB of temporaries, a copy of all five 2.5 GiB, and the fold would
 no longer fit beside a 1024-row admission: this is the guard that no such
-copy comes in.
+copy comes in — under the kernels the chip takes (``jax.default_backend()``
+said "tpu" where the program asks, as ``tests/test_parallel_step_v5e.py``
+does): the one full layer's decode read and, a state layer, the update that
+walks the live slots (``ops/ssm_step.py``), whose output IS its argument
+(``input_output_aliases``). Were the alias lost, each layer's call would
+bring the copy this guard is for.
 """
 import os
+import re
 import time
 
 import pytest
@@ -81,15 +87,27 @@ def test_the_state_cells_decode_fold_copies_no_state(v5e, monkeypatch):
         return gpt_decode_fold(params, pc, cur, pos, keys, temps, top_ks, top_ps, active, remaining, eos,
                                k_cache, v_cache, fold=int(rep["decode_fold"]))
 
-    # donated as serve/engine.py donates them: caches and the state the fold moves
-    m = jax.jit(step, donate_argnums=(1, 2, 3, 4, 8, 9, 10)).lower(
-        params, k_cache, v_cache, i32(), i32(), f32(), i32(), f32(), sds((B, 2), jnp.uint32),
-        sds((B,), jnp.bool_), i32(), i32(),
-    ).compile().memory_analysis()
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "default_backend", lambda: "tpu")  # what the chip takes: the decode kernel, the state's walk
+        # donated as serve/engine.py donates them: caches and the state the fold moves
+        compiled = jax.jit(step, donate_argnums=(1, 2, 3, 4, 8, 9, 10)).lower(
+            params, k_cache, v_cache, i32(), i32(), f32(), i32(), f32(), sds((B, 2), jnp.uint32),
+            sds((B,), jnp.bool_), i32(), i32(),
+        ).compile()
+    m = compiled.memory_analysis()
     whole = m.argument_size_in_bytes + m.output_size_in_bytes - m.alias_size_in_bytes + m.temp_size_in_bytes
     took = time.monotonic() - t0
     print(f"state cell's decode fold at {B} x {S}: temporaries {m.temp_size_in_bytes / GIB:.3f} GiB, "
           f"whole program {whole / GIB:.2f} GiB, built in {took:.0f} s")
     assert m.temp_size_in_bytes < 0.4 * GIB  # 0.081 read; one layer's state copied would be 0.5 more
+    assert m.alias_size_in_bytes >= state  # every layer's state is updated where it lies
+    text = compiled.as_text().splitlines()
+    mosaic = [ln for ln in text if 'custom_call_target="tpu_custom_call"' in ln]
+    # the fold is a scan: its body, one token step, is in the program once — Mosaic took the state's update at
+    # 128 x 128 x 64 x 128 in blocks of 16 heads, a call a state layer, beside the one full layer's read
+    names = sorted(ln.split(" = ")[0].strip().lstrip("%").split(".")[0] for ln in mosaic)
+    assert names == ["decode_attention"] + ["ssm_step"] * 5, mosaic
+    copies = [ln.strip()[:160] for ln in text if re.search(r"= f32\[128,128,64,128\]\S* (copy|transpose)\(", ln)]
+    assert not copies, copies
     assert whole < 12.0 * GIB  # 11.53 read
     assert took < 240, "the guard's own time limit: 15 s read"

@@ -83,3 +83,46 @@ def force_decode_kernel(monkeypatch: Any) -> None:
     monkeypatch.setattr(
         G, "_decode_rows_block", functools.partial(G._decode_rows_block, backend="tpu")
     )
+
+
+def mixed_program_hashes(cfg: Any) -> dict:
+    """sha256 of the jaxpr texts of a mixed configuration's two serving
+    programs, traced at toy sizes (three slots of 32 rows, a fold of two,
+    a bucket of eight): ``{"fold": ..., "admission": ...}`` —
+    ``gpt_decode_fold`` over both caches and an admission as
+    ``serve/engine.py:admit_impl`` makes it (the rows' pass, both halves
+    written into the slot, the head on the last real row). Two trees that
+    give one text run one program."""
+    import hashlib
+
+    import jax
+    import jax.numpy as jnp
+
+    from ray_lightning_tpu.models.gpt import _rmsnorm, gpt_decode_fold, init_gpt_params
+    from ray_lightning_tpu.models.mixed import empty_caches, mixed_logits, mixed_rows, write_prefill_rows
+
+    slots, rows, bucket, fold = 3, 32, 8, 2
+    params = jax.eval_shape(lambda: init_gpt_params(jax.random.PRNGKey(0), cfg))
+    k_cache, v_cache = jax.eval_shape(lambda: empty_caches(cfg, slots, rows, jnp.float32))
+    i32, f32 = jax.ShapeDtypeStruct((slots,), jnp.int32), jax.ShapeDtypeStruct((slots,), jnp.float32)
+
+    def step(params, k_cache, v_cache, cur, pos, temps, top_ks, top_ps, keys, active, remaining, eos):
+        return gpt_decode_fold(params, cfg, cur, pos, keys, temps, top_ks, top_ps, active, remaining, eos,
+                               k_cache, v_cache, fold=fold)
+
+    def admit(params, k_cache, v_cache, prompt, last_idx, slot):
+        h, pf_k, pf_v, counts = mixed_rows(params, cfg, prompt, true_len=last_idx + 1)
+        k_cache, v_cache = write_prefill_rows(k_cache, v_cache, pf_k, pf_v, slot, last_idx + 1)
+        h_last = jax.lax.dynamic_slice_in_dim(h, last_idx, 1, axis=1)
+        logits = mixed_logits(_rmsnorm(h_last, params["lnf_g"], cfg.norm_eps)[:, 0], params, cfg)
+        return k_cache, v_cache, jnp.argmax(logits, -1), counts
+
+    scalar = jax.ShapeDtypeStruct((), jnp.int32)
+    texts = {
+        "fold": jax.make_jaxpr(step)(
+            params, k_cache, v_cache, i32, i32, f32, i32, f32, jax.ShapeDtypeStruct((slots, 2), jnp.uint32),
+            jax.ShapeDtypeStruct((slots,), jnp.bool_), i32, i32),
+        "admission": jax.make_jaxpr(admit)(
+            params, k_cache, v_cache, jax.ShapeDtypeStruct((1, bucket), jnp.int32), scalar, scalar),
+    }
+    return {k: hashlib.sha256(str(v).encode()).hexdigest() for k, v in texts.items()}
