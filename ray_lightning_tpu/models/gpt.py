@@ -1332,7 +1332,7 @@ def gpt_prefill(
 
         if not _mesh_is_one_device(mesh):
             refuse_mixed(cfg, "prefill over a serve mesh of more than one device")
-        return mixed_rows(params, cfg, prompt)[:3]
+        return mixed_rows(params, cfg, prompt, prefill=True)[:3]
     cdt = jnp.dtype(cfg.compute_dtype)
     norm_fn = _make_norm(cfg)
     H, hd = cfg.n_head, cfg.head_dim

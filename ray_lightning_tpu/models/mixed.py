@@ -34,8 +34,11 @@ without them compiles to what it compiled to before they existed.
 One block, :func:`mixed_block`, serves the three modes the model runs in:
 
 - no cache (``gpt_forward``): attention among the rows given;
-- prefill (``gpt_prefill``): the same, and the rows' K/V come out so that
-  the caller can write them into a slot (:func:`write_prefill_rows`);
+- prefill (``gpt_prefill``, an admission): the same, and the rows' K/V come
+  out so that the caller can write them into a slot
+  (:func:`write_prefill_rows`); nobody differentiates these rows, so a full
+  or latent layer's attention may run in the forward flash kernel
+  (:func:`prefill_kernel` says when);
 - decode (``gpt_decode_step``): one row a slot at per-slot positions, its
   K/V written in place into the caches and attention read from them.
 
@@ -103,6 +106,29 @@ _MIXER_PREFIX = {"full": "full", "window": "swa", "latent": "lat", "ssm": "ssm"}
 #: Query rows a block of the no-cache full attention takes at a time: the
 #: float32 scores of a block against its causal prefix are what is live.
 _Q_BLOCK = 512
+#: Rows of a no-cache attention from which the forward flash kernel reads it
+#: (:func:`prefill_kernel`): the least bucket at which a PROGRAM gains on the
+#: chip. By the layer's call alone the kernel beats the blocked XLA read from
+#: 2,048 rows at all three served shapes (``tools/flash_check.py --time``, us
+#: for one layer's call, XLA / kernel, bfloat16, every row real; a call of
+#: either costs about 600 us of dispatch there; PERF.md §6, PR 49):
+#:
+#:   rows    32 heads 192/128    64 on 4, 192/128    20 on 4, 128/128
+#:    512       661 / 693           784 / 860           621 / 633
+#:   1024       771 / 903         1,611 / 1,166         688 / 674
+#:   2048     1,804 / 1,347       4,064 / 2,046         940 / 896
+#:   4096     6,467 / 3,134      11,700 / 5,063       3,120 / 1,605
+#:   6144    13,853 / 5,372      24,691 / 9,707       7,162 / 2,698
+#:
+#: (with the prompt at half the bucket + 1 the kernel's time falls further,
+#: 3,259 / 5,014 / 1,595 at 6,144 rows; XLA's does not). But sixteen latent
+#: layers over a 2,048-row prompt read 53.0 ms against 53.8 — the XLA read
+#: of that size overlaps the fusions around it — where 4,096 rows read 120.8
+#: against 164.4, and every admission program that holds the kernel costs
+#: a replica seconds of start (the docqa cell's warm ``setup_s`` 85-89 s with
+#: none, 90-98 with the two buckets from 4,096 up, 97-102 with three, 103
+#: with all five). So the 2,048 bucket keeps the XLA read.
+_KERNEL_ROWS = 4096
 
 
 @dataclass(frozen=True)
@@ -514,6 +540,53 @@ def _attend_rows_full(q, k, v, sink):
     return out[0] if len(out) == 1 else jnp.concatenate(out, axis=1)
 
 
+def prefill_kernel(cfg: Any, kind: str, rows: int, backend: Optional[str] = None) -> bool:
+    """Which read the no-cache attention of a ``kind`` layer takes over
+    ``rows`` rows that nobody differentiates (a prefill), from what it can
+    observe — as ``models/gpt.py:_decode_rows_block`` answers for decode:
+    True for the forward flash kernel (``ops/flash_attention.py``: the score
+    tile stays in VMEM, the KV heads are not repeated, query blocks past the
+    prompt's end are not computed), False for the blocked XLA read
+    (:func:`_attend_rows_full`, whose float32 scores and ``p`` go through
+    HBM). The kernel wants ``attn_impl="flash"``, a TPU (elsewhere it would
+    run interpreted, and the CPU's token-identity tests keep one order of
+    sums), the full or the latent kind — the window kind's read is already
+    ``rows x 2W`` (:func:`_attend_rows_window`) — without a learnable sink
+    logit, which the kernel's sums do not know, head widths Mosaic takes
+    (multiples of 64 up to 256: 64, 128, 192 and 256 lower for the v5e), rows
+    that its tile divides, and at least :data:`_KERNEL_ROWS` of them: below
+    that a program does not gain on the chip. ``serve/engine.py``
+    asks the same question, bucket by bucket, for ``stats()["attn"]``."""
+    from ray_lightning_tpu.ops.flash_attention import _default_block
+
+    tile = min(_default_block(rows), rows)
+    return (
+        cfg.attn_impl == "flash"
+        and (backend or jax.default_backend()) == "tpu"
+        and kind in ("full", "latent")
+        and kind not in cfg.attn_sink_logit
+        and all(d % 64 == 0 and d <= 256 for d in (qk_dim(cfg), v_dim(cfg)))
+        and rows >= _KERNEL_ROWS
+        and rows % tile == 0
+    )
+
+
+def _attend_rows(cfg, kind, q, k, v, sink, real_rows):
+    """Causal attention among the S rows of a full or latent layer with no
+    cache: q (B, S, G, R, d), k (B, S, G, d), v (B, S, G, dv) -> (B, S,
+    G * R, dv). ``real_rows`` None: a forward pass that may be
+    differentiated, the XLA read. Else the rows are a prefill's, the first
+    ``real_rows`` (int32 scalar) of them real, and :func:`prefill_kernel`
+    says which read; the kernel leaves the query blocks past ``real_rows``
+    zeros."""
+    B, S, G, R, d = q.shape
+    if real_rows is None or not prefill_kernel(cfg, kind, S):
+        return _attend_rows_full(q, k, v, sink)
+    from ray_lightning_tpu.ops.flash_attention import flash_attention
+
+    return flash_attention(q.reshape(B, S, G * R, d), k, v, causal=True, true_len=real_rows)
+
+
 def _attend_rows_window(q, k, v, sink, window: int):
     """Causal attention among S rows, each query seeing its last
     ``window`` positions: the queries go in blocks of ``window`` rows, and
@@ -672,11 +745,18 @@ def _mlp(x: jax.Array, wi: jax.Array, wo: jax.Array, cfg: Any, gate: float = 1.0
     return _scaled(jnp.einsum("...f,fd->...d", mlp_act(z, cfg.mlp_variant), wo.astype(cdt)), out)
 
 
-def _attention_part(h, lp, ls, cfg, rope, pos, caches, live=None, u=None):
+def _attention_part(h, lp, ls, cfg, rope, pos, caches, live=None, u=None, real_rows=None):
     """``(attention's write into the residual, kv)`` of one attention layer
     (``u``: the layer's normed input where the caller has it already, a
     parallel layer's; else it is ``RMSNorm(h; ln1_g)``).
-    In decode the read after the row's write is the one
+    With no cache the rows attend among themselves: the window kind in
+    blocks of ``attn_window`` rows (:func:`_attend_rows_window`), the full
+    kind a block of 512 queries at a time against its causal prefix in XLA
+    (:func:`_attend_rows_full`) or, where the rows are a prefill's
+    (``real_rows``) and :func:`prefill_kernel` says so — a TPU, no sink
+    logit, the bucket at or over the crossing —, in the forward flash
+    kernel, which takes ``k`` and ``v`` at their ``G`` KV heads as they
+    are. In decode the read after the row's write is the one
     ``models/gpt.py:_decode_rows_block`` names for the layer's kind: the
     decode kernel (``ops/decode_attention.py:decode_attention``) over the
     ``live`` slots' row blocks ``0 .. pos`` — a full kind without a sink
@@ -706,7 +786,7 @@ def _attention_part(h, lp, ls, cfg, rope, pos, caches, live=None, u=None):
             o = (
                 _attend_rows_window(q, k, v, sink, window)
                 if window
-                else _attend_rows_full(q, k, v, sink)
+                else _attend_rows(cfg, ls.mixer, q, k, v, sink, real_rows)
             )
         else:
             k_cache, v_cache = dict(caches[0]), dict(caches[1])
@@ -784,15 +864,19 @@ def _attend_latent_cache(cfg, q_lat, q_rope, c_cache, r_cache, li, pos, live=Non
     return jnp.einsum("bhs,bsc->bhc", p.astype(cc.dtype), cc)
 
 
-def _latent_part(h, lp, ls, cfg, rope, pos, caches, live=None):
+def _latent_part(h, lp, ls, cfg, rope, pos, caches, live=None, real_rows=None):
     """``(a latent layer's write into the residual, kv)``. Every head's keys
     and values are projections ``wkv_b`` of one normed latent ``c`` a
     position, beside one rotary key the heads share: ``s_h = (q_nope,h ·
     wkv_b[K,h]^T c + rope(q_rope,h) · rope(k_r)) / sqrt(dqk)``.
 
     With no cache (forward, prefill) the keys and values are built from
-    the latent, head by head, and the rows attend as the full kind's do;
-    ``kv`` is ``(c, rope(k_r))`` of every row, what the cache keeps. In
+    the latent, head by head, and the rows attend as the full kind's do
+    (:func:`_attend_rows`: the blocked XLA read, or for a prefill's rows,
+    ``real_rows``, on a TPU from the crossing up the forward flash kernel
+    on the built keys and values, every head a KV head of its own, q·k as
+    wide as ``qk_dim`` and v as ``v_dim``); ``kv`` is ``(c, rope(k_r))`` of
+    every row, what the cache keeps. In
     decode ``wkv_b`` moves onto the query and the output instead (the same
     sums in another order): ``q^_h = wkv_b[K,h] q_nope,h`` scores against
     the cached latents as they lie, ``p`` weighs the latents themselves,
@@ -819,7 +903,7 @@ def _latent_part(h, lp, ls, cfg, rope, pos, caches, live=None):
             kv_h = jnp.einsum("bsc,hck->bshk", c, wkv_b)
             k = jnp.concatenate([kv_h[..., :dn], jnp.broadcast_to(k_rope, (B, S, H, k_rope.shape[-1]))], axis=-1)
             qf = jnp.concatenate([q_nope, q_rope], axis=-1)[:, :, :, None]  # every head a KV head of its own
-            o = _attend_rows_full(qf, k, kv_h[..., dn:], None)
+            o = _attend_rows(cfg, "latent", qf, k, kv_h[..., dn:], None, real_rows)
         else:
             c_cache, r_cache = dict(caches[0]), dict(caches[1])
             c_cache["latent"] = _write_cache_rows(c_cache["latent"], ls.mixer_index, c[:, 0], pos)
@@ -855,7 +939,7 @@ def _state_part(h, lp, ls, cfg, caches, valid, u=None):
     return out, (k_cache, v_cache)
 
 
-def _parallel_part(h, lp, ls, cfg, rope, pos, caches, valid):
+def _parallel_part(h, lp, ls, cfg, rope, pos, caches, valid, real_rows=None):
     """``(A(u) + M(u), kv)`` of a parallel layer: full attention and a state
     layer on the one normed input ``u = RMSNorm(h; ln1_g)``. With no cache
     ``kv`` is ``{"full": (k, v), "ssm": (state, tail)}``; in decode the
@@ -868,7 +952,9 @@ def _parallel_part(h, lp, ls, cfg, rope, pos, caches, valid):
 
     with jax.named_scope("parallel"):
         u = _rmsnorm(h, lp["ln1_g"], cfg.norm_eps)
-        a, kv_a = _attention_part(h, lp, ls, cfg, rope, pos, caches, None if valid is None else valid[:, 0], u=u)
+        a, kv_a = _attention_part(
+            h, lp, ls, cfg, rope, pos, caches, None if valid is None else valid[:, 0], u=u, real_rows=real_rows,
+        )
         side = replace(ls, mixer=ls.side, mixer_index=ls.side_index, side=None)
         m, kv_m = _state_part(h, lp["side"], side, cfg, caches and kv_a, valid, u=u)
     return a + m, (kv_m if caches else {ls.mixer: kv_a, ls.side: kv_m})
@@ -911,6 +997,7 @@ def mixed_block(
     pos: Optional[jax.Array] = None,
     caches: Optional[Tuple[Dict[str, Any], Dict[str, Any]]] = None,
     valid: Optional[jax.Array] = None,
+    real_rows: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, Any, jax.Array]:
     """One layer over h (B, S, D) -> ``(h, kv, moe_stats)``: its mixer if
     it has one (a parallel layer's two, summed), then its MLP if it has
@@ -926,20 +1013,22 @@ def mixed_block(
     row, or its state and tail, are replaced in the caches and ``kv`` is
     the updated pair. A layer without a mixer hands back ``caches`` (None
     with no cache). ``valid`` (B, S) bool marks the real tokens for the
-    state layer and the expert layer. ``moe_stats`` is
+    state layer and the expert layer. ``real_rows`` (int32 scalar, with no
+    cache): the rows are a prefill's and that many of them real, so a full
+    or latent layer's attention asks :func:`prefill_kernel` which read. ``moe_stats`` is
     :func:`moe_ffn_held`'s (zeros for any other layer)."""
     from ray_lightning_tpu.models.gpt import _rmsnorm
 
     kv, stats = caches, jnp.zeros((3,), jnp.int32)
     if ls.side:
-        out, kv = _parallel_part(h, lp, ls, cfg, rope, pos, caches, valid)
+        out, kv = _parallel_part(h, lp, ls, cfg, rope, pos, caches, valid, real_rows)
         h = h + out
     elif ls.mixer == "ssm":
         out, kv = _state_part(h, lp, ls, cfg, caches, valid)
         h = h + out
     elif ls.mixer:
         part = _latent_part if ls.mixer == "latent" else _attention_part
-        out, kv = part(h, lp, ls, cfg, rope, pos, caches, None if valid is None else valid[:, 0])
+        out, kv = part(h, lp, ls, cfg, rope, pos, caches, None if valid is None else valid[:, 0], real_rows=real_rows)
         h = h + out
     if ls.mlp:
         m = _rmsnorm(h, lp["ln2_g"], cfg.norm_eps)
@@ -976,6 +1065,7 @@ def mixed_logits(h: jax.Array, params: Dict[str, Any], cfg: Any) -> jax.Array:
 
 def mixed_rows(
     params: Dict[str, Any], cfg: Any, tokens: jax.Array, true_len: Optional[jax.Array] = None,
+    prefill: bool = False,
 ) -> Tuple[jax.Array, Dict[str, Any], Dict[str, Any], jax.Array]:
     """The layers over tokens (B, S) with no cache: pre-final-norm hidden
     states, what the rows leave behind in its two halves by kind — K and V
@@ -985,7 +1075,12 @@ def mixed_rows(
     routed, pairs on held experts, held experts hit, rows, real rows]``
     (int32; the expert layers' ``moe_stats`` summed). ``true_len``
     (scalar): only the first ``true_len`` rows are real (a right-padded
-    prompt)."""
+    prompt). ``prefill``: nobody differentiates these rows (an admission,
+    ``gpt_prefill``), so the full and latent layers' attention may take the
+    forward flash kernel (:func:`prefill_kernel` says when), which leaves
+    the hidden states of query blocks past ``true_len`` unspecified; the
+    forward pass (``gpt_forward``) keeps the XLA read, which has a
+    gradient."""
     from ray_lightning_tpu.utils.quantize import embed_rows
 
     B, S = tokens.shape
@@ -994,12 +1089,13 @@ def mixed_rows(
     pos = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
     rope = _rope_by_kind(cfg, pos)
     valid = None if true_len is None else pos < true_len
+    real_rows = jnp.asarray(S if true_len is None else true_len, jnp.int32) if prefill else None
     ks: Dict[str, List[jax.Array]] = {}
     vs: Dict[str, List[jax.Array]] = {}
     stats = jnp.zeros((3,), jnp.int32)
     for ls in layer_specs(cfg):
         lp = _layer_leaves(params["blocks"], ls)
-        h, kv, st = mixed_block(h, lp, ls, cfg, rope, valid=valid)
+        h, kv, st = mixed_block(h, lp, ls, cfg, rope, valid=valid, real_rows=real_rows)
         for kind, (k, v) in (kv if ls.side else {ls.mixer: kv} if ls.mixer else {}).items():
             ks.setdefault(kind, []).append(k if kind == "ssm" else k.astype(cdt))
             vs.setdefault(kind, []).append(v.astype(cdt))

@@ -16,6 +16,22 @@ computes (p, ds) are rounded to that dtype once, at their product, as
 delta), the exponentials and the acc / dq / dk / dv accumulators are float32
 whatever the inputs; float32 inputs multiply float32.
 
+The forward kernel also takes what the backward kernels do not, and is
+then forward-only (differentiating says so): a v of its own width (the
+accumulator and the output are as wide as v, not q), K and V with fewer
+heads than q (``(B * Hkv, S, d)``; the block's index map sends query head
+``h`` to KV head ``h // (H // Hkv)``, so no KV head is repeated in HBM and
+the heads of a group, consecutive grid steps, reuse the block that is
+there), and ``true_len``, the count of real rows of a right-padded prompt,
+as a prefetched scalar: a grid cell whose first row is at or past it visits
+no key block and writes zeros. That is the read of a mixed layer's prefill
+attention — the full and latent kinds, q·k 192 / v 128, 64 heads on 4 —
+from the bucket rows up at which it beats the blocked XLA read, whose
+scores go through HBM (``models/mixed.py:prefill_kernel`` says when). With
+equal widths, a KV head a query head and no ``true_len`` the kernel lowers
+to what it lowered to before it could: the train step's three kernels and
+the dense engine's prefill keep their programs.
+
 On non-TPU backends the same kernels run in Pallas interpret mode (tests).
 Shapes the kernels cannot tile go to ``attention_reference``, with one
 warning per shape naming the rule that rejected it.
@@ -72,16 +88,21 @@ def _warn_once(key: Any, msg: str) -> None:
 
 
 def _fwd_kernel(
-    q_ref, k_ref, v_ref, o_ref, lse_ref,
-    *, block_k: int, causal: bool, sm_scale: float, window: int, sinks: int,
+    *refs,
+    block_k: int, causal: bool, sm_scale: float, window: int, sinks: int,
+    bounded: bool = False,
 ):
-    # Block shapes: q (1, block_q, d); k, v (1, Sk, d); o like q;
-    # lse (1, block_q, 8) — the stats row is padded to 8 lanes because TPU
-    # block shapes must have their last two dims (8, 128)-conformant; the
-    # wrapper slices lane 0 back out.
+    # Block shapes: q (1, block_q, d); k (1, Sk, d); v (1, Sk, dv); o
+    # (1, block_q, dv); lse (1, block_q, 8) — the stats row is padded to 8
+    # lanes because TPU block shapes must have their last two dims
+    # (8, 128)-conformant; the wrapper slices lane 0 back out. ``bounded``:
+    # a prefetched scalar leads the refs, the count of real rows.
+    len_ref, (q_ref, k_ref, v_ref, o_ref, lse_ref) = (
+        (refs[0], refs[1:]) if bounded else (None, refs)
+    )
     block_q = q_ref.shape[1]
     seq_k = k_ref.shape[1]
-    head_dim = q_ref.shape[2]
+    head_dim = v_ref.shape[2]
     iq = pl.program_id(1)
     q = q_ref[0]  # (bq, d)
 
@@ -101,6 +122,10 @@ def _fwd_kernel(
         first_kb = jnp.maximum(0, q_offset - window + 1) // block_k
     else:
         first_kb = 0
+    if bounded:
+        # A query block wholly past the prompt's end belongs to no request:
+        # it visits no key block, and the lines below write zeros for it.
+        num_kb = jnp.where(q_offset < len_ref[0], num_kb, 0)
 
     def body(i, carry):
         m_prev, l_prev, acc_prev = carry
@@ -141,10 +166,10 @@ def _fwd_kernel(
     if window and sinks:
         # Visit the sink block(s) not already covered by the band loop
         # (online softmax is order-agnostic, so two loops compose).
-        n_sink_kb = (sinks + block_k - 1) // block_k
-        init = jax.lax.fori_loop(
-            0, jnp.minimum(n_sink_kb, first_kb), body, init
-        )
+        n_sink_kb = jnp.minimum((sinks + block_k - 1) // block_k, first_kb)
+        if bounded:
+            n_sink_kb = jnp.minimum(n_sink_kb, num_kb)
+        init = jax.lax.fori_loop(0, n_sink_kb, body, init)
     m, l, acc = jax.lax.fori_loop(first_kb, num_kb, body, init)
     # Rows with no unmasked keys (can't happen for causal self-attention with
     # aligned blocks, but keep the kernel total) produce l=0 -> output 0.
@@ -165,10 +190,18 @@ def _flash_fwd(
     interpret: bool,
     window: int = 0,
     sinks: int = 0,
+    true_len: Optional[jax.Array] = None,
 ):
-    """Run the kernel on (B, S, H, D) inputs; returns (out, lse)."""
+    """Run the kernel on q (B, S, H, D), k (B, Sk, Hkv, D), v (B, Sk, Hkv,
+    Dv); returns (out (B, S, H, Dv), lse). Query head ``h`` reads KV head
+    ``h // (H // Hkv)``: the block's index map names it, so no KV head is
+    repeated in HBM and the query heads of a group, consecutive grid
+    steps, reuse the K/V block that is there. ``true_len`` (int32 scalar)
+    goes in as a prefetched scalar: query blocks wholly at or past it come
+    out zeros and visit no key block."""
     batch, seq_q, heads, head_dim = q.shape
-    seq_k = k.shape[1]
+    seq_k, kv_heads, v_dim = k.shape[1], k.shape[2], v.shape[3]
+    rep = heads // kv_heads
     block_q = min(block_q, seq_q)
     block_k = min(block_k, seq_k)
     if seq_q % block_q or seq_k % block_k:
@@ -180,10 +213,11 @@ def _flash_fwd(
         raise ValueError("causal flash kernel requires Sq == Sk (self-attention)")
     # Fold heads into the grid's batch dimension: (B*H, S, D).
     qf = q.transpose(0, 2, 1, 3).reshape(batch * heads, seq_q, head_dim)
-    kf = k.transpose(0, 2, 1, 3).reshape(batch * heads, seq_k, head_dim)
-    vf = v.transpose(0, 2, 1, 3).reshape(batch * heads, seq_k, head_dim)
+    kf = k.transpose(0, 2, 1, 3).reshape(batch * kv_heads, seq_k, head_dim)
+    vf = v.transpose(0, 2, 1, 3).reshape(batch * kv_heads, seq_k, v_dim)
 
     grid = (batch * heads, seq_q // block_q)
+    bounded = true_len is not None
     kernel = functools.partial(
         _fwd_kernel,
         block_k=block_k,
@@ -191,27 +225,44 @@ def _flash_fwd(
         sm_scale=sm_scale,
         window=window,
         sinks=sinks,
+        bounded=bounded,
     )
-    out, lse = pl.pallas_call(
-        kernel,
+    # ``*_``: the prefetched scalar, where there is one. One KV head a
+    # query head is today's map, letter for letter.
+    rows = lambda b, i, *_: (b, i, 0)  # noqa: E731
+    if rep == 1:
+        whole = lambda b, i, *_: (b, 0, 0)  # noqa: E731
+    else:
+        whole = lambda b, i, *_: (b // rep, 0, 0)  # noqa: E731
+    specs = dict(
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, block_q, head_dim), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, seq_k, head_dim), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, seq_k, head_dim), lambda b, i: (b, 0, 0)),
+            pl.BlockSpec((1, block_q, head_dim), rows),
+            pl.BlockSpec((1, seq_k, head_dim), whole),
+            pl.BlockSpec((1, seq_k, v_dim), whole),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, head_dim), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 8), lambda b, i: (b, i, 0)),
+            pl.BlockSpec((1, block_q, v_dim), rows),
+            pl.BlockSpec((1, block_q, 8), rows),
         ],
+    )
+    if bounded:
+        from jax.experimental.pallas import tpu as pltpu
+
+        specs = dict(
+            grid_spec=pltpu.PrefetchScalarGridSpec(num_scalar_prefetch=1, **specs)
+        )
+    out, lse = pl.pallas_call(
+        kernel,
+        **specs,
         out_shape=[
-            jax.ShapeDtypeStruct((batch * heads, seq_q, head_dim), q.dtype),
+            jax.ShapeDtypeStruct((batch * heads, seq_q, v_dim), q.dtype),
             jax.ShapeDtypeStruct((batch * heads, seq_q, 8), jnp.float32),
         ],
         interpret=interpret,
         name="flash_fwd",
-    )(qf, kf, vf)
-    out = out.reshape(batch, heads, seq_q, head_dim).transpose(0, 2, 1, 3)
+    )(*((jnp.reshape(true_len, (1,)).astype(jnp.int32),) if bounded else ()), qf, kf, vf)
+    out = out.reshape(batch, heads, seq_q, v_dim).transpose(0, 2, 1, 3)
     lse = lse[:, :, 0].reshape(batch, heads, seq_q)
     return out, lse
 
@@ -446,6 +497,30 @@ def _flash_vjp_bwd(
 _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9, 10))
+def _flash_forward_only(
+    q, k, v, true_len, causal, sm_scale, block_q, block_k, interpret, window, sinks
+):
+    """The forward kernel for what the backward kernels do not take: a v
+    of its own width, grouped KV heads, a count of real rows."""
+    return _flash_fwd(
+        q, k, v, causal, sm_scale, block_q, block_k, interpret, window, sinks,
+        true_len=true_len,
+    )[0]
+
+
+def _forward_only_refuses(*_):
+    raise NotImplementedError(
+        "flash_attention is forward-only with unequal q·k / v widths, grouped "
+        "KV heads or true_len: flash_dkv and flash_dq take one head width, "
+        "one KV head a query head and every row (repeat the KV heads and "
+        "pad v to differentiate through the kernels)"
+    )
+
+
+_flash_forward_only.defvjp(_forward_only_refuses, _forward_only_refuses)
+
+
 def flash_attention(
     q: jax.Array,
     k: jax.Array,
@@ -458,8 +533,17 @@ def flash_attention(
     window: int = 0,
     sinks: int = 0,
     mesh: Optional[jax.sharding.Mesh] = None,
+    true_len: Optional[jax.Array] = None,
 ) -> jax.Array:
     """Pallas flash attention on (B, S, H, D) tensors.
+
+    Forward only (module docstring), k and v may have fewer heads than q
+    — ``(B, Sk, Hkv, D)`` with ``H % Hkv == 0``, query head ``h`` reading KV
+    head ``h // (H // Hkv)`` — v a width of its own, ``(B, Sk, Hkv, Dv)``
+    for an output ``(B, S, H, Dv)``, and ``true_len`` (int32 scalar) may say
+    that only the first ``true_len`` rows are real (a right-padded prompt):
+    the rows at or past it come out unspecified — zeros where a whole
+    query block lies past it, which is then not computed.
 
     Products take their operands in the dtype of ``q`` / ``k`` / ``v`` and
     accumulate in float32 (module docstring). ``block_q`` / ``block_k`` left
@@ -491,6 +575,20 @@ def flash_attention(
         raise ValueError("sinks only apply with a sliding window")
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
+    heads, kv_heads = q.shape[2], k.shape[2]
+    if k.shape[-1] != q.shape[-1] or v.shape[:3] != k.shape[:3] or heads % kv_heads:
+        raise ValueError(
+            f"flash_attention: q {q.shape} / k {k.shape} / v {v.shape}: k takes "
+            "q's head width, v k's rows and heads, and the KV heads divide q's"
+        )
+    forward_only = (
+        true_len is not None or v.shape[-1] != q.shape[-1] or kv_heads != heads
+    )
+    if forward_only and mesh is not None and mesh.size > 1:
+        raise ValueError(
+            "flash_attention: the forward-only kernel (unequal widths, grouped "
+            "KV heads, true_len) runs on one device, not under a mesh"
+        )
     seq_q, seq_k = q.shape[1], k.shape[1]
     if block_q is None:
         block_q = _default_block(seq_q)
@@ -516,9 +614,16 @@ def flash_attention(
             f"flash_attention: q {q.shape} / k {k.shape} causal={causal} "
             f"runs attention_reference, not the Pallas kernel: {rejected}",
         )
+        if kv_heads != heads:
+            k, v = (jnp.repeat(x, heads // kv_heads, axis=2) for x in (k, v))
         return attention_reference(
             q, k, v, causal=causal, sm_scale=sm_scale, window=int(window),
             sinks=int(sinks),
+        )
+    if forward_only:
+        return _flash_forward_only(
+            q, k, v, true_len, causal, sm_scale, block_q, block_k, interpret,
+            int(window), int(sinks),
         )
 
     def kernel(q, k, v):

@@ -891,7 +891,27 @@ class DecodeEngine:
         #: positions the slots' records hold (no fetch, no sync).
         self.attn_totals: Dict[str, int] = {
             "rows_allocated": 0, "rows_visited": 0, "rows_live": 0,
+            "prefill_rows": 0, "prefill_rows_kernel": 0,
+            "prefill_tiles": 0, "prefill_tiles_visited": 0,
         }
+        #: What an admission of mixed layer kinds adds to the four prefill
+        #: counts, by bucket (``_count_prefill``): the attention layers, those
+        #: of them whose read is the causal square (the full and the latent
+        #: kinds; a window kind's is rows x 2W) and those of them the forward
+        #: flash kernel reads — the model's own answer, kind by kind
+        #: (models/mixed.py:prefill_kernel) —, and the rows of a score tile.
+        self._prefill_layers: Dict[int, Tuple[int, int, int, int]] = {}
+        if config.mixed:
+            from ray_lightning_tpu.models.mixed import ATTN_KINDS, _Q_BLOCK, count_kind, prefill_kernel
+            from ray_lightning_tpu.ops.flash_attention import _default_block
+
+            layers = sum(count_kind(config, k) for k in ATTN_KINDS)
+            square = {k: count_kind(config, k) for k in ("full", "latent")}
+            for pb in buckets:
+                kernel = sum(n for k, n in square.items() if prefill_kernel(config, k, pb))
+                self._prefill_layers[pb] = (
+                    layers, sum(square.values()), kernel, min(_default_block(pb) if kernel else _Q_BLOCK, pb),
+                )
         #: By the same kinds, the rows of the decode kernel's block when
         #: the fold's read of that kind is the kernel — the model's own
         #: answer (models/gpt.py:_decode_rows_block: a uniform cache of
@@ -1055,7 +1075,7 @@ class DecodeEngine:
                 )
 
                 h, pf_k, pf_v, moe = mixed_rows(
-                    params, cfg, prompt, true_len=last_idx + 1
+                    params, cfg, prompt, true_len=last_idx + 1, prefill=True
                 )
                 k_cache, v_cache = write_prefill_rows(
                     k_cache, v_cache, pf_k, pf_v, slot, last_idx + 1
@@ -2151,6 +2171,23 @@ class DecodeEngine:
             "prefill": dict(self.moe_totals["prefill"]),
         }
 
+    def _count_prefill(self, bucket: int, prompt_len: int) -> None:
+        """One admission of ``prompt_len`` tokens in ``bucket`` rows into
+        ``attn_totals``: row·layers of attention prefilled and those the
+        forward flash kernel read; score tiles of the padded causal square
+        over the layers that attend it, and those a read visited — all of
+        them on the XLA read, on the kernel those of the query blocks that
+        hold a real row. From the host's own numbers: no device read."""
+        if bucket not in self._prefill_layers:
+            return
+        layers, square, kernel, tile = self._prefill_layers[bucket]
+        n, m = -(-bucket // tile), -(-prompt_len // tile)
+        t = self.attn_totals
+        t["prefill_rows"] += bucket * layers
+        t["prefill_rows_kernel"] += bucket * kernel
+        t["prefill_tiles"] += square * n * (n + 1) // 2
+        t["prefill_tiles_visited"] += (kernel * m * (m + 1) + (square - kernel) * n * (n + 1)) // 2
+
     def attn_stats(self) -> Dict[str, int]:
         """``stats()["attn"]``: cache rows the decode steps' attention
         had allocated, visited and live, summed over token steps and
@@ -2163,7 +2200,10 @@ class DecodeEngine:
         are counted, the full and the latent layers' (read by the same
         kernel on a TPU: models/mixed.py:_attention_part, _latent_part),
         each kind by the read its layers take; ``{}`` without such a
-        layer."""
+        layer. Beside them the admissions of mixed layer kinds:
+        ``prefill_rows`` / ``prefill_rows_kernel`` and ``prefill_tiles`` /
+        ``prefill_tiles_visited`` (:meth:`_count_prefill`; zeros for a
+        uniform configuration, whose prefill is ``gpt_prefill``'s)."""
         return dict(self.attn_totals) if self._attn_layers else {}
 
     def ssm_stats(self) -> Dict[str, Any]:
@@ -2455,6 +2495,7 @@ class DecodeEngine:
                 temp, tk, tp, np.int32(n_new), np.int32(eos),
             )
             pending.append((slot, r, n_new, eos, tok))
+            self._count_prefill(pb, P)
             moe_counts.extend(moe)
             self.spans.device_busy()
             if self.tracer is not None:

@@ -638,6 +638,10 @@ class ServeReplica:
                 ("rows_allocated", "Cache rows allocated to the slots, summed over decode token steps and layers"),
                 ("rows_visited", "Cache rows of those the decode attention read (all of them on the XLA read; the decode kernel's blocks up to each live slot's position)"),
                 ("rows_live", "Cache rows of those that held a position of a live request"),
+                ("prefill_rows", "Rows of the admissions' buckets, summed over the attention layers of mixed layer kinds"),
+                ("prefill_rows_kernel", "Rows of those whose attention the forward flash kernel read (the full and latent kinds on a TPU, from the crossing up)"),
+                ("prefill_tiles", "Score tiles of the admissions' padded causal squares, summed over the full and latent layers"),
+                ("prefill_tiles_visited", "Score tiles of those a read visited (all on the XLA read; under the kernel those of query blocks that hold a prompt's row)"),
             )
         }
         self._moe_mirrored: Dict[Tuple[str, str], int] = {}

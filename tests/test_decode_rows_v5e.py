@@ -267,3 +267,32 @@ def test_flash_kernels_lower_with_bf16_operands(v5e, shape, kw):
     assert len(mosaic) == 3, mosaic
     for kernel in ("flash_fwd", "flash_dkv", "flash_dq"):
         assert sum(kernel in ln.split(" = ")[0] for ln in mosaic) == 1, kernel
+
+
+@pytest.mark.parametrize(
+    "shape",
+    [(6144, 32, 32, 192, 128), (4096, 64, 4, 192, 128), (1024, 20, 4, 128, 128)],
+    ids=["docqa_latent_6144x32x192v128", "mixedlen_full_4096x64on4x192v128", "burstchat_full_1024x20on4x128"],
+)
+def test_the_forward_flash_kernel_lowers_at_the_mixed_layers_prefill_shapes(v5e, shape):
+    """The forward-only kernel (PR 49) with a v of its own width, grouped KV
+    heads and the prefetched count of real rows, at the largest bucket of
+    each cell whose prefill may take it: one Mosaic call, K and V whole in
+    VMEM a head beside the 512 x 512 score tile."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from ray_lightning_tpu.ops.flash_attention import flash_attention
+
+    S, H, Hkv, dqk, dv = shape
+    one = SingleDeviceSharding(v5e)
+
+    def sds(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one)
+
+    text = jax.jit(
+        lambda q, k, v, n: flash_attention(q, k, v, true_len=n, interpret=False)
+    ).lower(sds(1, S, H, dqk), sds(1, S, Hkv, dqk), sds(1, S, Hkv, dv), sds(dtype=jnp.int32)).compile().as_text()
+    mosaic = [ln for ln in text.splitlines() if 'custom_call_target="tpu_custom_call"' in ln]
+    assert len(mosaic) == 1 and "flash_fwd" in mosaic[0].split(" = ")[0], mosaic

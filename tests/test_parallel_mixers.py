@@ -368,6 +368,11 @@ def test_a_mixed_configuration_of_the_benchmark_has_the_trees_and_counters_it_ha
     if stats["ssm"]:
         # the one key since the snapshot (PR 48): off a TPU the XLA pass moves every slot's state
         assert stats["ssm"]["decode"].pop("slot_steps_visited") == stats["ssm"]["decode"]["slot_steps"]
+    # the four keys since the snapshot (PR 49): one admission of a bucket of 8, off a TPU the XLA read's
+    layers = sum(mixed.count_kind(cfg, kind) for kind in mixed.ATTN_KINDS)
+    square = sum(mixed.count_kind(cfg, kind) for kind in ("full", "latent"))
+    prefill = [stats["attn"].pop("prefill_" + key) for key in ("rows", "rows_kernel", "tiles", "tiles_visited")]
+    assert prefill == [8 * layers, 0, square, square]
     assert {k: keys(v) for k, v in stats.items()} == want["stats"]
     assert {"ssm": stats["ssm"], "attn": stats["attn"]} == want["counts"]
 

@@ -85,6 +85,19 @@ def force_decode_kernel(monkeypatch: Any) -> None:
     )
 
 
+def force_prefill_kernel(monkeypatch: Any, rows: int = 0) -> None:
+    """Tell the prefill's selection function (``models/mixed.py:prefill_kernel``)
+    "tpu", and take the kernel from ``rows`` rows up: the forward flash kernel
+    is then the read wherever its other conditions hold, and off the chip it
+    runs interpreted. No option does this."""
+    import functools
+
+    from ray_lightning_tpu.models import mixed
+
+    monkeypatch.setattr(mixed, "prefill_kernel", functools.partial(mixed.prefill_kernel, backend="tpu"))
+    monkeypatch.setattr(mixed, "_KERNEL_ROWS", rows)
+
+
 def mixed_program_hashes(cfg: Any) -> dict:
     """sha256 of the jaxpr texts of a mixed configuration's two serving
     programs, traced at toy sizes (three slots of 32 rows, a fold of two,
