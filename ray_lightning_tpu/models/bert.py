@@ -29,12 +29,8 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 
-from ray_lightning_tpu.models.gpt import (
-    _layernorm,
-    _lm_head,
-    chunked_lm_loss,
-    make_fake_text,
-)
+from ray_lightning_tpu.models.gpt import chunked_lm_loss, make_fake_text
+from ray_lightning_tpu.models.layers import _layernorm, _lm_head
 from ray_lightning_tpu.trainer.data import DataLoader, Dataset
 from ray_lightning_tpu.trainer.module import TPUModule
 

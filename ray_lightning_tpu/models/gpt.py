@@ -33,7 +33,23 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 
-from ray_lightning_tpu.models.mixed import refuse_mixed
+from ray_lightning_tpu.models import layers
+from ray_lightning_tpu.models.layers import (  # noqa: F401
+    _lm_head,
+    _make_norm,
+    _rmsnorm,  # not used here: the benchmark's files import it from this module
+    _rope,
+    _rope_tables,
+)
+from ray_lightning_tpu.models.mixed import (
+    count_kind,
+    init_mixed_params,
+    mixed_decode_step,
+    mixed_logits,
+    mixed_rows,
+    refuse_mixed,
+    validate_mixed,
+)
 from ray_lightning_tpu.trainer.data import ArrayDataset, DataLoader, Dataset
 from ray_lightning_tpu.trainer.module import TPUModule
 from ray_lightning_tpu.utils.quantize import dequant, embed_rows
@@ -243,8 +259,6 @@ class GPTConfig:
                 "'rmsnorm'"
             )
         if self.mixed:
-            from ray_lightning_tpu.models.mixed import validate_mixed
-
             validate_mixed(self)
         elif self.experts_held or self.moe_scoring != "softmax":
             raise ValueError(
@@ -296,8 +310,6 @@ def init_gpt_params(rng: jax.Array, cfg: GPTConfig) -> Dict[str, Any]:
     """Parameter pytree with stacked per-layer leaves (leading dim L)."""
     cfg.validate_variants()
     if cfg.mixed:
-        from ray_lightning_tpu.models.mixed import init_mixed_params
-
         return init_mixed_params(rng, cfg)
     L, D, H, hd, F = (
         cfg.n_layer,
@@ -600,48 +612,6 @@ def _mesh_is_one_device(mesh: Any) -> bool:
     return mesh is None or mesh.size == 1
 
 
-def _lm_head(h: jax.Array, wte: jax.Array) -> jax.Array:
-    """Tied LM head: ``(..., D) x (V, D) -> (..., V)`` logits.
-
-    Operands stay in the hidden states' compute dtype — TPU matmul units
-    consume bf16 anyway, and fp32 operands only double the HBM read
-    traffic on the V-by-D table (which also bounds per-token decode) —
-    while ``preferred_element_type`` keeps accumulation/logits in fp32.
-    The single definition keeps the dense, chunked, and decode heads on
-    one precision scheme (their grad/value equality is asserted in
-    tests/test_gpt.py).
-    """
-    return jnp.einsum(
-        "...d,vd->...v",
-        h,
-        dequant(wte, h.dtype),
-        preferred_element_type=jnp.float32,
-    )
-
-
-def _layernorm(
-    x: jax.Array, g: jax.Array, b: jax.Array, eps: float = 1e-5
-) -> jax.Array:
-    x32 = x.astype(jnp.float32)
-    mu = x32.mean(-1, keepdims=True)
-    var = x32.var(-1, keepdims=True)
-    return ((x32 - mu) * jax.lax.rsqrt(var + eps) * g + b).astype(x.dtype)
-
-
-def _rmsnorm(x: jax.Array, g: jax.Array, eps: float = 1e-5) -> jax.Array:
-    x32 = x.astype(jnp.float32)
-    ms = jnp.mean(x32 * x32, -1, keepdims=True)
-    return (x32 * jax.lax.rsqrt(ms + eps) * g).astype(x.dtype)
-
-
-def _make_norm(cfg: GPTConfig):
-    """The block-norm function for the config: ``fn(x, g, b)``. RMSNorm
-    ignores the bias leaf (kept in the tree so the layout is uniform)."""
-    if cfg.norm_impl == "rmsnorm":
-        return lambda x, g, b: _rmsnorm(x, g, cfg.norm_eps)
-    return lambda x, g, b: _layernorm(x, g, b, cfg.norm_eps)
-
-
 #: The projections an engine holds flat: (L, D, *out) -> (L, D, prod(out)).
 _ENGINE_FLAT = ("wq", "wkv")
 
@@ -744,36 +714,28 @@ def _head_weight(params: Dict[str, Any], cfg: GPTConfig) -> jax.Array:
     return params["wte"] if cfg.tie_word_embeddings else params["lm_head"]
 
 
-def _rope_tables(
-    pos: jax.Array, theta: float, head_dim: int
-) -> Tuple[jax.Array, jax.Array]:
-    """cos/sin tables (S, hd/2) for explicit positions (S,) (any leading
-    shape of positions gives tables of that shape + (hd/2,)).
-
-    Positions are passed (not implied by index) so permuted layouts —
-    zigzag sequence parallelism — rotate by the TRUE token position.
-    Computed ONCE per forward and closed over by the layer scan: the trig
-    is position-only, recomputing it per layer (and again under remat)
-    would be pure waste at long context.
-    """
-    half = head_dim // 2
-    freqs = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
-    ang = pos.astype(jnp.float32)[..., None] * freqs  # (S, half)
-    return jnp.cos(ang), jnp.sin(ang)
-
-
-def _rope(x: jax.Array, tables: Tuple[jax.Array, jax.Array]) -> jax.Array:
-    """Apply the half-split (NeoX-style) rotation to (B, S, H, hd) — two
-    multiplies and two adds, fused by XLA; fp32 compute, x.dtype out."""
-    cos, sin = tables
-    cos = cos[None, :, None, :]
-    sin = sin[None, :, None, :]
-    half = x.shape[-1] // 2
-    x32 = x.astype(jnp.float32)
-    x1, x2 = x32[..., :half], x32[..., half:]
-    return jnp.concatenate(
-        [x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1
-    ).astype(x.dtype)
+def _embed(
+    params: Dict[str, Any],
+    cfg: GPTConfig,
+    toks: jax.Array,
+    positions: jax.Array,
+    wpe_at: Any,
+) -> Tuple[jax.Array, Optional[Tuple[jax.Array, jax.Array]]]:
+    """Tokens into the layers' input, in the compute dtype, and the rotary
+    tables of their ``positions`` (None without rotary positions: the one
+    place a serving mode makes them, once for all its layers). With learned
+    positions the rows ``wpe[wpe_at]`` are added: how a mode reads the table
+    is its own — a slice from the start, a row a slot, a gather clipped
+    where padded rows run past the end."""
+    x = embed_rows(params["wte"], toks)
+    if cfg.pos_embed == "learned":
+        x = x + params["wpe"][wpe_at]
+    rope_tables = (
+        _rope_tables(positions, cfg.rope_theta, cfg.head_dim)
+        if cfg.pos_embed == "rope"
+        else None
+    )
+    return x.astype(jnp.dtype(cfg.compute_dtype)), rope_tables
 
 
 def _project_gqa(
@@ -809,41 +771,90 @@ def _project_qkv(
     a: jax.Array,
     lp: Dict[str, jax.Array],
     cfg: GPTConfig,
-    cdt: Any,
     rope_tables: Optional[Tuple[jax.Array, jax.Array]] = None,
-    repeat_kv: bool = True,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """(B, S, D) -> q (B, S, H, hd) and k/v (B, S, H or Hkv, hd).
+    """The first part of a uniform layer, the same in every mode: normed
+    rows ``a`` (..., D) of any leading shape, projected -> q (..., H, hd)
+    and k, v (..., Hkv, hd), q and k rotated where the configuration has
+    rotary positions (``rope_tables``: those rows' :func:`_rope_tables`;
+    None with learned positions).
 
-    Fused MHA projection, or separate q / grouped-kv projections (GQA) with
-    kv heads repeated up to H — compute matches MHA, while params and the
-    decode cache stay Hkv-sized. RoPE (when configured) rotates q/k here,
-    BEFORE the kv repeat, so the rotation runs at Hkv width.
-    ``repeat_kv=False`` returns k/v at their native Hkv width (what the
-    decode cache stores — the prefill path repeats locally for attention
-    but caches the grouped heads).
-
-    The GQA leaves come stored or in an engine's flat form
-    (:func:`_project_gqa`, which the decode step and the verify share);
-    the fused ``wqkv`` has one form.
+    Fused MHA projection, or separate q / grouped-kv projections (GQA:
+    :func:`_project_gqa`, stored or in an engine's flat form; the fused
+    ``wqkv`` has one form). K and V come out at their native Hkv width,
+    what a cache stores, and the rotation runs at that width; a read that
+    wants a KV head a query head repeats them itself (:func:`_repeat_kv`).
     """
+    cdt = jnp.dtype(cfg.compute_dtype)
     if cfg.kv_head == cfg.n_head:
         qkv = (
-            jnp.einsum("bsd,dthk->bsthk", a, dequant(lp["wqkv"], cdt))
+            jnp.einsum("...d,dthk->...thk", a, dequant(lp["wqkv"], cdt))
             + lp["bqkv"].astype(cdt)
         )
-        q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+        q, k, v = qkv[..., 0, :, :], qkv[..., 1, :, :], qkv[..., 2, :, :]
     else:
         q, kv = _project_gqa(a, lp, cfg, cdt)
-        k, v = kv[:, :, 0], kv[:, :, 1]
+        k, v = kv[..., 0, :, :], kv[..., 1, :, :]
     if rope_tables is not None:
         q = _rope(q, rope_tables)
         k = _rope(k, rope_tables)
-    if repeat_kv and cfg.kv_head != cfg.n_head:
-        rep = cfg.n_head // cfg.kv_head
-        k = jnp.repeat(k, rep, axis=2)
-        v = jnp.repeat(v, rep, axis=2)
     return q, k, v
+
+
+def _repeat_kv(x: jax.Array, cfg: GPTConfig) -> jax.Array:
+    """K or V rows (..., Hkv, hd) with every KV head repeated for the query
+    heads of its group, (..., H, hd): compute then matches MHA, while the
+    parameters and the decode cache stay Hkv-sized."""
+    rep = cfg.n_head // cfg.kv_head
+    return x if rep == 1 else jnp.repeat(x, rep, axis=-2)
+
+
+def _attn_out(
+    h: jax.Array, o: jax.Array, lp: Dict[str, jax.Array], cfg: GPTConfig
+) -> jax.Array:
+    """The second part: the heads' outputs ``o`` (..., H, hd) through the
+    output projection, added with its bias to the residual ``h`` (..., D)."""
+    cdt = jnp.dtype(cfg.compute_dtype)
+    return h + jnp.einsum(
+        "...hk,hkd->...d", o, dequant(lp["wo"], cdt)
+    ) + lp["bo"].astype(cdt)
+
+
+def _ffn(m: jax.Array, lp: Dict[str, jax.Array], cfg: GPTConfig) -> jax.Array:
+    """The third part, of every mode that serves: the feed-forward of normed
+    rows ``m`` (..., D) — the dense MLP or, with ``n_experts``, the routed
+    experts with capacity for every token (inference never drops: see
+    :func:`gpt_generate`; all rows are one pool of tokens to the router,
+    whatever their leading shape). Training's feed-forward is
+    :func:`gpt_forward`'s own: capacity from the config, the aux loss."""
+    cdt = jnp.dtype(cfg.compute_dtype)
+    if cfg.n_experts == 0:
+        return _dense_mlp(m, lp, cfg, cdt)
+    from ray_lightning_tpu.parallel.moe import moe_ffn
+
+    m_out, _ = moe_ffn(
+        _moe_layer_params(lp),
+        m.reshape((1, -1, m.shape[-1])),
+        capacity_factor=float(cfg.n_experts),  # never drop
+        compute_dtype=cdt,
+        top_k=cfg.moe_top_k,
+    )
+    return m_out.reshape(m.shape)
+
+
+def gpt_final_norm(params: Dict[str, Any], cfg: GPTConfig, h: jax.Array) -> jax.Array:
+    """Hidden states ``h`` (..., D) as the last layer leaves them, through
+    the model's final norm (a bias-free tree has no ``lnf_b``)."""
+    return _make_norm(cfg)(h, params["lnf_g"], params.get("lnf_b"))
+
+
+def gpt_logits(params: Dict[str, Any], cfg: GPTConfig, h: jax.Array) -> jax.Array:
+    """Float32 logits (..., V) of final-normed hidden states ``h`` (..., D):
+    the head's — the tied embedding or ``lm_head``, a configuration of mixed
+    layer kinds' with its multiplier (models/mixed.py:mixed_logits)."""
+    if cfg.mixed:
+        return mixed_logits(h, params, cfg)
+    return _lm_head(h, _head_weight(params, cfg))
 
 
 def gpt_forward(
@@ -873,13 +884,10 @@ def gpt_forward(
     cfg.validate_variants()
     if cfg.mixed:
         # Layers of more than one kind: the block of models/mixed.py.
-        from ray_lightning_tpu.models.mixed import mixed_logits, mixed_rows
-
         if not _mesh_is_one_device(mesh):
             refuse_mixed(cfg, "a forward pass over a mesh of more than one device")
-        x = mixed_rows(params, cfg, tokens)[0]
-        x = _rmsnorm(x, params["lnf_g"], cfg.norm_eps)
-        out = x if return_hidden else mixed_logits(x, params, cfg)
+        x = gpt_final_norm(params, cfg, mixed_rows(params, cfg, tokens)[0])
+        out = x if return_hidden else gpt_logits(params, cfg, x)
         return (out, jnp.zeros((), jnp.float32)) if return_aux else out
     cdt = jnp.dtype(cfg.compute_dtype)
     norm_fn = _make_norm(cfg)
@@ -919,17 +927,17 @@ def gpt_forward(
         )
 
     def _seq_sharded(h):
-        # Pin (B, S, D) activations to batch x seq sharding after layout
-        # permutes — the gathers would otherwise leave them replicated,
-        # materializing full-sequence activations on every seq rank.
+        # Pin (B, S) indices or (B, S, D) activations to batch x seq
+        # sharding after layout permutes — the gathers would otherwise leave
+        # them replicated, materializing full-sequence activations on every
+        # seq rank.
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         batch_axes = tuple(
             ax for ax in ("data", "fsdp") if mesh.shape.get(ax, 1) > 1
         )
-        return jax.lax.with_sharding_constraint(
-            h, NamedSharding(mesh, P(batch_axes or None, seq_axis, None))
-        )
+        spec = (batch_axes or None, seq_axis) + (None,) * (h.ndim - 2)
+        return jax.lax.with_sharding_constraint(h, NamedSharding(mesh, P(*spec)))
 
     if use_zigzag:
         from ray_lightning_tpu.ops.zigzag_attention import (
@@ -942,56 +950,34 @@ def gpt_forward(
         zz_inv = jnp.asarray(inverse_permutation(zz_perm_np))
         from jax.sharding import NamedSharding, PartitionSpec as P
 
-        batch_axes = tuple(
-            ax for ax in ("data", "fsdp") if mesh.shape.get(ax, 1) > 1
-        )
         # Pin the PERMUTED INDICES to batch x seq sharding so the embedding
         # gather lands already sharded the way the blocks want it; letting
         # the partitioner pick a sharding for the gather output and then
         # reshard triggers "involuntary full rematerialization" (the gather
         # result gets replicated on every seq rank first).
-        toks_z = jax.lax.with_sharding_constraint(
-            tokens[:, zz_perm],
-            NamedSharding(mesh, P(batch_axes or None, seq_axis)),
-        )
+        toks_z = _seq_sharded(tokens[:, zz_perm])
         # Explicitly all-gather the (vocab/embed-sharded) table before the
         # lookup: a gather FROM a sharded table into a seq-sharded output
         # has no efficient SPMD lowering (the partitioner falls back to
         # "involuntary full rematerialization"); from a replicated table
         # it's a clean shard-local gather. The all-gather happens either
         # way — this just routes it through the cheap path.
-        # Replicate the table at its STORED width (int8 when quantized —
-        # dequantizing first would 4x the gather/replication bytes), then
-        # dequantize only the gathered rows.
-        from ray_lightning_tpu.utils.quantize import is_quantized
-
-        wte_node = params["wte"]
-        if is_quantized(wte_node):
-            rep = NamedSharding(mesh, P(None, None))
-            wte_rep = {
-                "q": jax.lax.with_sharding_constraint(wte_node["q"], rep),
-                "s": jax.lax.with_sharding_constraint(wte_node["s"], rep),
-            }
-        else:
-            wte_rep = jax.lax.with_sharding_constraint(
-                wte_node, NamedSharding(mesh, P(None, None))
-            )
-        x = embed_rows(wte_rep, toks_z)
-        if cfg.pos_embed == "learned":
-            x = x + params["wpe"][zz_perm]
+        # Replicate the table at its STORED width (int8 when quantized, a
+        # node of values and scales — dequantizing first would 4x the
+        # gather/replication bytes), then dequantize only the gathered rows.
+        wte_rep = jax.tree_util.tree_map(
+            lambda a: jax.lax.with_sharding_constraint(
+                a, NamedSharding(mesh, P(None, None))
+            ),
+            params["wte"],
+        )
+        # zz_perm: the true token positions in the permuted layout
+        x, rope_tables = _embed(
+            {**params, "wte": wte_rep}, cfg, toks_z, zz_perm, zz_perm
+        )
         x = _seq_sharded(x)
-        positions = zz_perm  # true token positions in the permuted layout
     else:
-        x = embed_rows(params["wte"], tokens)
-        if cfg.pos_embed == "learned":
-            x = x + params["wpe"][:S]
-        positions = jnp.arange(S)
-    x = x.astype(cdt)
-    rope_tables = (
-        _rope_tables(positions, cfg.rope_theta, cfg.head_dim)
-        if cfg.pos_embed == "rope"
-        else None
-    )
+        x, rope_tables = _embed(params, cfg, tokens, jnp.arange(S), slice(S))
 
     def attend(q, k, v):
         if cfg.attn_window and use_ring:
@@ -1063,24 +1049,18 @@ def gpt_forward(
         if cfg.n_experts > 0:
             from ray_lightning_tpu.parallel.moe import moe_ffn, moe_ffn_ep
 
-            if use_a2a:
-                out, aux = moe_ffn_ep(
-                    _moe_layer_params(lp),
-                    m,
-                    mesh,
-                    ep_axis="ep",
-                    capacity_factor=cfg.moe_capacity_factor,
-                    compute_dtype=cdt,
-                    top_k=cfg.moe_top_k,
-                )
-                return out, aux["aux_loss"]
-            out, aux = moe_ffn(
-                _moe_layer_params(lp),
-                m,
+            # training's own: capacity from the config, and the aux loss
+            kw = dict(
                 capacity_factor=cfg.moe_capacity_factor,
                 compute_dtype=cdt,
                 top_k=cfg.moe_top_k,
             )
+            if use_a2a:
+                out, aux = moe_ffn_ep(
+                    _moe_layer_params(lp), m, mesh, ep_axis="ep", **kw
+                )
+            else:
+                out, aux = moe_ffn(_moe_layer_params(lp), m, **kw)
             return out, aux["aux_loss"]
         return _dense_mlp(m, lp, cfg, cdt), jnp.zeros((), jnp.float32)
 
@@ -1089,57 +1069,40 @@ def gpt_forward(
     ) -> Tuple[Tuple[jax.Array, jax.Array], None]:
         h, aux_acc = carry
         a = norm_fn(h, lp["ln1_g"], lp["ln1_b"])
-        q, k, v = _project_qkv(a, lp, cfg, cdt, rope_tables)  # (B,S,H,hd)
-        o = attend(q, k, v)
-        h = h + jnp.einsum("bshk,hkd->bsd", o, dequant(lp["wo"], cdt)) + lp[
-            "bo"
-        ].astype(cdt)
+        q, k, v = _project_qkv(a, lp, cfg, rope_tables)
+        # every read here wants a KV head a query head: (B, S, H, hd)
+        o = attend(q, _repeat_kv(k, cfg), _repeat_kv(v, cfg))
+        h = _attn_out(h, o, lp, cfg)
         m_out, aux = mlp(h, lp)
         return (h + m_out, aux_acc + aux), None
 
     if pp_size > 1:
         from ray_lightning_tpu.parallel.pipeline import pipeline_apply
 
-        if cfg.n_experts > 0:
-            # MoE composes with the pipeline: the pp shard_map is manual
-            # over "pp" only, so the expert routing stays a GSPMD concern
-            # inside each stage — moe_ffn's ep-sharded weights route
-            # tokens across the "ep" axis exactly as in the unpipelined
-            # path. (The explicit a2a dispatch nests and runs FORWARD
-            # here, but its backward trips the Shardy partitioner; see
-            # a2a_applicable.) The per-layer load-balancing aux rides
-            # pipeline_apply's aux channel (mean over microbatches; see
-            # its docstring for the batch-statistics contract).
-            def stage_aux(
-                lp: Dict[str, jax.Array], h: jax.Array
-            ) -> Tuple[jax.Array, jax.Array]:
-                (h2, a), _ = block((h, jnp.zeros((), jnp.float32)), lp)
-                return h2, a
+        # MoE composes with the pipeline: the pp shard_map is manual over
+        # "pp" only, so the expert routing stays a GSPMD concern inside each
+        # stage — moe_ffn's ep-sharded weights route tokens across the "ep"
+        # axis exactly as in the unpipelined path. (The explicit a2a
+        # dispatch nests and runs FORWARD here, but its backward trips the
+        # Shardy partitioner; see a2a_applicable.) The per-layer
+        # load-balancing aux rides pipeline_apply's aux channel (mean over
+        # microbatches; see its docstring for the batch-statistics
+        # contract); a dense model's stage hands back its rows alone.
+        with_aux = cfg.n_experts > 0
 
-            body = jax.checkpoint(stage_aux) if cfg.remat else stage_aux
-            x, aux_total = pipeline_apply(
-                body,
-                params["blocks"],
-                x,
-                mesh,
-                num_microbatches=cfg.num_microbatches or None,
-                with_aux=True,
-            )
-        else:
+        def stage(lp: Dict[str, jax.Array], h: jax.Array) -> Any:
+            (h2, a), _ = block((h, jnp.zeros((), jnp.float32)), lp)
+            return (h2, a) if with_aux else h2
 
-            def stage(lp: Dict[str, jax.Array], h: jax.Array) -> jax.Array:
-                (h2, _), _ = block((h, jnp.zeros((), jnp.float32)), lp)
-                return h2
-
-            stage_body = jax.checkpoint(stage) if cfg.remat else stage
-            x = pipeline_apply(
-                stage_body,
-                params["blocks"],
-                x,
-                mesh,
-                num_microbatches=cfg.num_microbatches or None,
-            )
-            aux_total = jnp.zeros((), jnp.float32)
+        out = pipeline_apply(
+            jax.checkpoint(stage) if cfg.remat else stage,
+            params["blocks"],
+            x,
+            mesh,
+            num_microbatches=cfg.num_microbatches or None,
+            with_aux=with_aux,
+        )
+        x, aux_total = out if with_aux else (out, jnp.zeros((), jnp.float32))
     else:
         body = jax.checkpoint(block) if cfg.remat else block
         (x, aux_total), _ = jax.lax.scan(
@@ -1328,16 +1291,11 @@ def gpt_prefill(
     """
     cfg.validate_variants()
     if cfg.mixed:
-        from ray_lightning_tpu.models.mixed import mixed_rows
-
         if not _mesh_is_one_device(mesh):
             refuse_mixed(cfg, "prefill over a serve mesh of more than one device")
         return mixed_rows(params, cfg, prompt, prefill=True)[:3]
     cdt = jnp.dtype(cfg.compute_dtype)
     norm_fn = _make_norm(cfg)
-    H, hd = cfg.n_head, cfg.head_dim
-    Hkv = cfg.kv_head
-    rep = H // Hkv
     _, P = prompt.shape
     import functools
 
@@ -1348,47 +1306,20 @@ def gpt_prefill(
         if cfg.attn_impl == "flash"
         else attention_reference
     )
-    pf_tables = (
-        _rope_tables(jnp.arange(P), cfg.rope_theta, hd)
-        if cfg.pos_embed == "rope"
-        else None
-    )
-    x0 = embed_rows(params["wte"], prompt)
-    if cfg.pos_embed == "learned":
-        x0 = x0 + params["wpe"][:P]
-    x0 = x0.astype(cdt)
+    x0, pf_tables = _embed(params, cfg, prompt, jnp.arange(P), slice(P))
 
     def prefill_block(h, lp):
         a = norm_fn(h, lp["ln1_g"], lp["ln1_b"])
-        q, k_kv, v_kv = _project_qkv(
-            a, lp, cfg, cdt, pf_tables, repeat_kv=False
-        )
-        if Hkv != H:
-            k_att = jnp.repeat(k_kv, rep, axis=2)
-            v_att = jnp.repeat(v_kv, rep, axis=2)
-        else:
-            k_att, v_att = k_kv, v_kv
+        q, k_kv, v_kv = _project_qkv(a, lp, cfg, pf_tables)
+        # its own: the rows attend among themselves, and leave K/V as rows
+        # of the cache, at their Hkv width
         o = attn_fn(
-            q, k_att, v_att, causal=True, window=cfg.attn_window,
-            sinks=cfg.attn_sinks,
+            q, _repeat_kv(k_kv, cfg), _repeat_kv(v_kv, cfg), causal=True,
+            window=cfg.attn_window, sinks=cfg.attn_sinks,
         )
-        h = h + jnp.einsum("bshk,hkd->bsd", o, dequant(lp["wo"], cdt)) + lp[
-            "bo"
-        ].astype(cdt)
-        m = norm_fn(h, lp["ln2_g"], lp["ln2_b"])
-        if cfg.n_experts > 0:
-            from ray_lightning_tpu.parallel.moe import moe_ffn
-
-            m_out, _ = moe_ffn(
-                _moe_layer_params(lp),
-                m,
-                capacity_factor=float(cfg.n_experts),  # never drop
-                compute_dtype=cdt,
-                top_k=cfg.moe_top_k,
-            )
-        else:
-            m_out = _dense_mlp(m, lp, cfg, cdt)
-        return h + m_out, (k_kv.astype(cdt), v_kv.astype(cdt))
+        h = _attn_out(h, o, lp, cfg)
+        h = h + _ffn(norm_fn(h, lp["ln2_g"], lp["ln2_b"]), lp, cfg)
+        return h, (k_kv.astype(cdt), v_kv.astype(cdt))
 
     h_pf, (pf_k, pf_v) = jax.lax.scan(prefill_block, x0, params["blocks"])
     return h_pf, pf_k, pf_v
@@ -1437,27 +1368,19 @@ def gpt_prefill_chunk(
     refuse_mixed(cfg, "chunked prefill (gpt_prefill_chunk)")
     cdt = jnp.dtype(cfg.compute_dtype)
     norm_fn = _make_norm(cfg)
-    L, H, hd = cfg.n_layer, cfg.n_head, cfg.head_dim
-    Hkv = cfg.kv_head
-    rep = H // Hkv
+    L, hd = cfg.n_layer, cfg.head_dim
     _, C = chunk.shape
     S = k_cache.shape[2]
     start = jnp.asarray(start_pos, jnp.int32)
     tl = jnp.asarray(C if true_len is None else true_len, jnp.int32)
     positions = start + jnp.arange(C, dtype=jnp.int32)
 
-    x = embed_rows(params["wte"], chunk)
-    if cfg.pos_embed == "learned":
-        # Per-row gather (not a dynamic slice): a slice whose window runs
-        # past the table end would CLAMP its start and hand real rows the
-        # wrong positional embeddings; clipping only the (garbage) padded
-        # rows' indices keeps every real row exact.
-        x = x + params["wpe"][jnp.clip(positions, 0, cfg.max_seq - 1)]
-    x = x.astype(cdt)
-    rope_tables = (
-        _rope_tables(positions, cfg.rope_theta, hd)
-        if cfg.pos_embed == "rope"
-        else None
+    # Learned positions by a per-row gather (not a dynamic slice): a slice
+    # whose window runs past the table end would CLAMP its start and hand
+    # real rows the wrong positional embeddings; clipping only the (garbage)
+    # padded rows' indices keeps every real row exact.
+    x, rope_tables = _embed(
+        params, cfg, chunk, positions, jnp.clip(positions, 0, cfg.max_seq - 1)
     )
 
     rows = jnp.arange(S, dtype=jnp.int32)
@@ -1477,9 +1400,7 @@ def gpt_prefill_chunk(
     for li in range(L):
         lp = jax.tree_util.tree_map(lambda a: a[li], params["blocks"])
         a = norm_fn(h, lp["ln1_g"], lp["ln1_b"])
-        q, k_new, v_new = _project_qkv(
-            a, lp, cfg, cdt, rope_tables, repeat_kv=False
-        )
+        q, k_new, v_new = _project_qkv(a, lp, cfg, rope_tables)
         kc, vc = k_cache[li], v_cache[li]  # (1, S, Hkv, hd)
         # Masked row-gather write: only rows [start, start+true_len) take
         # chunk values — padded chunk rows are never written (a block
@@ -1488,11 +1409,7 @@ def gpt_prefill_chunk(
         wmask = valid[None, :, None, None]
         kc = jnp.where(wmask, k_new.astype(cdt)[:, gidx], kc)
         vc = jnp.where(wmask, v_new.astype(cdt)[:, gidx], vc)
-        if Hkv != H:
-            k_att = jnp.repeat(kc, rep, axis=2)
-            v_att = jnp.repeat(vc, rep, axis=2)
-        else:
-            k_att, v_att = kc, vc
+        k_att, v_att = _repeat_kv(kc, cfg), _repeat_kv(vc, cfg)
         # attention_reference's exact op order against the S-wide cache.
         s = (
             jnp.einsum(
@@ -1508,50 +1425,11 @@ def gpt_prefill_chunk(
         o = jnp.einsum(
             "bhqk,bkhd->bqhd", p.astype(v_att.dtype), v_att
         ).astype(q.dtype)
-        h = h + jnp.einsum("bshk,hkd->bsd", o, dequant(lp["wo"], cdt)) + lp[
-            "bo"
-        ].astype(cdt)
-        m = norm_fn(h, lp["ln2_g"], lp["ln2_b"])
-        if cfg.n_experts > 0:
-            from ray_lightning_tpu.parallel.moe import moe_ffn
-
-            m_out, _ = moe_ffn(
-                _moe_layer_params(lp),
-                m,
-                capacity_factor=float(cfg.n_experts),  # never drop
-                compute_dtype=cdt,
-                top_k=cfg.moe_top_k,
-            )
-        else:
-            m_out = _dense_mlp(m, lp, cfg, cdt)
-        h = h + m_out
+        h = _attn_out(h, o, lp, cfg)
+        h = h + _ffn(norm_fn(h, lp["ln2_g"], lp["ln2_b"]), lp, cfg)
         new_k.append(kc)
         new_v.append(vc)
     return h, jnp.stack(new_k), jnp.stack(new_v)
-
-
-def _write_cache_rows(
-    cache: jax.Array, li: int, new: jax.Array, pos: jax.Array
-) -> jax.Array:
-    """Write layer ``li``'s new rows ``new`` into the stacked cache at
-    ``[li, b, pos[b]]`` and return the cache: ``new`` (B, Hkv, hd) into a
-    cache of (L, B, S, Hkv, hd), or (B, Hkv * hd) into one of
-    (L, B, S, Hkv * hd). B rows move, everything else stays where it
-    lies, so a caller that donates the cache (or carries it through a
-    scan) has it updated in place.
-
-    A position past the end lands on the last row, ``S - 1``, as a
-    ``dynamic_update_slice`` clamps its start; the scatter used here would
-    drop such a row, so the clamp is explicit. Frozen slots and
-    :func:`gpt_decode_step_paged` rely on it.
-    """
-    B, S = new.shape[0], cache.shape[2]
-    return cache.at[li, jnp.arange(B), jnp.clip(pos, 0, S - 1)].set(
-        new,
-        indices_are_sorted=True,
-        unique_indices=True,
-        mode="promise_in_bounds",
-    )
 
 
 def cache_strip(
@@ -1591,56 +1469,25 @@ def _kv_head_of_group(n_kv_head: int) -> jax.Array:
     return jnp.eye(n_kv_head, dtype=jnp.float32)
 
 
-def _decode_rows_block(
-    cfg: GPTConfig,
-    q_len: int,
-    k_cache: Any,
-    v_cache: Any,
-    kind: Optional[str] = None,
-    backend: Optional[str] = None,
-) -> int:
-    """Which read a cached attention takes, from what it can observe: the
-    rows of a block of the decode kernel (``ops/decode_attention.py``), or
-    0 for the XLA read. The kernel wants a stacked cache of rows
-    ``(L, B, S, Hkv * hd)``, one query row a slot, ``attn_impl="flash"``,
-    a TPU (elsewhere it would run interpreted: the engine's token-identity
-    tests compare two XLA reads in one order of sums) and shapes Mosaic
-    takes (``decode_block``: row widths a multiple of 128, a block that
-    divides S).
-
-    The caches being the dicts by kind of mixed layers (models/mixed.py),
-    ``kind`` names the one asked about, and the answer is that kind's:
-
-    - ``"latent"``: the pair of latents ``(L, B, S, rank)`` and rotary
-      keys ``(L, B, S, rope)`` (``latent_decode_attention``);
-    - ``"full"``: K rows ``(L, B, S, Hkv * qk)`` and V rows ``(L, B, S,
-      Hkv * v)``, row ``r`` position ``r`` (``decode_attention``; the two
-      widths may differ) — unless a learnable sink logit joins that kind's
-      softmax (``cfg.attn_sink_logit``), which the kernel's sums do not
-      know;
-    - ``"window"``: 0. Its rows are a ring (row ``pos mod R``), not
-      positions ``0 .. pos``, and ``R`` rows a slot are all there is to
-      read (models/mixed.py:_attend_cache);
-    - a kind the model has no layer of, or one with no rows: 0.
-
-    ``serve/engine.py`` asks the same question, kind by kind, for its
-    ``stats()["attn"]`` counters."""
-    from ray_lightning_tpu.ops.decode_attention import decode_block
-
-    if isinstance(k_cache, dict):
-        if kind not in k_cache or kind not in ("full", "latent") or kind in cfg.attn_sink_logit:
-            return 0
-        k_cache, v_cache = k_cache[kind], v_cache[kind]
-    if (
-        k_cache.ndim != 4
-        or q_len != 1
-        or cfg.attn_impl != "flash"
-        or (backend or jax.default_backend()) != "tpu"
-    ):
-        return 0
-    return decode_block(
-        k_cache.shape[2], k_cache.shape[3], v_cache.shape[3], latent=kind == "latent"
-    )
+def decode_reads(
+    cfg: GPTConfig, q_len: int, k_cache: Any, v_cache: Any
+) -> Dict[Optional[str], Tuple[int, int]]:
+    """What a decode step of ``q_len`` query rows a slot reads on these
+    slot caches, for the engine's counters: by the kind of cache whose rows
+    are a request's positions — None, a uniform configuration's one; of
+    mixed layer kinds "full" and "latent", where the model has such layers
+    (a window kind's ring is read whole and not counted) — ``(layers, rows
+    of the decode kernel's block)``, the rows 0 where the layers' read is
+    XLA's over every allocated row (models/layers.py:decode_rows_block,
+    the answer the layers themselves act on). ``k_cache`` None: there is
+    no slot cache (a paged pool, gathered into a view a step), XLA's read."""
+    kinds: Dict[Optional[str], int] = {None: cfg.n_layer}
+    if cfg.mixed:
+        kinds = {k: n for k in ("full", "latent") if (n := count_kind(cfg, k))}
+    return {
+        kind: (n, 0 if k_cache is None else layers.decode_rows_block(cfg, q_len, k_cache, v_cache, kind))
+        for kind, n in kinds.items()
+    }
 
 
 def _attend_layer_cache(
@@ -1661,7 +1508,7 @@ def _attend_layer_cache(
     (Q = 1) and :func:`gpt_decode_verify` both call it.
 
     The cache's rank says which layout it reads, and
-    :func:`_decode_rows_block` which read.
+    ``models/layers.py:decode_rows_block`` which read.
 
     - ``(L, B, S, Hkv, hd)``: grouped attention. The q heads fold to
       (Hkv, rep) groups (head h reads KV head h // rep, matching
@@ -1693,7 +1540,7 @@ def _attend_layer_cache(
     from ray_lightning_tpu.ops.attention import band_allowed
 
     B, Q, H, hd = q.shape
-    block = _decode_rows_block(cfg, Q, k_cache, v_cache)
+    block = layers.decode_rows_block(cfg, Q, k_cache, v_cache)
     if block:
         from ray_lightning_tpu.ops.decode_attention import decode_attention
 
@@ -1772,7 +1619,7 @@ def gpt_decode_step(
     and the step's own write refreshes each position before any read.
 
     The caches come in and go out as the two stacked arrays. Each layer
-    writes its B new rows straight into them (:func:`_write_cache_rows`:
+    writes its B new rows straight into them (``models/layers.py:_write_cache_rows``:
     ``[li, b, pos[b]]``, a position past the end clamped to the last row)
     and attends against ``cache[li]`` after its own write; nothing
     rebuilds the arrays, so a caller that donates them or carries them
@@ -1793,76 +1640,18 @@ def gpt_decode_step(
     """
     cfg.validate_variants()
     if cfg.mixed:
-        from ray_lightning_tpu.models.mixed import mixed_decode_step
-
         return mixed_decode_step(
             params, cfg, cur, pos, k_cache, v_cache, active=active
         )[:3]
     cdt = jnp.dtype(cfg.compute_dtype)
     norm_fn = _make_norm(cfg)
-    L, H, hd = cfg.n_layer, cfg.n_head, cfg.head_dim
-    Hkv = cfg.kv_head
     B = cur.shape[0]
 
-    x = embed_rows(params["wte"], cur)
-    if cfg.pos_embed == "learned":
-        x = x + params["wpe"][pos]
-    x = x.astype(cdt)  # (B, D)
-    rope_tables = (
-        _rope_tables(pos, cfg.rope_theta, hd)
-        if cfg.pos_embed == "rope"
-        else None
-    )  # (B, half) each: one angle per slot, shared by all layers
-
-    def _rope_slot(y: jax.Array) -> jax.Array:
-        # Per-slot rotation on (B, H*, hd): same half-split math as _rope,
-        # with the table's leading axis aligned to batch instead of seq.
-        cos, sin = rope_tables
-        c = cos[:, None, :]
-        s = sin[:, None, :]
-        half = y.shape[-1] // 2
-        y32 = y.astype(jnp.float32)
-        y1, y2 = y32[..., :half], y32[..., half:]
-        return jnp.concatenate(
-            [y1 * c - y2 * s, y1 * s + y2 * c], axis=-1
-        ).astype(y.dtype)
-
-    def qkv_rope(h, lp):
-        a = norm_fn(h[:, None], lp["ln1_g"], lp["ln1_b"])[:, 0]
-        if Hkv == H:
-            qkv = (
-                jnp.einsum("bd,dthk->bthk", a, dequant(lp["wqkv"], cdt))
-                + lp["bqkv"].astype(cdt)
-            )
-            q, k_new, v_new = qkv[:, 0], qkv[:, 1], qkv[:, 2]  # (B,H,hd)
-        else:
-            q, kv = _project_gqa(a, lp, cfg, cdt)
-            k_new, v_new = kv[:, 0], kv[:, 1]  # (B, Hkv, hd)
-        if rope_tables is not None:
-            q = _rope_slot(q)
-            k_new = _rope_slot(k_new)
-        return q, k_new, v_new
-
+    # (B, D); tables (B, half) each: one angle per slot, shared by all layers
+    x, rope_tables = _embed(params, cfg, cur, pos, pos)
     # A step's K/V rows in the cache's own form: (B, Hkv, hd), or
     # (B, Hkv * hd) for a cache of rows.
     row_shape = (B,) + k_cache.shape[3:]
-
-    def mlp(h, lp):
-        m = norm_fn(h[:, None], lp["ln2_g"], lp["ln2_b"])
-        if cfg.n_experts > 0:
-            from ray_lightning_tpu.parallel.moe import moe_ffn
-
-            m_out, _ = moe_ffn(
-                _moe_layer_params(lp),
-                m,
-                # capacity >= all tokens: decode never drops (see
-                # gpt_generate docstring).
-                capacity_factor=float(cfg.n_experts),
-                compute_dtype=cdt,
-                top_k=cfg.moe_top_k,
-            )
-            return m_out[:, 0]
-        return _dense_mlp(m[:, 0], lp, cfg, cdt)
 
     # The parts of a layer carry names into the compiled program's
     # metadata (jax.named_scope), so a profile says which part an
@@ -1870,33 +1659,35 @@ def gpt_decode_step(
     def layer(h, li, k_cache, v_cache):
         lp = jax.tree_util.tree_map(lambda a: a[li], params["blocks"])
         with jax.named_scope("qkv_rope"):
-            q, k_new, v_new = qkv_rope(h, lp)
+            a = norm_fn(h[:, None], lp["ln1_g"], lp["ln1_b"])[:, 0]
+            q, k_new, v_new = _project_qkv(a, lp, cfg, rope_tables)
+        # its own: one row a slot into the stacked caches where they lie,
+        # and the read of that layer's rows after its write
         with jax.named_scope("cache_write"):
-            k_cache = _write_cache_rows(
+            k_cache = layers._write_cache_rows(
                 k_cache, li, k_new.reshape(row_shape), pos
             )
-            v_cache = _write_cache_rows(
+            v_cache = layers._write_cache_rows(
                 v_cache, li, v_new.reshape(row_shape), pos
             )
         with jax.named_scope("cache_attention"):
             o = _attend_layer_cache(
                 cfg, q[:, None], k_cache, v_cache, li, pos[:, None], active
             )[:, 0].astype(cdt)
-            h = h + jnp.einsum(
-                "bhk,hkd->bd", o, dequant(lp["wo"], cdt)
-            ) + lp["bo"].astype(cdt)
+            h = _attn_out(h, o, lp, cfg)
         with jax.named_scope("mlp"):
-            h = h + mlp(h, lp)
+            m = norm_fn(h[:, None], lp["ln2_g"], lp["ln2_b"])[:, 0]
+            h = h + _ffn(m, lp, cfg)
         return h, k_cache, v_cache
 
     h = x
     # Python loop over layers (L is small and static); the caches stay
     # the stacked arrays throughout (see the docstring).
-    for li in range(L):
+    for li in range(cfg.n_layer):
         h, k_cache, v_cache = layer(h, li, k_cache, v_cache)
     with jax.named_scope("lm_head"):
-        h = norm_fn(h[:, None], params["lnf_g"], params["lnf_b"])[:, 0]
-        logits = _lm_head(h, _head_weight(params, cfg))
+        h = gpt_final_norm(params, cfg, h[:, None])[:, 0]
+        logits = gpt_logits(params, cfg, h)
     return logits, k_cache, v_cache
 
 
@@ -2011,10 +1802,8 @@ def _piggyback_prefill(
         pb_tp, pb_n_new, pb_eos, pb_final, pb_on,
     ) = piggyback
     refuse_mixed(cfg, "piggybacked prefill chunks in the decode fold")
-    norm_fn = _make_norm(cfg)
     Hkv, hd = cfg.kv_head, cfg.head_dim
     C_rows, cb = pb_chunk.shape
-    head_w = _head_weight(params, cfg)
     toks_out = []
     # Python loop over rows: C is small and static, and each row may
     # target a different slot (the engine never schedules two chunks of
@@ -2047,8 +1836,7 @@ def _piggyback_prefill(
         h_last = jax.lax.dynamic_slice_in_dim(
             h, jnp.maximum(tl - 1, 0), 1, axis=1
         )
-        h_last = norm_fn(h_last, params["lnf_g"], params["lnf_b"])[:, 0]
-        logits = _lm_head(h_last, head_w)
+        logits = gpt_logits(params, cfg, gpt_final_norm(params, cfg, h_last)[:, 0])
         key, sub = jax.random.split(pb_key0[r])
         tok = sample_logits_batched(
             sub[None], logits, pb_temp[r][None], pb_tk[r][None],
@@ -2164,7 +1952,6 @@ def gpt_decode_fold(
             refuse_mixed(cfg, "a paged KV cache (gpt_decode_step_paged)")
         if piggyback is not None:
             refuse_mixed(cfg, "piggybacked prefill chunks in the decode fold")
-        from ray_lightning_tpu.models.mixed import mixed_decode_step
         from ray_lightning_tpu.models.ssm import _step_heads
 
         states = k_cache.get("ssm", ())
@@ -2217,27 +2004,16 @@ def gpt_decode_fold(
         None,
         length=int(fold),
     )
-    cur, pos, keys, active, remaining, k_cache, v_cache, moe = carry
+    *state, moe = carry  # cur, pos, keys, active, remaining, k_cache, v_cache
     if cfg.mixed:
-        return (
-            tok_block, emit_block, cur, pos, keys, active, remaining,
-            k_cache, v_cache, moe,
-        )
+        return (tok_block, emit_block, *state, moe)
     if piggyback is None:
-        return (
-            tok_block, emit_block, cur, pos, keys, active, remaining,
-            k_cache, v_cache,
-        )
-    (
-        pb_toks, cur, pos, keys, active, remaining, k_cache, v_cache, _,
-    ) = _piggyback_prefill(
-        params, cfg, piggyback, cur, pos, keys, active, remaining,
-        k_cache, v_cache, page_table=page_table, page_size=page_size,
+        return (tok_block, emit_block, *state)
+    pb_toks, *state, _ = _piggyback_prefill(
+        params, cfg, piggyback, *state,
+        page_table=page_table, page_size=page_size,
     )
-    return (
-        tok_block, emit_block, cur, pos, keys, active, remaining,
-        k_cache, v_cache, pb_toks,
-    )
+    return (tok_block, emit_block, *state, pb_toks)
 
 
 def gpt_decode_verify(
@@ -2277,39 +2053,16 @@ def gpt_decode_verify(
     refuse_mixed(cfg, "speculative decoding (gpt_decode_verify)")
     cdt = jnp.dtype(cfg.compute_dtype)
     norm_fn = _make_norm(cfg)
-    L, H, hd = cfg.n_layer, cfg.n_head, cfg.head_dim
-    Hkv = cfg.kv_head
     B, Q = toks.shape
     S = k_cache.shape[2]
 
     positions = pos[:, None] + jnp.arange(Q, dtype=jnp.int32)[None]  # (B,Q)
-    x = embed_rows(params["wte"], toks)
-    if cfg.pos_embed == "learned":
-        # Clip only the (garbage) rows running past the table — a real
-        # (accepted) row always sits below max_seq.
-        x = x + params["wpe"][jnp.clip(positions, 0, cfg.max_seq - 1)]
-    x = x.astype(cdt)  # (B, Q, D)
-    if cfg.pos_embed == "rope":
-        half = hd // 2
-        freqs = cfg.rope_theta ** (
-            -jnp.arange(half, dtype=jnp.float32) / half
-        )
-        ang = positions.astype(jnp.float32)[..., None] * freqs  # (B,Q,half)
-        rope_tables = (jnp.cos(ang), jnp.sin(ang))
-    else:
-        rope_tables = None
-
-    def _rope_rows(y: jax.Array) -> jax.Array:
-        # (B, Q, H*, hd): _rope_slot with a query axis.
-        cos, sin = rope_tables
-        c = cos[:, :, None, :]
-        s = sin[:, :, None, :]
-        half = y.shape[-1] // 2
-        y32 = y.astype(jnp.float32)
-        y1, y2 = y32[..., :half], y32[..., half:]
-        return jnp.concatenate(
-            [y1 * c - y2 * s, y1 * s + y2 * c], axis=-1
-        ).astype(y.dtype)
+    # (B, Q, D), tables (B, Q, half). Learned positions: clip only the
+    # (garbage) rows running past the table — a real (accepted) row always
+    # sits below max_seq.
+    x, rope_tables = _embed(
+        params, cfg, toks, positions, jnp.clip(positions, 0, cfg.max_seq - 1)
+    )
 
     rows = jnp.arange(S, dtype=jnp.int32)
     idx = rows[None] - pos[:, None]  # (B, S): row's index into the chunk
@@ -2319,72 +2072,40 @@ def gpt_decode_verify(
     def layer(h, args):
         lp, kc_l, vc_l = args  # caches (B, S, Hkv, hd) or (B, S, Hkv * hd)
         a = norm_fn(h, lp["ln1_g"], lp["ln1_b"])
-        if Hkv == H:
-            qkv = (
-                jnp.einsum("bqd,dthk->bqthk", a, dequant(lp["wqkv"], cdt))
-                + lp["bqkv"].astype(cdt)
-            )
-            q, k_new, v_new = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-        else:
-            q, kv = _project_gqa(a, lp, cfg, cdt)
-            k_new, v_new = kv[:, :, 0], kv[:, :, 1]
-        if rope_tables is not None:
-            q = _rope_rows(q)
-            k_new = _rope_rows(k_new)
+        q, k_new, v_new = _project_qkv(a, lp, cfg, rope_tables)
         # Masked row-gather write of all Q rows into [pos, pos + Q); a
         # cache of rows (B, S, Hkv * hd) takes them with the KV heads
         # side by side.
         tail = kc_l.shape[2:]
         wmask = wvalid.reshape((B, S) + (1,) * len(tail))
         widx = gidx.reshape(wmask.shape)
-        kc_l = jnp.where(
-            wmask,
-            jnp.take_along_axis(
-                k_new.astype(cdt).reshape((B, Q) + tail), widx, axis=1
-            ),
-            kc_l,
-        )
-        vc_l = jnp.where(
-            wmask,
-            jnp.take_along_axis(
-                v_new.astype(cdt).reshape((B, Q) + tail), widx, axis=1
-            ),
-            vc_l,
+        kc_l, vc_l = (
+            jnp.where(
+                wmask,
+                jnp.take_along_axis(
+                    new.astype(cdt).reshape((B, Q) + tail), widx, axis=1
+                ),
+                old,
+            )
+            for new, old in ((k_new, kc_l), (v_new, vc_l))
         )
         # query row i of a slot sees absolute positions <= pos + i
         o = _attend_layer_cache(
             cfg, q, kc_l[None], vc_l[None], 0, positions
         ).astype(cdt)
-        h = h + jnp.einsum(
-            "bqhk,hkd->bqd", o, dequant(lp["wo"], cdt)
-        ) + lp["bo"].astype(cdt)
-        m = norm_fn(h, lp["ln2_g"], lp["ln2_b"])
-        if cfg.n_experts > 0:
-            from ray_lightning_tpu.parallel.moe import moe_ffn
-
-            m_out, _ = moe_ffn(
-                _moe_layer_params(lp),
-                m,
-                capacity_factor=float(cfg.n_experts),  # never drop
-                compute_dtype=cdt,
-                top_k=cfg.moe_top_k,
-            )
-        else:
-            m_out = _dense_mlp(m, lp, cfg, cdt)
-        return h + m_out, (kc_l, vc_l)
+        h = _attn_out(h, o, lp, cfg)
+        h = h + _ffn(norm_fn(h, lp["ln2_g"], lp["ln2_b"]), lp, cfg)
+        return h, (kc_l, vc_l)
 
     h = x
     new_k, new_v = [], []
-    for li in range(L):
+    for li in range(cfg.n_layer):
         lp = jax.tree_util.tree_map(lambda a: a[li], params["blocks"])
         h, (kc_l, vc_l) = layer(h, (lp, k_cache[li], v_cache[li]))
         new_k.append(kc_l)
         new_v.append(vc_l)
-    k_cache = jnp.stack(new_k)
-    v_cache = jnp.stack(new_v)
-    h = norm_fn(h, params["lnf_g"], params["lnf_b"])
-    logits = _lm_head(h, _head_weight(params, cfg))
-    return logits, k_cache, v_cache
+    logits = gpt_logits(params, cfg, gpt_final_norm(params, cfg, h))
+    return logits, jnp.stack(new_k), jnp.stack(new_v)
 
 
 # ---------------------------------------------------------------------------
@@ -2452,7 +2173,7 @@ def gpt_decode_step_paged(
     pages into the dense (L, B, S, Hkv, hd) layout, run the UNCHANGED
     dense step (bit-identical logits), and scatter the one written row
     per slot (position ``clip(pos, S-1)`` — the same clamp the dense
-    step's :func:`_write_cache_rows` applies) back to its page."""
+    step's ``models/layers.py:_write_cache_rows`` applies) back to its page."""
     refuse_mixed(cfg, "a paged KV cache (gpt_decode_step_paged)")
     S = table.shape[1] * int(page)
     k_view = paged_gather(pool_k, table, page)
@@ -2616,19 +2337,17 @@ def model_propose(
         draft_params, draft_cfg, toks_w, mesh=mesh
     )
     cdt = jnp.dtype(draft_cfg.compute_dtype)
-    norm_fn = _make_norm(draft_cfg)
     Hkv, hd = draft_cfg.kv_head, draft_cfg.head_dim
     Ld = draft_cfg.n_layer
     kc = jnp.zeros((Ld, B, window + depth, Hkv, hd), cdt)
     vc = jnp.zeros_like(kc)
     kc = kc.at[:, :, :window].set(pf_k)
     vc = vc.at[:, :, :window].set(pf_v)
-    h_last = norm_fn(
-        h_pf[:, window - 1 : window],
-        draft_params["lnf_g"], draft_params["lnf_b"],
+    h_last = gpt_final_norm(
+        draft_params, draft_cfg, h_pf[:, window - 1 : window]
     )[:, 0]
     t = jnp.argmax(
-        _lm_head(h_last, _head_weight(draft_params, draft_cfg)), axis=-1
+        gpt_logits(draft_params, draft_cfg, h_last), axis=-1
     ).astype(jnp.int32)
     drafts = [t]
     for i in range(depth - 1):
@@ -2769,28 +2488,16 @@ def gpt_decode_fold_spec(
         None,
         length=int(fold),
     )
-    cur, pos, keys, active, remaining, hist, k_cache, v_cache = carry
-    B = cur.shape[0]
+    *state, hist, k_cache, v_cache = carry  # cur, pos, keys, active, remaining
+    # (fold, D + 1, B) -> (fold * (D + 1), B)
+    blocks = tuple(b.reshape(-1, b.shape[-1]) for b in (tok_block, emit_block))
     if piggyback is None:
-        return (
-            tok_block.reshape(int(fold) * (D + 1), B),
-            emit_block.reshape(int(fold) * (D + 1), B),
-            cur, pos, keys, active, remaining, hist, k_cache, v_cache,
-        )
-    (
-        pb_toks, cur, pos, keys, active, remaining, k_cache, v_cache,
-        hist,
-    ) = _piggyback_prefill(
-        params, cfg, piggyback, cur, pos, keys, active, remaining,
-        k_cache, v_cache, hist=hist, page_table=page_table,
-        page_size=page_size,
+        return (*blocks, *state, hist, k_cache, v_cache)
+    pb_toks, *state, k_cache, v_cache, hist = _piggyback_prefill(
+        params, cfg, piggyback, *state, k_cache, v_cache, hist=hist,
+        page_table=page_table, page_size=page_size,
     )
-    return (
-        tok_block.reshape(int(fold) * (D + 1), B),
-        emit_block.reshape(int(fold) * (D + 1), B),
-        cur, pos, keys, active, remaining, hist, k_cache, v_cache,
-        pb_toks,
-    )
+    return (*blocks, *state, hist, k_cache, v_cache, pb_toks)
 
 
 def _hist_write_at(
@@ -2840,8 +2547,7 @@ def gpt_generate(
         )
     cfg.validate_variants()
     cdt = jnp.dtype(cfg.compute_dtype)
-    norm_fn = _make_norm(cfg)
-    L, H, hd = cfg.n_layer, cfg.n_head, cfg.head_dim
+    L, hd = cfg.n_layer, cfg.head_dim
     if rng is None:
         rng = jax.random.PRNGKey(0)
     if int(max_new_tokens) == 0:
@@ -2868,13 +2574,11 @@ def gpt_generate(
     h_pf, pf_k, pf_v = gpt_prefill(params, cfg, prompt)
     k_cache = k_cache.at[:, :, :P].set(pf_k)
     v_cache = v_cache.at[:, :, :P].set(pf_v)
-    h_last = norm_fn(
-        h_pf[:, P - 1 : P], params["lnf_g"], params["lnf_b"]
-    )[:, 0]
+    h_last = gpt_final_norm(params, cfg, h_pf[:, P - 1 : P])[:, 0]
     rng, sub = jax.random.split(rng)
     first_new = sample_logits(
         sub,
-        _lm_head(h_last, _head_weight(params, cfg)),
+        gpt_logits(params, cfg, h_last),
         temperature=temperature,
         top_k=top_k,
         top_p=top_p,
@@ -2993,19 +2697,18 @@ class GPTLM(TPUModule):
                 return_aux=return_aux,
                 return_hidden=chunked,
             )
-        if chunked:
-            def head(o):
-                with jax.named_scope("loss"):
-                    return chunked_lm_loss(
-                        o,
-                        _head_weight(params, self.config),
-                        toks[:, 1:],
-                        self.config.loss_chunk,
-                    )
-        else:
-            def head(o):
-                with jax.named_scope("loss"):
+
+        def head(o):
+            with jax.named_scope("loss"):
+                if not chunked:
                     return lm_loss(o, toks[:, 1:])
+                return chunked_lm_loss(
+                    o,
+                    _head_weight(params, self.config),
+                    toks[:, 1:],
+                    self.config.loss_chunk,
+                )
+
         if return_aux:
             hidden_or_logits, aux = out
             loss, acc = head(hidden_or_logits)

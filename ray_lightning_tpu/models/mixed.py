@@ -94,6 +94,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ray_lightning_tpu.models import layers
+from ray_lightning_tpu.models.layers import _lm_head, _rmsnorm, _rope, _rope_tables
+
 #: Attention kinds that keep a K and a V row a position.
 KV_KINDS = ("full", "window")
 ATTN_KINDS = KV_KINDS + ("latent",)
@@ -473,28 +476,6 @@ def empty_caches(cfg: Any, slots: int, max_seq: int, dtype: Any) -> Tuple[Dict[s
 
 
 # -- pieces --------------------------------------------------------------------
-def _rope(x: jax.Array, tables: Tuple[jax.Array, jax.Array], interleave: bool = False) -> jax.Array:
-    """Rotate the first ``2 * half`` dims of x (B, S, H, d) by position
-    (half-split pairs ``(i, i + half)``); the dims after them pass. With
-    ``interleave`` pair ``i`` is the neighbours ``(2i, 2i + 1)``, and the
-    rotated dims come out half-split (every first member, then every
-    second): a permutation that queries and keys share, so no score sees
-    it."""
-    cos, sin = tables  # (B, S, half)
-    half = cos.shape[-1]
-    cos, sin = cos[:, :, None, :], sin[:, :, None, :]
-    x32 = x.astype(jnp.float32)
-    rest = x32[..., 2 * half:]
-    if interleave:
-        pairs = x32[..., :2 * half].reshape(x.shape[:-1] + (half, 2))
-        x1, x2 = pairs[..., 0], pairs[..., 1]
-    else:
-        x1, x2 = x32[..., :half], x32[..., half:2 * half]
-    return jnp.concatenate(
-        [x1 * cos - x2 * sin, x1 * sin + x2 * cos, rest], axis=-1
-    ).astype(x.dtype)
-
-
 def _softmax_with_sink(s: jax.Array, sink: Optional[jax.Array]) -> jax.Array:
     """Softmax over the last axis of float32 scores (B, G, R, Q, K). With
     ``sink`` (G, R), one logit a query head, the sink joins the
@@ -543,7 +524,7 @@ def _attend_rows_full(q, k, v, sink):
 def prefill_kernel(cfg: Any, kind: str, rows: int, backend: Optional[str] = None) -> bool:
     """Which read the no-cache attention of a ``kind`` layer takes over
     ``rows`` rows that nobody differentiates (a prefill), from what it can
-    observe — as ``models/gpt.py:_decode_rows_block`` answers for decode:
+    observe — as ``models/layers.py:decode_rows_block`` answers for decode:
     True for the forward flash kernel (``ops/flash_attention.py``: the score
     tile stays in VMEM, the KV heads are not repeated, query blocks past the
     prompt's end are not computed), False for the blocked XLA read
@@ -555,8 +536,8 @@ def prefill_kernel(cfg: Any, kind: str, rows: int, backend: Optional[str] = None
     logit, which the kernel's sums do not know, head widths Mosaic takes
     (multiples of 64 up to 256: 64, 128, 192 and 256 lower for the v5e), rows
     that its tile divides, and at least :data:`_KERNEL_ROWS` of them: below
-    that a program does not gain on the chip. ``serve/engine.py``
-    asks the same question, bucket by bucket, for ``stats()["attn"]``."""
+    that a program does not gain on the chip. :func:`prefill_reads` puts
+    the answers together, bucket by bucket, for ``stats()["attn"]``."""
     from ray_lightning_tpu.ops.flash_attention import _default_block
 
     tile = min(_default_block(rows), rows)
@@ -568,6 +549,24 @@ def prefill_kernel(cfg: Any, kind: str, rows: int, backend: Optional[str] = None
         and all(d % 64 == 0 and d <= 256 for d in (qk_dim(cfg), v_dim(cfg)))
         and rows >= _KERNEL_ROWS
         and rows % tile == 0
+    )
+
+
+def prefill_reads(cfg: Any, rows: int) -> Tuple[int, int, int, int]:
+    """What an admission of ``rows`` rows (a bucket) reads, for the engine's
+    counters: the attention layers; those of them whose read is the causal
+    square (the full and the latent kinds; a window kind's is rows x 2W);
+    those of the square ones that the forward flash kernel reads
+    (:func:`prefill_kernel`, kind by kind); and the rows of a score tile —
+    the kernel's where any layer takes it, else :func:`_attend_rows_full`'s
+    block of queries —, clipped to the bucket."""
+    from ray_lightning_tpu.ops.flash_attention import _default_block
+
+    square = {kind: count_kind(cfg, kind) for kind in ("full", "latent")}
+    kernel = sum(n for kind, n in square.items() if prefill_kernel(cfg, kind, rows))
+    return (
+        sum(count_kind(cfg, kind) for kind in ATTN_KINDS), sum(square.values()), kernel,
+        min(_default_block(rows) if kernel else _Q_BLOCK, rows),
     )
 
 
@@ -757,14 +756,12 @@ def _attention_part(h, lp, ls, cfg, rope, pos, caches, live=None, u=None, real_r
     logit, the bucket at or over the crossing —, in the forward flash
     kernel, which takes ``k`` and ``v`` at their ``G`` KV heads as they
     are. In decode the read after the row's write is the one
-    ``models/gpt.py:_decode_rows_block`` names for the layer's kind: the
+    ``models/layers.py:decode_rows_block`` names for the layer's kind: the
     decode kernel (``ops/decode_attention.py:decode_attention``) over the
     ``live`` slots' row blocks ``0 .. pos`` — a full kind without a sink
     logit, on a TPU; a slot that is not live reads zeros — or
     :func:`_attend_cache` over every allocated row, which ``live`` does
     not reach: the ring, a kind with a sink, the CPU."""
-    from ray_lightning_tpu.models.gpt import _decode_rows_block, _rmsnorm, _write_cache_rows
-
     cdt = jnp.dtype(cfg.compute_dtype)
     B, S, _ = h.shape
     G = kv_heads(cfg, ls.mixer)
@@ -795,14 +792,14 @@ def _attention_part(h, lp, ls, cfg, rope, pos, caches, live=None, u=None, real_r
             # (frozen slots: _write_cache_rows clamps); a ring row is
             # always in bounds
             row = pos % k_cache[ls.mixer].shape[2] if ring else pos
-            k_cache[ls.mixer] = _write_cache_rows(
+            k_cache[ls.mixer] = layers._write_cache_rows(
                 k_cache[ls.mixer], ls.mixer_index, k[:, 0].reshape(B, -1), row
             )
-            v_cache[ls.mixer] = _write_cache_rows(
+            v_cache[ls.mixer] = layers._write_cache_rows(
                 v_cache[ls.mixer], ls.mixer_index, v[:, 0].reshape(B, -1), row
             )
             kv = (k_cache, v_cache)
-            block = _decode_rows_block(cfg, S, k_cache, v_cache, ls.mixer)
+            block = layers.decode_rows_block(cfg, S, k_cache, v_cache, ls.mixer)
             if block:
                 from ray_lightning_tpu.ops.decode_attention import decode_attention
 
@@ -827,7 +824,7 @@ def _attend_latent_cache(cfg, q_lat, q_rope, c_cache, r_cache, li, pos, live=Non
     rotated parts, ``pos`` (B,) the queries' positions; the weighted
     latents ``softmax((q_lat · c + q_rope · k_r) / sqrt(qk)) · c`` over each
     slot's positions ``0 .. pos``, (B, H, rank) in the compute dtype.
-    ``models/gpt.py:_decode_rows_block`` says which read:
+    ``models/layers.py:decode_rows_block`` says which read:
 
     - the XLA read: two matmuls over all ``S`` allocated rows of every
       slot, the scores, then the weighted sum — every latent is read
@@ -841,10 +838,8 @@ def _attend_latent_cache(cfg, q_lat, q_rope, c_cache, r_cache, li, pos, live=Non
       float32 up to the product, whose operands the MXU takes rounded to
       bfloat16 (Mosaic's default contraction: PERF.md §6, PR 39) — what
       the XLA read's cast of p to the cache's dtype does."""
-    from ray_lightning_tpu.models.gpt import _decode_rows_block
-
     scale = 1.0 / np.sqrt(qk_dim(cfg))
-    block = _decode_rows_block(cfg, 1, c_cache, r_cache, "latent")
+    block = layers.decode_rows_block(cfg, 1, c_cache, r_cache, "latent")
     if block:
         from ray_lightning_tpu.ops.decode_attention import latent_decode_attention
 
@@ -885,8 +880,6 @@ def _latent_part(h, lp, ls, cfg, rope, pos, caches, live=None, real_rows=None):
     two is :func:`_attend_latent_cache`'s: on a TPU the decode kernel over
     the ``live`` slots' positions, elsewhere XLA's over every allocated
     row."""
-    from ray_lightning_tpu.models.gpt import _rmsnorm, _write_cache_rows
-
     cdt = jnp.dtype(cfg.compute_dtype)
     B, S, _ = h.shape
     H, r, dn = cfg.n_head, cfg.kv_lora_rank, qk_dim(cfg) - rope_dim(cfg)
@@ -906,8 +899,8 @@ def _latent_part(h, lp, ls, cfg, rope, pos, caches, live=None, real_rows=None):
             o = _attend_rows(cfg, "latent", qf, k, kv_h[..., dn:], None, real_rows)
         else:
             c_cache, r_cache = dict(caches[0]), dict(caches[1])
-            c_cache["latent"] = _write_cache_rows(c_cache["latent"], ls.mixer_index, c[:, 0], pos)
-            r_cache["latent"] = _write_cache_rows(r_cache["latent"], ls.mixer_index, k_rope[:, 0, 0], pos)
+            c_cache["latent"] = layers._write_cache_rows(c_cache["latent"], ls.mixer_index, c[:, 0], pos)
+            r_cache["latent"] = layers._write_cache_rows(r_cache["latent"], ls.mixer_index, k_rope[:, 0, 0], pos)
             kv = (c_cache, r_cache)
             q_lat = jnp.einsum("bhk,hck->bhc", q_nope[:, 0], wkv_b[..., :dn])
             o_lat = _attend_latent_cache(cfg, q_lat, q_rope[:, 0], c_cache, r_cache, ls.mixer_index, pos, live)
@@ -923,7 +916,6 @@ def _state_part(h, lp, ls, cfg, caches, valid, u=None):
     bit (``models/ssm.py:ssm_step``: on a TPU its state is not even read).
     ``u``: the layer's normed input where the caller has it already."""
     from ray_lightning_tpu.models import ssm
-    from ray_lightning_tpu.models.gpt import _rmsnorm
 
     if u is None:
         u = _rmsnorm(h, lp["ln1_g"], cfg.norm_eps)
@@ -947,8 +939,6 @@ def _parallel_part(h, lp, ls, cfg, rope, pos, caches, valid, real_rows=None):
     state half advances its state in what comes back, so ``kv`` is the
     pair of dicts with both kinds replaced."""
     from dataclasses import replace
-
-    from ray_lightning_tpu.models.gpt import _rmsnorm
 
     with jax.named_scope("parallel"):
         u = _rmsnorm(h, lp["ln1_g"], cfg.norm_eps)
@@ -1017,8 +1007,6 @@ def mixed_block(
     cache): the rows are a prefill's and that many of them real, so a full
     or latent layer's attention asks :func:`prefill_kernel` which read. ``moe_stats`` is
     :func:`moe_ffn_held`'s (zeros for any other layer)."""
-    from ray_lightning_tpu.models.gpt import _rmsnorm
-
     kv, stats = caches, jnp.zeros((3,), jnp.int32)
     if ls.side:
         out, kv = _parallel_part(h, lp, ls, cfg, rope, pos, caches, valid, real_rows)
@@ -1045,8 +1033,6 @@ def _rope_by_kind(cfg: Any, pos: jax.Array) -> Dict[str, Tuple[jax.Array, jax.Ar
     """The rotation tables of positions ``pos`` (B, S), one pair a kind of
     attention the model has: computed once, shared by its layers (none
     for attention without positions)."""
-    from ray_lightning_tpu.models.gpt import _rope_tables
-
     if cfg.pos_embed != "rope":
         return {}
     return {
@@ -1058,8 +1044,6 @@ def _rope_by_kind(cfg: Any, pos: jax.Array) -> Dict[str, Tuple[jax.Array, jax.Ar
 def mixed_logits(h: jax.Array, params: Dict[str, Any], cfg: Any) -> jax.Array:
     """Float32 logits of final-normed hidden states h (..., D): the untied
     head's, times the ``lm_head`` multiplier where the model has one."""
-    from ray_lightning_tpu.models.gpt import _lm_head
-
     return _scaled(_lm_head(h, params["lm_head"]), cfg.multiplier("lm_head"))
 
 
@@ -1117,7 +1101,6 @@ def mixed_decode_step(
     the summed ``moe_stats``. ``active`` (B,) bool: idle lanes route to
     no expert (their logits are not read; a state layer advances their
     own state, which the next admission into the slot overwrites)."""
-    from ray_lightning_tpu.models.gpt import _rmsnorm
     from ray_lightning_tpu.utils.quantize import embed_rows
 
     cdt = jnp.dtype(cfg.compute_dtype)

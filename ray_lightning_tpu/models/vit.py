@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 import optax
 
+from ray_lightning_tpu.models.layers import _layernorm
 from ray_lightning_tpu.models.resnet import ImageClassifierModule
 from ray_lightning_tpu.trainer.data import ArrayDataset
 
@@ -126,13 +127,6 @@ def init_vit_params(rng: jax.Array, cfg: ViTConfig) -> Dict[str, Any]:
         "head_w": norm(ks[7], (D, cfg.num_classes), D**-0.5),
         "head_b": jnp.zeros((cfg.num_classes,)),
     }
-
-
-def _layernorm(x: jax.Array, g: jax.Array, b: jax.Array) -> jax.Array:
-    x32 = x.astype(jnp.float32)
-    mu = x32.mean(-1, keepdims=True)
-    var = x32.var(-1, keepdims=True)
-    return ((x32 - mu) * jax.lax.rsqrt(var + 1e-5) * g + b).astype(x.dtype)
 
 
 def patchify(images: jax.Array, cfg: ViTConfig) -> jax.Array:

@@ -47,7 +47,7 @@ of 768 and 512; a head of 192, one and a half lane tiles, is laid over
 the row by the same concatenation along the lanes, which Mosaic takes
 (``tests/test_latent_step_v5e.py``). A window kind's ring (row ``pos mod
 R``) and a kind whose softmax a learnable sink logit joins keep the XLA
-read (``models/gpt.py:_decode_rows_block`` says which, kind by kind).
+read (``models/layers.py:decode_rows_block`` says which, kind by kind).
 :func:`latent_decode_attention` reads a latent layer's pair
 (``models/mixed.py:_latent_part``'s absorbed decode): one latent row a
 position, ``(L, B, S, rank)``, is the keys AND the values of every head, so

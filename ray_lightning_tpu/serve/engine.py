@@ -869,21 +869,22 @@ class DecodeEngine:
             "decode": {"slot_steps": 0, "slot_steps_live": 0, "slot_steps_visited": 0},
             "prefill": {"rows_scanned": 0, "rows_real": 0},
         }
-        self._state_layers = 0
-        #: Layers whose decode read ``attn_totals`` counts, by the kind of
-        #: cache they read: every layer of a uniform configuration (under
-        #: None); of mixed layer kinds the full and the latent ones, whose
-        #: rows are a request's positions (a window kind's ring is read
-        #: whole, a slot's last ``attn_window`` positions, and not counted).
-        self._attn_layers: Dict[Optional[str], int] = {None: config.n_layer}
-        if config.mixed:
-            from ray_lightning_tpu.models.mixed import count_kind
+        from ray_lightning_tpu.models.gpt import decode_reads
+        from ray_lightning_tpu.models.mixed import count_kind, prefill_reads
 
-            self._state_layers = count_kind(config, "ssm")
-            self._attn_layers = {
-                kind: count_kind(config, kind)
-                for kind in ("full", "latent") if count_kind(config, kind)
-            }
+        self._state_layers = count_kind(config, "ssm")
+        #: The decode reads ``attn_totals`` counts, by the kind of cache
+        #: read: ``(layers, rows of the decode kernel's block, or 0 where
+        #: the read is XLA's over every allocated row)`` — the model's own
+        #: answer (models/gpt.py:decode_reads) for the step this engine
+        #: folds: every layer of a uniform configuration (under None); of
+        #: mixed layer kinds the full and the latent ones, whose rows are a
+        #: request's positions (a window kind's ring is read whole, a
+        #: slot's last ``attn_window`` positions, and not counted).
+        self._attn_reads: Dict[Optional[str], Tuple[int, int]] = decode_reads(
+            config, 1 if self.spec == "off" else self.spec_depth + 1,
+            self._k, self._v,
+        )
         #: What the decode steps' cached attention read, in cache rows
         #: summed over token steps and layers: the rows allocated to the
         #: slots, those the read visited and those of live requests'
@@ -896,36 +897,13 @@ class DecodeEngine:
         }
         #: What an admission of mixed layer kinds adds to the four prefill
         #: counts, by bucket (``_count_prefill``): the attention layers, those
-        #: of them whose read is the causal square (the full and the latent
-        #: kinds; a window kind's is rows x 2W) and those of them the forward
-        #: flash kernel reads — the model's own answer, kind by kind
-        #: (models/mixed.py:prefill_kernel) —, and the rows of a score tile.
-        self._prefill_layers: Dict[int, Tuple[int, int, int, int]] = {}
-        if config.mixed:
-            from ray_lightning_tpu.models.mixed import ATTN_KINDS, _Q_BLOCK, count_kind, prefill_kernel
-            from ray_lightning_tpu.ops.flash_attention import _default_block
-
-            layers = sum(count_kind(config, k) for k in ATTN_KINDS)
-            square = {k: count_kind(config, k) for k in ("full", "latent")}
-            for pb in buckets:
-                kernel = sum(n for k, n in square.items() if prefill_kernel(config, k, pb))
-                self._prefill_layers[pb] = (
-                    layers, sum(square.values()), kernel, min(_default_block(pb) if kernel else _Q_BLOCK, pb),
-                )
-        #: By the same kinds, the rows of the decode kernel's block when
-        #: the fold's read of that kind is the kernel — the model's own
-        #: answer (models/gpt.py:_decode_rows_block: a uniform cache of
-        #: rows, the latent layers' pair, a full kind's K and V without a
-        #: sink logit; on a TPU) —, else 0: the XLA read visits every
-        #: allocated row.
-        self._attn_block: Dict[Optional[str], int] = dict.fromkeys(self._attn_layers, 0)
-        if self._k is not None and self.spec == "off":
-            from ray_lightning_tpu.models.gpt import _decode_rows_block
-
-            for kind in self._attn_block:
-                self._attn_block[kind] = _decode_rows_block(
-                    config, 1, self._k, self._v, kind
-                )
+        #: of them whose read is the causal square, those of them the forward
+        #: flash kernel reads and the rows of a score tile — the model's own
+        #: answer (models/mixed.py:prefill_reads).
+        self._prefill_layers: Dict[int, Tuple[int, int, int, int]] = (
+            {pb: prefill_reads(config, pb) for pb in buckets}
+            if config.mixed else {}
+        )
         from ray_lightning_tpu.obs.registry import get_registry as _greg
 
         _reg = _greg()
@@ -1003,13 +981,12 @@ class DecodeEngine:
         import jax.numpy as jnp
 
         from ray_lightning_tpu.models.gpt import (
-            _head_weight,
-            _lm_head,
-            _make_norm,
             cache_strip,
             cache_strip_put,
             gpt_decode_fold,
             gpt_decode_fold_spec,
+            gpt_final_norm,
+            gpt_logits,
             gpt_prefill,
             gpt_prefill_chunk,
             model_propose,
@@ -1018,7 +995,6 @@ class DecodeEngine:
         )
 
         cfg = self.cfg
-        norm_fn = _make_norm(cfg)
         # Mesh mode: every aval carries its array's sharding, so each
         # executable lowers ONCE under the mesh with the partitioner
         # seeing exactly the layouts the donated buffers will arrive in;
@@ -1069,7 +1045,6 @@ class DecodeEngine:
                 # state layer's state and conv tail are written whole; the
                 # layers' counts come out with the first token.
                 from ray_lightning_tpu.models.mixed import (
-                    mixed_logits,
                     mixed_rows,
                     write_prefill_rows,
                 )
@@ -1087,13 +1062,8 @@ class DecodeEngine:
                 k_cache = cache_strip_put(k_cache, pf_k, slot, 0)
                 v_cache = cache_strip_put(v_cache, pf_v, slot, 0)
             h_last = jax.lax.dynamic_slice_in_dim(h, last_idx, 1, axis=1)
-            h_last = norm_fn(
-                h_last, params["lnf_g"], params.get("lnf_b")
-            )[:, 0]
-            logits = (
-                mixed_logits(h_last, params, cfg) if cfg.mixed
-                else _lm_head(h_last, _head_weight(params, cfg))
-            )
+            h_last = gpt_final_norm(params, cfg, h_last)[:, 0]
+            logits = gpt_logits(params, cfg, h_last)
             key, sub = jax.random.split(key0)
             tok = sample_logits_batched(
                 sub[None], logits, temp[None], tk[None], tp[None]
@@ -1264,8 +1234,8 @@ class DecodeEngine:
             k_cache = cache_strip_put(k_cache, k_slot, slot, 0)
             v_cache = cache_strip_put(v_cache, v_slot, slot, 0)
             h_last = jax.lax.dynamic_slice_in_dim(h, true_len - 1, 1, axis=1)
-            h_last = norm_fn(h_last, params["lnf_g"], params["lnf_b"])[:, 0]
-            logits = _lm_head(h_last, _head_weight(params, cfg))
+            h_last = gpt_final_norm(params, cfg, h_last)[:, 0]
+            logits = gpt_logits(params, cfg, h_last)
             key, sub = jax.random.split(key0)
             tok = sample_logits_batched(
                 sub[None], logits, temp[None], tk[None], tp[None]
@@ -1369,8 +1339,8 @@ class DecodeEngine:
                 true_len, page=page,
             )
             h_last = jax.lax.dynamic_slice_in_dim(h, true_len - 1, 1, axis=1)
-            h_last = norm_fn(h_last, params["lnf_g"], params["lnf_b"])[:, 0]
-            logits = _lm_head(h_last, _head_weight(params, cfg))
+            h_last = gpt_final_norm(params, cfg, h_last)[:, 0]
+            logits = gpt_logits(params, cfg, h_last)
             key, sub = jax.random.split(key0)
             tok = sample_logits_batched(
                 sub[None], logits, temp[None], tk[None], tp[None]
@@ -2204,7 +2174,7 @@ class DecodeEngine:
         ``prefill_rows`` / ``prefill_rows_kernel`` and ``prefill_tiles`` /
         ``prefill_tiles_visited`` (:meth:`_count_prefill`; zeros for a
         uniform configuration, whose prefill is ``gpt_prefill``'s)."""
-        return dict(self.attn_totals) if self._attn_layers else {}
+        return dict(self.attn_totals) if self._attn_reads else {}
 
     def ssm_stats(self) -> Dict[str, Any]:
         """``stats()["ssm"]``: what the state layers of a mixed
@@ -3906,7 +3876,7 @@ class DecodeEngine:
         counts: Dict[Tuple[int, int], int] = {}
         # rows the kernel visits, by the block it walks in (a block a
         # counted kind at most: one of them in every configuration so far)
-        rows_live, rows_visited = 0, {b: 0 for b in self._attn_block.values() if b}
+        rows_live, rows_visited = 0, {b: 0 for _, b in self._attn_reads.values() if b}
         for kk in range(toks.shape[0]):
             for slot, info in enumerate(snapshot):
                 if info is None or info.released or not emits[kk, slot]:
@@ -3930,8 +3900,7 @@ class DecodeEngine:
                     counts[key] = counts.get(key, 0) + 1
                 if done:
                     self._release_synced(slot, info)
-        for kind, layers in self._attn_layers.items():
-            blk = self._attn_block[kind]
+        for layers, blk in self._attn_reads.values():
             allocated = (
                 (toks.shape[0] // group) * layers * self.num_slots
                 * self.max_seq

@@ -14,6 +14,7 @@ Mosaic's view of it is ``tests/test_latent_step_v5e.py`` too.
 import numpy as np
 import pytest
 
+from ray_lightning_tpu.models import layers
 from ray_lightning_tpu.models.gpt import GPTConfig
 
 BLOCK, S, L = 128, 384, 2  # three blocks a slot: 512 and 256 do not divide 384
@@ -74,9 +75,9 @@ def test_kernel_equals_the_xla_rows_read(variant, where, monkeypatch):
     q, kc, vc, positions = _inputs(cfg, [POSITIONS[where], 200, 300, 5])
     live = jnp.asarray([True, True, False, True])
     want = G._attend_layer_cache(cfg, q, kc, vc, 1, positions)
-    assert G._decode_rows_block(cfg, 1, kc, vc) == 0, "off the TPU the engine keeps the XLA read"
+    assert layers.decode_rows_block(cfg, 1, kc, vc) == 0, "off the TPU the engine keeps the XLA read"
     got = _read(monkeypatch, kernel=True)(cfg, q, kc, vc, 1, positions, live)
-    assert G._decode_rows_block(cfg, 1, kc, vc) == BLOCK
+    assert layers.decode_rows_block(cfg, 1, kc, vc) == BLOCK
     assert got.shape == want.shape and got.dtype == want.dtype == jnp.float32
     np.testing.assert_allclose(np.asarray(got)[[0, 1, 3]], np.asarray(want)[[0, 1, 3]], atol=1e-5, rtol=0)
     assert not np.asarray(got)[2].any()
@@ -156,8 +157,8 @@ def test_everything_else_keeps_the_xla_read(case, kw):
         shape = (L, 2, rows, cfg.kv_head, cfg.head_dim)
     cache = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
     q_len = 3 if case == "verify_q3" else 1
-    assert G._decode_rows_block(cfg, q_len, cache, cache, backend="tpu") == 0
-    assert G._decode_rows_block(cfg, q_len, cache, cache) == 0
+    assert layers.decode_rows_block(cfg, q_len, cache, cache, backend="tpu") == 0
+    assert layers.decode_rows_block(cfg, q_len, cache, cache) == 0
 
 
 @pytest.mark.parametrize("variant", sorted(VARIANTS))
@@ -248,9 +249,9 @@ def test_latent_kernel_equals_the_xla_read_of_latents(shape, where, monkeypatch)
     q_lat, q_rope, cc, rc, pos = _latent_inputs(shape, [_latent_positions(S, block)[where], 200, 300, 5])
     live = jnp.asarray([True, True, False, True])
     want = _latent_read(monkeypatch, kernel=False)(cfg, q_lat, q_rope, cc, rc, L - 1, pos)
-    assert G._decode_rows_block(cfg, 1, cc, rc, "latent") == 0, "off the TPU the engine keeps the XLA read"
+    assert layers.decode_rows_block(cfg, 1, cc, rc, "latent") == 0, "off the TPU the engine keeps the XLA read"
     got = _latent_read(monkeypatch, kernel=True)(cfg, q_lat, q_rope, cc, rc, L - 1, pos, live)
-    assert G._decode_rows_block(cfg, 1, cc, rc, "latent") == block
+    assert layers.decode_rows_block(cfg, 1, cc, rc, "latent") == block
     assert got.shape == want.shape == (4, 4, rank) and got.dtype == want.dtype == jnp.float32
     np.testing.assert_allclose(np.asarray(got)[[0, 1, 3]], np.asarray(want)[[0, 1, 3]], atol=1e-5, rtol=0)
     assert not np.asarray(got)[2].any()
@@ -385,10 +386,10 @@ def test_everything_else_keeps_the_xla_read_of_latents(case):
     cc = {kind: jax.ShapeDtypeStruct((16, 64, rows, rank), jnp.bfloat16)}
     rc = {kind: jax.ShapeDtypeStruct((16, 64, rows, rope), jnp.bfloat16)}
     q_len = 3 if case == "verify_q3" else 1
-    assert G._decode_rows_block(cfg, q_len, cc, rc, "latent", backend=None if case == "cpu" else "tpu") == 0
+    assert layers.decode_rows_block(cfg, q_len, cc, rc, "latent", backend=None if case == "cpu" else "tpu") == 0
     sound = {"latent": jax.ShapeDtypeStruct((16, 64, 6656, 512), jnp.bfloat16)}, {
         "latent": jax.ShapeDtypeStruct((16, 64, 6656, 64), jnp.bfloat16)}
-    assert G._decode_rows_block(_latent_cfg(), 1, *sound, "latent", backend="tpu") == 512
+    assert layers.decode_rows_block(_latent_cfg(), 1, *sound, "latent", backend="tpu") == 512
 
 
 # -- a mixed configuration's full kind: K rows and V rows of different widths ---------------
@@ -482,7 +483,7 @@ def test_a_k_and_v_block_count_off_by_one_fails(off, monkeypatch):
     ("a_kind_the_model_has_no_layer_of", "latent", 0), ("the_state_layers_tuples", "ssm", 0),
 ])
 def test_the_selection_answers_kind_by_kind(case, kind, want):
-    """``_decode_rows_block`` on a mixed configuration's caches: the full
+    """``decode_rows_block`` on a mixed configuration's caches: the full
     kind's rows walk where no sink joins its softmax; the ring, whose rows
     are not positions ``0 .. pos``, and everything the uniform selection
     refuses keep the XLA read."""
@@ -502,8 +503,8 @@ def test_the_selection_answers_kind_by_kind(case, kind, want):
     kc = {"full": rows(S, 2 * 192), "window": rows(128, 2 * 192), "ssm": (rows(S, 384),)}
     vc = {"full": rows(S, 2 * dv), "window": rows(128, 2 * dv), "ssm": (rows(S, 256),)}
     q_len = 3 if case == "verify_q3" else 1
-    assert G._decode_rows_block(cfg, q_len, kc, vc, kind, backend=None if case == "cpu" else "tpu") == want
-    assert G._decode_rows_block(cfg, q_len, kc, vc, kind) == 0
+    assert layers.decode_rows_block(cfg, q_len, kc, vc, kind, backend=None if case == "cpu" else "tpu") == want
+    assert layers.decode_rows_block(cfg, q_len, kc, vc, kind) == 0
 
 
 def test_a_mixed_decode_step_with_the_kernel_gives_the_xla_steps_logits(monkeypatch):

@@ -8,7 +8,7 @@ mesh axes, tensor/sequence-parallel forwards agree with the dense one.
 import numpy as np
 import pytest
 
-from ray_lightning_tpu.models import GPTConfig, GPTLM, make_fake_text
+from ray_lightning_tpu.models import GPTConfig, GPTLM, layers, make_fake_text
 from ray_lightning_tpu.models.gpt import gpt_forward, init_gpt_params
 from ray_lightning_tpu.strategies import GSPMDStrategy
 from ray_lightning_tpu.trainer.module import unpack_optimizers
@@ -968,7 +968,7 @@ def test_decode_step_clamps_a_position_past_the_cache(
 
         return cache.at[li].set(jax.vmap(one)(cache[li], new, pos))
 
-    monkeypatch.setattr(G, "_write_cache_rows", write_by_slot)
+    monkeypatch.setattr(layers, "_write_cache_rows", write_by_slot)
     want_logits, want_k, want_v = step(params, cur, pos, k_cache, v_cache)
     np.testing.assert_array_equal(np.asarray(k_out), np.asarray(want_k))
     np.testing.assert_array_equal(np.asarray(v_out), np.asarray(want_v))

@@ -9,6 +9,7 @@ import numpy as np
 import pytest
 from test_mixed_layers import _call, _engine, _int8, _mesh2
 
+from ray_lightning_tpu.models import layers
 from ray_lightning_tpu.models.gpt import GPTConfig, init_gpt_params
 
 LATENT = dict(
@@ -76,8 +77,7 @@ def test_neighbouring_pairs_turn_as_written_and_come_out_half_split():
     import jax
     import jax.numpy as jnp
 
-    from ray_lightning_tpu.models.gpt import _rope_tables
-    from ray_lightning_tpu.models.mixed import _rope
+    from ray_lightning_tpu.models.layers import _rope, _rope_tables
 
     x = jax.random.normal(jax.random.PRNGKey(2), (2, 9, 3, 10), jnp.float32)
     pos = jnp.broadcast_to(jnp.arange(9)[None], (2, 9))
@@ -192,9 +192,9 @@ def test_a_decode_step_through_the_kernel_gives_the_xla_steps_logits(monkeypatch
     pos = jnp.asarray([128, 383, 9], jnp.int32)  # a block's first row, the cache's last, and a slot that is not live
     active = jnp.asarray([True, True, False])
     want = G.gpt_decode_step(params, cfg, cur, pos, kc, vc, active)
-    assert G._decode_rows_block(cfg, 1, kc, vc, "latent") == 0
+    assert layers.decode_rows_block(cfg, 1, kc, vc, "latent") == 0
     force_decode_kernel(monkeypatch)
-    assert G._decode_rows_block(cfg, 1, kc, vc, "latent") == 128
+    assert layers.decode_rows_block(cfg, 1, kc, vc, "latent") == 128
     got = G.gpt_decode_step(params, cfg, cur, pos, kc, vc, active)
     np.testing.assert_allclose(np.asarray(got[0])[:2], np.asarray(want[0])[:2], atol=2e-4, rtol=0)
     for a, b in zip(got[1:], want[1:]):

@@ -254,7 +254,7 @@ def test_the_replicas_attn_counter_counts_the_full_layers(params, read, monkeypa
     """``stats()["attn"]`` of the toy mimo configuration: its two full layers'
     rows (the window layer's ring is not counted). On the CPU the read is
     XLA's, every allocated row; with a block planted in the engine's
-    ``_attn_block`` the counter rounds each step's rows up to whole blocks."""
+    ``_attn_reads`` the counter rounds each step's rows up to whole blocks."""
     from ray_lightning_tpu.obs import registry
     from ray_lightning_tpu.serve.server import ServeReplica
 
@@ -263,9 +263,9 @@ def test_the_replicas_attn_counter_counts_the_full_layers(params, read, monkeypa
     rep = ServeReplica(params=params, model_config=dict(MIXED), num_slots=2, max_seq=64,
                        prefill_buckets=[16], decode_fold=4, watchdog=False)
     try:
-        assert rep.engine._attn_layers == {"full": 2} and rep.engine._attn_block == {"full": 0}
+        assert rep.engine._attn_reads == {"full": (2, 0)}
         if read == "planted_block":
-            rep.engine._attn_block["full"] = 16
+            rep.engine._attn_reads["full"] = (2, 16)
         sizes = (10, 3, 12)
         _serve(rep, sizes)
         attn = rep.stats()["attn"]
@@ -303,7 +303,7 @@ def test_the_full_layers_read_through_the_kernel_serves_the_xla_reads_tokens(mon
         rep = ServeReplica(params=kernel_params, model_config=dict(KERNEL), num_slots=3, max_seq=384,
                            prefill_buckets=[16, 128], decode_fold=4, watchdog=False)
         try:
-            assert rep.engine._attn_block == {"full": 128 if read == "kernel" else 0}
+            assert rep.engine._attn_reads == {"full": (2, 128 if read == "kernel" else 0)}
             seen[read] = (_serve(rep, sizes), rep.stats()["attn"])
             assert rep.stats()["compiles_since_init"] == 0
         finally:

@@ -157,7 +157,7 @@ def test_the_largest_admission_fits_beside_the_caches(cell):
     import jax
     import jax.numpy as jnp
 
-    from ray_lightning_tpu.models.gpt import _rmsnorm
+    from ray_lightning_tpu.models.layers import _rmsnorm
     from ray_lightning_tpu.models.mixed import mixed_logits, mixed_rows, write_prefill_rows
 
     pc, rep, params, k_cache, v_cache, sds = cell

@@ -157,7 +157,7 @@ def test_the_rows_of_a_prompt_come_out_the_same_under_both_reads(name, true_len,
     import jax.numpy as jnp
 
     from ray_lightning_tpu.models import mixed
-    from ray_lightning_tpu.models.gpt import _rmsnorm
+    from ray_lightning_tpu.models.layers import _rmsnorm
     from tests.utils import force_prefill_kernel
 
     cfg = GPTConfig(**dict(WIDE[name], compute_dtype="float32"))

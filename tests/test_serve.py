@@ -437,10 +437,10 @@ def test_engine_with_the_decode_kernel_serves_the_xla_reads_tokens(fold, monkeyp
         return eng, outs
 
     xla, want = serve()
-    assert xla._attn_block == {None: 0}
+    assert xla._attn_reads == {None: (cfg.n_layer, 0)}
     force_decode_kernel(monkeypatch)
     kernel, got = serve()
-    assert kernel._attn_block == {None: 128}
+    assert kernel._attn_reads == {None: (cfg.n_layer, 128)}
     assert got == want
     a, b = kernel.attn_stats(), xla.attn_stats()
     assert a["rows_live"] == b["rows_live"] and a["rows_allocated"] == b["rows_allocated"]

@@ -3,6 +3,7 @@
 ray_lightning/tests/utils.py:213-272)."""
 from __future__ import annotations
 
+import functools
 from typing import Any, Optional
 
 import numpy as np
@@ -73,15 +74,15 @@ def budget_freeze_requests(rng: Any, max_seq: int = 64) -> tuple:
 
 
 def force_decode_kernel(monkeypatch: Any) -> None:
-    """Tell the one selection function (``models/gpt.py:_decode_rows_block``)
+    """Tell the one selection function (``models/layers.py:decode_rows_block``)
     "tpu": the decode kernel is then the read wherever its other conditions
     hold, and off the chip it runs interpreted. No option does this."""
     import functools
 
-    from ray_lightning_tpu.models import gpt as G
+    from ray_lightning_tpu.models import layers
 
     monkeypatch.setattr(
-        G, "_decode_rows_block", functools.partial(G._decode_rows_block, backend="tpu")
+        layers, "decode_rows_block", functools.partial(layers.decode_rows_block, backend="tpu")
     )
 
 
@@ -111,7 +112,8 @@ def mixed_program_hashes(cfg: Any) -> dict:
     import jax
     import jax.numpy as jnp
 
-    from ray_lightning_tpu.models.gpt import _rmsnorm, gpt_decode_fold, init_gpt_params
+    from ray_lightning_tpu.models.gpt import gpt_decode_fold, init_gpt_params
+    from ray_lightning_tpu.models.layers import _rmsnorm
     from ray_lightning_tpu.models.mixed import empty_caches, mixed_logits, mixed_rows, write_prefill_rows
 
     slots, rows, bucket, fold = 3, 32, 8, 2
@@ -139,3 +141,121 @@ def mixed_program_hashes(cfg: Any) -> dict:
             params, k_cache, v_cache, jax.ShapeDtypeStruct((1, bucket), jnp.int32), scalar, scalar),
     }
     return {k: hashlib.sha256(str(v).encode()).hexdigest() for k, v in texts.items()}
+
+
+#: The uniform configurations whose programs ``uniform_program_hashes`` runs:
+#: ``name -> (GPTConfig fields, the tree is an engine's)``. GPT-2's parts
+#: (learned positions, LayerNorm, the fused QKV, GELU, the tied head),
+#: Mistral's (rotary, RMSNorm, grouped KV heads, SwiGLU, an untied head) on
+#: the stored tree and on ``engine_weights``' tree (whose fold and verify
+#: read a cache of rows, as the single-device engine keeps it), the same
+#: under a window with sinks, and a uniform layer of routed experts.
+_MISTRAL = dict(
+    n_head=4, n_kv_head=2, pos_embed="rope", norm_impl="rmsnorm", mlp_variant="swiglu",
+    tie_word_embeddings=False, compute_dtype="bfloat16", attn_impl="flash",
+)
+UNIFORM_CASES = {
+    "gpt2": (dict(n_head=4, attn_impl="reference"), False),
+    "mistral": (_MISTRAL, False),
+    "mistral_engine": (_MISTRAL, True),
+    "mistral_window": (dict(_MISTRAL, attn_window=4, attn_sinks=1), False),
+    "mistral_window_engine": (dict(_MISTRAL, attn_window=4, attn_sinks=1), True),
+    "moe": (dict(n_head=2, pos_embed="rope", norm_impl="rmsnorm", mlp_variant="swiglu", n_experts=4,
+                 moe_top_k=2, attn_impl="reference"), False),
+}
+UNIFORM_MODES = ("forward_grad", "prefill", "prefill_chunk", "decode_fold", "decode_verify", "decode_step_paged")
+#: Every (case, mode) that runs somewhere: training never takes an engine's tree.
+UNIFORM_PROGRAMS = [
+    (case, mode) for case, (_, engine) in UNIFORM_CASES.items() for mode in UNIFORM_MODES
+    if not (engine and mode == "forward_grad")
+]
+
+
+@functools.lru_cache(maxsize=None)
+def _uniform_inputs(case: str) -> tuple:
+    """``(cfg, params, tokens (3, 17), caches)`` of a case, from fixed keys;
+    ``caches`` by name, a K and a V each."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_lightning_tpu.models import gpt as G
+
+    fields, engine = UNIFORM_CASES[case]
+    cfg = G.GPTConfig(vocab_size=61, n_layer=2, d_model=32, d_ff=48, max_seq=16, **fields)
+    L, B, S, Hkv, hd = cfg.n_layer, 3, cfg.max_seq, cfg.kv_head, cfg.head_dim
+    lead = {"slot": ((L, 1, S), False), "slots": ((L, B, S), engine), "pages": ((L, 1 + B * 4, S // 4), False)}
+
+    # one draw from the one key, cut up on the host (a draw a leaf is a compile a leaf): weights
+    # and biases 0.05 wide, gains about one, the caches whatever a last tenant left
+    shapes = jax.eval_shape(lambda: G.init_gpt_params(jax.random.PRNGKey(0), cfg))
+    noise = np.asarray(jax.random.normal(jax.random.PRNGKey(49), (1 << 17,), jnp.float32))
+    taken = [0]
+
+    def take(shape, scale=1.0, base=0.0):
+        n = int(np.prod(shape))
+        taken[0] += n
+        assert taken[0] <= noise.size
+        return jnp.asarray(base + scale * noise[taken[0] - n:taken[0]].reshape(shape))
+
+    paths, tree = jax.tree_util.tree_flatten_with_path(shapes)
+    params = jax.tree_util.tree_unflatten(tree, [
+        take(a.shape, 0.1, 1.0) if jax.tree_util.keystr(path).endswith("_g']") else take(a.shape, 0.05)
+        for path, a in paths
+    ])
+    caches = {
+        name: tuple(
+            take(shape + ((Hkv * hd,) if rows else (Hkv, hd))).astype(jnp.dtype(cfg.compute_dtype)) for _ in range(2))
+        for name, (shape, rows) in lead.items()
+    }
+    toks = jax.random.randint(jax.random.PRNGKey(50), (B, S + 1), 0, cfg.vocab_size, jnp.int32)
+    return cfg, G.engine_weights(params, cfg) if engine else params, toks, caches
+
+
+def uniform_program_hashes(case: str, mode: str) -> str:
+    """sha256 of the bytes that one mode of ``models/gpt.py`` puts out for a
+    uniform configuration of :data:`UNIFORM_CASES` at toy sizes (two layers
+    of width 32, three slots of 16 rows), every input drawn from one fixed
+    key: the loss and every gradient of ``gpt_forward``'s training loss;
+    hidden states or logits and both caches of ``gpt_prefill``,
+    ``gpt_prefill_chunk``, ``gpt_decode_fold`` (with its tokens and slot
+    state), ``gpt_decode_verify`` and ``gpt_decode_step_paged``. Each leaf's
+    shape and dtype go into the hash before its bytes. Two trees that give
+    one hash compute, on this backend, the same numbers to the bit."""
+    import hashlib
+
+    import jax
+    import jax.numpy as jnp
+
+    from ray_lightning_tpu.models import gpt as G
+
+    cfg, params, toks, caches = _uniform_inputs(case)
+    B = toks.shape[0]
+    pos, cur = jnp.asarray([3, 9, 12], jnp.int32), toks[:, 0]
+    if mode == "forward_grad":
+        module = G.GPTLM(config=cfg)
+        out = jax.jit(jax.value_and_grad(lambda p: module.training_step(p, (toks,), None)[0]))(params)
+    elif mode == "prefill":
+        out = jax.jit(lambda p: G.gpt_prefill(p, cfg, toks[:2, :8]))(params)
+    elif mode == "prefill_chunk":
+        out = jax.jit(lambda p, k, v: G.gpt_prefill_chunk(p, cfg, toks[:1, :8], k, v, jnp.int32(5), jnp.int32(6)))(
+            params, *caches["slot"])
+    elif mode == "decode_fold":
+        keys = jax.random.split(jax.random.PRNGKey(50), B)
+        out = jax.jit(lambda p, k, v: G.gpt_decode_fold(
+            p, cfg, cur, pos, keys, jnp.asarray([0.0, 0.8, 0.0]), jnp.asarray([0, 5, 0], jnp.int32),
+            jnp.asarray([1.0, 0.9, 1.0]), jnp.asarray([True, True, False]), jnp.asarray([9, 2, 0], jnp.int32),
+            jnp.full((B,), -1, jnp.int32), k, v, fold=3))(params, *caches["slots"])
+    elif mode == "decode_verify":
+        out = jax.jit(lambda p, k, v: G.gpt_decode_verify(p, cfg, toks[:, :4], pos, k, v))(params, *caches["slots"])
+    elif mode == "decode_step_paged":
+        table = 1 + jnp.arange(B * 4, dtype=jnp.int32).reshape(B, 4)
+        out = jax.jit(lambda p, k, v: G.gpt_decode_step_paged(p, cfg, cur, pos, k, v, table, 4))(
+            params, *caches["pages"])
+    else:
+        raise ValueError(f"unknown mode {mode!r}: one of {UNIFORM_MODES}")
+    digest = hashlib.sha256()
+    for leaf in jax.tree_util.tree_leaves(out):
+        a = np.asarray(leaf)
+        digest.update(f"{a.shape}{a.dtype}".encode())
+        digest.update(a.tobytes())
+    return digest.hexdigest()
